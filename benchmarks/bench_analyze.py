@@ -14,8 +14,8 @@ reports:
   the log it just produced (events parsed per second: the analyzer must
   stay cheap enough to run in a post-job hook).
 
-On a TPU host the same script prices the real step distribution;
-``capture_tpu_proofs.sh`` has the rung.
+On a TPU host the same script prices the real step distribution
+(on chip: not measured).
 
 Usage: python benchmarks/bench_analyze.py [--steps-per-epoch N]
            [--epochs N] [--keep-dir]

@@ -22,8 +22,8 @@ measurement, ``_BLOCKWISE_AUTO_LEN`` is only the unmeasured fallback.
 
 On a non-TPU host the mesh is 8 simulated CPU devices and the blockwise
 kernel runs in interpret mode (the only way the kernel code runs here);
-on the TPU host the same ladder prices real Mosaic
-(``capture_tpu_proofs.sh`` rung).
+on the TPU host the same ladder prices real Mosaic (on chip: not
+measured).
 
 Usage: python benchmarks/bench_attention.py [--seqs 256,512] [--json]
        TPUFRAME_KERNEL_LEDGER_DIR=... python benchmarks/bench_attention.py  # persist

@@ -13,8 +13,7 @@ step semantics:
   (``make_compressed_pmean``: ``comms/allreduce`` spans,
   ``comms/allreduce_s`` histogram) per mode, p50 over ``--iters`` calls.
   On CPU the quantize/dequantize arithmetic *costs* wall (no DCN to
-  win back) — the honest number is the TPU one; ``capture_tpu_proofs.sh``
-  has the rung.
+  win back) — the honest number is the TPU one (on chip: not measured).
 - **step time** — a short matched A/B fit of the SAME model/batches
   through ``make_train_step`` exact vs compressed (EF on), committed as
   ``step_time_compressed`` (deliberately NOT a top-level ``step_time``
@@ -957,9 +956,10 @@ def main() -> int:
     import jax.numpy as jnp
     import numpy as np
     import optax
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from tpuframe.core.runtime import MeshSpec, shard_map
+    from tpuframe.core.runtime import MeshSpec
     from tpuframe.parallel import ParallelPlan
     from tpuframe.parallel.compression import (
         CommsConfig,

@@ -22,7 +22,7 @@ The committed record carries a ``time_to_first_step`` block, so
 regression-gates compile/startup time exactly like step time (exit 3).
 
 CPU-friendly by design; on a TPU host the same script prices the real
-XLA compile (``capture_tpu_proofs.sh`` has the rung).
+XLA compile (on chip: not measured).
 
 Usage: python benchmarks/bench_compile.py [--steps N] [--batch N]
            [--item-cost-ms F] [--image-size N]

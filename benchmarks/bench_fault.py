@@ -20,7 +20,7 @@ Two windows, one JSON line:
 
 CPU-friendly by design (tiny MnistNet on synthetic data) so the chaos
 path runs in CI; on a TPU host the same script measures the real
-restore + recompile cost (``capture_tpu_proofs.sh`` has the rung).
+restore + recompile cost (on chip: not measured).
 
 Usage: python benchmarks/bench_fault.py [--steps-per-epoch N] [--epochs N]
            [--snapshot-every N] [--kill-seed N]

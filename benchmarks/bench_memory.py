@@ -21,8 +21,7 @@ regression-gates the footprint as ``ratio_peak_hbm`` exactly like step
 time (exit 3): a plan whose peak ballooned fails CI even at flat speed.
 
 CPU-friendly by design (``memory_analysis`` works on the CPU backend;
-``memory_stats`` doesn't); ``capture_tpu_proofs.sh`` has the rung that
-re-stamps it on a real chip.
+``memory_stats`` doesn't); on chip: not measured.
 
 Usage: python benchmarks/bench_memory.py [--dim N] [--hidden N]
            [--batch N] [--steps N]

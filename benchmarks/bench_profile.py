@@ -24,7 +24,7 @@ Four numbers, one instrumented CPU/TPU fit:
 On a TPU host the same script prices the real XLA capture (CPU captures
 are dominated by host TraceMe serialization — megabytes per window for
 a toy fit — which is why capture cost is reported per window, not
-buried in a total); ``capture_tpu_proofs.sh`` has the rung.
+buried in a total); on chip: not measured.
 
 Usage: python benchmarks/bench_profile.py [--steps-per-epoch N]
            [--epochs N] [--reps N] [--keep-dir]

@@ -92,12 +92,15 @@ def train_cifar(cfg: dict):
     predict = make_predict_fn(policy)
     img, label = train_ds[0]
     pred = int(np.argmax(np.asarray(predict(state, np.asarray(img)[None]))))
-    return {**summary, "demo_label": label, "demo_pred": pred}, elapsed
+    # name where it ran: a worker that fell back to another backend must
+    # not read as a run on the chip
+    where = {"platform": rt.platform, "devices": rt.device_count}
+    return {**summary, "demo_label": label, "demo_pred": pred, **where}, elapsed
 
 
 def main(argv=None):
     p = base_parser(__doc__)
-    p.add_argument("--num-processes", type=int, default=2)
+    p.add_argument("--num-processes", type=int, default=1)
     args = p.parse_args(argv)
     cfg = {
         "epochs": 1,

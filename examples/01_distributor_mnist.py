@@ -105,7 +105,7 @@ def train_mnist(cfg: dict) -> str:
 
 def main(argv=None):
     p = base_parser(__doc__)
-    p.add_argument("--num-processes", type=int, default=2)
+    p.add_argument("--num-processes", type=int, default=1)
     args = p.parse_args(argv)
     cfg = {
         "epochs": args.epochs,

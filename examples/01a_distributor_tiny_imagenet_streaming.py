@@ -217,7 +217,7 @@ def train_tiny_imagenet(cfg: dict):
 def main(argv=None):
     p = base_parser(__doc__)
     p.set_defaults(image_size=64, num_classes=200, train_samples=256, eval_samples=64)
-    p.add_argument("--num-processes", type=int, default=2)
+    p.add_argument("--num-processes", type=int, default=1)
     p.add_argument("--patience", type=int, default=3)
     args = p.parse_args(argv)
 

@@ -16,9 +16,10 @@ import numpy as np
 import optax
 import pytest
 from flax import linen as nn
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from tpuframe.core.runtime import MeshSpec, shard_map
+from tpuframe.core.runtime import MeshSpec
 from tpuframe.parallel import ParallelPlan
 from tpuframe.parallel.compression import (
     CommsConfig,

@@ -8,6 +8,8 @@ from __future__ import annotations
 
 import json
 import os
+import subprocess
+import sys
 import time
 
 import numpy as np
@@ -58,24 +60,70 @@ def _delta(a, b):
 
 
 class TestCacheDir:
-    def test_explicit_dir_wins(self, monkeypatch, tmp_path):
+    def test_knob_path_places_the_cache(self, monkeypatch, tmp_path):
         monkeypatch.setenv("TPUFRAME_COMPILE_CACHE", str(tmp_path / "x"))
         assert cc.cache_dir_from_env() == str(tmp_path / "x")
 
     @pytest.mark.parametrize("v", ["0", "off", "false", "no", "disabled"])
-    def test_falsy_disables(self, monkeypatch, v):
+    def test_falsy_disables(self, monkeypatch, tmp_path, v):
         monkeypatch.setenv("TPUFRAME_COMPILE_CACHE", v)
         assert cc.cache_dir_from_env() is None
         assert cc.enable() is None
+        # ... even a cache placed from outside
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert cc.cache_dir_from_env() is None
+        assert cc.enable(str(tmp_path / "other")) is None
 
-    def test_default_is_host_shared_scratch(self, monkeypatch, tmp_path):
-        """No per-rank subdir: every rank on a host shares one cache —
-        a new rank on the host must hit the warm entries."""
+    def test_default_is_one_fixed_path_inside_the_checkout(self, monkeypatch):
+        """Not the temp dir, not a per-rank scratch, nothing made from a
+        pid or the time: the path is part of the cache's key, so every
+        process of a checkout must resolve the same one."""
         monkeypatch.delenv("TPUFRAME_COMPILE_CACHE", raising=False)
-        monkeypatch.setenv("TPUFRAME_LOCAL_SCRATCH", str(tmp_path))
-        d = cc.cache_dir_from_env()
-        assert d == str(tmp_path / "compile_cache")
-        assert "host" not in os.path.basename(d)
+        monkeypatch.setenv("TPUFRAME_LOCAL_SCRATCH", "/somewhere/else")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert cc.cache_dir_from_env() == os.path.join(repo, ".cache", "xla")
+        env = {k: v for k, v in os.environ.items()
+               if k not in ("TPUFRAME_COMPILE_CACHE", "JAX_COMPILATION_CACHE_DIR")}
+        src = ("from tpuframe.compile.cache import cache_dir_from_env; "
+               "print(cache_dir_from_env())")
+        seen = {
+            subprocess.run(
+                [sys.executable, "-c", src], cwd=cwd, capture_output=True,
+                text=True, check=True,
+                env={**env, "RANK": rank, "PYTHONPATH": repo},
+            ).stdout.strip()
+            for rank, cwd in (("0", repo), ("1", os.path.join(repo, "tests")))
+        }
+        assert seen == {os.path.join(repo, ".cache", "xla")}
+
+    def test_cache_placed_from_outside_is_the_only_cache(
+            self, monkeypatch, tmp_path):
+        """JAX_COMPILATION_CACHE_DIR set => that directory, whatever the
+        knob or an explicit argument says, and still so after
+        core.initialize()."""
+        import jax
+
+        from tpuframe.core import runtime as rt
+
+        placed = str(tmp_path / "placed")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+        monkeypatch.setenv("TPUFRAME_COMPILE_CACHE", str(tmp_path / "knob"))
+        prev = cc.enabled_dir()
+        try:
+            assert cc.cache_dir_from_env() == placed
+            assert cc.enable(str(tmp_path / "argument")) == placed
+            assert jax.config.jax_compilation_cache_dir == placed
+            rt.reset_runtime()
+            rt.initialize()
+            assert cc.enabled_dir() == placed
+            assert jax.config.jax_compilation_cache_dir == placed
+            assert not os.path.exists(tmp_path / "knob")
+            assert not os.path.exists(tmp_path / "argument")
+        finally:
+            rt.reset_runtime()
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+            monkeypatch.delenv("TPUFRAME_COMPILE_CACHE")
+            cc.enable(prev) if prev is not None else cc.disable()
 
 
 # -- keep-K / size-cap eviction ----------------------------------------------
@@ -153,6 +201,19 @@ class TestPersistentCache:
         d = _delta(before, _counters())
         assert d["cache_hits"] >= 1
         assert d["backend_compiles"] == 0  # retrieval, not a compile
+
+    def test_last_compile_verdict_tells_a_retrieval_from_a_compile(
+            self, cache_env):
+        """What the precompile report's ``persistent_cache`` field reads:
+        per-thread, cleared by reading."""
+        cc.last_compile_verdict()
+        assert cc.last_compile_verdict() is None
+        x = np.ones((8, 8), np.float32)
+        jax.jit(lambda x: x * 3 - 7)(x).block_until_ready()
+        assert cc.last_compile_verdict() == "miss"
+        assert cc.last_compile_verdict() is None  # reading clears it
+        jax.jit(lambda x: x * 3 - 7)(x).block_until_ready()  # fresh fn: re-trace
+        assert cc.last_compile_verdict() == "hit"
 
     def test_real_compile_emits_loud_event(self, cache_env, tmp_path):
         tele = Telemetry(str(tmp_path / "ev.jsonl"))
@@ -335,6 +396,16 @@ class TestTrainerPrecompile:
         rep = tr.precompile()
         assert rep is tr.precompile()  # second call: same report, no redo
         assert tr._shape_guard.armed
+
+    def test_report_says_whether_the_train_step_was_retrieved(self, cache_env):
+        """Cold: the train step's AOT compile is a real backend compile
+        (``miss``); the same program again, into the same cache: ``hit``."""
+        first, _ = self._fit(True)
+        second, _ = self._fit(True)
+        for tr, want in ((first, "miss"), (second, "hit")):
+            got = {s["kind"]: s["persistent_cache"]
+                   for s in tr._precompile_report["steps"]}
+            assert got == {"train": want, "eval": want}
 
     def test_opt_out_env(self, monkeypatch):
         monkeypatch.setenv("TPUFRAME_PRECOMPILE", "0")

@@ -8,13 +8,13 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from tpuframe.core.runtime import MeshSpec
 from tpuframe.parallel import ParallelPlan
 from tpuframe.parallel.compression import quantized_pmean
 from tpuframe.train import create_train_state, make_train_step
-from tpuframe.core.runtime import shard_map
 
 
 def _mesh(n=8):

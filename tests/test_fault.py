@@ -621,7 +621,7 @@ def test_chaos_killworker_through_distributor_recovers(tmp_path):
     from tpuframe.launch import Distributor, run_with_restarts
 
     flag = str(tmp_path / "killed_once")
-    d = Distributor(num_processes=2, timeout_s=300.0)
+    d = Distributor(num_processes=2, simulate_devices=1, timeout_s=300.0)
     out = run_with_restarts(
         lambda: d.run(_chaos_killed_worker, flag), max_restarts=1,
         backoff_s=0.0,

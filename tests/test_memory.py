@@ -389,6 +389,16 @@ class TestExecutableRecords:
         recs = tmem.executable_records()
         assert recs["train/step"]["peak_mb"] == pytest.approx(130.0)
 
+    def test_records_follow_a_cache_placed_from_outside(self, tmp_path,
+                                                        monkeypatch):
+        """JAX_COMPILATION_CACHE_DIR places the cache, so it places the
+        executable records next to it — over the knob."""
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "placed"))
+        monkeypatch.setenv("TPUFRAME_COMPILE_CACHE", str(tmp_path / "knob"))
+        tmem.record_executable_memory(_FakeCompiled(), "train/step")
+        assert os.listdir(tmp_path / "placed" / "memory")
+        assert not os.path.exists(tmp_path / "knob")
+
     def test_cache_hit_restart_keeps_the_real_compile_record(
             self, tmp_path, monkeypatch):
         """A persistent-cache HIT deserializes the executable without

@@ -8,9 +8,12 @@ jax ships a persistent compilation cache that turns a repeat backend
 compile into a file read; nothing in tpuframe wired it.  This module is
 that wiring, shaped like the rest of the observability stack:
 
-- :func:`enable` points jax's compilation cache at a directory (default:
-  a host-shared location under the local scratch, so a supervised
-  restart or a *new rank on the same host* hits warm cache), drops the
+- :func:`enable` points jax's compilation cache at a directory — where
+  ``JAX_COMPILATION_CACHE_DIR`` is set, that directory and no other;
+  unset, one fixed path inside the checkout (:data:`DEFAULT_CACHE_DIR`),
+  so a supervised restart, a new rank on the same host and the next
+  run of the same checkout all hit warm cache (the path is part of the
+  cache's contract: a directory that moves never hits) — drops the
   min-compile-time floor so small steps cache too, and installs
   monitoring listeners that surface every compile in tpuframe telemetry.
 - :func:`trim` is the size-capped keep-K eviction, mirroring the
@@ -30,8 +33,12 @@ Env knobs (``COMPILE_ENV_VARS`` — shipped to every remote worker by
 ``launch.remote`` and printed by the doctor, exactly like
 ``telemetry.OBSERVABILITY_ENV_VARS``)::
 
-    TPUFRAME_COMPILE_CACHE         cache dir; 0/off/false disables; unset
-                                   = <local scratch>/compile_cache
+    JAX_COMPILATION_CACHE_DIR      jax's own knob: when set it IS the cache
+                                   dir (placed from outside; nothing here
+                                   overrides it)
+    TPUFRAME_COMPILE_CACHE         0/off/false disables; a path places the
+                                   cache when the jax knob is unset; unset
+                                   = <checkout>/.cache/xla
     TPUFRAME_COMPILE_CACHE_MAX_MB  trim() size cap (default 1024; junk =
                                    unbounded, lenient like telemetry)
     TPUFRAME_COMPILE_CACHE_KEEP    newest entries never evicted (default 16)
@@ -52,7 +59,6 @@ from __future__ import annotations
 import contextlib
 import logging
 import os
-import tempfile
 import threading
 from typing import Any, Iterator
 
@@ -70,6 +76,7 @@ __all__ = [
     "enable_from_env",
     "enabled_dir",
     "install_listeners",
+    "last_compile_verdict",
     "trim",
 ]
 
@@ -101,6 +108,15 @@ COMPILE_ENV_DOMAINS = {
 
 _FALSY = ("0", "false", "no", "off", "disabled")
 
+#: where the cache lives when nothing places it from outside: ONE fixed
+#: path inside the checkout (gitignored).  Not the temp dir, not a
+#: per-rank scratch, nothing made from a pid or the time — the path is
+#: part of the cache's key, so a directory that moves never hits.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".cache", "xla",
+)
+
 #: process-wide state: the enabled cache dir (None = not enabled here)
 _STATE: dict[str, Any] = {"dir": None, "listeners": False}
 
@@ -119,22 +135,21 @@ def _env_float(name: str, default: float) -> float:
 
 
 def cache_dir_from_env() -> str | None:
-    """Resolve the cache directory from ``TPUFRAME_COMPILE_CACHE``.
+    """Resolve the cache directory from the environment.
 
-    Unset -> a host-shared default under the local scratch (the same
-    root ``Workspace.local_scratch`` uses, WITHOUT the per-rank subdir:
-    every rank on a host shares one cache, which is the point).  An
-    explicitly falsy value disables the cache entirely.
+    A falsy ``TPUFRAME_COMPILE_CACHE`` disables the cache entirely.
+    Otherwise ``JAX_COMPILATION_CACHE_DIR`` — jax's own knob, how a
+    driver places the cache from outside — wins when set; then a path
+    in ``TPUFRAME_COMPILE_CACHE``; else :data:`DEFAULT_CACHE_DIR`.
     """
     v = os.environ.get("TPUFRAME_COMPILE_CACHE", "").strip()
     if v and v.lower() in _FALSY:
         return None
-    if v:
-        return v
-    base = os.environ.get("TPUFRAME_LOCAL_SCRATCH") or os.path.join(
-        tempfile.gettempdir(), "tpuframe_scratch"
+    return (
+        os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+        or v
+        or DEFAULT_CACHE_DIR
     )
-    return os.path.join(base, "compile_cache")
 
 
 def enabled_dir() -> str | None:
@@ -147,12 +162,16 @@ def enable(cache_dir: str | None = None, *,
     """Point jax's persistent compilation cache at ``cache_dir``.
 
     Idempotent; returns the enabled directory (or None when disabled by
-    env / jax too old / dir uncreatable — a broken cache must degrade to
-    today's cold-compile behavior, never take training down).  Also
-    installs the telemetry listeners and runs a :func:`trim` pass so a
-    long-lived host cache stays inside its size cap.
+    env / dir uncreatable — a broken cache must degrade to cold-compile
+    behavior, never take training down).  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set the cache was placed from
+    outside: that directory is used and ``cache_dir`` is ignored, so
+    jax's config is never pointed anywhere else.  Also installs the
+    telemetry listeners and runs a :func:`trim` pass so a long-lived
+    host cache stays inside its size cap.
     """
-    cache_dir = cache_dir or cache_dir_from_env()
+    if not cache_dir or os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip():
+        cache_dir = cache_dir_from_env()
     if cache_dir is None:
         return None
     if min_compile_s is None:
@@ -179,7 +198,7 @@ def enable(cache_dir: str | None = None, *,
         from jax._src import compilation_cache as _cc
 
         _cc.reset_cache()
-    except Exception as e:  # old jax / readonly dir / exotic backend
+    except Exception as e:  # readonly dir / exotic backend
         logger.warning("compile cache disabled: %s", e)
         try:
             import jax
@@ -241,6 +260,17 @@ def compile_label(label: str, *, span: bool = False) -> Iterator[None]:
         _TLS.in_span = prev_span
 
 
+def last_compile_verdict() -> str | None:
+    """How this thread's most recent compile request was served —
+    ``"hit"`` (persistent-cache retrieval, no backend compile),
+    ``"miss"`` (real compile, written to the cache) or ``"uncached"``
+    (real compile that never consulted the cache); None when nothing
+    compiled since the last read.  Reading clears it."""
+    verdict = getattr(_TLS, "last_compile", None)
+    _TLS.last_compile = None
+    return verdict
+
+
 def _on_event(name: str, **kw: Any) -> None:
     # verdict protocol: each compile request that consults the
     # persistent cache records hit/miss on this thread; the
@@ -265,6 +295,7 @@ def _on_duration(name: str, dur: float, **kw: Any) -> None:
             tele.registry.histogram("compile/backend_compile_s").observe(dur)
             verdict = getattr(_TLS, "verdict", None)
             _TLS.verdict = None
+            _TLS.last_compile = verdict or "uncached"
             # a persistent-cache hit is a retrieval, not a compile; a
             # miss — or a compile that never consulted the cache — is
             # the real thing, counted and (unless an explicit compile

@@ -33,7 +33,7 @@ LAYOUT_VERSION = 1
 #: worker — the fifth spine knob list, aggregated by
 #: ``launch.remote.all_env_vars()`` next to OBSERVABILITY/COMPILE/HEALTH/
 #: SERVE.  Declared here (stdlib-only module) so the aggregate resolves
-#: on a wedged-backend doctor run; documented in PERF.md.  A knob read
+#: on a wedged-backend doctor run; documented in OBSERVABILITY.md.  A knob read
 #: anywhere in tpuframe that appears in no ``*_ENV_VARS`` list is a
 #: ``tpuframe.lint`` finding (KN001) — that is what keeps this list and
 #: its consumers honest.

@@ -24,9 +24,8 @@ The env contract (``RANK``/``WORLD_SIZE``/``MASTER_ADDR``/…) arrives in
 the header and is applied to ``os.environ`` *before* the payload is
 unpickled; the header's ``PYTHONPATH`` additionally lands on ``sys.path``
 so by-reference functions resolve.  Vars that must exist before
-interpreter start (e.g. an image sitecustomize that pins a TPU plugin off
-an env trigger) belong in the *transport command* (the ``connect`` hook),
-not the header — by header time the interpreter is already up.
+interpreter start belong in the *transport command* (the ``connect``
+hook), not the header — by header time the interpreter is already up.
 """
 
 from __future__ import annotations
@@ -123,8 +122,7 @@ def main() -> None:
 
     if env.get("TPUFRAME_SIMULATE_DEVICES"):
         # virtual CPU mesh for pod-topology tests; must beat any real
-        # backend init AND undo an image sitecustomize's platform pin,
-        # which simulate_cpu_devices handles (env + live jax config)
+        # backend init (simulate_cpu_devices sets env + live jax config)
         from tpuframe.core.runtime import simulate_cpu_devices
 
         simulate_cpu_devices(int(env["TPUFRAME_SIMULATE_DEVICES"]))

@@ -337,8 +337,7 @@ class RemoteDistributor:
             env["TPUFRAME_CP_TOKEN"] = token
         if self.simulate_devices:
             # the agent resolves this into a virtual CPU platform before
-            # the payload runs (env + live jax config, beating any image
-            # sitecustomize platform pin)
+            # the payload runs (env + live jax config)
             env["TPUFRAME_SIMULATE_DEVICES"] = str(self.simulate_devices)
         if hb_port:
             env["TPUFRAME_HB_PORT"] = str(hb_port)

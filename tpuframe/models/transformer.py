@@ -23,6 +23,7 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from tpuframe.core.runtime import (
@@ -35,7 +36,6 @@ from tpuframe.core.runtime import (
 from tpuframe.ops.ring_attention import attention_reference, ring_attention_local
 from tpuframe.ops.layer_norm import FusedLayerNorm
 from tpuframe.ops.ulysses import ulysses_attention_local
-from tpuframe.core.runtime import shard_map
 
 #: attn_impl="auto" switches full -> blockwise at this unsharded sequence
 #: length: 4k tokens is a 64 MB f32 score matrix PER (batch, head) — the

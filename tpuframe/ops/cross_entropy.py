@@ -19,12 +19,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.sharding import PartitionSpec as P
 
 from tpuframe.ops.dispatch import batch_sharding_info, pad_to, resolve_interpret
 from tpuframe.ops.ledger import ce_rows, shape_class
-from tpuframe.core.runtime import shard_map
 
 # rows per grid step: domain-clamped knob (TPUFRAME_KERNEL_CE_ROWS,
 # default 16, sublane-aligned) the kernel ledger probes per shape class
@@ -89,6 +89,7 @@ def _fwd_pallas(logits, labels, interpret):
         in_specs=[_row_spec(rows, kp), _row_spec(rows, 1)],
         out_specs=_row_spec(rows, 1),
         interpret=interpret,
+        name="tpuframe_ce_fwd",
     )(logits_p, labels_p)
     return loss[:b, 0]
 
@@ -104,6 +105,7 @@ def _bwd_pallas(logits, labels, g, interpret):
         in_specs=[_row_spec(rows, kp), _row_spec(rows, 1), _row_spec(rows, 1)],
         out_specs=_row_spec(rows, kp),
         interpret=interpret,
+        name="tpuframe_ce_bwd",
     )(logits_p, labels_p, g_p)
     return grad[:b, :k]
 

@@ -165,12 +165,8 @@ def inside_shard_map() -> bool:
     compose with shard_map-based steps like
     ``make_train_step(grad_compression=...)``.
     """
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        manual = jax.sharding.AxisType.Manual
-        return manual in (getattr(am, "axis_types", ()) or ())
-    except AttributeError:  # much older jax: no abstract-mesh API
-        return False
+    am = jax.sharding.get_abstract_mesh()
+    return jax.sharding.AxisType.Manual in am.axis_types
 
 
 def effective_mesh(mesh):

@@ -21,12 +21,12 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import optax
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from tpuframe.ops.dispatch import pad_to, resolve_interpret
-from tpuframe.core.runtime import shard_map
 
 _LANES = 128
 _TILE_ROWS = 256
@@ -81,6 +81,7 @@ def _pallas_update(step2, fp, fg, fm, fv, hp, interpret):
         in_specs=[scalar_spec, spec, spec, spec, spec],
         out_specs=(spec, spec, spec),
         interpret=interpret,
+        name="tpuframe_fused_adamw",
     )(step2, fp, fg, fm, fv)
 
 

@@ -21,6 +21,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.sharding import PartitionSpec as P
 
@@ -31,7 +32,6 @@ from tpuframe.core.runtime import (
     current_runtime,
 )
 from tpuframe.ops.dispatch import batch_sharding_info, pad_to, resolve_interpret
-from tpuframe.core.runtime import shard_map
 
 _ROWS = 16
 _LANES = 128
@@ -115,6 +115,7 @@ def _fwd_pallas(x, scale, bias, eps, interpret):
         ],
         out_specs=pl.BlockSpec((_ROWS, dp), lambda i: (i, 0)),
         interpret=interpret,
+        name="tpuframe_layer_norm_fwd",
     )(xp, sp, bp)
     return y[:n, :d]
 
@@ -143,6 +144,7 @@ def _bwd_pallas(x, scale, g, eps, interpret):
             pl.BlockSpec((_ROWS, dp), lambda i: (0, 0)),
         ),
         interpret=interpret,
+        name="tpuframe_layer_norm_bwd",
     )(xp, sp, gp)
     dscale = jnp.sum(dscale_p, 0)[:d].astype(scale.dtype)
     dbias = jnp.sum(dbias_p, 0)[:d].astype(scale.dtype)

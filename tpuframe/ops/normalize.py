@@ -18,12 +18,12 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.sharding import PartitionSpec as P
 
 from tpuframe.ops.dispatch import batch_sharding_info, resolve_interpret
 from tpuframe.ops.ledger import norm_tile_rows, shape_class
-from tpuframe.core.runtime import shard_map
 
 _LANES = 128
 # row-tile height: domain-clamped knob (TPUFRAME_KERNEL_NORM_TILE_ROWS,
@@ -93,6 +93,7 @@ def _pallas_normalize(flat, weights, biases, n_channels, out_dtype, interpret):
         in_specs=[pl.BlockSpec((tile, _LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((tile, _LANES), lambda i: (i, 0)),
         interpret=interpret,
+        name="tpuframe_normalize",
     )(flat.reshape(rows, _LANES))
     return out.reshape(padded)[:n]
 

@@ -159,6 +159,7 @@ def _pallas_bucket_abs_max(v, interpret: bool):
         in_specs=[pl.BlockSpec((_TILE_ROWS, block), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((_TILE_ROWS, _LANES), lambda i, j: (i, 0)),
         interpret=interpret,
+        name="tpuframe_quant_amax",
     )(_pad2(v, rows, cols))
     return out[:nb, :1]
 
@@ -187,6 +188,7 @@ def _pallas_encode(v, amax, mode: str, noise, interpret: bool):
         in_specs=in_specs,
         out_specs=vspec,
         interpret=interpret,
+        name="tpuframe_quant_encode",
     )(*operands)
     return q[:nb, :be]
 
@@ -210,6 +212,7 @@ def _pallas_decode(total, amax, mode: str, world: int, interpret: bool):
         in_specs=[vspec, aspec],
         out_specs=vspec,
         interpret=interpret,
+        name="tpuframe_quant_decode",
     )(_pad2(total, rows, cols), _amax_lanes(amax, rows))
     return out[:nb, :be]
 

@@ -27,10 +27,10 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from tpuframe.core.runtime import DATA_AXIS, FSDP_AXIS, SEQUENCE_AXIS
-from tpuframe.core.runtime import named_axis_size, shard_map
 
 
 def attention_reference(
@@ -137,7 +137,7 @@ def _causal_skip(pred, update, carry):
 
 def _ring_fwd_loop(q, k, v, axis_name, causal):
     """The rotating online-softmax sweep -> (out, lse)."""
-    axis_size = named_axis_size(axis_name)
+    axis_size = jax.lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     b, lq, h, d = q.shape
     scale = 1.0 / math.sqrt(d)
@@ -195,7 +195,7 @@ def _ring_fused_bwd(axis_name, causal, res, g):
     its home device.  dQ accumulates locally.
     """
     q, k, v, out, lse = res
-    axis_size = named_axis_size(axis_name)
+    axis_size = jax.lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     b, lq, h, d = q.shape
     scale = 1.0 / math.sqrt(d)

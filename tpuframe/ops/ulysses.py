@@ -31,11 +31,11 @@ import functools
 
 import jax
 from jax import lax
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from tpuframe.core.runtime import DATA_AXIS, FSDP_AXIS, SEQUENCE_AXIS
 from tpuframe.ops.ring_attention import attention_reference
-from tpuframe.core.runtime import named_axis_size, shard_map
 
 
 def ulysses_attention_local(
@@ -51,7 +51,7 @@ def ulysses_attention_local(
     Args are this device's sequence shards, (B, L_local, H, D); returns
     the same shard layout.  Exact — identical to full attention.
     """
-    n = named_axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return attention_reference(q, k, v, causal=causal)
     heads = q.shape[2]

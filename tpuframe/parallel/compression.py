@@ -892,9 +892,9 @@ def make_compressed_pmean(plan, config: CommsConfig | str = "int8"):
     — the benchmark/standalone face of the same primitive the
     compressed train step fuses.
     """
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from tpuframe.core.runtime import shard_map
     from tpuframe.track.telemetry import get_telemetry
 
     if not isinstance(config, CommsConfig):

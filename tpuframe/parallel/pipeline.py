@@ -39,10 +39,10 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from tpuframe.core.runtime import DATA_AXIS, FSDP_AXIS, PIPELINE_AXIS
-from tpuframe.core.runtime import shard_map
 from tpuframe.parallel.comms_env import PP_SCHEDULE_CHOICES
 
 

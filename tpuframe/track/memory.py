@@ -232,16 +232,19 @@ _EXECUTABLES: dict[str, dict] = {}
 
 def _memory_dir(cache_dir: str | None = None) -> str | None:
     if cache_dir is None:
-        from tpuframe.compile.cache import cache_dir_from_env, enabled_dir
+        from tpuframe.compile.cache import (
+            DEFAULT_CACHE_DIR,
+            cache_dir_from_env,
+            enabled_dir,
+        )
 
-        # an explicitly-set TPUFRAME_COMPILE_CACHE is authoritative (the
+        # a cache placed (or switched off) by env is authoritative (the
         # doctor reads records wherever the env points, possibly from a
         # process that never enabled the cache); otherwise records live
         # next to whatever cache this process actually enabled
-        if os.environ.get("TPUFRAME_COMPILE_CACHE", "").strip():
-            cache_dir = cache_dir_from_env()
-        else:
-            cache_dir = enabled_dir() or cache_dir_from_env()
+        cache_dir = cache_dir_from_env()
+        if cache_dir == DEFAULT_CACHE_DIR:
+            cache_dir = enabled_dir() or cache_dir
     return os.path.join(cache_dir, "memory") if cache_dir else None
 
 

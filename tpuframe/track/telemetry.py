@@ -2,12 +2,11 @@
 
 tpuframe's observability was point solutions — an XLA trace callback
 (`track/profiler.py`), epoch-total wall-clock buckets buried in
-``Trainer._run_epoch``, a background ``/proc`` sampler — while the repo's
-own benchmark history (BENCH_r01–r05) shows the dominant failure mode is
-*silent wedging*: ``jax.devices()`` and preflight compiles hanging >90 s
-with zero diagnostics.  Production pre-training frameworks (TorchTitan,
-PAPERS.md) treat metrics/profiling as a first-class subsystem; this module
-is that subsystem for tpuframe.
+``Trainer._run_epoch``, a background ``/proc`` sampler — while the
+failure mode that costs the most is *silent wedging*: ``jax.devices()``
+or a compile hanging with zero diagnostics.  Production pre-training
+frameworks (TorchTitan, PAPERS.md) treat metrics/profiling as a
+first-class subsystem; this module is that subsystem for tpuframe.
 
 Three pieces, all stdlib-only (telemetry must keep working precisely when
 jax is wedged, so this module NEVER imports jax):

@@ -22,15 +22,11 @@ from tpuframe.parallel.sharding import ParallelPlan
 
 def _any_host_resident(tree: Any) -> bool:
     """True if any leaf's (traced or concrete) aval sits in host memory."""
-    try:
-        host_space = jax.memory.Space.Host
-    except AttributeError:  # older jax: no memory-space API => never offloaded
-        return False
-    for leaf in jax.tree.leaves(tree):
-        aval = getattr(leaf, "aval", None)
-        if getattr(aval, "memory_space", None) == host_space:
-            return True
-    return False
+    host_space = jax.memory.Space.Host
+    return any(
+        getattr(getattr(leaf, "aval", None), "memory_space", None) == host_space
+        for leaf in jax.tree.leaves(tree)
+    )
 
 
 class TrainState(flax.struct.PyTreeNode):

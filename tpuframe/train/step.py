@@ -20,11 +20,11 @@ from typing import Any, Callable, Mapping
 import jax
 import jax.numpy as jnp
 import optax
+from jax import shard_map
 
 from tpuframe.parallel.precision import Policy, full_precision
 from tpuframe.parallel.sharding import ParallelPlan
 from tpuframe.train.state import TrainState
-from tpuframe.core.runtime import shard_map
 
 #: loss_fn(logits, labels) -> per-example losses, pluggable.
 LossFn = Callable[[jax.Array, jax.Array], jax.Array]

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-from tpuframe.compile.cache import compile_label
+from tpuframe.compile.cache import compile_label, last_compile_verdict
 from tpuframe.compile.precompile import (
     ShapeGuard,
     batch_signature,
@@ -871,6 +871,9 @@ class Trainer:
                     label=f"precompile/{kind}@{plan_sig}",
                 )
                 entry["wall_s"] = round(time.perf_counter() - t1, 6)
+                # hit = retrieved from the persistent cache, no backend
+                # compile ran (what a warm restart should report)
+                entry["persistent_cache"] = last_compile_verdict()
                 # arm the guard even when direct dispatch isn't possible
                 # (offload wrapper): the signature is still the contract,
                 # and the persistent cache is warm for the jit path
