@@ -1,0 +1,7 @@
+"""chipbench's own tests: CPU only, outside tier-1 (``pytest chipbench/tests``)."""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
