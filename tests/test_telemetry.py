@@ -98,6 +98,125 @@ class TestSpans:
         assert tele.registry.histogram("span/quiet").count == 1
 
 
+# -- the span log -------------------------------------------------------------
+
+
+class TestSpanLog:
+    def test_one_clock_across_threads_and_nested_parent_ids(self):
+        tele = T.configure()
+        seen = {}
+
+        def worker():
+            with tele.span("w/outer", emit=False) as o:
+                with tele.span("w/inner", emit=False) as i:
+                    time.sleep(0.002)
+            seen["w"] = (o, i)
+
+        before = time.perf_counter_ns()
+        with tele.span("m/outer") as mo:
+            th = threading.Thread(target=worker, name="span-log-worker")
+            th.start()
+            with tele.span("m/inner") as mi:
+                time.sleep(0.002)
+            th.join()
+        after = time.perf_counter_ns()
+        log = tele.span_log()
+        # oldest first (by close), every thread's, emitted or not
+        assert {r.name for r in log} == {"w/outer", "w/inner", "m/outer", "m/inner"}
+        assert log[-1] is mo
+        for r in log:
+            assert before <= r.start_ns < r.end_ns <= after
+            assert r.elapsed == pytest.approx((r.end_ns - r.start_ns) / 1e9)
+        wo, wi = seen["w"]
+        assert (mi.parent_id, wi.parent_id) == (mo.id, wo.id)
+        assert mo.parent_id is None and wo.parent_id is None
+        assert len({r.id for r in log}) == 4
+        assert {r.thread for r in (wo, wi)} == {"span-log-worker"}
+        assert mo.thread == threading.current_thread().name
+        # children lie inside their parents on the shared clock
+        for child, parent in ((mi, mo), (wi, wo)):
+            assert parent.start_ns <= child.start_ns
+            assert child.end_ns <= parent.end_ns
+        inner = tele.span_log(names=["m/inner", "w/inner"])
+        assert sorted(r.name for r in inner) == ["m/inner", "w/inner"]
+
+    def test_anchor_places_the_log_beside_the_wall_clock(self):
+        tele = T.configure()
+        with tele.span("a") as sp:
+            pass
+        wall_ns = int(tele.anchor_wall * 1e9) + (sp.end_ns - tele.anchor_perf_ns)
+        assert abs(wall_ns - time.time_ns()) < 1e9
+        env = tele.recent_events()[-1]
+        assert env["name"] == "a" and env["kind"] == "span"
+        assert env["ts"] == pytest.approx(wall_ns / 1e9, abs=1e-3)
+
+    def test_ring_drops_the_oldest(self):
+        tele = T.Telemetry(None, max_spans=4)
+        for i in range(10):
+            with tele.span("s", emit=False, i=i):
+                pass
+        assert [r.attrs["i"] for r in tele.span_log()] == [6, 7, 8, 9]
+
+    def test_raising_span_is_recorded_not_ok(self):
+        tele = T.configure()
+        with pytest.raises(KeyError):
+            with tele.span("boom", emit=False):
+                raise KeyError("k")
+        (rec,) = tele.span_log()
+        assert rec.ok is False and rec.error.startswith("KeyError")
+        assert rec.end_ns > rec.start_ns
+
+    def test_step_is_inherited_and_attrs_are_held_by_reference(self):
+        tele = T.configure()
+        with tele.span("parent", step=7):
+            with tele.span("child", emit=False) as c:
+                with tele.span("own", step=9) as o:
+                    pass
+                c.attrs["late"] = True
+        with tele.span("orphan") as orphan:
+            pass
+        assert (c.step, o.step, orphan.step) == (7, 9, None)
+        assert tele.span_log(names=["child"])[0].attrs == {"late": True}
+        events = {e["name"]: e for e in tele.recent_events()}
+        assert "child" not in events  # emit=False: in the log, not an event
+        assert events["own"]["step"] == 9 and "step" not in events["orphan"]
+
+    def test_annotation_hook_sees_every_span_with_its_step(self, monkeypatch):
+        opened = []
+
+        class Ann:
+            def __init__(self, name, step):
+                self.rec = [name, step, "open"]
+                opened.append(self.rec)
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                self.rec[2] = "closed"
+
+        monkeypatch.setattr(T, "_annotate", Ann)
+        tele = T.configure()
+        with tele.span("train/iter", emit=False, step=3):
+            with tele.span("train/step"):
+                assert opened[-1] == ["train/step", 3, "open"]
+        assert opened == [["train/iter", 3, "closed"], ["train/step", 3, "closed"]]
+
+    def test_telemetry_imports_without_jax(self):
+        import subprocess
+        import sys
+
+        code = (
+            "import sys; sys.modules['jax'] = None\n"
+            "from tpuframe.track import telemetry as T\n"
+            "t = T.Telemetry(None)\n"
+            "with t.span('a', step=1): pass\n"
+            "assert t.span_log()[0].step == 1 and T._annotate is None\n"
+        )
+        subprocess.run([sys.executable, "-c", code], check=True,
+                       cwd=os.path.dirname(os.path.dirname(__file__)))
+
+
 # -- metrics registry ---------------------------------------------------------
 
 
@@ -361,7 +480,7 @@ class TestTrainerTelemetry:
         assert epochs and epochs[0]["attrs"] == {"epoch": 0}
         # per-step distributions come free via the registry
         assert tele.registry.histogram("span/train/step").count == 3
-        assert tele.registry.counter("data/batches_prefetched").value >= 3
+        assert tele.registry.histogram("span/data/h2d").count >= 3
         # the legacy wall-clock breakdown keys survive, span-derived now
         for key in ("data_wait_s", "dispatch_s", "host_block_s", "epoch_time_s"):
             assert key in result.metrics and result.metrics[key] >= 0
@@ -373,6 +492,149 @@ class TestTrainerTelemetry:
         )
         assert inside <= result.metrics["epoch_time_s"] + 0.05
         assert result.metrics["dispatch_s"] > 0
+
+    def test_span_log_joins_every_step_to_what_fed_it(self, cpu_runtime):
+        """Three windows on the CPU: every step has exactly one iteration,
+        pull, dispatch and device copy and at least one assembly under the
+        same step id, every drain names its window, and no child outlasts
+        its ``train/iter``."""
+        from tpuframe.models import MnistNet
+        from tpuframe.train import Trainer
+        from tpuframe.train.callbacks import Callback
+
+        tele = T.configure()
+        trainer = Trainer(
+            MnistNet(num_classes=4),
+            train_dataloader=_tiny_loader(n=16 * 12),
+            max_duration="12ba",
+            num_classes=4,
+            log_interval=4,
+            eval_interval=0,
+            callbacks=[Callback()],
+        )
+        trainer.fit()
+        log = tele.span_log()
+        by = {}
+        for r in log:
+            by.setdefault(r.name, []).append(r)
+        steps = list(range(1, 13))
+        for name in ("train/iter", "train/data_wait", "train/step", "data/h2d"):
+            assert sorted(r.step for r in by[name] if r.step is not None) == steps, name
+        assert {r.step for r in by["data/assemble"]} >= set(steps)
+        assert all("fresh_alloc" in r.attrs for r in by["data/assemble"])
+        # the producer's spans are another thread's, on the same clock
+        assert {r.thread for r in by["data/h2d"]} != {r.thread for r in by["train/step"]}
+        # a step's batch was on the device before its pull returned
+        h2d = {r.step: r for r in by["data/h2d"]}
+        for w in by["train/data_wait"]:
+            if w.step is not None:
+                assert h2d[w.step].end_ns <= w.end_ns
+        # every drain names its window
+        drains = [(r.attrs["first_step"], r.step) for r in by["train/host_block"]]
+        assert drains == [(1, 4), (5, 8), (9, 12)]
+        # the iteration that found the stop feeds no step
+        assert [r.step for r in by["train/iter"]][-1] is None
+        # children: inside their iteration, and never longer than it
+        iters = {r.id: r for r in by["train/iter"]}
+        covered = dict.fromkeys(iters, 0)
+        kids = [r for r in log if r.parent_id in iters]
+        assert {"train/data_wait", "train/step", "train/callbacks",
+                "train/host_block"} <= {r.name for r in kids}
+        assert {r.attrs["hook"] for r in by["train/callbacks"]} >= {
+            "on_step_start", "on_step_end", "on_batch_end"}
+        for r in kids:
+            it = iters[r.parent_id]
+            assert it.start_ns <= r.start_ns and r.end_ns <= it.end_ns
+            assert r.step == it.step
+            covered[it.id] += r.end_ns - r.start_ns
+        for i, it in iters.items():
+            assert covered[i] <= it.end_ns - it.start_ns
+        # the health sentinel's fetch has a span of its own, with its step
+        assert [r.step for r in by["train/health_fetch"]] == [12]
+
+    def test_empty_queue_dispatch_is_seen_and_counted(self, cpu_runtime):
+        """A step dispatched after the device ran dry says so."""
+        import jax
+
+        from tpuframe.models import MnistNet
+        from tpuframe.train import Trainer
+        from tpuframe.train.callbacks import Callback
+
+        class DrainAfter(Callback):
+            def on_step_end(self, trainer):
+                if trainer.batches_seen in (2, 4):
+                    jax.block_until_ready(trainer.state)
+
+        tele = T.configure()
+        Trainer(
+            MnistNet(num_classes=4),
+            train_dataloader=_tiny_loader(n=16 * 6),
+            max_duration="6ba",
+            num_classes=4,
+            log_interval=0,
+            eval_interval=0,
+            callbacks=[DrainAfter()],
+        ).fit()
+        flags = {r.step: r.attrs.get("device_idle_at_dispatch")
+                 for r in tele.span_log(names=["train/step"])}
+        assert flags[1] is None  # no step before it to ask
+        assert flags[3] is True and flags[5] is True
+        assert all(isinstance(flags[n], bool) for n in range(2, 7))
+        assert tele.registry.counter("train/empty_queue_dispatches").value == sum(
+            1 for v in flags.values() if v)
+
+    def test_fresh_alloc_marks_exactly_the_ring_allocations(self, cpu_runtime):
+        from tpuframe.data import DevicePrefetcher
+
+        tele = T.configure()
+        loader = _tiny_loader(n=16 * 8)
+        allocs0 = tele.registry.counter("data/ring_allocs").value
+        for _ in DevicePrefetcher(loader, first_step=1):
+            pass
+        fresh = [r.attrs["fresh_alloc"] for r in tele.span_log(names=["data/assemble"])]
+        assert len(fresh) == 8
+        n_alloc = tele.registry.counter("data/ring_allocs").value - allocs0
+        assert 1 <= sum(fresh) == n_alloc < 8
+        assert fresh[0] is True and fresh[-1] is False  # the ring recycles
+        assert [r.step for r in tele.span_log(names=["data/h2d"])] == list(range(1, 9))
+
+    def test_hot_path_spans_reach_the_profiler_hook(self, cpu_runtime, monkeypatch):
+        """With the factory replaced by a recorder: one ``tpuframe/<name>``
+        annotation for each hot-path span, carrying its step."""
+        import contextlib
+
+        from tpuframe.models import MnistNet
+        from tpuframe.track import profiler
+        from tpuframe.train import Trainer
+
+        made = []
+
+        def recorder(name, **kwargs):
+            made.append((name, kwargs.get("step")))
+            return contextlib.nullcontext()
+
+        monkeypatch.setattr(profiler.jax.profiler, "TraceAnnotation", recorder)
+        tele = T.configure()
+        assert T._annotate is profiler._span_annotation
+        Trainer(
+            MnistNet(num_classes=4),
+            train_dataloader=_tiny_loader(),
+            max_duration="3ba",
+            num_classes=4,
+            eval_interval=0,
+        ).fit()
+        fed = {1, 2, 3}
+        for name in ("train/iter", "train/data_wait", "train/step",
+                     "train/host_block", "data/prefetch_fetch",
+                     "data/assemble", "data/h2d"):
+            # (the pull that finds the stop is opened for a step it never feeds)
+            want = sorted((f"tpuframe/{name}", r.step)
+                          for r in tele.span_log(names=[name]) if r.step in fed)
+            got = sorted(m for m in made
+                         if m[0] == f"tpuframe/{name}" and m[1] in fed)
+            assert got == want and got, name
+            if name != "train/host_block":  # one drain, at the end
+                assert {s for _, s in got} == fed, name
 
     def test_stalled_train_step_triggers_watchdog_report(
         self, tmp_path, cpu_runtime
@@ -450,30 +712,6 @@ class TestTrainerTelemetry:
         last = bridged[-1]
         assert last["telemetry/span/train/step_count"] == 2.0
         assert last["telemetry/span/train/step_p50"] >= 0
-
-
-# -- StepTimer ring (satellite) ----------------------------------------------
-
-
-class TestStepTimerRing:
-    def test_ring_keeps_sampling_past_max_samples(self):
-        from tpuframe.track.profiler import StepTimer
-
-        T.configure()
-        timer = StepTimer(max_samples=8)
-        for i in range(20):
-            timer.on_step_start(None)
-            timer._t0 -= 0.001 * (i + 1)  # synthesize increasing durations
-            timer.on_step_end(None)
-        s = timer.summary()
-        assert s["steps_seen"] == 20.0
-        assert s["steps_sampled"] == 8.0  # the ring, not the lifetime
-        # the window is the RECENT samples: all >= the 13th duration
-        assert min(timer.samples) >= 0.012
-        assert s["step_time_p99_s"] >= s["step_time_p50_s"]
-        # folded into the shared registry
-        reg = T.get_telemetry().registry
-        assert reg.histogram("callback/step_time_s").count == 20
 
 
 # -- doctor integration (satellite) ------------------------------------------

@@ -151,20 +151,19 @@ def test_trace_context_manager_captures(tmp_path):
 def test_profiler_callback_in_trainer(tmp_path):
     from tpuframe.data import DataLoader, SyntheticImageDataset
     from tpuframe.models import MnistNet
-    from tpuframe.track import MLflowLogger, ProfilerCallback, StepTimer
+    from tpuframe.track import MLflowLogger, ProfilerCallback
     from tpuframe.train import Trainer
 
     ds = SyntheticImageDataset(n=64, num_classes=4, image_size=28, channels=1)
     loader = DataLoader(ds, batch_size=16, process_index=0, process_count=1)
     logger = MLflowLogger("prof-exp", tracking_uri=str(tmp_path / "mlruns"))
     prof = ProfilerCallback(skip_steps=1, num_steps=2)
-    timer = StepTimer()
     trainer = Trainer(
         MnistNet(num_classes=4),
         train_dataloader=loader,
         max_duration="1ep",
         num_classes=4,
-        callbacks=[prof, timer],
+        callbacks=[prof],
         loggers=[logger],
         log_interval=2,
     )
@@ -175,9 +174,6 @@ def test_profiler_callback_in_trainer(tmp_path):
     # the trace was captured and logged as a run artifact
     assert prof.artifact is not None and prof.artifact.endswith(".zip")
     assert os.path.exists(prof.artifact)
-    s = timer.summary()
-    assert s["steps_sampled"] == 4  # 64/16 batches
-    assert s["step_time_p95_s"] >= s["step_time_p50_s"] >= 0
 
 
 @pytest.mark.slow

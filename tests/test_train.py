@@ -69,6 +69,34 @@ class TestDuration:
 
 
 class TestSteps:
+    @pytest.mark.parametrize("flavor", ["plain", "health", "grad_accum"])
+    def test_lowered_step_carries_the_device_side_names(self, flavor):
+        """``tpuframe/forward``, ``tpuframe/optimizer`` and
+        ``tpuframe/input_normalize`` are in the lowered step's op metadata
+        (the backward pass under ``transpose(jvp(tpuframe/forward))``):
+        what a trace's reduction finds the phases by after a refactor."""
+        from tpuframe.fault.health import HealthPolicy
+
+        _, state = small_state()
+
+        def transform(batch):
+            batch["image"] = batch["image"] * 2.0 - 1.0
+            return batch
+
+        shape = (32, 28, 28, 1)
+        if flavor == "grad_accum":
+            step = make_grad_accum_step(2, batch_transform=transform)
+            shape = (2, 16, 28, 28, 1)
+        else:
+            step = make_train_step(
+                batch_transform=transform,
+                health=HealthPolicy() if flavor == "health" else None)
+        batch = {"image": jnp.zeros(shape), "label": jnp.zeros(shape[:-3], jnp.int32)}
+        text = step.lower(state, batch).as_text(debug_info=True)
+        for name in ("tpuframe/forward", "transpose(jvp(tpuframe/forward))",
+                     "tpuframe/optimizer", "tpuframe/input_normalize"):
+            assert name in text, name
+
     def test_train_step_reduces_loss(self):
         _, state = small_state()
         step = make_train_step()

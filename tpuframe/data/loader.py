@@ -128,6 +128,9 @@ class BatchBufferPool:
         reg = get_telemetry().registry
         self._allocs = reg.counter("data/ring_allocs")
         self._recycled = reg.counter("data/ring_recycled")
+        #: fresh allocations of THIS pool (the counter above is shared by
+        #: every pool of the process)
+        self.allocs = 0
 
     def acquire(self, batch: int, item_shape: tuple, dtype,
                 with_valid: bool, label_shape: tuple = (),
@@ -146,6 +149,7 @@ class BatchBufferPool:
             if self._free:
                 return self._free.popleft()
         self._allocs.inc()
+        self.allocs += 1
         return _BatchLease(
             _alloc_unaliasable((batch,) + tuple(item_shape), dtype),
             _alloc_unaliasable((batch,) + tuple(label_shape), label_dtype),
@@ -692,22 +696,28 @@ class DataLoader:
                     self._dropped_leases += 1
             return out
 
+        def assembled(sl: slice, b: int) -> tuple:
+            # the span takes its train step from the prefetcher's
+            # data/prefetch_fetch span around this pull, where there is one
+            with tele.span("data/assemble", batch=b) as sp:
+                allocs0 = self._pool.allocs
+                out = assemble(*screen(fetch(indices[sl]), genuine[sl], b))
+                # did the ring have to allocate (steady state: never)?
+                sp.attrs["fresh_alloc"] = self._pool.allocs != allocs0
+            return out
+
         try:
             for b in range(start, nb_full):
-                sl = slice(b * self.local_batch_size, (b + 1) * self.local_batch_size)
-                with tele.span("data/assemble", batch=b):
-                    out = assemble(*screen(fetch(indices[sl]), genuine[sl], b))
+                out = assembled(slice(b * self.local_batch_size,
+                                      (b + 1) * self.local_batch_size), b)
                 # count BEFORE the yield: a generator suspends AT the
                 # yield, so a post-yield update would lag one batch behind
                 # what the caller has already consumed
                 self._pos = (epoch, b + 1)
                 yield out
             if tail and not self.drop_last and start <= nb_full:
-                sl = slice(nb_full * self.local_batch_size, None)
-                with tele.span("data/assemble", batch=nb_full):
-                    out = assemble(
-                        *screen(fetch(indices[sl]), genuine[sl], nb_full)
-                    )
+                out = assembled(slice(nb_full * self.local_batch_size, None),
+                                nb_full)
                 self._pos = (epoch, nb_full + 1)
                 yield out
         finally:
@@ -740,8 +750,11 @@ class DevicePrefetcher:
 
     def __init__(self, it: Any, depth: int = 2, sharding=None,
                  track_loader: "DataLoader | None" = None,
-                 recycler: Any = None):
+                 recycler: Any = None, first_step: int | None = None):
         self.it = it
+        # the train step the first batch feeds (the Trainer says); the
+        # worker counts on from there and tags its spans with it
+        self.first_step = first_step
         if sharding is None:
             sharding = rt.current_runtime().data_sharding()
         self.sharding = sharding
@@ -818,12 +831,12 @@ class DevicePrefetcher:
             # with its wall-clock interval is exactly what proves the
             # transfer of batch k+1 overlapped the step of batch k.
             tele = get_telemetry()
-            prefetched = tele.registry.counter("data/batches_prefetched")
             try:
                 it = iter(self.it)
                 n = 0
                 while True:
-                    with tele.span("data/prefetch_fetch", emit=False):
+                    step = None if self.first_step is None else self.first_step + n
+                    with tele.span("data/prefetch_fetch", emit=False, step=step):
                         try:
                             batch = next(it)
                         except StopIteration:
@@ -837,7 +850,7 @@ class DevicePrefetcher:
                         if self.track_loader is not None
                         else None
                     )
-                    with tele.span("data/h2d", batch=n):
+                    with tele.span("data/h2d", batch=n, step=step):
                         device_batch = self._put(batch)
                         # wait for the copy itself (NOT any consumer
                         # compute): after this the host buffers are free
@@ -846,7 +859,6 @@ class DevicePrefetcher:
                         jax.block_until_ready(device_batch)
                     if self.recycler is not None:
                         self.recycler.release_oldest(device_batch)
-                    prefetched.inc()
                     n += 1
                     if not put((device_batch, snap)):
                         return  # consumer went away

@@ -57,7 +57,6 @@ _EXPORTS = {
     "MetricsServer": "http_store",
     "make_tracker": "http_store",
     "ProfilerCallback": "profiler",
-    "StepTimer": "profiler",
     "trace": "profiler",
     "trace_step_window": "profiler",
     "HttpModelRegistry": "registry",

@@ -210,9 +210,10 @@ def _is_exec_track(pname: str, tname: str) -> bool:
     "XLA Ops" threads carry per-op events; the "Steps" / "XLA Modules"
     threads frame the same time at coarser granularity and would double
     count.  CPU traces have no device process — XLA:CPU op execution
-    lands on ``tf_XLATfrtCpuClient/<tid>`` threads of the host process
-    (the ``python`` thread's nested durations are host bookkeeping, not
-    device time) AND on the ``tf_XLAEigen/<tid>`` intra-op pool, which
+    lands on ``tf_XLAPjRtCpuClient/<tid>`` threads of the host process
+    (jax 0.9; ``tf_XLATfrtCpuClient`` before it; the ``python`` thread's
+    nested durations are host bookkeeping, not device time) AND on the
+    ``tf_XLAEigen/<tid>`` intra-op pool, which
     is where the thunk runtime actually runs the named HLO ops —
     including every collective (an all-reduce under simulated multi-CPU
     appears ONLY there).  Both pools belong to one host process, so
@@ -222,7 +223,8 @@ def _is_exec_track(pname: str, tname: str) -> bool:
     t = tname.lower()
     if pname.startswith("/device:"):
         return "step" not in t and "module" not in t
-    return "xlatfrtcpuclient" in t or "xlaeigen" in t
+    return ("xlapjrtcpuclient" in t or "xlatfrtcpuclient" in t
+            or "xlaeigen" in t)
 
 
 def _tracks(trace: dict) -> dict[tuple[Any, Any], dict]:

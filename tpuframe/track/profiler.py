@@ -1,4 +1,4 @@
-"""Profiling/tracing: programmatic ``jax.profiler`` capture + step timing.
+"""Profiling/tracing: programmatic ``jax.profiler`` capture.
 
 The TPU-native equivalent of the reference's tracing toolbox (SURVEY.md §5
 "Tracing / profiling"): DeepSpeed's ``wall_clock_breakdown: True`` +
@@ -9,6 +9,10 @@ and the ``nvidia-smi``/screenshot evidence (`/root/reference/README.md:18-20`)
 
 - :func:`trace` — context manager around any region; produces a TensorBoard-
   loadable trace directory (per-op device timeline, HLO, memory viewer).
+  Importing this module also lets every telemetry span show in such a trace
+  as a ``tpuframe/<name>`` ``TraceAnnotation`` carrying its ``step``
+  (`track/telemetry.py` may not import jax, so the factory is installed
+  from here).
 - :class:`ProfilerCallback` — Trainer callback that captures a window of
   train steps.  Two modes: one-shot (capture steps [skip_steps,
   skip_steps + num_steps) then log the zipped trace as a run artifact,
@@ -39,10 +43,24 @@ import tempfile
 import time
 from typing import TYPE_CHECKING, Any
 
+import jax
+
 if TYPE_CHECKING:  # pragma: no cover
     from tpuframe.train.trainer import Trainer
 
+from tpuframe.track import telemetry
 from tpuframe.train.callbacks import Callback
+
+
+def _span_annotation(name: str, step: int | None):
+    """A telemetry span on the profiler's clock; a flag test while no
+    profiler session runs."""
+    if step is None:
+        return jax.profiler.TraceAnnotation(f"tpuframe/{name}")
+    return jax.profiler.TraceAnnotation(f"tpuframe/{name}", step=step)
+
+
+telemetry.set_annotation_hook(_span_annotation)
 
 
 @contextlib.contextmanager
@@ -55,8 +73,6 @@ def trace(logdir: str):
     so it can neither mask the real exception nor leave the profiler
     started and wedge the next capture.
     """
-    import jax
-
     os.makedirs(logdir, exist_ok=True)
     jax.profiler.start_trace(logdir)
     try:
@@ -79,8 +95,6 @@ def trace_step_window(fn, n_steps: int, logdir: str, *args, **kwargs) -> str:
     — the partial window is real evidence of the step that raised.
     Returns ``logdir``.
     """
-    import jax
-
     with trace(logdir):
         for _ in range(n_steps):
             out = fn(*args, **kwargs)
@@ -208,8 +222,6 @@ class ProfilerCallback(Callback):
         )
         if trainer.batches_seen < start_at:
             return
-        import jax
-
         self._start_batch = trainer.batches_seen
         target = self._target()
         os.makedirs(target, exist_ok=True)
@@ -238,8 +250,6 @@ class ProfilerCallback(Callback):
             self._done = True  # no fresh session after the fit ended
 
     def _finalize(self, trainer: "Trainer", *, partial: bool) -> None:
-        import jax
-
         try:
             # include in-flight device work; a poisoned state (the step
             # raised) must not leave the profiler started
@@ -323,60 +333,3 @@ class ProfilerCallback(Callback):
             if target is not None:
                 self.artifact = target.log_artifact(archive, "profile")
         shutil.rmtree(os.path.dirname(archive), ignore_errors=True)
-
-
-class StepTimer(Callback):
-    """Lightweight per-step wall-clock sampler (host side).
-
-    Records the host time of each dispatched step; ``summary()`` gives
-    mean/p50/p95/p99 step wall time over the sampled window.  The window
-    is a **ring** of the most recent ``max_samples`` steps (the old capped
-    list stopped sampling after the first ``max_samples`` and reported a
-    10-hour run's first minutes forever), and every sample is also folded
-    into the process telemetry registry (``callback/step_time_s``) so the
-    spine's exporters — logger bridge, Prometheus page, JSONL snapshot —
-    see the same distribution.
-
-    Largely superseded by the Trainer's own ``train/step`` spans (the
-    ``span/train/step`` histogram is recorded unconditionally); kept for
-    explicit-callback workflows and any duck-typed loop that drives
-    callbacks without the Trainer.
-    """
-
-    def __init__(self, max_samples: int = 4096):
-        from collections import deque
-
-        self.max_samples = max_samples
-        self.samples: "deque[float]" = deque(maxlen=max_samples)
-        self.steps_seen = 0
-        self._t0: float | None = None
-
-    def on_step_start(self, trainer: "Trainer") -> None:
-        self._t0 = time.perf_counter()
-
-    def on_step_end(self, trainer: "Trainer") -> None:
-        if self._t0 is None:
-            return
-        dt = time.perf_counter() - self._t0
-        self.samples.append(dt)
-        self.steps_seen += 1
-        self._t0 = None
-        from tpuframe.track.telemetry import get_telemetry
-
-        get_telemetry().registry.histogram(
-            "callback/step_time_s", max_samples=self.max_samples
-        ).observe(dt)
-
-    def summary(self) -> dict[str, float]:
-        if not self.samples:
-            return {}
-        s = sorted(self.samples)
-        n = len(s)
-        return {
-            "step_time_mean_s": sum(s) / n,
-            "step_time_p50_s": s[n // 2],
-            "step_time_p95_s": s[min(n - 1, int(n * 0.95))],
-            "step_time_p99_s": s[min(n - 1, int(n * 0.99))],
-            "steps_sampled": float(n),
-            "steps_seen": float(self.steps_seen),
-        }

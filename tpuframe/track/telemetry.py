@@ -16,7 +16,12 @@ jax is wedged, so this module NEVER imports jax):
   the registry (p50/p95/p99 for free) and, when a sink is configured, one
   rank-tagged JSONL event.  The live span stack per thread is readable by
   the watchdog (`track/watchdog.py`), so a stall report says *where* each
-  thread was, in tpuframe terms, not just python frames.
+  thread was, in tpuframe terms, not just python frames.  Every span, emitted
+  or not, also stays behind as one record of the bounded **span log**
+  (:meth:`Telemetry.span_log`): id, parent id, thread, start and end on
+  ``time.perf_counter_ns()`` (one clock for all threads), and the train
+  ``step`` it feeds — what joins a step to the assembly and the copy that
+  fed it.
 - :class:`MetricsRegistry` — counters, gauges, histograms (bounded
   reservoir: long runs keep *recent* distribution data).  Exports as a
   flat dict for the existing ``TensorBoardLogger``/``MLflowLogger``
@@ -29,8 +34,8 @@ jax is wedged, so this module NEVER imports jax):
   bench children) or :func:`configure`.
 
 The process-wide instance comes from :func:`get_telemetry`; with no
-configuration it is memory-only (ring buffer + registry, no file I/O), so
-instrumented hot paths cost two ``perf_counter`` calls and a dict update.
+configuration it is memory-only (span log + ring buffer + registry, no file
+I/O), so instrumented hot paths cost two clock reads, a lock and two appends.
 
 Env knobs::
 
@@ -62,7 +67,7 @@ import socket
 import threading
 import time
 from collections import deque
-from typing import Any, Iterator, Mapping, Sequence
+from typing import Any, Iterable, Sequence
 
 __all__ = [
     "Counter",
@@ -76,6 +81,7 @@ __all__ = [
     "get_telemetry",
     "publish_to_loggers",
     "reset",
+    "set_annotation_hook",
     "start_metrics_server",
 ]
 
@@ -247,7 +253,7 @@ class Histogram:
 class MetricsRegistry:
     """Name -> instrument table; get-or-create, thread-safe.
 
-    Names are slash-namespaced (``span/train/step``, ``data/batches_prefetched``
+    Names are slash-namespaced (``span/train/step``, ``data/ring_allocs``
     — conventions in OBSERVABILITY.md).  Exports: :meth:`snapshot` (flat
     dict for the Trainer's logger contract) and :meth:`prometheus_text`.
     """
@@ -335,24 +341,89 @@ class MetricsRegistry:
 # -- spans --------------------------------------------------------------------
 
 
+#: installed by ``track/profiler.py`` (which may import jax; this module may
+#: not): ``hook(name, step)`` returns a context manager that shows the span
+#: in the profiler's trace.  None until then.
+_annotate = None
+
+
+def set_annotation_hook(hook) -> None:
+    """Let every span also open ``hook(name, step)`` around its region."""
+    global _annotate
+    _annotate = hook
+
+
 class Span:
-    """Handle yielded by :meth:`Telemetry.span`; ``elapsed`` is valid after
-    the ``with`` block exits (the Trainer reads it to keep its legacy
-    ``data_wait_s``/``dispatch_s``/``host_block_s`` epoch totals)."""
+    """What :meth:`Telemetry.span` returns: the ``with`` region's handle,
+    and its record in the span log once it has closed.
 
-    __slots__ = ("name", "attrs", "stack", "elapsed", "ok", "error", "_t0")
+    ``start_ns``/``end_ns`` are ``time.perf_counter_ns()`` readings (one
+    clock for every thread of the process); ``elapsed`` (seconds) is valid
+    after the ``with`` block exits.  ``parent_id`` is the enclosing span of
+    the same thread, ``step`` the train step the span feeds (its own, or
+    its parent's).  ``attrs`` is held by reference: what the region writes
+    into it while open (``sp.attrs["fresh_alloc"] = True``) is in the log
+    and on the JSONL line."""
 
-    def __init__(self, name: str, attrs: Mapping[str, Any]):
+    __slots__ = ("id", "parent_id", "name", "thread", "start_ns", "end_ns",
+                 "step", "attrs", "stack", "elapsed", "ok", "error", "emit",
+                 "_tele", "_ident", "_ann")
+
+    def __init__(self, tele: "Telemetry", name: str, attrs: dict,
+                 step: int | None = None, emit: bool = True):
+        self.id = 0
+        self.parent_id: int | None = None
         self.name = name
-        self.attrs = dict(attrs)
-        self.stack: list[str] = []
+        self.thread = ""
+        self.start_ns = self.end_ns = 0
+        self.step = step
+        self.attrs = attrs
+        self.stack: list[str] = [name]
         self.elapsed = 0.0
         self.ok = True
         self.error: str | None = None
-        self._t0 = 0.0
+        self.emit = emit
+        self._tele = tele
+        self._ident = 0
+        self._ann = None
 
     def __repr__(self):
         return f"Span({self.name!r}, elapsed={self.elapsed:.6f}, ok={self.ok})"
+
+    def __enter__(self) -> "Span":
+        tele = self._tele
+        th = threading.current_thread()
+        self.thread = th.name
+        ident = self._ident = th.ident
+        with tele._lock:
+            self.id = tele._span_seq = tele._span_seq + 1
+            stack = tele._active.get(ident)
+            if stack is None:
+                stack = tele._active[ident] = []
+            elif stack:
+                parent = stack[-1]
+                self.parent_id = parent.id
+                self.stack = parent.stack + [self.name]
+                if self.step is None:
+                    self.step = parent.step
+            stack.append(self)
+        if _annotate is not None:
+            self._ann = _annotate(self.name, self.step)
+            self._ann.__enter__()
+        self.start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.end_ns = time.perf_counter_ns()
+        self.elapsed = (self.end_ns - self.start_ns) / 1e9
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
+        if exc is not None:
+            self.ok = False
+            self.error = f"{exc_type.__name__}: {exc}"[:300]
+        self._tele._close_span(self)
+        return False
 
 
 class Telemetry:
@@ -363,6 +434,8 @@ class Telemetry:
         None = memory-only (ring buffer + registry, no file I/O).
       rank: tag on every record; defaults to the launch env's rank.
       max_events: ring-buffer length (the watchdog dumps the tail of this).
+      max_spans: span-log length (sized for a benchmark run of a few
+        hundred steps at ten spans a step, with room).
       registry: share an existing :class:`MetricsRegistry` (default: new).
       watchdog: a ``track.watchdog.Watchdog`` to attach (wires both ways).
       span_histograms: auto-observe every span duration into
@@ -379,6 +452,7 @@ class Telemetry:
         *,
         rank: int | None = None,
         max_events: int = 512,
+        max_spans: int = 16384,
         registry: MetricsRegistry | None = None,
         watchdog: Any = None,
         span_histograms: bool = True,
@@ -400,7 +474,15 @@ class Telemetry:
         # timeline fixed at configure time — immune to mid-run NTP steps
         self.anchor_wall = time.time()
         self.anchor_mono = time.monotonic()
-        self._recent: deque[dict] = deque(maxlen=max_events)
+        # ... and the span log's clock, read at the same moment: a span's
+        # perf_counter_ns readings minus this, plus either anchor above,
+        # place it beside any JSONL line
+        self.anchor_perf_ns = time.perf_counter_ns()
+        # events (envelope dicts) and emitted spans (the Span itself: its
+        # envelope is built when someone asks, not at every close)
+        self._recent: deque[dict | Span] = deque(maxlen=max_events)
+        self._spans: deque[Span] = deque(maxlen=max_spans)
+        self._span_seq = 0
         self._bytes = 0  # current JSONL segment size (approx, for rotation)
         # _lock guards only in-memory state (span stacks, ring buffer) and
         # is never held across file I/O: the watchdog reads active_spans/
@@ -434,6 +516,7 @@ class Telemetry:
             "hostname": hostname,
             "anchor_wall": round(self.anchor_wall, 6),
             "anchor_mono": round(self.anchor_mono, 6),
+            "anchor_perf_ns": self.anchor_perf_ns,
         }
 
     # -- wiring --------------------------------------------------------------
@@ -445,53 +528,46 @@ class Telemetry:
         return watchdog
 
     # -- spans ---------------------------------------------------------------
-    @contextlib.contextmanager
-    def span(self, name: str, *, emit: bool = True, **attrs: Any) -> Iterator[Span]:
+    def span(self, name: str, *, emit: bool = True, step: int | None = None,
+             **attrs: Any) -> Span:
         """Time a region; nestable, exception-transparent.
 
-        ``emit=False`` records the histogram + live-stack visibility but
-        skips the JSONL event — for per-batch inner regions where one event
-        per occurrence would dominate the log.
+        ``step`` is the train step the region feeds (the value
+        ``Trainer.batches_seen`` has after that step's dispatch); a span
+        given none takes its parent's.  ``emit=False`` keeps the
+        histogram, the live-stack visibility and the span-log record but
+        skips the JSONL event — for per-batch inner regions where one
+        event per occurrence would dominate the log.
         """
-        sp = Span(name, attrs)
-        ident = threading.get_ident()
+        return Span(self, name, attrs, step, emit)
+
+    def _close_span(self, sp: Span) -> None:
         with self._lock:
-            stack = self._active.setdefault(ident, [])
-            stack.append(sp)
-            sp.stack = [s.name for s in stack]
-        sp._t0 = time.perf_counter()
-        try:
-            yield sp
-        except BaseException as e:
-            sp.ok = False
-            sp.error = f"{type(e).__name__}: {e}"[:300]
-            raise
-        finally:
-            sp.elapsed = time.perf_counter() - sp._t0
-            with self._lock:
-                stack = self._active.get(ident)
-                if stack:
-                    if stack[-1] is sp:
-                        stack.pop()
-                    elif sp in stack:  # mis-nested exit: drop just this span
-                        stack.remove(sp)
-                    if not stack:
-                        del self._active[ident]
-            if self.span_histograms:
-                self.registry.histogram(f"span/{name}").observe(sp.elapsed)
-            if emit:
-                rec = {
-                    "kind": "span",
-                    "name": name,
-                    "stack": sp.stack,
-                    "dur_s": round(sp.elapsed, 6),
-                    "ok": sp.ok,
-                }
-                if sp.error:
-                    rec["error"] = sp.error
-                if attrs:
-                    rec["attrs"] = attrs
-                self._write(rec)
+            stack = self._active.get(sp._ident)
+            if stack:
+                if stack[-1] is sp:
+                    stack.pop()
+                elif sp in stack:  # mis-nested exit: drop just this span
+                    stack.remove(sp)
+                if not stack:
+                    del self._active[sp._ident]
+            self._spans.append(sp)
+            if sp.emit:
+                self._recent.append(sp)
+        if self.span_histograms:
+            self.registry.histogram(f"span/{sp.name}").observe(sp.elapsed)
+        if sp.emit and self.jsonl_path is not None:
+            self._sink(self._span_envelope(sp))
+
+    def span_log(self, names: Iterable[str] | None = None) -> list[Span]:
+        """The closed spans still in the log, oldest first (by close),
+        every thread's, emitted or not; ``names`` keeps only those."""
+        with self._lock:
+            out = list(self._spans)
+        if names is not None:
+            names = frozenset(names)
+            out = [sp for sp in out if sp.name in names]
+        return out
 
     def active_spans(self) -> dict[str, list[str]]:
         """``{thread_name (ident): [span names, outermost first]}`` — the
@@ -523,23 +599,54 @@ class Telemetry:
 
     def recent_events(self, n: int = 50) -> list[dict]:
         with self._lock:
-            return list(self._recent)[-n:]
+            tail = list(self._recent)[-n:]
+        return [self._span_envelope(r) if isinstance(r, Span) else r
+                for r in tail]
 
-    def _envelope(self, rec: dict) -> dict:
+    def _envelope(self, rec: dict, closed_ns: int | None = None,
+                  thread: str | None = None) -> dict:
+        """Stamp ``rec`` with now and the calling thread, or (a span's) with
+        the ``perf_counter_ns`` moment it closed, placed on both clocks
+        through the anchors, and its own thread."""
+        if closed_ns is None:
+            ts, mono = time.time(), time.monotonic()
+        else:
+            since = (closed_ns - self.anchor_perf_ns) / 1e9
+            ts, mono = self.anchor_wall + since, self.anchor_mono + since
         return {
             "v": SCHEMA_VERSION,
-            "ts": round(time.time(), 6),
-            "mono": round(time.monotonic(), 6),
+            "ts": round(ts, 6),
+            "mono": round(mono, 6),
             "rank": self.rank,
             "pid": os.getpid(),
-            "thread": threading.current_thread().name,
+            "thread": thread or threading.current_thread().name,
             **rec,
         }
+
+    def _span_envelope(self, sp: Span) -> dict:
+        """A closed span in the JSONL record's shape."""
+        rec = {
+            "kind": "span",
+            "name": sp.name,
+            "stack": sp.stack,
+            "dur_s": round(sp.elapsed, 6),
+            "ok": sp.ok,
+        }
+        if sp.error:
+            rec["error"] = sp.error
+        if sp.step is not None:
+            rec["step"] = sp.step
+        if sp.attrs:
+            rec["attrs"] = sp.attrs
+        return self._envelope(rec, sp.end_ns, sp.thread)
 
     def _write(self, rec: dict) -> None:
         rec = self._envelope(rec)
         with self._lock:
             self._recent.append(rec)
+        self._sink(rec)
+
+    def _sink(self, rec: dict) -> None:
         if self.jsonl_path is None:
             return
         line = json.dumps(rec, default=str) + "\n"

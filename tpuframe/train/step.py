@@ -67,6 +67,29 @@ def _supports_mutable(apply_fn) -> bool:
         return False
 
 
+# Stable names on the device side: ``jax.named_scope`` writes them into the
+# metadata of every op traced under it (and ``transpose(jvp(<name>))`` into
+# the backward pass's), which is where a profiler trace's reduction finds
+# forward, backward, optimizer and the input transform after any refactor.
+# Metadata only: the compiled program's memory and code do not change.
+
+
+def _named_transform(batch_transform: Callable[[dict], dict] | None):
+    """The on-device input transform (the Trainer's: the normalize kernel
+    and the layout changes around it) under ``tpuframe/input_normalize``."""
+    if batch_transform is None:
+        return None
+    return jax.named_scope("tpuframe/input_normalize")(
+        lambda batch: batch_transform(dict(batch))
+    )
+
+
+@jax.named_scope("tpuframe/optimizer")
+def _apply_gradients(state: TrainState, grads: Any, **changes) -> TrainState:
+    return state.apply_gradients(grads, **changes)
+
+
+@jax.named_scope("tpuframe/forward")
 def _forward(state: TrainState, params: Any, batch: Mapping[str, jax.Array],
              policy: Policy, train: bool, rng: jax.Array | None,
              loss_fn: LossFn):
@@ -157,7 +180,7 @@ def _apply_with_health(state: TrainState, grads: Any, new_stats: Any,
         loss, grads, hstate, state.step, health, grad_sq=grad_sq
     )
     if apply_fn is None:
-        applied = state.apply_gradients(grads, batch_stats=new_stats)
+        applied = _apply_gradients(state, grads, batch_stats=new_stats)
     else:
         applied = apply_fn(grads)
 
@@ -264,6 +287,7 @@ def make_train_step(
     (branch-free skip) — see :func:`_apply_with_health`.
     """
     policy = policy or full_precision()
+    batch_transform = _named_transform(batch_transform)
     if grad_compression is not None:
         # the step body runs INSIDE shard_map there: the loss must stay
         # unbound (mesh=None) or the fused-CE kernel would open a second,
@@ -282,7 +306,7 @@ def make_train_step(
 
     def step(state: TrainState, batch: Mapping[str, jax.Array]):
         if batch_transform is not None:
-            batch = batch_transform(dict(batch))
+            batch = batch_transform(batch)
         rng = state.step_rng("dropout")
 
         def compute_loss(params):
@@ -299,7 +323,7 @@ def make_train_step(
         )(state.params)
         metrics = _train_metrics(loss, logits, batch["label"])
         if health is None:
-            new_state = state.apply_gradients(grads, batch_stats=new_stats)
+            new_state = _apply_gradients(state, grads, batch_stats=new_stats)
             return new_state, metrics
         return _apply_with_health(state, grads, new_stats, loss, metrics, health)
 
@@ -525,7 +549,7 @@ def _make_compressed_train_step(
                 rng = jax.random.fold_in(rng, jax.lax.axis_index(ax))
 
             if n_microbatches == 1:
-                b = batch_transform(dict(batch)) if batch_transform else batch
+                b = batch_transform(batch) if batch_transform else batch
 
                 def compute_loss(params):
                     losses, logits, new_stats, aux = _forward(
@@ -548,7 +572,7 @@ def _make_compressed_train_step(
                 def micro(carry, scanned):
                     mb, micro_idx = scanned
                     if batch_transform is not None:
-                        mb = batch_transform(dict(mb))
+                        mb = batch_transform(mb)
                     grads_acc, stats, acc_metrics = carry
                     mb_rng = jax.random.fold_in(rng, micro_idx)
 
@@ -642,8 +666,8 @@ def _make_compressed_train_step(
                     )
                     synced = jax.tree.map(lambda g: g * scale, synced)
                 if health is None:
-                    new_state = state.apply_gradients(
-                        synced, batch_stats=new_stats
+                    new_state = _apply_gradients(
+                        state, synced, batch_stats=new_stats
                     ).replace(comms=new_comms)
                     return _reslice((new_state, metrics))
                 # the verdict must be identical on every shard (params
@@ -677,6 +701,7 @@ def _make_compressed_train_step(
                     leaf, layout.axes, axis=dim, tiled=True
                 )
 
+            @jax.named_scope("tpuframe/optimizer")
             def zero_apply(grads_mixed):
                 # opt_state arrived SLICED (the step's in_specs shard it
                 # per update_shard_specs); update the owned slices, then
@@ -799,11 +824,12 @@ def make_eval_step(
     (the reference's rank-0-only eval sidesteps this by not distributing eval
     at all, `01_basic_torch_distributor.py:302-323`)."""
     policy = policy or full_precision()
+    batch_transform = _named_transform(batch_transform)
     loss_fn = _bind_loss(loss_fn, plan)
 
     def step(state: TrainState, batch: Mapping[str, jax.Array]):
         if batch_transform is not None:
-            batch = batch_transform(dict(batch))
+            batch = batch_transform(batch)
         losses, logits, _, _ = _forward(
             state, state.params, batch, policy, False, None, loss_fn
         )
@@ -873,6 +899,7 @@ def make_grad_accum_step(
     (not per micro-step) — see :func:`_make_compressed_train_step`.
     """
     policy = policy or full_precision()
+    batch_transform = _named_transform(batch_transform)
     if grad_compression is not None:
         # the step body runs inside shard_map there: the loss must stay
         # unbound (mesh=None), same as make_train_step's compressed path
@@ -898,7 +925,7 @@ def make_grad_accum_step(
             # before the scan would materialize the full float copy and
             # defeat grad-accum's memory purpose
             if batch_transform is not None:
-                mb = batch_transform(dict(mb))
+                mb = batch_transform(mb)
             grads_acc, stats, metrics = carry
             # distinct dropout mask per microbatch — matching what the same
             # samples would draw as separate steps
@@ -933,7 +960,7 @@ def make_grad_accum_step(
         )
         grads = jax.tree.map(lambda g: g / n_microbatches, grads)
         if health is None:
-            new_state = state.apply_gradients(grads, batch_stats=new_stats)
+            new_state = _apply_gradients(state, grads, batch_stats=new_stats)
             return new_state, metrics
         # the super-batch is the unit of update, so it is the unit of
         # health too: one NaN microbatch poisons the accumulated grads

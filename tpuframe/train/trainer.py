@@ -48,6 +48,9 @@ from tpuframe.fault import preempt as _preempt
 from tpuframe.fault.health import Divergence
 from tpuframe.fault.preempt import Preempted
 from tpuframe.track import memory as _memory
+# importing the profiler module is what shows the loop's spans in any
+# jax.profiler trace (it installs the spans' TraceAnnotation factory)
+from tpuframe.track import profiler as _profiler
 from tpuframe.track.analyze import StragglerMonitor
 from tpuframe.track.telemetry import get_telemetry
 from tpuframe.parallel.precision import Policy, align_model_dtype, get_policy
@@ -246,12 +249,11 @@ class Trainer:
         # code change; an explicitly-passed ProfilerCallback keeps
         # authority over its own cadence
         if os.environ.get("TPUFRAME_PROFILE_STEPS", "").strip():
-            from tpuframe.track.profiler import ProfilerCallback
-
             if not any(
-                isinstance(cb, ProfilerCallback) for cb in self.callbacks
+                isinstance(cb, _profiler.ProfilerCallback)
+                for cb in self.callbacks
             ):
-                env_profiler = ProfilerCallback.from_env()
+                env_profiler = _profiler.ProfilerCallback.from_env()
                 if env_profiler is not None:
                     self.callbacks.append(env_profiler)
         self.loggers = list(loggers)
@@ -536,8 +538,11 @@ class Trainer:
         return self._intra_ck
 
     def _emit(self, hook: str, *args) -> None:
-        for cb in self.callbacks:
-            getattr(cb, hook)(self, *args)
+        if not self.callbacks:
+            return
+        with get_telemetry().span("train/callbacks", emit=False, hook=hook):
+            for cb in self.callbacks:
+                getattr(cb, hook)(self, *args)
 
     def _meter_comms(self, tele) -> None:
         """Per-step bytes-on-wire accounting: the compressed step's wire
@@ -717,14 +722,18 @@ class Trainer:
 
         if self.health is None or not self._health_flags:
             return
-        stats = jax.device_get(self._health_flags)
+        tele = get_telemetry()
+        # the loop's one host sync besides the window drain
+        with tele.span("train/health_fetch", emit=False,
+                       step=self.batches_seen):
+            stats = jax.device_get(self._health_flags)
+            hs = {
+                k: float(v)
+                for k, v in jax.device_get(self.state.health).items()
+            }
         n_bad = int(round(sum(float(s[0]) for s in stats)))
         window_steps = len(stats)
         self._health_flags = []
-        tele = get_telemetry()
-        hs = {
-            k: float(v) for k, v in jax.device_get(self.state.health).items()
-        }
         for key, name in (("loss_ewma", "health/loss_ewma"),
                           ("grad_norm", "health/grad_norm")):
             if math.isfinite(hs.get(key, float("nan"))):
@@ -775,10 +784,11 @@ class Trainer:
         )
 
     def _log_metrics(self, metrics: Mapping[str, float], step: int) -> None:
-        if not self.is_main:
+        if not self.is_main or not self.loggers:
             return
-        for lg in self.loggers:
-            lg.log_metrics(dict(metrics), step=step)
+        with get_telemetry().span("train/log", emit=False):
+            for lg in self.loggers:
+                lg.log_metrics(dict(metrics), step=step)
 
     def _log_params(self, params: Mapping[str, Any]) -> None:
         if not self.is_main:
@@ -1043,6 +1053,9 @@ class Trainer:
             # prefetcher's release-after-H2D stays FIFO-aligned with the
             # loader's lease order
             recycler=loader if hasattr(loader, "release_oldest") else None,
+            # the train step this epoch's first batch feeds: the producer's
+            # spans then carry the same step id as the loop's
+            first_step=self.batches_seen + 1 if train else None,
         )
         if train:
             self._train_prefetcher = pf
@@ -1352,10 +1365,12 @@ class Trainer:
         assemble0, h2d0 = _h_assemble.total, _h_h2d.total
         _epoch_end = object()
 
-        def drain(window):
+        def drain(window, first_step):
             """Materialize the device-side window (the only host sync)."""
             nonlocal host_block
-            with tele.span("train/host_block", emit=False) as sp:
+            # the drained window: first_step .. step
+            with tele.span("train/host_block", emit=False,
+                           step=self.batches_seen, first_step=first_step) as sp:
                 out = {
                     k: float(v) for k, v in window.items()
                     if k != "health_stats"
@@ -1371,6 +1386,9 @@ class Trainer:
             return out
 
         batches = iter(self._device_batches(self.train_dataloader, train=True))
+        empty_queue = tele.registry.counter("train/empty_queue_dispatches")
+        prev_out = None  # one leaf of the previous step's metrics
+        window_first = 0  # the first step summed into ``window``
         # straggler boundary: the gap back to the previous epoch (eval,
         # epoch-end checkpoint) must not read as one slow step
         self._straggler.mark()
@@ -1378,106 +1396,122 @@ class Trainer:
             # chaos site: a scheduled loader fault raises here, exactly
             # where a real worker-pool / shard-fetch failure surfaces
             chaos.maybe_fire("loader", step=self.batches_seen)
-            with tele.span("train/data_wait", emit=False) as sp:
-                batch = next(batches, _epoch_end)
-            if batch is _epoch_end:
-                break  # the exhausted final pull never counted toward data_wait
-            wait_s = sp.elapsed
-            data_wait += wait_s
-            if self._done() or self._stop_reason is not None:
-                break
-            self._emit("on_step_start")
-            try:
-                chaos.maybe_fire("step", step=self.batches_seen)
-                # the guard turns a wedged dispatch (first-step compile,
-                # stuck collective) into an attributed watchdog report
-                # instead of a silent hang; unmonitored unless a watchdog
-                # is configured.  data_wait_s rides as a span attr so the
-                # fleet analyzer can classify this step input-bound
-                # without a second JSONL line.
-                with tele.span("train/step", batch=self.batches_seen,
-                               data_wait_s=round(wait_s, 6)) as sp, \
-                        tele.guard("train/step"):
-                    self.state, metrics = self._step_call(
-                        "train", self._train_step, self.state, batch
-                    )
-            except Exception as e:
-                # OOM forensics: a RESOURCE_EXHAUSTED here (the chaos
-                # OomAt fires inside this block too) becomes one
-                # memory/oom event with the attribution table + fit
-                # suggestion; everything re-raises untouched
-                _memory.maybe_oom_event(e, where="step",
-                                        step=self.batches_seen)
-                raise
-            dispatch += sp.elapsed
-            self.batches_seen += 1
-            self.samples_seen += self.train_dataloader.global_batch_size
-            self._meter_comms(tele)
-            self._meter_pp(tele)
-            # boundary-to-boundary step time: charges whatever actually
-            # slowed this rank (wait, dispatch, snapshot, callback)
-            self._straggler.observe()
-            # health sentinel: accumulate the step's bad-flag on device
-            # (async, like the metrics window) and check once per window
-            # — may raise Divergence, BEFORE this step's interval
-            # snapshot would write yet another doomed checkpoint
-            self._health_step(metrics)
-            if (
-                self.checkpointer is not None
-                and self.checkpoint_interval_batches
-                and self.batches_seen % self.checkpoint_interval_batches == 0
-            ):
+            # one parent per iteration, tagged with the step it feeds (its
+            # children inherit the tag): what no child covers is the
+            # loop's own time
+            with tele.span("train/iter", emit=False,
+                           step=self.batches_seen + 1) as it:
+                with tele.span("train/data_wait", emit=False) as sp:
+                    batch = next(batches, _epoch_end)
+                # the exhausted final pull never counts toward data_wait
+                stop = batch is _epoch_end
+                if not stop:
+                    wait_s = sp.elapsed
+                    data_wait += wait_s
+                    stop = self._done() or self._stop_reason is not None
+                if stop:
+                    it.step = sp.step = None  # this iteration feeds no step
+                    break
+                self._emit("on_step_start")
                 try:
-                    epoch_len = len(self.train_dataloader) or 1
-                except TypeError:  # duck-typed iterable without __len__
-                    epoch_len = 1 << 62
-                snap = self._train_prefetcher.state_dict()
-                # the epoch-final batch is followed immediately by the
-                # epoch-end save — a snapshot there would be a throwaway
-                # full serialization of the same state.  The WITHIN-epoch
-                # position decides (cumulative batches_seen desyncs from
-                # epoch boundaries after any mid-epoch stop).
-                if snap["batches_yielded"] < epoch_len:
-                    # mid-epoch snapshot (sibling checkpointer): model/opt
-                    # state + the consumer-true loader position, so a
-                    # crash resumes with the very next batch (no replayed
-                    # or skipped samples)
-                    self._intra_checkpointer().save(
-                        self.state,
-                        meta={
-                            "epoch": self.epoch,
-                            "batches_seen": self.batches_seen,
-                            "samples_seen": self.samples_seen,
-                            "loader_state": snap,
-                            "global_batch": self.train_dataloader.global_batch_size,
-                        },
-                        plan=self.plan,
-                        health=self._health_stamp(),
+                    chaos.maybe_fire("step", step=self.batches_seen)
+                    # the guard turns a wedged dispatch (first-step compile,
+                    # stuck collective) into an attributed watchdog report
+                    # instead of a silent hang; unmonitored unless a watchdog
+                    # is configured.  data_wait_s rides as a span attr so the
+                    # fleet analyzer can classify this step input-bound
+                    # without a second JSONL line.
+                    with tele.span("train/step", batch=self.batches_seen,
+                                   data_wait_s=round(wait_s, 6)) as sp, \
+                            tele.guard("train/step"):
+                        if prev_out is not None:
+                            # the previous step already complete means
+                            # nothing is queued: the device sits idle until
+                            # this dispatch lands (no sync, no dispatch)
+                            idle = prev_out.is_ready()
+                            sp.attrs["device_idle_at_dispatch"] = idle
+                            if idle:
+                                empty_queue.inc()
+                        self.state, metrics = self._step_call(
+                            "train", self._train_step, self.state, batch
+                        )
+                except Exception as e:
+                    # OOM forensics: a RESOURCE_EXHAUSTED here (the chaos
+                    # OomAt fires inside this block too) becomes one
+                    # memory/oom event with the attribution table + fit
+                    # suggestion; everything re-raises untouched
+                    _memory.maybe_oom_event(e, where="step",
+                                            step=self.batches_seen)
+                    raise
+                dispatch += sp.elapsed
+                prev_out = jax.tree.leaves(metrics)[0]
+                self.batches_seen += 1
+                self.samples_seen += self.train_dataloader.global_batch_size
+                self._meter_comms(tele)
+                self._meter_pp(tele)
+                # boundary-to-boundary step time: charges whatever actually
+                # slowed this rank (wait, dispatch, snapshot, callback)
+                self._straggler.observe()
+                # health sentinel: accumulate the step's bad-flag on device
+                # (async, like the metrics window) and check once per window
+                # — may raise Divergence, BEFORE this step's interval
+                # snapshot would write yet another doomed checkpoint
+                self._health_step(metrics)
+                if (
+                    self.checkpointer is not None
+                    and self.checkpoint_interval_batches
+                    and self.batches_seen % self.checkpoint_interval_batches == 0
+                ):
+                    try:
+                        epoch_len = len(self.train_dataloader) or 1
+                    except TypeError:  # duck-typed iterable without __len__
+                        epoch_len = 1 << 62
+                    snap = self._train_prefetcher.state_dict()
+                    # the epoch-final batch is followed immediately by the
+                    # epoch-end save — a snapshot there would be a throwaway
+                    # full serialization of the same state.  The WITHIN-epoch
+                    # position decides (cumulative batches_seen desyncs from
+                    # epoch boundaries after any mid-epoch stop).
+                    if snap["batches_yielded"] < epoch_len:
+                        # mid-epoch snapshot (sibling checkpointer): model/opt
+                        # state + the consumer-true loader position, so a
+                        # crash resumes with the very next batch (no replayed
+                        # or skipped samples)
+                        self._intra_checkpointer().save(
+                            self.state,
+                            meta={
+                                "epoch": self.epoch,
+                                "batches_seen": self.batches_seen,
+                                "samples_seen": self.samples_seen,
+                                "loader_state": snap,
+                                "global_batch": self.train_dataloader.global_batch_size,
+                            },
+                            plan=self.plan,
+                            health=self._health_stamp(),
+                        )
+                # step boundary = the preemption exit point: the step is the
+                # atomic unit of progress, so a SIGTERM/maintenance notice is
+                # acted on here — last-chance checkpoint, then Preempted out
+                self._maybe_preempt_exit()
+                # Accumulate on device (async) — floating every step would
+                # block the host on each step's completion and serialize the
+                # pipeline.
+                if window is None:
+                    window, window_first = metrics, self.batches_seen
+                else:
+                    window = jax.tree.map(jnp.add, window, metrics)
+                self._emit("on_step_end")
+                if self.log_interval and self.batches_seen % self.log_interval == 0:
+                    w = drain(window, window_first)
+                    acc = merge_metrics(acc, w)
+                    self._emit("on_batch_end", w)
+                    self._log_metrics(
+                        summarize_metrics(w, prefix="train_batch_"),
+                        step=self.batches_seen,
                     )
-            # step boundary = the preemption exit point: the step is the
-            # atomic unit of progress, so a SIGTERM/maintenance notice is
-            # acted on here — last-chance checkpoint, then Preempted out
-            self._maybe_preempt_exit()
-            # Accumulate on device (async) — floating every step would
-            # block the host on each step's completion and serialize the
-            # pipeline.
-            window = (
-                metrics
-                if window is None
-                else jax.tree.map(jnp.add, window, metrics)
-            )
-            self._emit("on_step_end")
-            if self.log_interval and self.batches_seen % self.log_interval == 0:
-                w = drain(window)
-                acc = merge_metrics(acc, w)
-                self._emit("on_batch_end", w)
-                self._log_metrics(
-                    summarize_metrics(w, prefix="train_batch_"),
-                    step=self.batches_seen,
-                )
-                window = None
+                    window = None
         if window is not None:
-            w = drain(window)
+            w = drain(window, window_first)
             acc = merge_metrics(acc, w)
             self._emit("on_batch_end", w)
         # flush the partial health window: max_bad bad steps are max_bad
