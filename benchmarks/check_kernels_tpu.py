@@ -195,15 +195,19 @@ def _check_normalize(jax, jnp, np, rng) -> None:
              ((3, 5, 7, 3), jnp.float32, 1e-5))
     for shape, out_dtype, tol in cases:
         raw = jnp.asarray(rng.integers(0, 256, shape), jnp.uint8)
-        got = jax.jit(lambda r, d=out_dtype: normalize_images(
-            r, mean, std, out_dtype=d))(raw)
         want = normalize_images_reference(raw, mean, std, out_dtype=out_dtype)
-        record(
-            f"normalize_images_{'x'.join(map(str, shape))}_{jnp.dtype(out_dtype).name}",
-            float(jnp.max(jnp.abs(got.astype(jnp.float32)
-                                  - want.astype(jnp.float32)))),
-            tol,
-        )
+        # auto keeps an image batch in its own layout (plain jnp, what the
+        # Trainer's step runs); interpret=False is the kernel itself
+        for form, interpret in (("", None), ("_kernel", False)):
+            got = jax.jit(lambda r, d=out_dtype, i=interpret: normalize_images(
+                r, mean, std, out_dtype=d, interpret=i))(raw)
+            record(
+                f"normalize_images{form}_{'x'.join(map(str, shape))}"
+                f"_{jnp.dtype(out_dtype).name}",
+                float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                      - want.astype(jnp.float32)))),
+                tol,
+            )
 
 
 def _check_quant_wire(jax, jnp, np, rng) -> None:

@@ -53,6 +53,117 @@ def test_normalize_auto_dispatch_matches_reference(monkeypatch):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want))
 
 
+def _ulp_ceiling(dtype) -> float:
+    """One unit in the last place of ``dtype`` at the largest magnitude a
+    normalized ImageNet pixel takes (|x| < 4: spacing of [2, 4))."""
+    return float(jnp.finfo(dtype).eps) * 2.0
+
+
+class TestNormalizeLayoutRule:
+    """Where the kernel would engage, auto dispatch keeps an image batch in
+    its own layout (PR 25): on the chip the kernel's flat ``(rows, 128)``
+    view of a channels-last array with 3 or 1 in the minor dimension is a
+    physical re-layout.  The backend's answer is pinned to "compiled" (what
+    a TPU says); the decision is read off the traced program, and the jnp
+    form runs anywhere."""
+
+    @pytest.fixture(autouse=True)
+    def _pinned(self, compiled_backend):
+        pass
+
+    @staticmethod
+    def _traces_kernel(shape, **kw) -> bool:
+        per_channel = (0.5,) * shape[-1]  # "channels" are the last axis
+        text = str(jax.make_jaxpr(
+            lambda x: normalize_images(x, per_channel, per_channel, **kw)
+        )(jax.ShapeDtypeStruct(shape, jnp.uint8)))
+        return "pallas_call" in text
+
+    @pytest.mark.parametrize("shape,kernel", [
+        ((4, 17, 17, 3), False),    # NHWC, 3 channels
+        ((2, 28, 28, 1), False),    # NHWC, grayscale
+        ((128,), True),             # 1-D: already the flat stream
+        ((100,), True),             # 1-D, ragged: padded, still no re-layout
+        ((2, 3, 128), True),        # whole 128-lane rows
+    ])
+    def test_shape_decides_the_path(self, shape, kernel):
+        assert self._traces_kernel(shape) is kernel
+
+    @pytest.mark.parametrize("interpret", [True, False])
+    def test_explicit_interpret_runs_the_kernel_on_an_image(self, interpret):
+        assert self._traces_kernel((4, 17, 17, 3), interpret=interpret)
+
+    @pytest.mark.parametrize("channels", [3, 1])
+    @pytest.mark.parametrize("in_dtype", [np.uint8, np.float32])
+    @pytest.mark.parametrize("out_dtype", [jnp.float32, jnp.bfloat16])
+    def test_in_layout_matches_reference(self, channels, in_dtype, out_dtype):
+        rng = np.random.default_rng(channels)
+        raw = rng.integers(0, 256, (4, 17, 17, channels), dtype=np.uint8)
+        imgs = jnp.asarray(raw.astype(in_dtype))  # 0-255 floats: MixUp's
+        mean, std = MEAN[:channels], STD[:channels]
+        got = normalize_images(imgs, mean, std, out_dtype=out_dtype)
+        want = normalize_images_reference(imgs, mean, std, out_dtype=out_dtype)
+        assert got.dtype == out_dtype and got.shape == imgs.shape
+        # the folded constants round differently from the three-op chain:
+        # a few f32 ulps before the one rounding to out_dtype
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            atol=max(_ulp_ceiling(out_dtype), 4 * _ulp_ceiling(jnp.float32)),
+            rtol=0)
+
+    @pytest.mark.parametrize("out_dtype", [jnp.float32, jnp.bfloat16])
+    def test_in_layout_agrees_with_kernel_to_one_ulp(self, out_dtype):
+        rng = np.random.default_rng(7)
+        imgs = jnp.asarray(rng.integers(0, 256, (4, 17, 17, 3), dtype=np.uint8))
+        got = normalize_images(imgs, MEAN, STD, out_dtype=out_dtype)
+        kern = normalize_images(imgs, MEAN, STD, out_dtype=out_dtype,
+                                interpret=True)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(kern, np.float32),
+            atol=_ulp_ceiling(out_dtype), rtol=0)
+
+    def test_sharded_image_needs_no_shard_map(self, mesh8):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        rng = np.random.default_rng(13)
+        raw = rng.integers(0, 256, (8, 5, 5, 3), dtype=np.uint8)
+        sharded = jax.device_put(
+            raw, NamedSharding(mesh8, P(mesh8.axis_names[0])))
+        fn = jax.jit(lambda x: normalize_images(x, MEAN, STD, mesh=mesh8))
+        assert "shard_map" not in str(jax.make_jaxpr(fn)(sharded))
+        got = fn(sharded)
+        assert got.sharding.is_equivalent_to(sharded.sharding, raw.ndim)
+        np.testing.assert_allclose(
+            np.asarray(got),
+            np.asarray(normalize_images_reference(jnp.asarray(raw), MEAN, STD)),
+            atol=4 * _ulp_ceiling(jnp.float32))
+
+    def test_one_layout_verdict_per_decision(self, tmp_path):
+        from tpuframe.track import telemetry as T
+
+        tele = T.configure(str(tmp_path / "events.jsonl"))
+        try:
+            img = jnp.zeros((4, 17, 17, 3), jnp.uint8)
+            for _ in range(3):
+                normalize_images(img, MEAN, STD)
+                normalize_images(img, MEAN, STD, interpret=True)
+            events = [e for e in tele.recent_events(50)
+                      if e["name"] == "ops/kernel_verdict"]
+            assert len(events) == 1
+            (e,) = events
+            assert (e["op"], e["enable"], e["source"]) == (
+                "normalize", False, "layout")
+            # the ledger was not asked: neither coverage counter moves
+            assert tele.registry.counter("ops/ledger_hit").value == 0
+            assert tele.registry.counter("ops/ledger_miss").value == 0
+            # another shape class is another decision
+            normalize_images(jnp.zeros((2, 28, 28, 1), jnp.uint8), (0.5,), (0.5,))
+            assert len([e for e in tele.recent_events(50)
+                        if e["name"] == "ops/kernel_verdict"]) == 2
+        finally:
+            T.reset()
+
+
 def test_disable_flag_is_strict():
     from tpuframe.ops import use_pallas
     import os

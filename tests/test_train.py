@@ -471,6 +471,29 @@ class TestDeviceNormalize:
         p_float = trainers[1].predict(floats[:4].astype(np.float32))
         np.testing.assert_allclose(p_raw, p_float, atol=1e-3)
 
+    def test_lowered_image_step_holds_no_normalize_custom_call(self, request):
+        """An image Trainer's train step, lowered for the TPU with the
+        backend's answer pinned to "compiled": the kernels that stay are
+        there under their names, the normalize is plain ops under
+        ``tpuframe/input_normalize`` and no custom call (PR 25: the layout
+        changes a custom call forces around an NHWC batch cost seventy
+        times the kernel)."""
+        trainer = Trainer(
+            MnistNet(num_classes=4), max_duration="1ba", num_classes=4,
+            log_interval=0, normalize=((0.1307,), (0.3081,)),
+            sample_input=np.zeros((1, 28, 28, 1), np.float32),
+        )
+        state = trainer.init_state()  # runs on this backend: pin after it
+        request.getfixturevalue("compiled_backend")
+        step = getattr(trainer._train_step, "_inner_jit", trainer._train_step)
+        batch = {"image": jax.ShapeDtypeStruct((16, 28, 28, 1), np.uint8),
+                 "label": jax.ShapeDtypeStruct((16,), np.int32)}
+        text = step.trace(state, batch).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+        assert 'kernel_name = "tpuframe_ce_fwd"' in text
+        assert "tpuframe/input_normalize" in text
+        assert "tpuframe_normalize" not in text
+
     def test_normalize_with_grad_accum(self):
         from flax import linen as nn
 

@@ -7,10 +7,11 @@ collectives; this package hand-writes the remaining hot spots as Pallas
 kernels, each with a jnp reference implementation that is both the CPU
 fallback and the correctness oracle for tests.
 
-- :func:`normalize_images` — fused uint8→float, scale, per-channel
-  mean/std normalize in one VMEM pass (the input-pipeline hot op;
-  replaces torchvision's ToTensor+Normalize chain,
-  `/root/reference/utils/hf_dataset_utilities.py:58-81`).
+- :func:`normalize_images` — uint8→float, scale, per-channel mean/std
+  normalize in one pass (replaces torchvision's ToTensor+Normalize
+  chain, `/root/reference/utils/hf_dataset_utilities.py:58-81`): an
+  image batch in its own layout as one XLA fusion, a flat stream by the
+  kernel.
 - :func:`fused_cross_entropy` — softmax cross entropy with a custom VJP
   that recomputes the softmax in the backward kernel instead of
   materializing it in HBM.

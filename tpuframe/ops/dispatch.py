@@ -17,6 +17,13 @@ real kernel code on CPU).  Env knobs:
   ``ops/kernel_verdict`` event, so a trace of a misdispatched run says
   which verdict (and whose measurement) chose the path.
 
+An op may decline its own kernel from what it sees in its input before
+any of these is asked: ``normalize_images`` keeps an NHWC image batch in
+its own layout as plain jnp wherever a kernel could run (``source="layout"``
+on its verdict event), because the kernel's flat view of such an array is
+a physical re-layout on the chip that the ledger's stand-alone A/B does
+not price.
+
 Multi-chip: a ``pl.pallas_call`` lowers to a custom call the GSPMD
 partitioner cannot split, so ops invoke their kernels *per shard* under
 ``jax.shard_map`` when the caller supplies a mesh (the pattern proven by
@@ -95,7 +102,10 @@ def _cached_ledger(*, backend: str | None = None, signature: str | None = None):
 def _emit_verdict(op: str, shape_cls: str | None, *, enable: bool,
                   source: str, **extra) -> None:
     """One ``ops/kernel_verdict`` event per distinct (op, shape class,
-    decision), plus the ledger hit/miss counters."""
+    decision), plus the ledger hit/miss counters.  ``source="layout"`` is
+    an op that saw in its input's shape that its kernel's view of it is a
+    physical re-layout and kept the jnp form (``ops/normalize.py``): the
+    ledger was not asked, so neither counter moves."""
     key = (op, shape_cls, enable, source)
     if key in _VERDICT_EMITTED:
         return
@@ -104,9 +114,10 @@ def _emit_verdict(op: str, shape_cls: str | None, *, enable: bool,
         from tpuframe.track.telemetry import get_telemetry
 
         tele = get_telemetry()
-        tele.registry.counter(
-            "ops/ledger_hit" if source == "ledger" else "ops/ledger_miss"
-        ).inc()
+        if source != "layout":
+            tele.registry.counter(
+                "ops/ledger_hit" if source == "ledger" else "ops/ledger_miss"
+            ).inc()
         tele.event(
             "ops/kernel_verdict", op=op, shape_class=shape_cls,
             enable=bool(enable), source=source,

@@ -1,14 +1,26 @@
-"""Fused image normalization: uint8 → scaled, mean/std-normalized float.
+"""Image normalization: uint8 → scaled, mean/std-normalized float.
 
-One VMEM pass replaces the reference's three-op torchvision chain
-(``ToTensor`` divide-by-255 + ``Normalize`` subtract/divide,
-`/root/reference/utils/hf_dataset_utilities.py:70-80`): the uint8 bytes
-are read from HBM once and the normalized activation dtype is written
-once — the op is HBM-bandwidth-bound, so halving traffic halves time.
+The reference's torchvision chain (``ToTensor`` divide-by-255 +
+``Normalize`` subtract/divide,
+`/root/reference/utils/hf_dataset_utilities.py:70-80`) as one pass on
+the device: for channel ``c`` the transform is ``x * w[c] + b[c]`` in
+float32 with ``w = scale/std`` and ``b = -mean/std`` folded on the
+host, rounded once to ``out_dtype``.
 
-Channel constants are compile-time: for channel ``c`` the transform is
-``x * w[c] + b[c]`` with ``w = scale/std`` and ``b = -mean/std`` folded
-on the host.
+Two forms of that pass, chosen by the input's shape:
+
+- an image batch (channels-last, 3 or 1 in the minor dimension) is
+  normalized in its own layout by plain ``jnp``.  XLA fuses convert,
+  multiply and add into one pass under a jit (it always did: the chain
+  was never three passes), reads the parameter in the layout it
+  arrived in and writes the layout the first convolution asks for.
+- the Pallas kernel works on a flat ``(rows, 128)`` stream, and stays
+  for inputs whose flat view is a bitcast (1-D, or a last dimension of
+  whole 128-lane rows).  For an image batch that view is a physical
+  re-layout on the chip, and a custom call pins the layout on both of
+  its sides: on a v5e the kernel took 0.36 ms for 256 images of 224 px
+  and the reshapes and copies around it 26 ms (PERF.md, PR 25), so auto
+  dispatch no longer sends image batches through it.
 """
 
 from __future__ import annotations
@@ -22,6 +34,7 @@ from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.sharding import PartitionSpec as P
 
+from tpuframe.ops import dispatch
 from tpuframe.ops.dispatch import batch_sharding_info, resolve_interpret
 from tpuframe.ops.ledger import norm_tile_rows, shape_class
 
@@ -43,6 +56,23 @@ def normalize_images_reference(
     std = jnp.asarray(std, jnp.float32)
     x = images.astype(jnp.float32) * scale
     return ((x - mean) / std).astype(out_dtype)
+
+
+def _flat_view_is_free(shape: tuple) -> bool:
+    """Is ``reshape(-1, 128)`` a bitcast on the chip?  The TPU tiles the
+    two minor dimensions, so only a 1-D array or one whose last dimension
+    is whole 128-lane rows lies in memory as the flat stream the kernel
+    reads; any other shape (every NHWC image batch) is re-laid-out."""
+    return len(shape) <= 1 or shape[-1] % _LANES == 0
+
+
+def _normalize_in_layout(images, weights, biases, out_dtype):
+    """The kernel's arithmetic on the array as it is shaped: float32
+    ``x * w[c] + b[c]``, one rounding.  Elementwise, so XLA fuses it into
+    one pass and GSPMD shards it without a ``shard_map``."""
+    w = jnp.asarray(weights, jnp.float32)
+    b = jnp.asarray(biases, jnp.float32)
+    return (images.astype(jnp.float32) * w + b).astype(out_dtype)
 
 
 def _kernel(x_ref, out_ref, *, weights, biases, n_channels, block_elems):
@@ -111,8 +141,11 @@ def normalize_images(
 ) -> jax.Array:
     """Fused ``(images * scale - mean) / std``; channels on the last axis.
 
-    ``interpret``: None = auto (compiled kernel on TPU, jnp reference
-    elsewhere); True = run the kernel in interpreter mode (tests).
+    ``interpret``: None = auto (on TPU the compiled kernel, or, for an
+    input whose flat view is not free there, which every image batch is,
+    :func:`_normalize_in_layout` with one ``ops/kernel_verdict`` event of
+    ``source="layout"``; the jnp reference elsewhere); True/False = run
+    the kernel, interpreted (tests) or compiled, whatever the shape.
 
     ``mesh`` + ``batch_axes`` run the kernel per batch shard under
     ``shard_map`` for multi-chip use.  Sharding splits the *leading*
@@ -127,17 +160,24 @@ def normalize_images(
         raise ValueError(
             f"mean/std length {len(mean)}/{len(std)} != channels {n_channels}"
         )
+    weights = tuple(scale / s for s in std)
+    biases = tuple(-m / s for m, s in zip(mean, std))
+    shape_cls = shape_class(n=images.size)
+    if (interpret is None and dispatch.pallas_mode() is not None
+            and not _flat_view_is_free(images.shape)):
+        # decided before the ledger and TPUFRAME_KERNELS are asked: they
+        # price the kernel standing alone, not the layout changes it forces
+        dispatch._emit_verdict(
+            "normalize", shape_cls, enable=False, source="layout")
+        return _normalize_in_layout(images, weights, biases, out_dtype)
     axes, n_shards, shardable = batch_sharding_info(
         mesh, batch_axes, images.shape[0] if images.ndim >= 2 else 0
     )
     interpret = resolve_interpret(
-        interpret, shardable, op="normalize",
-        shape_class=shape_class(n=images.size),
+        interpret, shardable, op="normalize", shape_class=shape_cls,
     )
     if interpret is None:
         return normalize_images_reference(images, mean, std, scale, out_dtype)
-    weights = tuple(scale / s for s in std)
-    biases = tuple(-m / s for m, s in zip(mean, std))
 
     def run(x):
         out = _pallas_normalize(

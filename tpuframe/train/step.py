@@ -75,8 +75,9 @@ def _supports_mutable(apply_fn) -> bool:
 
 
 def _named_transform(batch_transform: Callable[[dict], dict] | None):
-    """The on-device input transform (the Trainer's: the normalize kernel
-    and the layout changes around it) under ``tpuframe/input_normalize``."""
+    """The on-device input transform (the Trainer's: the image normalize,
+    one fusion in front of the first convolution) under
+    ``tpuframe/input_normalize``."""
     if batch_transform is None:
         return None
     return jax.named_scope("tpuframe/input_normalize")(

@@ -396,7 +396,9 @@ class Trainer:
         self.grad_accum = grad_accum
         # ``normalize=(mean, std[, scale])``: images cross host->HBM raw
         # (uint8 = 4x less PCIe traffic than f32) and are normalized
-        # *inside* the jitted step by the fused Pallas kernel — the
+        # *inside* the jitted step by ``ops.normalize_images``, which
+        # keeps an image batch in its own layout (one XLA fusion that
+        # feeds the first convolution; no kernel) — the
         # reference's host-side ToTensor+Normalize
         # (`utils/hf_dataset_utilities.py:70-80`) with the same
         # convention: inputs in 0-255 (uint8 or float — algorithms like
@@ -424,11 +426,11 @@ class Trainer:
 
         train_transform = eval_transform = None
         if normalize is not None:
-            # the mesh-sharded kernel matches the plain (B, ...) layout;
-            # grad-accum train batches are (n_micro, micro, ...) and are
-            # normalized per microbatch inside the scan (mesh=None there —
-            # XLA shards + fuses the jnp path natively).  Eval batches are
-            # never microbatched, so eval always keeps the kernel path.
+            # the mesh is for ``normalize_images``' kernel form (per shard
+            # over the plain (B, ...) layout), which no image batch takes:
+            # NHWC input is elementwise jnp that GSPMD shards natively.
+            # Grad-accum train batches are (n_micro, micro, ...) and are
+            # normalized per microbatch inside the scan (mesh=None there).
             def train_transform(batch: dict) -> dict:
                 mesh = self.plan.mesh if self.grad_accum == 1 else None
                 batch["image"] = image_transform(batch["image"], mesh)
