@@ -1,0 +1,315 @@
+"""Latent attention, YaRN rotary, the no-drop expert layer with held and
+shared experts, and ``ops.grouped_matmul``: the program against the plain
+reference of ``chipbench/reference/deepseek-v2-lite.py`` and against
+hand-computed values, at small sizes on the CPU."""
+
+import json
+import math
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench import correct
+from tpuframe.models import TransformerLM
+from tpuframe.models import transformer as tr
+from tpuframe.models.moe import MoEMLP
+from tpuframe.ops.blockwise_attention import blockwise_attention
+from tpuframe.ops.grouped_matmul import (
+    grouped_matmul,
+    grouped_matmul_reference,
+    tiles_visited,
+)
+from tpuframe.ops.ring_attention import attention_reference
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _merge(base, over):
+    out = dict(base)
+    for k, v in over.items():
+        out[k] = _merge(out[k], v) if isinstance(v, dict) and isinstance(out.get(k), dict) else v
+    return out
+
+
+@pytest.fixture(scope="module")
+def small():
+    """The configuration's rehearsal sizes (1 dense + 2 sparse layers, 8
+    experts of which 4 held), its reference, seeded weights and a batch."""
+    with open(os.path.join(ROOT, "chipbench", "configs", "deepseek-v2-lite.json")) as f:
+        full = json.load(f)
+    cfg = _merge(full, full["rehearsal"])
+    ref = correct.load_by_name("reference", cfg["name"])
+    params = correct.init_params(ref.param_shapes(cfg), 2147483999)
+    rng = np.random.default_rng(5)
+    rows = rng.integers(0, cfg["vocab_size"], (2, cfg["seq_len"] + 1))
+    return {"full": full, "cfg": cfg, "ref": ref, "params": params,
+            "x": jnp.asarray(rows[:, :-1], jnp.int32), "y": jnp.asarray(rows[:, 1:], jnp.int32),
+            "model": TransformerLM(**cfg["model"]["kwargs"])}
+
+
+def _program_losses(model, params, x, y):
+    logits, upd = model.apply({"params": params}, x, train=True,
+                              mutable=["aux_loss", "counters", "gauges"])
+    logp = jax.nn.log_softmax(logits, -1)
+    ce = -jnp.mean(jnp.take_along_axis(logp, y[..., None], -1))
+    aux = sum(jnp.sum(a) for a in jax.tree.leaves(upd["aux_loss"]))
+    return ce, aux, upd
+
+
+class TestProgramAgainstReference:
+    def test_parameter_tree_is_the_references(self, small):
+        got = jax.eval_shape(lambda: small["model"].init(jax.random.PRNGKey(0), small["x"]))
+        got = jax.tree.map(lambda a: tuple(a.shape), got["params"])
+        assert got == jax.tree.map(lambda a: tuple(a.shape), small["params"])
+
+    def test_loss_and_every_gradient_leaf(self, small):
+        m, p, x, y = small["model"], small["params"], small["x"], small["y"]
+
+        def objective(params):
+            ce, aux, _ = _program_losses(m, params, x, y)
+            return ce + aux, ce
+
+        (_, ce), grads = jax.value_and_grad(objective, has_aux=True)(p)
+        want_loss, want = jax.value_and_grad(small["ref"].loss)(p, x, y, small["cfg"])
+        assert abs(float(ce) - float(want_loss)) < 1e-5
+        assert float(want_loss) == pytest.approx(math.log(small["cfg"]["vocab_size"]), abs=0.2)
+        for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
+                                jax.tree.leaves(want)):
+            err = float(jnp.linalg.norm(g - w) / (jnp.linalg.norm(w) + 1e-12))
+            assert err < 2e-4, (jax.tree_util.keystr(path), err)
+
+    def test_balance_loss_and_its_gradient_on_the_router(self, small):
+        m, p, x, y = small["model"], small["params"], small["x"], small["y"]
+        aux, g = jax.value_and_grad(lambda q: _program_losses(m, q, x, y)[1])(p)
+        want, gw = jax.value_and_grad(
+            lambda q: small["ref"].logits(q, x, small["cfg"])[1])(p)
+        assert float(aux) == pytest.approx(float(want), rel=1e-5) and float(aux) > 0
+        for i in (1, 2):
+            a = g[f"block{i}"]["moe"]["router"]["kernel"]
+            b = gw[f"block{i}"]["moe"]["router"]["kernel"]
+            assert float(jnp.linalg.norm(b)) > 0
+            assert float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b)) < 2e-4
+
+    def test_counters_say_how_many_assignments_were_here(self, small):
+        m, p, x, y = small["model"], small["params"], small["x"], small["y"]
+        cfg = small["cfg"]
+        _, _, upd = _program_losses(m, p, x, y)
+        pairs = x.size * cfg["num_experts_per_tok"]
+        for i in (1, 2):
+            c = upd["counters"][f"block{i}"]["moe"]
+            here = float(c["moe/assignments_here"])
+            assert 0 < here < pairs
+            assert float(c["moe/rows_computed"]) >= here
+            assert float(upd["gauges"][f"block{i}"]["moe"]["moe/expert_load_max_over_mean"]) >= 1.0
+        assert "block0" not in upd["counters"]  # the leading dense layer
+
+    def test_an_expert_left_out_is_seen(self, small):
+        p = jax.tree.map(lambda a: a, small["params"])
+        moe = dict(p["block1"]["moe"])
+        moe["w_out"] = moe["w_out"].at[1].set(0.0)
+        p = {**p, "block1": {**p["block1"], "moe": moe}}
+        ce, _, _ = _program_losses(small["model"], p, small["x"], small["y"])
+        want = small["ref"].loss(small["params"], small["x"], small["y"], small["cfg"])
+        assert abs(float(ce) - float(want)) > 1e-6
+
+
+class TestSharesAddUpToTheUncutLayer:
+    def test_routed_parts_of_all_shares_plus_shared_once(self, small):
+        cfg, ref = small["cfg"], small["ref"]
+        uncut = {**cfg, "n_routed_experts": 8, "held_first": 0}
+        d, e, h = cfg["hidden_size"], 8, cfg["moe_intermediate_size"]
+        k = jax.random.split(jax.random.PRNGKey(3), 8)
+        n = lambda key, *s: 0.2 * jax.random.normal(key, s, jnp.float32)  # noqa: E731
+        p = {"router": {"kernel": n(k[0], d, e)},
+             "w_gate": n(k[1], e, d, h), "w_in": n(k[2], e, d, h), "w_out": n(k[3], e, h, d),
+             "shared_gate": {"kernel": n(k[4], d, 2 * h)}, "shared_in": {"kernel": n(k[5], d, 2 * h)},
+             "shared_out": {"kernel": n(k[6], 2 * h, d)}}
+        x = jax.random.normal(k[7], (2, 16, d), jnp.float32)
+        want, _ = ref._moe(p, x, uncut, lambda f: f, False)
+        shared = ref._gated(x, p["shared_gate"]["kernel"], p["shared_in"]["kernel"],
+                            p["shared_out"]["kernel"], lambda f: f)
+        total = shared
+        for first in range(0, e, 2):   # four chips, two experts each
+            layer = MoEMLP(num_experts=e, top_k=cfg["num_experts_per_tok"], expert_dim=h,
+                           held=(first, 2), gated=True, shared_dim=2 * h, renormalize=False,
+                           seq_aux=True, capacity_factor=None)
+            share = {**p, **{w: p[w][first:first + 2] for w in ("w_gate", "w_in", "w_out")}}
+            out = layer.apply({"params": share}, x)
+            total = total + (out - shared)
+        np.testing.assert_allclose(np.asarray(total), np.asarray(want), rtol=2e-5, atol=2e-6)
+
+
+class TestNoTokenDropped:
+    def _layer(self, held):
+        return MoEMLP(num_experts=8, top_k=1, expert_dim=8, held=held, gated=True,
+                      renormalize=False, capacity_factor=None)
+
+    def _params(self, d=4):
+        k = jax.random.split(jax.random.PRNGKey(0), 3)
+        router = jnp.zeros((d, 8)).at[:, 0].set(5.0)   # positive inputs all choose expert 0
+        return {"router": {"kernel": router},
+                "w_gate": jax.random.normal(k[0], (2, d, 8)), "w_in": jax.random.normal(k[1], (2, d, 8)),
+                "w_out": jax.random.normal(k[2], (2, 8, d))}
+
+    def test_every_token_chooses_one_held_expert(self):
+        p = self._params()
+        x = jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (3, 10, 4))) + 0.1
+        out, upd = self._layer((0, 2)).apply({"params": p}, x, mutable=["counters", "gauges", "aux_loss"])
+        probs = jax.nn.softmax(x @ p["router"]["kernel"], -1)[..., 0:1]
+        dense = (jax.nn.silu(x @ p["w_gate"][0]) * (x @ p["w_in"][0])) @ p["w_out"][0]
+        np.testing.assert_allclose(np.asarray(out), np.asarray(probs * dense), rtol=1e-5, atol=1e-6)
+        assert float(upd["counters"]["moe/assignments_here"]) == 30.0   # 30 of 30: far past capacity 1.25
+        assert float(upd["gauges"]["moe/expert_load_max_over_mean"]) == 2.0
+
+    def test_no_token_chooses_a_held_expert(self):
+        x = jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (3, 10, 4))) + 0.1
+        out, upd = self._layer((4, 2)).apply({"params": self._params()}, x,
+                                             mutable=["counters", "gauges", "aux_loss"])
+        assert float(jnp.max(jnp.abs(out))) == 0.0
+        assert float(upd["counters"]["moe/assignments_here"]) == 0.0
+        assert float(upd["counters"]["moe/rows_computed"]) == 0.0
+
+    def test_held_experts_need_the_no_drop_layer(self):
+        with pytest.raises(ValueError, match="no-drop"):
+            MoEMLP(num_experts=8, held=(0, 2)).init(jax.random.PRNGKey(0), jnp.ones((1, 4, 4)))
+
+
+class TestGroupedMatmul:
+    @pytest.mark.parametrize("sizes", [(3, 0, 5, 0), (0, 0, 0, 0), (16, 0, 0, 0), (1, 2, 3, 4)])
+    def test_forward_and_both_gradients_with_empty_groups(self, sizes):
+        k = jax.random.split(jax.random.PRNGKey(7), 2)
+        rows = jax.random.normal(k[0], (16, 6), jnp.float32)
+        w = jax.random.normal(k[1], (4, 6, 5), jnp.float32)
+        g = jnp.asarray(sizes, jnp.int32)
+        np.testing.assert_allclose(np.asarray(grouped_matmul(rows, w, g)),
+                                   np.asarray(grouped_matmul_reference(rows, w, g)),
+                                   rtol=1e-5, atol=1e-6)
+        loss = lambda f: lambda r, m: jnp.sum(jnp.sin(f(r, m, g)))  # noqa: E731
+        got = jax.grad(loss(grouped_matmul), argnums=(0, 1))(rows, w)
+        want = jax.grad(loss(grouped_matmul_reference), argnums=(0, 1))(rows, w)
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
+        # rows past the groups come out zero, an empty group's weight gets no gradient
+        assert float(jnp.max(jnp.abs(grouped_matmul(rows, w, g)[sum(sizes):]), initial=0.0)) == 0.0
+        for i, s in enumerate(sizes):
+            if s == 0:
+                assert float(jnp.max(jnp.abs(got[1][i]))) == 0.0
+
+    def test_tiles_visited_counts_a_shared_tile_for_each_group(self):
+        sizes = jnp.asarray([768, 0, 300, 1], jnp.int32)
+        # [0,768): tiles 0,1; [768,1068): tiles 1,2; [1068,1069): tile 2
+        assert int(tiles_visited(sizes, 512)) == 2 + 0 + 2 + 1
+
+    def test_shapes_are_checked(self):
+        with pytest.raises(ValueError):
+            grouped_matmul(jnp.ones((4, 3)), jnp.ones((2, 4, 5)), jnp.ones((2,), jnp.int32))
+
+
+class TestYarn:
+    """Against values computed by hand from the source's rope_scaling:
+    width 64, theta 10000, factor 40 over 4096, beta 32 / 1, mscale 0.707."""
+
+    SCALING = {"factor": 40, "original_max_position_embeddings": 4096, "beta_fast": 32,
+               "beta_slow": 1, "mscale": 0.707, "mscale_all_dim": 0.707}
+
+    def test_correction_range(self):
+        # 64 ln(4096 / (2 pi 32)) / (2 ln 1e4) = 10.47 -> 10; with 1 turn: 22.51 -> 23
+        assert tr.yarn_correction_range(64, 10000.0, 4096, 32, 1) == (10, 23)
+
+    def test_inverse_frequencies(self):
+        f = tr.rope_inv_freq(64, 10000.0, self.SCALING)
+        assert f.shape == (32,)
+        assert f[0] == pytest.approx(1.0)                       # below the range: as published
+        assert f[10] == pytest.approx(10000.0 ** (-20 / 64))
+        assert f[16] == pytest.approx(0.0055, rel=1e-6)         # ramp 6/13 between f/40 and f
+        assert f[31] == pytest.approx(10000.0 ** (-62 / 64) / 40)
+        assert np.allclose(tr.rope_inv_freq(64, 10000.0), 10000.0 ** (-np.arange(32) / 32))
+
+    def test_temperature_and_softmax_scale(self):
+        assert tr.yarn_mscale(40, 0.707) == pytest.approx(1.260804, rel=1e-6)
+        assert tr.yarn_mscale(1, 0.707) == 1.0
+        lm = TransformerLM(vocab_size=8, head_dim=128, rope_dim=64, kv_lora_rank=512,
+                           rope_scaling=dict(self.SCALING))
+        assert lm.attn_scale() == pytest.approx(192 ** -0.5 * 1.260804 ** 2, rel=1e-6)
+        assert TransformerLM(vocab_size=8).attn_scale() is None
+        cos, sin = tr.rope_tables(8, 64, 10000.0, self.SCALING)
+        assert cos.shape == (8, 64) and float(cos[0, 0]) == 1.0 and float(sin[0, 5]) == 0.0
+
+    def test_the_reference_computes_the_same(self, small):
+        ref, full = small["ref"], small["full"]
+        np.testing.assert_allclose(ref.yarn_inv_freq(full), tr.rope_inv_freq(64, 10000.0, self.SCALING))
+        assert ref.softmax_scale(full) == pytest.approx(192 ** -0.5 * 1.260804 ** 2, rel=1e-6)
+
+    def test_rotation_keeps_norms_and_depends_on_distance_only(self):
+        cos, sin = tr.rope_tables(16, 8, 10000.0)
+        q = jax.random.normal(jax.random.PRNGKey(0), (1, 1, 1, 8))
+        q = jnp.broadcast_to(q, (1, 16, 1, 8))
+        r = tr.apply_rope(q, cos, sin)
+        np.testing.assert_allclose(np.linalg.norm(r, axis=-1), np.linalg.norm(q, axis=-1), rtol=1e-5)
+        dots = jnp.einsum("ld,md->lm", r[0, :, 0], r[0, :, 0])
+        np.testing.assert_allclose(np.asarray(dots[2, 5]), np.asarray(dots[7, 10]), rtol=1e-4)
+
+
+class TestWidenedAttention:
+    @pytest.mark.parametrize("causal", [True, False])
+    def test_full_against_blockwise_with_scale_and_a_value_width(self, causal):
+        k = jax.random.split(jax.random.PRNGKey(2), 3)
+        q = jax.random.normal(k[0], (2, 40, 3, 12))
+        kk = jax.random.normal(k[1], (2, 40, 3, 12))
+        v = jax.random.normal(k[2], (2, 40, 3, 8))
+        full = lambda q, k, v: attention_reference(q, k, v, causal=causal, scale=0.31)  # noqa: E731
+        blk = lambda q, k, v: blockwise_attention(  # noqa: E731
+            q, k, v, causal=causal, block_size=16, scale=0.31)
+        assert blk(q, kk, v).shape == (2, 40, 3, 8)
+        np.testing.assert_allclose(np.asarray(blk(q, kk, v)), np.asarray(full(q, kk, v)),
+                                   rtol=2e-5, atol=2e-6)
+        loss = lambda f: lambda *a: jnp.sum(f(*a) ** 2)  # noqa: E731
+        for a, b in zip(jax.grad(loss(blk), argnums=(0, 1, 2))(q, kk, v),
+                        jax.grad(loss(full), argnums=(0, 1, 2))(q, kk, v)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5)
+
+    def test_the_default_scale_is_unchanged(self):
+        q = jax.random.normal(jax.random.PRNGKey(0), (1, 8, 2, 4))
+        a = attention_reference(q, q, q, causal=True)
+        b = attention_reference(q, q, q, causal=True, scale=0.5)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
+
+
+class TestModelStatsRideTheMetricsWindow:
+    def test_the_trainer_publishes_them_at_the_drain(self, small):
+        from tpuframe.data import DataLoader
+        from tpuframe.track.telemetry import get_telemetry
+        from tpuframe.train import Trainer
+
+        cfg = small["cfg"]
+        rng = np.random.default_rng(0)
+        rows = rng.integers(0, cfg["vocab_size"], (32, cfg["seq_len"] + 1)).astype(np.int32)
+
+        class Rows:
+            def __len__(self):
+                return len(rows)
+
+            def __getitem__(self, i):
+                return rows[i, :-1], rows[i, 1:]
+
+        reg = get_telemetry().registry
+        before = reg.counter("moe/assignments_here").value
+        trainer = Trainer(TransformerLM(**cfg["model"]["kwargs"]),
+                          train_dataloader=DataLoader(Rows(), batch_size=8, shuffle=False),
+                          optimizer="sgd", lr=1e-3, max_duration="4ba", log_interval=2,
+                          eval_interval=0, seed=0)
+        result = trainer.fit()
+        assert result.error is None
+        here = reg.counter("moe/assignments_here").value - before
+        pairs = 4 * 8 * cfg["seq_len"] * cfg["num_experts_per_tok"] * 2   # steps x rows x tokens x k x layers
+        assert 0 < here < pairs
+        assert reg.counter("moe/rows_computed").value >= here
+        assert reg.gauge("moe/expert_load_max_over_mean").value >= 1.0
+
+    def test_a_model_without_them_adds_no_leaf(self):
+        from tpuframe.train.step import _model_stats
+
+        assert _model_stats({"aux_loss": {"moe": jnp.ones(())}}) == {}

@@ -10,14 +10,18 @@ that imperative MoE frameworks hand-write.
 
 Components:
 - :class:`MoEMLP` — drop-in replacement for a transformer block's MLP:
-  top-k softmax gating, capacity-factor truncation, load-balancing aux
-  loss (Switch-style) exposed via the ``"aux_loss"`` mutable collection.
+  top-k softmax gating and a balance loss exposed via the ``"aux_loss"``
+  mutable collection; either capacity-factor truncation (GShard dense
+  dispatch) or, with ``capacity_factor=None``, no token dropped: sorted
+  assignments through ``ops.grouped_matmul``, the experts ``held`` here
+  out of all the router scores, and a shared MLP beside them.
 - :func:`moe_rules` — ParallelPlan rules placing expert weights on the
   ``expert`` axis (compose with the TP/fsdp rules).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import flax.linen as nn
@@ -26,6 +30,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from tpuframe.core.runtime import EXPERT_AXIS
+from tpuframe.ops.grouped_matmul import TILE_ROWS, grouped_matmul, tiles_visited
 from tpuframe.ops.moe_gating import moe_dispatch_combine
 
 
@@ -36,26 +41,100 @@ def moe_rules():
     )
 
 
+def _sum_choices_impl(rows, inv, n):
+    return rows[inv].reshape(n, -1, rows.shape[-1]).sum(axis=1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_tokens(tokens, tok, inv, n):
+    """``rows[a] = tokens[tok[a]]`` for the sorted assignments.  Its
+    transpose is written as a gather too (un-sort by ``inv``, then sum
+    each token's choices): the scatter-add autodiff would emit is the
+    slow form on the TPU."""
+    return tokens[tok]
+
+
+def _take_tokens_fwd(tokens, tok, inv, n):
+    return tokens[tok], (tok, inv)
+
+
+def _take_tokens_bwd(n, res, g):
+    tok, inv = res
+    return _sum_choices(g, tok, inv, n), None, None
+
+
+_take_tokens.defvjp(_take_tokens_fwd, _take_tokens_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _sum_choices(rows, tok, inv, n):
+    """``out[t] = sum of the rows assigned from token t``: the transpose
+    of :func:`_take_tokens`, again a gather."""
+    return _sum_choices_impl(rows, inv, n)
+
+
+def _sum_choices_fwd(rows, tok, inv, n):
+    return _sum_choices_impl(rows, inv, n), (tok, inv)
+
+
+def _sum_choices_bwd(n, res, g):
+    tok, inv = res
+    return _take_tokens(g, tok, inv, n), None, None
+
+
+_sum_choices.defvjp(_sum_choices_fwd, _sum_choices_bwd)
+
+
 class MoEMLP(nn.Module):
-    """Top-k gated mixture of expert MLPs (dense dispatch).
+    """Top-k gated mixture of expert MLPs: the one expert layer.
 
     Args:
-      num_experts: E.
-      mlp_ratio: hidden = d_model * mlp_ratio per expert.
+      num_experts: E, the router's width.
+      mlp_ratio / expert_dim: an expert's hidden width
+        (``expert_dim`` or ``d_model * mlp_ratio``).
       top_k: experts per token (1 = Switch, 2 = GShard default).
       capacity_factor: per-expert slots = ceil(top_k * N / E * factor);
         overflow tokens are dropped (their combine weight is zero), the
-        standard Switch behavior.
-      aux_loss_weight: weight of the load-balancing loss, stored in the
+        standard Switch behavior, through ``ops.moe_dispatch_combine``.
+        ``None`` drops nothing: assignments are sorted by expert into a
+        buffer with a slot for every (token, choice) pair and the expert
+        matmuls are ``ops.grouped_matmul`` over whatever group sizes the
+        router made.
+      held: ``(first, count)``: the experts this layer holds out of
+        ``num_experts`` (expert parallelism's share; ``None`` = all).
+        Every token is still routed over all E; the layer computes
+        ``sum p_e E_e(x)`` over the held experts only, and what the
+        others would add is left out.  Needs ``capacity_factor=None``.
+      gated: experts (and the shared MLP) are SiLU-gated,
+        ``(silu(x W_gate) * (x W_in)) W_out``; else GELU, no gate.
+      shared_dim: > 0 adds a shared MLP of this width every token passes
+        through (shared experts, built as one MLP).
+      renormalize: chosen gates are rescaled to sum to 1 (GShard);
+        False keeps the router's probabilities as they are.
+      seq_aux: the balance loss is taken per sequence over all top-k
+        choices (``f_be = count * E / (k T)``, ``P_be = mean_t p``,
+        ``mean_b sum_e f P``); else Switch's top-1 form over the batch.
+      aux_loss_weight: weight of the balance loss, stored in the
         ``aux_loss`` mutable collection for the train step to pick up.
+
+    With ``capacity_factor=None`` the layer also sows, for the step's
+    metrics window, the counters ``moe/assignments_here`` and
+    ``moe/rows_computed`` and the gauge
+    ``moe/expert_load_max_over_mean`` (OBSERVABILITY.md).
     """
 
     num_experts: int = 8
     mlp_ratio: int = 4
     top_k: int = 2
-    capacity_factor: float = 1.25
+    capacity_factor: float | None = 1.25
     aux_loss_weight: float = 1e-2
     dtype: Any = jnp.float32
+    expert_dim: int = 0
+    held: tuple | None = None
+    gated: bool = False
+    shared_dim: int = 0
+    renormalize: bool = True
+    seq_aux: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
@@ -66,46 +145,116 @@ class MoEMLP(nn.Module):
         tokens = x.reshape(n, d)
         e = self.num_experts
         k = min(self.top_k, e)
-        capacity = max(1, int(-(-(k * n) // e) * self.capacity_factor))
+        first, count = self.held if self.held is not None else (0, e)
+        if self.held is not None and self.capacity_factor is not None:
+            raise ValueError("held experts need the no-drop layer "
+                             "(capacity_factor=None)")
+        if not (0 <= first and first + count <= e and count > 0):
+            raise ValueError(f"held={self.held} does not lie in {e} experts")
 
         # --- routing ----------------------------------------------------
-        logits = nn.Dense(
-            e, use_bias=False, dtype=jnp.float32, name="router"
-        )(tokens.astype(jnp.float32))
-        probs = jax.nn.softmax(logits, axis=-1)  # (N, E)
+        with jax.named_scope("tpuframe/moe/route"):
+            logits = nn.Dense(
+                e, use_bias=False, dtype=jnp.float32, name="router"
+            )(tokens.astype(jnp.float32))
+            probs = jax.nn.softmax(logits, axis=-1)  # (N, E)
+            # top-k expert choices per token
+            gate_vals, gate_idx = jax.lax.top_k(probs, k)  # (N, k)
+            if self.renormalize:
+                # chosen gates sum to 1 (GShard convention)
+                gate_vals = gate_vals / jnp.maximum(
+                    jnp.sum(gate_vals, -1, keepdims=True), 1e-9
+                )
 
-        # top-k expert choices per token
-        gate_vals, gate_idx = jax.lax.top_k(probs, k)  # (N, k)
-        # renormalize chosen gates to sum 1 (GShard convention)
-        gate_vals = gate_vals / jnp.maximum(
-            jnp.sum(gate_vals, -1, keepdims=True), 1e-9
-        )
+        h = self.expert_dim or d * self.mlp_ratio
+        init = nn.initializers.lecun_normal()
+        # float32 master weights like every other layer's (the policy casts
+        # them for compute); the products run in the layer's dtype
+        w_gate = (self.param("w_gate", init, (count, d, h)).astype(self.dtype)
+                  if self.gated else None)
+        w_in = self.param("w_in", init, (count, d, h)).astype(self.dtype)
+        w_out = self.param("w_out", init, (count, h, d)).astype(self.dtype)
+        act = nn.silu if self.gated else nn.gelu
 
-        # --- dispatch / expert MLPs / combine ----------------------------
-        # tpuframe.ops.moe_gating owns the mechanics: the fused path
-        # scatter-adds kept tokens straight into the (E, C, D) expert
-        # buffers (no (kN, E, C) one-hot tensor), the dense-einsum
-        # reference is the oracle, and the kernel ledger decides which
-        # runs (TPUFRAME_KERNELS / a priced per-shape verdict).
-        h = d * self.mlp_ratio
-        w_in = self.param(
-            "w_in", nn.initializers.lecun_normal(), (e, d, h), self.dtype
-        )
-        w_out = self.param(
-            "w_out", nn.initializers.lecun_normal(), (e, h, d), self.dtype
-        )
-        out = moe_dispatch_combine(
-            tokens, gate_vals, gate_idx, w_in, w_out,
-            capacity=capacity, act=nn.gelu,
-        )
+        if self.capacity_factor is not None:
+            # --- dispatch / expert MLPs / combine, capacity-truncated ----
+            # tpuframe.ops.moe_gating owns the mechanics: the fused path
+            # scatter-adds kept tokens straight into the (E, C, D) expert
+            # buffers (no (kN, E, C) one-hot tensor), the dense-einsum
+            # reference is the oracle, and the kernel ledger decides which
+            # runs (TPUFRAME_KERNELS / a priced per-shape verdict).
+            if self.gated:
+                raise ValueError("gated experts run in the no-drop layer "
+                                 "(capacity_factor=None)")
+            capacity = max(1, int(-(-(k * n) // e) * self.capacity_factor))
+            out = moe_dispatch_combine(
+                tokens, gate_vals, gate_idx, w_in, w_out,
+                capacity=capacity, act=act,
+            )
+        else:
+            out = self._no_drop(tokens, gate_vals, gate_idx, first, count,
+                                w_gate, w_in, w_out, act)
 
-        # --- load-balance aux loss (Switch eq. 4) ------------------------
-        # fraction of tokens routed to each expert (by top-1 choice) x
-        # mean router prob; scaled by E so balanced = 1.0
-        top1 = jax.nn.one_hot(gate_idx[:, 0], e, dtype=jnp.float32)
-        aux = jnp.sum(
-            jnp.mean(top1, axis=0) * jnp.mean(probs, axis=0)
-        ) * e * self.aux_loss_weight
+        if self.shared_dim:
+            with jax.named_scope("tpuframe/moe/shared"):
+                dense = lambda m, name: nn.Dense(  # noqa: E731
+                    m, use_bias=not self.gated, dtype=self.dtype, name=name
+                )
+                t = tokens.astype(self.dtype)
+                hid = dense(self.shared_dim, "shared_in")(t)
+                hid = (act(dense(self.shared_dim, "shared_gate")(t)) * hid
+                       if self.gated else act(hid))
+                out = out + dense(d, "shared_out")(hid).astype(out.dtype)
+
+        # --- load-balance aux loss ---------------------------------------
+        if self.seq_aux:
+            # per sequence, over all k choices (DeepSeek-V2 eq. 12-14)
+            b = lead[0] if len(lead) > 1 else 1
+            chosen = jnp.sum(jax.nn.one_hot(gate_idx, e, dtype=jnp.float32), axis=1)
+            f = jnp.sum(chosen.reshape(b, -1, e), axis=1) * (e / (k * (n // b)))
+            p_mean = jnp.mean(probs.reshape(b, -1, e), axis=1)
+            aux = jnp.mean(jnp.sum(f * p_mean, axis=-1)) * self.aux_loss_weight
+        else:
+            # Switch eq. 4: fraction of tokens routed to each expert (by
+            # top-1 choice) x mean router prob; scaled by E so balanced = 1.0
+            top1 = jax.nn.one_hot(gate_idx[:, 0], e, dtype=jnp.float32)
+            aux = jnp.sum(
+                jnp.mean(top1, axis=0) * jnp.mean(probs, axis=0)
+            ) * e * self.aux_loss_weight
         self.sow("aux_loss", "moe", aux)
 
         return out.reshape(*lead, d).astype(x.dtype)
+
+    def _no_drop(self, tokens, gate_vals, gate_idx, first, count,
+                 w_gate, w_in, w_out, act):
+        """``sum p_e E_e(x)`` over the held experts, no token dropped."""
+        n, k = gate_idx.shape
+        with jax.named_scope("tpuframe/moe/route"):
+            # one slot for every (token, choice) pair; the pairs routed
+            # to held experts sort to the front, grouped by expert
+            local = gate_idx.reshape(-1) - first
+            here = (local >= 0) & (local < count)
+            key = jnp.where(here, local, count)
+            order = jnp.argsort(key, stable=True)
+            inv = jnp.argsort(order)
+            sizes = jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32)
+            tok = order // k
+            weight = (gate_vals.reshape(-1) * here)[order]
+        with jax.named_scope("tpuframe/moe/experts"):
+            rows = _take_tokens(tokens.astype(self.dtype), tok, inv, n)
+            hid = grouped_matmul(rows, w_in, sizes)
+            hid = (act(grouped_matmul(rows, w_gate, sizes)) * hid
+                   if w_gate is not None else act(hid))
+            y = grouped_matmul(hid, w_out, sizes)
+            out = _sum_choices(y * weight[:, None].astype(y.dtype), tok, inv, n)
+        f32 = jnp.float32
+        load = sizes.astype(f32)
+        self.sow("counters", "moe/assignments_here", jnp.sum(load),
+                 reduce_fn=lambda a, b: b, init_fn=lambda: f32(0))
+        self.sow("counters", "moe/rows_computed",
+                 (tiles_visited(sizes) * TILE_ROWS).astype(f32),
+                 reduce_fn=lambda a, b: b, init_fn=lambda: f32(0))
+        self.sow("gauges", "moe/expert_load_max_over_mean",
+                 jnp.max(load) / jnp.maximum(jnp.mean(load), 1.0),
+                 reduce_fn=lambda a, b: b, init_fn=lambda: f32(0))
+        return out

@@ -18,11 +18,13 @@ family is the workload that exercises the ``seq`` mesh axis.  Design:
 
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
@@ -63,6 +65,153 @@ def _mesh_or_none():
         return None
 
 
+class RMSNorm(nn.Module):
+    """``x * rsqrt(mean(x^2) + eps) * scale``, statistics in float32.
+
+    Plain jnp on purpose: XLA fuses it into its neighbours, and a kernel
+    of its own would cost layout copies around it (PERF.md, PR 25)."""
+
+    eps: float = 1e-6
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(
+            jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps
+        )
+        return (y * scale.astype(jnp.float32)).astype(self.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: ``0.1 * mscale * ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(dim: int, theta: float, original_len: int,
+                          beta_fast: float, beta_slow: float) -> tuple[int, int]:
+    """(low, high): the rotary pairs between which YaRN blends scaled and
+    unscaled frequencies; ``dim_of(n)`` is the pair that turns ``n`` times
+    over the original context."""
+    def dim_of(n):
+        return dim * math.log(original_len / (2 * math.pi * n)) / (2 * math.log(theta))
+
+    return (max(math.floor(dim_of(beta_fast)), 0),
+            min(math.ceil(dim_of(beta_slow)), dim - 1))
+
+
+def rope_inv_freq(dim: int, theta: float, scaling: dict | None = None) -> np.ndarray:
+    """(dim/2,) inverse frequencies; ``scaling`` (a config's
+    ``rope_scaling`` of type yarn) blends ``f/factor`` into ``f`` over the
+    correction range."""
+    f = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if not scaling:
+        return f
+    low, high = yarn_correction_range(
+        dim, theta, scaling["original_max_position_embeddings"],
+        scaling["beta_fast"], scaling["beta_slow"])
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return f / scaling["factor"] * ramp + f * (1 - ramp)
+
+
+def rope_tables(length: int, dim: int, theta: float,
+                scaling: dict | None = None) -> tuple[jax.Array, jax.Array]:
+    """(cos, sin), each (length, dim) float32, rotate-half convention.
+    YaRN's factor on the tables, mscale / mscale_all_dim, is applied."""
+    ang = np.arange(length, dtype=np.float64)[:, None] * rope_inv_freq(dim, theta, scaling)
+    ang = np.concatenate([ang, ang], axis=-1)
+    m = 1.0
+    if scaling:
+        m = (yarn_mscale(scaling["factor"], scaling.get("mscale", 1.0))
+             / yarn_mscale(scaling["factor"], scaling.get("mscale_all_dim", 0.0)))
+    return (jnp.asarray(np.cos(ang) * m, jnp.float32),
+            jnp.asarray(np.sin(ang) * m, jnp.float32))
+
+
+def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """Rotate (B, L, H, dim) by position: ``x cos + rotate_half(x) sin``."""
+    half = x.shape[-1] // 2
+    x32 = x.astype(jnp.float32)
+    rot = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
+    return (x32 * cos[None, :, None, :] + rot * sin[None, :, None, :]).astype(x.dtype)
+
+
+def _attend(q, k, v, *, impl: str, causal: bool, num_heads: int,
+            initializing: bool, scale: float | None = None) -> jax.Array:
+    """The attention core every attention module dispatches to:
+    (B, L, H, D) q/k and (B, L, H, Dv) v -> (B, L, H, Dv) by ``impl``.
+    ``scale`` (None: ``1/sqrt(D)``) and a value width of its own are
+    taken by ``full`` and ``blockwise``; the sequence-sharded forms keep
+    one head width and the default scale."""
+    l = q.shape[1]
+    mesh = _mesh_or_none()
+    if initializing:
+        # init traces with a sample batch that need not divide the mesh;
+        # attention has no params, so the full path initializes
+        # identically to ring.
+        impl = "full"
+    elif impl == "auto":
+        seq_sharded = mesh is not None and mesh.shape.get(SEQUENCE_AXIS, 1) > 1
+        if seq_sharded:
+            impl = "ring"
+        else:
+            # measured first: the kernel ledger's priced verdict for
+            # this seq-length shape class (bench_attention persists
+            # them); the static memory-hazard heuristic is only the
+            # fallback when nothing has been measured here
+            from tpuframe.ops.ledger import attention_choice
+
+            impl = attention_choice(l)
+            if impl is None:
+                # long unsharded context: the (B,H,L,L) score matrix
+                # is the memory hazard; take the flash-style
+                # linear-memory path
+                impl = (
+                    "blockwise" if l >= _BLOCKWISE_AUTO_LEN else "full"
+                )
+    widened = {} if scale is None else {"scale": scale}
+    if impl in ("ring", "ulysses"):
+        if mesh is None:
+            raise ValueError(
+                f"attn_impl={impl!r} needs an initialized runtime mesh"
+            )
+        if widened or v.shape != q.shape:
+            raise ValueError(
+                f"attn_impl={impl!r} takes one head width and the default "
+                "scale; latent attention runs full or blockwise"
+            )
+        if impl == "ulysses":
+            # the all-to-all owns the head dim during attention, so no
+            # head_axis sharding here (TP composes via the projections)
+            local_fn = ulysses_attention_local
+            head_axis = None
+        else:
+            local_fn = ring_attention_local
+            head_axis = MODEL_AXIS if (
+                mesh.shape.get(MODEL_AXIS, 1) > 1
+                and num_heads % mesh.shape[MODEL_AXIS] == 0
+            ) else None
+        spec = P((DATA_AXIS, FSDP_AXIS), SEQUENCE_AXIS, head_axis, None)
+        return shard_map(
+            lambda q, k, v: local_fn(q, k, v, causal=causal),
+            mesh=mesh,
+            in_specs=(spec, spec, spec),
+            out_specs=spec,
+            check_vma=False,
+        )(q, k, v)
+    if impl == "blockwise":
+        from tpuframe.ops.blockwise_attention import blockwise_attention
+
+        return blockwise_attention(q, k, v, causal=causal, **widened)
+    if impl == "full":
+        return attention_reference(q, k, v, causal=causal, **widened)
+    raise ValueError(
+        f"unknown attn_impl {impl!r}; known: auto, full, ring, "
+        "ulysses, blockwise"
+    )
+
+
 class SelfAttention(nn.Module):
     """Causal multi-head self-attention with ring/full dispatch."""
 
@@ -92,80 +241,94 @@ class SelfAttention(nn.Module):
         k = dense("key")(x).reshape(heads)
         v = dense("value")(x).reshape(heads)
 
-        impl = self.attn_impl
-        mesh = _mesh_or_none()
-        if self.is_initializing():
-            # init traces with a sample batch that need not divide the mesh;
-            # attention has no params, so the full path initializes
-            # identically to ring.
-            impl = "full"
-        elif impl == "auto":
-            seq_sharded = mesh is not None and mesh.shape.get(SEQUENCE_AXIS, 1) > 1
-            if seq_sharded:
-                impl = "ring"
-            else:
-                # measured first: the kernel ledger's priced verdict for
-                # this seq-length shape class (bench_attention persists
-                # them); the static memory-hazard heuristic is only the
-                # fallback when nothing has been measured here
-                from tpuframe.ops.ledger import attention_choice
-
-                impl = attention_choice(l)
-                if impl is None:
-                    # long unsharded context: the (B,H,L,L) score matrix
-                    # is the memory hazard; take the flash-style
-                    # linear-memory path
-                    impl = (
-                        "blockwise" if l >= _BLOCKWISE_AUTO_LEN else "full"
-                    )
-        if impl in ("ring", "ulysses"):
-            if mesh is None:
-                raise ValueError(
-                    f"attn_impl={impl!r} needs an initialized runtime mesh"
-                )
-            if impl == "ulysses":
-                # the all-to-all owns the head dim during attention, so no
-                # head_axis sharding here (TP composes via the projections)
-                local_fn = ulysses_attention_local
-                head_axis = None
-            else:
-                local_fn = ring_attention_local
-                head_axis = MODEL_AXIS if (
-                    mesh.shape.get(MODEL_AXIS, 1) > 1
-                    and self.num_heads % mesh.shape[MODEL_AXIS] == 0
-                ) else None
-            spec = P((DATA_AXIS, FSDP_AXIS), SEQUENCE_AXIS, head_axis, None)
-            out = shard_map(
-                lambda q, k, v: local_fn(q, k, v, causal=self.causal),
-                mesh=mesh,
-                in_specs=(spec, spec, spec),
-                out_specs=spec,
-                check_vma=False,
-            )(q, k, v)
-        elif impl == "blockwise":
-            from tpuframe.ops.blockwise_attention import blockwise_attention
-
-            out = blockwise_attention(q, k, v, causal=self.causal)
-        elif impl == "full":
-            out = attention_reference(q, k, v, causal=self.causal)
-        else:
-            raise ValueError(
-                f"unknown attn_impl {impl!r}; known: auto, full, ring, "
-                "ulysses, blockwise"
-            )
+        out = _attend(
+            q, k, v, impl=self.attn_impl, causal=self.causal,
+            num_heads=self.num_heads, initializing=self.is_initializing(),
+        )
         out = out.reshape(b, l, features)
         return nn.Dense(
             x.shape[-1], use_bias=False, dtype=self.dtype, name="attn_out"
         )(out)
 
 
-class Block(nn.Module):
-    """Pre-norm transformer block: LN -> attn -> +res, LN -> MLP -> +res.
+class GatedMLP(nn.Module):
+    """``(silu(x W_gate) * (x W_in)) W_out``, no biases."""
 
-    ``moe_experts > 0`` replaces the dense MLP with a top-k gated
-    MoE (GShard pattern): expert weights shard over the ``expert`` mesh
-    axis via ``moe_rules`` and the router's load-balancing loss rides the
-    ``aux_loss`` collection into the train objective.
+    hidden: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, use_bias=False, dtype=self.dtype, name=name
+        )
+        h = nn.silu(dense(self.hidden, "gate")(x)) * dense(self.hidden, "in")(x)
+        return dense(x.shape[-1], "out")(h)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (MLA) without a query latent.
+
+    Keys and values come from one ``kv_lora_rank``-wide latent per token:
+    ``x W_kva -> [c | k_rope]``, ``RMSNorm(c) W_kvb ->`` per head
+    ``[k_nope | v]``.  Rotary positions turn ``q_rope`` of every head and
+    the one ``k_rope`` all heads share; queries and keys are
+    ``head_dim + rope_dim`` wide, values ``v_head_dim``, and the softmax
+    scale is the caller's (YaRN's temperature folded in).  The core is
+    :func:`_attend`, ``full`` or ``blockwise``.
+    """
+
+    num_heads: int
+    head_dim: int        # the part of a query/key head without position
+    rope_dim: int
+    v_head_dim: int
+    kv_lora_rank: int
+    scale: float
+    norm_eps: float = 1e-6
+    causal: bool = True
+    attn_impl: str = "auto"
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array, rope, train: bool = False) -> jax.Array:
+        h, dn, dr, dv = self.num_heads, self.head_dim, self.rope_dim, self.v_head_dim
+        b, l, _ = x.shape
+        cos, sin = rope
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, use_bias=False, dtype=self.dtype, name=name
+        )
+        with jax.named_scope("tpuframe/mla"):
+            q = dense(h * (dn + dr), "query")(x).reshape(b, l, h, dn + dr)
+            kva = dense(self.kv_lora_rank + dr, "kv_a")(x)
+            c = RMSNorm(eps=self.norm_eps, dtype=self.dtype, name="kv_norm")(
+                kva[..., :self.kv_lora_rank]
+            )
+            kvb = dense(h * (dn + dv), "kv_b")(c).reshape(b, l, h, dn + dv)
+            k_rope = apply_rope(kva[..., None, self.kv_lora_rank:], cos, sin)
+            q = jnp.concatenate(
+                [q[..., :dn], apply_rope(q[..., dn:], cos, sin)], axis=-1
+            )
+            k = jnp.concatenate(
+                [kvb[..., :dn], jnp.broadcast_to(k_rope, (b, l, h, dr))], axis=-1
+            )
+            out = _attend(
+                q, k, kvb[..., dn:], impl=self.attn_impl, causal=self.causal,
+                num_heads=h, initializing=self.is_initializing(),
+                scale=self.scale,
+            )
+            return dense(x.shape[-1], "attn_out")(out.reshape(b, l, h * dv))
+
+
+class Block(nn.Module):
+    """Pre-norm transformer block: norm -> attn -> +res, norm -> FFN -> +res.
+
+    ``moe_experts > 0`` replaces the dense MLP with the expert layer
+    (:class:`tpuframe.models.moe.MoEMLP`): expert weights shard over the
+    ``expert`` mesh axis via ``moe_rules`` and the router's balance loss
+    rides the ``aux_loss`` collection into the train objective.
+    ``norm="rms"``, ``kv_lora_rank > 0`` (latent attention, rotary
+    positions given as ``rope``) and ``mlp_gated`` (SiLU-gated MLP, no
+    bias) are the other kinds of layer; the defaults are GPT-2's.
     """
 
     num_heads: int
@@ -180,18 +343,44 @@ class Block(nn.Module):
     ln_use_mesh: bool = True
     moe_experts: int = 0
     moe_top_k: int = 2
+    norm: str = "layer"  # "layer" | "rms"
+    norm_eps: float = 1e-6
+    #: > 0: latent attention with this latent width; ``head_dim`` is then
+    #: the position-free part of a query/key head
+    kv_lora_rank: int = 0
+    rope_dim: int = 0
+    v_head_dim: int = 0
+    attn_scale: float | None = None
+    #: 0: ``d_model * mlp_ratio``
+    mlp_dim: int = 0
+    mlp_gated: bool = False
+    #: further arguments of the expert layer, as a tuple of (name, value)
+    moe_kwargs: tuple = ()
 
     @nn.compact
-    def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
+    def __call__(self, x: jax.Array, train: bool = False, rope=None) -> jax.Array:
         d = x.shape[-1]
-        ln = lambda name: FusedLayerNorm(  # noqa: E731
-            dtype=self.dtype, use_mesh=self.ln_use_mesh, name=name
-        )
+        if self.norm == "rms":
+            ln = lambda name: RMSNorm(  # noqa: E731
+                eps=self.norm_eps, dtype=self.dtype, name=name
+            )
+        else:
+            ln = lambda name: FusedLayerNorm(  # noqa: E731
+                dtype=self.dtype, use_mesh=self.ln_use_mesh, name=name
+            )
         y = ln("ln1")(x)
-        y = SelfAttention(
-            self.num_heads, self.head_dim, causal=self.causal,
-            attn_impl=self.attn_impl, dtype=self.dtype, name="attn",
-        )(y, train=train)
+        if self.kv_lora_rank:
+            y = LatentAttention(
+                self.num_heads, self.head_dim, self.rope_dim, self.v_head_dim,
+                self.kv_lora_rank, scale=self.attn_scale,
+                norm_eps=self.norm_eps, causal=self.causal,
+                attn_impl=self.attn_impl, dtype=self.dtype, name="attn",
+            )(y, rope, train=train)
+        else:
+            y = SelfAttention(
+                self.num_heads, self.head_dim, causal=self.causal,
+                attn_impl=self.attn_impl, dtype=self.dtype, name="attn",
+            )(y, train=train)
         if self.dropout:
             y = nn.Dropout(self.dropout, deterministic=not train)(y)
         x = x + y
@@ -202,10 +391,15 @@ class Block(nn.Module):
             y = MoEMLP(
                 num_experts=self.moe_experts, top_k=self.moe_top_k,
                 mlp_ratio=self.mlp_ratio, dtype=self.dtype, name="moe",
+                **dict(self.moe_kwargs),
             )(y, train=train)
+        elif self.mlp_gated:
+            y = GatedMLP(self.mlp_dim or d * self.mlp_ratio, dtype=self.dtype,
+                         name="mlp")(y)
         else:
             y = nn.Dense(
-                d * self.mlp_ratio, dtype=self.dtype, name="mlp_in"
+                self.mlp_dim or d * self.mlp_ratio, dtype=self.dtype,
+                name="mlp_in",
             )(y)
             y = nn.gelu(y)
             y = nn.Dense(d, dtype=self.dtype, name="mlp_out")(y)
@@ -222,6 +416,16 @@ RematBlock = nn.remat(Block, static_argnums=(2,))
 
 class TransformerLM(nn.Module):
     """Decoder-only LM: (B, L) int tokens -> (B, L, vocab) logits.
+
+    The defaults build GPT-2's kind of layer (learned positions,
+    LayerNorm, multi-head attention, a GELU MLP).  The other kinds are
+    switched on by their sizes: ``norm="rms"``; ``rope_dim > 0`` rotary
+    positions (with a config's YaRN ``rope_scaling``) in place of the
+    position table; ``kv_lora_rank > 0`` latent attention, its heads
+    ``head_dim + rope_dim`` wide for queries and keys and ``v_head_dim``
+    for values; ``mlp_gated`` a SiLU-gated MLP of width ``mlp_dim``;
+    ``moe_experts > 0`` the expert layer in every block from
+    ``moe_first_dense`` on, with the dense MLP before it.
 
     ``remat=True`` rematerializes each block in the backward pass
     (``jax.checkpoint`` via ``nn.remat``): activation memory drops from
@@ -243,24 +447,79 @@ class TransformerLM(nn.Module):
     #: compose with ParallelPlan(rules=moe_rules()) for expert parallelism
     moe_experts: int = 0
     moe_top_k: int = 2
+    #: 0: ``num_heads * head_dim``
+    d_model: int = 0
+    norm: str = "layer"
+    norm_eps: float = 1e-6
+    rope_dim: int = 0
+    rope_theta: float = 10000.0
+    #: a config's ``rope_scaling`` (YaRN): a dict, kept as sorted items
+    rope_scaling: Any = None
+    kv_lora_rank: int = 0
+    v_head_dim: int = 0
+    mlp_dim: int = 0
+    mlp_gated: bool = False
+    #: blocks before this one keep the dense MLP
+    moe_first_dense: int = 0
+    #: further arguments of the expert layer (``MoEMLP``): a dict, kept
+    #: as sorted items
+    moe_kwargs: Any = ()
+
+    def __post_init__(self):
+        # module attributes are hashed with the train state's treedef:
+        # what a JSON config hands over as dict or list becomes tuples
+        def frozen(v):
+            if isinstance(v, dict):
+                return tuple(sorted((k, frozen(x)) for k, x in v.items()))
+            return tuple(frozen(x) for x in v) if isinstance(v, list) else v
+
+        for name in ("rope_scaling", "moe_kwargs"):
+            object.__setattr__(self, name, frozen(getattr(self, name)))
+        super().__post_init__()
+
+    def attn_scale(self) -> float | None:
+        """Latent attention's softmax scale: ``width^-0.5`` times the
+        square of YaRN's temperature over all dimensions."""
+        if not self.kv_lora_rank:
+            return None
+        scaling = dict(self.rope_scaling or ())
+        m = yarn_mscale(scaling.get("factor", 1.0), scaling.get("mscale_all_dim", 0.0))
+        return (self.head_dim + self.rope_dim) ** -0.5 * m * m
 
     @nn.compact
     def __call__(self, tokens: jax.Array, train: bool = False) -> jax.Array:
-        d_model = self.num_heads * self.head_dim
+        d_model = self.d_model or self.num_heads * self.head_dim
         x = nn.Embed(self.vocab_size, d_model, dtype=self.dtype, name="embed")(tokens)
-        pos = nn.Embed(self.max_len, d_model, dtype=self.dtype, name="pos_embed")(
-            jnp.arange(tokens.shape[1])[None, :]
-        )
-        x = x + pos
+        rope = None
+        if self.rope_dim:
+            if not self.kv_lora_rank:
+                raise ValueError("rotary positions are built for latent "
+                                 "attention (kv_lora_rank > 0) only")
+            rope = rope_tables(tokens.shape[1], self.rope_dim, self.rope_theta,
+                               dict(self.rope_scaling or ()) or None)
+        else:
+            pos = nn.Embed(self.max_len, d_model, dtype=self.dtype, name="pos_embed")(
+                jnp.arange(tokens.shape[1])[None, :]
+            )
+            x = x + pos
         block_cls = RematBlock if self.remat else Block
         for i in range(self.num_layers):
+            sparse = self.moe_experts and i >= self.moe_first_dense
             x = block_cls(
                 self.num_heads, self.head_dim, mlp_ratio=self.mlp_ratio,
                 dropout=self.dropout, causal=True, attn_impl=self.attn_impl,
-                dtype=self.dtype, moe_experts=self.moe_experts,
-                moe_top_k=self.moe_top_k, name=f"block{i}",
-            )(x, train)
-        x = FusedLayerNorm(dtype=self.dtype, name="ln_f")(x)
+                dtype=self.dtype, moe_experts=self.moe_experts if sparse else 0,
+                moe_top_k=self.moe_top_k, norm=self.norm,
+                norm_eps=self.norm_eps, kv_lora_rank=self.kv_lora_rank,
+                rope_dim=self.rope_dim, v_head_dim=self.v_head_dim,
+                attn_scale=self.attn_scale(), mlp_dim=self.mlp_dim,
+                mlp_gated=self.mlp_gated, moe_kwargs=self.moe_kwargs,
+                name=f"block{i}",
+            )(x, train, rope)
+        if self.norm == "rms":
+            x = RMSNorm(eps=self.norm_eps, dtype=self.dtype, name="ln_f")(x)
+        else:
+            x = FusedLayerNorm(dtype=self.dtype, name="ln_f")(x)
         logits = nn.Dense(
             self.vocab_size, use_bias=False, dtype=self.dtype, name="lm_head"
         )(x)

@@ -18,6 +18,9 @@ fallback and the correctness oracle for tests.
 - :func:`fused_adamw` — one-kernel AdamW moment+param update (the
   DeepSpeed "fused Adam" role, engaged via its ZeRO configs,
   `/root/reference/02_deepspeed/deepspeed_config.py:28-40`).
+- :func:`grouped_matmul` — rows sorted by group times one weight a
+  group, the expert product of the no-drop mixture-of-experts layer
+  (``jax.lax.ragged_dot``: XLA's own tiled kernel on the TPU).
 - :func:`quant_encode` / :func:`quant_decode` — the compressed gradient
   wire's amax/scale/round/pack stages in one VMEM pass each
   (``parallel.compression`` calls them for the bucketed transport).
@@ -38,6 +41,8 @@ _LAZY = {
     "use_pallas": "tpuframe.ops.dispatch",
     "kernel_enabled": "tpuframe.ops.dispatch",
     "kernels_mode": "tpuframe.ops.ledger",
+    "grouped_matmul": "tpuframe.ops.grouped_matmul",
+    "grouped_matmul_reference": "tpuframe.ops.grouped_matmul",
     "moe_dispatch_combine": "tpuframe.ops.moe_gating",
     "moe_dispatch_combine_reference": "tpuframe.ops.moe_gating",
     "normalize_images": "tpuframe.ops.normalize",
@@ -83,8 +88,9 @@ def __dir__():
 
 
 class _OpsModule(_types.ModuleType):
-    """Three exports share their kernel module's name
-    (``blockwise_attention``, ``fused_adamw``, ``ring_attention``), and
+    """Four exports share their kernel module's name
+    (``blockwise_attention``, ``fused_adamw``, ``grouped_matmul``,
+    ``ring_attention``), and
     importing such a submodule makes the import machinery rebind the
     module object over the package attribute of the same name — which
     would shadow the function for every later
@@ -102,7 +108,8 @@ def _shadow_proof(name):
     )
 
 
-for _name in ("blockwise_attention", "fused_adamw", "ring_attention"):
+for _name in ("blockwise_attention", "fused_adamw", "grouped_matmul",
+              "ring_attention"):
     setattr(_OpsModule, _name, _shadow_proof(_name))
 
 _sys.modules[__name__].__class__ = _OpsModule

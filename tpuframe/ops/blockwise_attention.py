@@ -63,13 +63,14 @@ def _from_blocks(a):
 
 def _fwd_schedule(q_blocks, k_blocks, v_blocks, causal, scale, block, kv_len):
     """Online-softmax forward over blocks -> (out_blocks, lse_blocks)."""
-    n, b, _, h, d = q_blocks.shape
+    n, b, _, h, _ = q_blocks.shape
+    dv = v_blocks.shape[-1]  # the output takes the values' width
     block_pos = jnp.arange(block)
 
     def q_body(q_blk, q_idx):
         q_pos = q_idx * block + block_pos
         init = (
-            jnp.zeros((b, block, h, d), jnp.float32),
+            jnp.zeros((b, block, h, dv), jnp.float32),
             jnp.zeros((b, h, block), jnp.float32),
             jnp.full((b, h, block), -jnp.inf, jnp.float32),
         )
@@ -109,16 +110,15 @@ def _fwd_schedule(q_blocks, k_blocks, v_blocks, causal, scale, block, kv_len):
     return outs, lses  # (n, B, blk, H, D) storage dtype, (n, B, H, blk) f32
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _blockwise_padded(q, k, v, causal, block, kv_len):
-    out, _ = _blockwise_padded_fwd(q, k, v, causal, block, kv_len)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _blockwise_padded(q, k, v, causal, block, kv_len, scale):
+    out, _ = _blockwise_padded_fwd(q, k, v, causal, block, kv_len, scale)
     return out
 
 
-def _blockwise_padded_fwd(q, k, v, causal, block, kv_len):
+def _blockwise_padded_fwd(q, k, v, causal, block, kv_len, scale):
     b, l_pad, h, d = q.shape
     n = l_pad // block
-    scale = 1.0 / math.sqrt(d)
     outs, lses = _fwd_schedule(
         _to_blocks(q, n, block), _to_blocks(k, n, block),
         _to_blocks(v, n, block), causal, scale, block, kv_len,
@@ -127,11 +127,10 @@ def _blockwise_padded_fwd(q, k, v, causal, block, kv_len):
     return out, (q, k, v, out, lses)
 
 
-def _blockwise_padded_bwd(causal, block, kv_len, res, g):
+def _blockwise_padded_bwd(causal, block, kv_len, scale, res, g):
     q, k, v, out, lses = res
     b, l_pad, h, d = q.shape
     n = l_pad // block
-    scale = 1.0 / math.sqrt(d)
     do = g.astype(q.dtype)
 
     q_blocks = _to_blocks(q, n, block)
@@ -205,9 +204,11 @@ def _blockwise_padded_bwd(causal, block, kv_len, res, g):
             )
             return carry, None
 
-        zero = jnp.zeros((b, block, h, d), jnp.float32)
+        zero_k = jnp.zeros((b, block, h, d), jnp.float32)
+        zero_v = jnp.zeros((b, block, h, v.shape[-1]), jnp.float32)
         (dk, dv), _ = lax.scan(
-            inner, (zero, zero), (q_blocks, do_blocks, lses, delta_blocks, idx)
+            inner, (zero_k, zero_v),
+            (q_blocks, do_blocks, lses, delta_blocks, idx),
         )
         return dk, dv
 
@@ -231,8 +232,13 @@ def blockwise_attention(
     *,
     causal: bool = False,
     block_size: int | None = None,
+    scale: float | None = None,
 ) -> jax.Array:
     """Exact attention over (B, L, H, D) without materializing (.., L, L).
+
+    ``scale`` replaces the default ``1/sqrt(D)``; ``v`` may have a width
+    of its own (latent attention: 192-wide queries and keys, 128-wide
+    values), which the output takes.
 
     ``block_size`` defaults to the domain-clamped
     ``TPUFRAME_KERNEL_ATTN_BLOCK`` knob (512) — the tile the kernel
@@ -241,7 +247,7 @@ def blockwise_attention(
     if block_size is None:
         block_size = attn_block()
     b, l, h, d = q.shape
-    if k.shape != q.shape or v.shape != q.shape:
+    if k.shape != q.shape or v.shape[:3] != q.shape[:3]:
         raise ValueError(
             f"q/k/v shapes must match, got {q.shape}/{k.shape}/{v.shape}"
         )
@@ -251,5 +257,7 @@ def blockwise_attention(
     if l_pad != l:
         pad = [(0, 0), (0, l_pad - l), (0, 0), (0, 0)]
         q, k, v = (jnp.pad(a, pad) for a in (q, k, v))
-    out = _blockwise_padded(q, k, v, causal, block, l)
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    out = _blockwise_padded(q, k, v, causal, block, l, float(scale))
     return out[:, :l]

@@ -150,6 +150,14 @@ OPS_REGISTRY = {
         "parity_test": "tests/test_ulysses.py::test_ulysses_matches_full",
         "tile_knobs": (),
     },
+    "grouped_matmul": {
+        "module": "tpuframe.ops.grouped_matmul",
+        "symbol": "grouped_matmul",
+        "reference": "grouped_matmul_reference",
+        "parity_test":
+            "tests/test_latent_moe.py::TestGroupedMatmul::test_forward_and_both_gradients_with_empty_groups",
+        "tile_knobs": (),
+    },
     "moe_gating": {
         "module": "tpuframe.ops.moe_gating",
         "symbol": "moe_dispatch_combine",
@@ -225,6 +233,7 @@ OP_NAME_TOKENS = (
     ("normalize", ("normalize", "per_image_standard")),
     ("quant_wire", ("quant", "dequant", "stochastic_round")),
     (ATTENTION_OP, ("attention", "flash", "fmha", "scaled_dot_product")),
+    ("grouped_matmul", ("ragged-dot", "ragged_dot", "grouped_matmul")),
     ("moe_gating", ("top_k_gating", "moe", "expert_dispatch")),
 )
 

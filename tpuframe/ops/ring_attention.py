@@ -34,10 +34,16 @@ from tpuframe.core.runtime import DATA_AXIS, FSDP_AXIS, SEQUENCE_AXIS
 
 
 def attention_reference(
-    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = False
+    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = False,
+    scale: float | None = None,
 ) -> jax.Array:
-    """Full (unsharded) attention oracle, (B, L, H, D) layout."""
-    scale = 1.0 / math.sqrt(q.shape[-1])
+    """Full (unsharded) attention oracle, (B, L, H, D) layout.
+
+    ``scale`` replaces the default ``1/sqrt(D)`` (latent attention folds
+    its rotary scaling into it); ``v`` may be narrower or wider than
+    ``q``/``k``: the output takes ``v``'s width."""
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:
         qi = jnp.arange(q.shape[1])[:, None]

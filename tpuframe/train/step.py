@@ -97,9 +97,13 @@ def _forward(state: TrainState, params: Any, batch: Mapping[str, jax.Array],
     """Shared forward: handles batch_stats mutability, dropout rngs, and
     auxiliary losses (``aux_loss`` collection — MoE load balancing).
 
-    Returns (losses, logits, new_stats, aux) where ``aux`` is the summed
-    auxiliary loss (0.0 when the model sows none); train steps add it to
-    the objective so e.g. MoE routers actually feel their balance loss."""
+    Returns (losses, logits, new_stats, aux, model_stats) where ``aux`` is
+    the summed auxiliary loss (0.0 when the model sows none); train steps
+    add it to the objective so e.g. MoE routers actually feel their
+    balance loss.  ``model_stats`` is what the model's layers sowed into
+    the ``counters`` and ``gauges`` collections under telemetry names,
+    reduced over the layers (counters summed, gauges by their worst
+    layer); empty for a model that sows none."""
     variables = {"params": policy.cast_params_for_compute(params)}
     has_stats = bool(jax.tree.leaves(state.batch_stats))
     if has_stats:
@@ -114,7 +118,8 @@ def _forward(state: TrainState, params: Any, batch: Mapping[str, jax.Array],
     aux = jnp.zeros((), jnp.float32)
     if train:
         if _supports_mutable(state.apply_fn):
-            mutable = ["aux_loss"] + (["batch_stats"] if has_stats else [])
+            mutable = ["aux_loss", "counters", "gauges"] + (
+                ["batch_stats"] if has_stats else [])
             logits, updates = state.apply_fn(variables, x, mutable=mutable, **kwargs)
         else:
             # non-flax apply_fn (e.g. PipelinedTransformerLM's duck-typed
@@ -125,12 +130,32 @@ def _forward(state: TrainState, params: Any, batch: Mapping[str, jax.Array],
         aux_leaves = jax.tree.leaves(updates.get("aux_loss", {}))
         if aux_leaves:
             aux = sum(jnp.sum(a) for a in aux_leaves)
+        model_stats = _model_stats(updates)
     else:
         logits = state.apply_fn(variables, x, **kwargs)
         new_stats = state.batch_stats
+        model_stats = {}
     logits = policy.cast_outputs(logits)
     losses = loss_fn(logits, batch["label"])
-    return losses, logits, new_stats, aux
+    return losses, logits, new_stats, aux, model_stats
+
+
+def _model_stats(updates: Mapping[str, Any]) -> dict:
+    """{"counters": {name: sum over layers}, "gauges": {name: max over
+    layers}} of what the layers sowed; a collection nothing was sown
+    into is left out, so a model without them adds no leaf to the
+    step's metrics."""
+    out: dict[str, dict] = {}
+    for collection, reduce in (("counters", jnp.sum), ("gauges", jnp.max)):
+        by_name: dict[str, list] = {}
+        flat = jax.tree_util.tree_flatten_with_path(updates.get(collection, {}))[0]
+        for path, leaf in flat:
+            name = [k.key for k in path if hasattr(k, "key")][-1]
+            by_name.setdefault(name, []).append(jnp.asarray(leaf, jnp.float32))
+        if by_name:
+            out[collection] = {name: reduce(jnp.stack(leaves))
+                               for name, leaves in by_name.items()}
+    return out
 
 
 def _train_metrics(loss, logits, labels) -> dict:
@@ -311,22 +336,28 @@ def make_train_step(
         rng = state.step_rng("dropout")
 
         def compute_loss(params):
-            losses, logits, new_stats, aux = _forward(
+            losses, logits, new_stats, aux, model_stats = _forward(
                 state, params, batch, policy, True, rng, loss_fn
             )
             data_loss = jnp.mean(losses)
             # aux (MoE load balance etc.) joins the objective; metrics
             # report the data loss so learning curves stay comparable
-            return data_loss + aux, (data_loss, logits, new_stats)
+            return data_loss + aux, (data_loss, logits, new_stats, model_stats)
 
-        (_, (loss, logits, new_stats)), grads = jax.value_and_grad(
+        (_, (loss, logits, new_stats, model_stats)), grads = jax.value_and_grad(
             compute_loss, has_aux=True
         )(state.params)
         metrics = _train_metrics(loss, logits, batch["label"])
         if health is None:
             new_state = _apply_gradients(state, grads, batch_stats=new_stats)
-            return new_state, metrics
-        return _apply_with_health(state, grads, new_stats, loss, metrics, health)
+        else:
+            new_state, metrics = _apply_with_health(
+                state, grads, new_stats, loss, metrics, health)
+        if model_stats:
+            # the layers' own counters ride the metrics window to its
+            # drain, as health_stats does: no sync of their own
+            metrics["model_stats"] = model_stats
+        return new_state, metrics
 
     return _wrap_offload(jax.jit(step, donate_argnums=(0,) if donate else ()), plan)
 
@@ -553,7 +584,7 @@ def _make_compressed_train_step(
                 b = batch_transform(batch) if batch_transform else batch
 
                 def compute_loss(params):
-                    losses, logits, new_stats, aux = _forward(
+                    losses, logits, new_stats, aux, _ = _forward(
                         state, params, b, policy, True, rng, loss_fn
                     )
                     return (
@@ -578,7 +609,7 @@ def _make_compressed_train_step(
                     mb_rng = jax.random.fold_in(rng, micro_idx)
 
                     def compute_loss(params):
-                        losses, logits, new_stats, aux = _forward(
+                        losses, logits, new_stats, aux, _ = _forward(
                             state.replace(batch_stats=stats),
                             params, mb, policy, True, mb_rng, loss_fn,
                         )
@@ -831,7 +862,7 @@ def make_eval_step(
     def step(state: TrainState, batch: Mapping[str, jax.Array]):
         if batch_transform is not None:
             batch = batch_transform(batch)
-        losses, logits, _, _ = _forward(
+        losses, logits, _, _, _ = _forward(
             state, state.params, batch, policy, False, None, loss_fn
         )
         labels = batch["label"]
@@ -933,7 +964,7 @@ def make_grad_accum_step(
             mb_rng = jax.random.fold_in(rng, micro_idx)
 
             def compute_loss(params):
-                losses, logits, new_stats, aux = _forward(
+                losses, logits, new_stats, aux, _ = _forward(
                     state.replace(batch_stats=stats),
                     params, mb, policy, True, mb_rng, loss_fn,
                 )

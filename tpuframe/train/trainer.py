@@ -1375,8 +1375,17 @@ class Trainer:
                            step=self.batches_seen, first_step=first_step) as sp:
                 out = {
                     k: float(v) for k, v in window.items()
-                    if k != "health_stats"
+                    if k not in ("health_stats", "model_stats")
                 }
+                # what the model's layers counted (step._model_stats),
+                # summed over the window's steps: counters advance by
+                # the sum, gauges show the mean step
+                stats = window.get("model_stats", {})
+                steps = self.batches_seen - first_step + 1
+                for name, v in stats.get("counters", {}).items():
+                    tele.registry.counter(name).inc(float(v))
+                for name, v in stats.get("gauges", {}).items():
+                    tele.registry.gauge(name).set(float(v) / steps)
                 # the sentinel's packed vector splits into its named
                 # scalar sums (one device leaf on the hot path, five
                 # host columns in the summary)
