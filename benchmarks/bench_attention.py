@@ -25,8 +25,16 @@ kernel runs in interpret mode (the only way the kernel code runs here);
 on the TPU host the same ladder prices real Mosaic (on chip: not
 measured).
 
+``--standalone B,L,H,D[,Dv]`` times the op alone instead, on one
+device, no mesh and no ledger: causal bf16 attention at that shape
+through the flash kernels, the scan schedule and ``attention_reference``,
+forward and forward + backward under one ``jax.grad``, with each
+compiled program's temporaries — the figures that size moving a shape
+onto the kernels (PERF.md section 7, ROADMAP S3).
+
 Usage: python benchmarks/bench_attention.py [--seqs 256,512] [--json]
        TPUFRAME_KERNEL_LEDGER_DIR=... python benchmarks/bench_attention.py  # persist
+       python benchmarks/bench_attention.py --standalone 4,1024,16,64
 """
 
 from __future__ import annotations
@@ -84,8 +92,69 @@ def make_fit(seq: int, impl: str, max_len: int):
     return mk_state, toks
 
 
+def standalone(shape: tuple[int, ...], calls: int) -> int:
+    """One JSON line per implementation: ms a call, forward and forward
+    + backward, and the MiB of temporaries of each compiled program."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from tpuframe.ops.blockwise_attention import (
+        blockwise_attention,
+        blockwise_attention_reference,
+    )
+    from tpuframe.ops.ring_attention import attention_reference
+
+    b, l, h, d = shape[:4]
+    dv = shape[4] if len(shape) > 4 else d
+    interpret = None if jax.default_backend() == "tpu" else True
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    qkv = tuple(jax.random.normal(k, (b, l, h, w), jnp.bfloat16) * 0.5
+                for k, w in zip(keys, (d, d, dv)))
+    impls = {
+        "flash_kernels": lambda q, k, v: blockwise_attention(
+            q, k, v, causal=True, interpret=interpret),
+        "scan_schedule": lambda q, k, v: blockwise_attention_reference(
+            q, k, v, causal=True),
+        "attention_reference": lambda q, k, v: attention_reference(
+            q, k, v, causal=True),
+    }
+
+    def ms_and_mib(fn):
+        compiled = fn.lower(*qkv).compile()
+        jax.block_until_ready(compiled(*qkv))
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = compiled(*qkv)
+        jax.block_until_ready(out)
+        return ((time.perf_counter() - t0) / calls * 1e3,
+                compiled.memory_analysis().temp_size_in_bytes / 2**20)
+
+    dev = jax.devices()[0]
+    for name, f in impls.items():
+        rec = {"metric": "attention_standalone", "impl": name,
+               "shape": [b, l, h, d, dv], "dtype": "bfloat16", "causal": True,
+               "calls": calls, "backend": dev.platform,
+               "device_kind": dev.device_kind,
+               "pallas_interpret": bool(interpret)}
+        try:
+            rec["fwd_ms"], rec["fwd_temp_mib"] = ms_and_mib(jax.jit(f))
+            rec["fwd_bwd_ms"], rec["fwd_bwd_temp_mib"] = ms_and_mib(jax.jit(jax.grad(
+                lambda *a, f=f: jnp.sum(f(*a).astype(jnp.float32) ** 2), (0, 1, 2))))
+        except Exception as e:  # a shape an implementation cannot hold
+            rec["error"] = f"{type(e).__name__}: {e}"[:300]
+        print(json.dumps(rec), flush=True)
+    return 0
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--standalone", default=None, metavar="B,L,H,D[,Dv]",
+                    help="time the op alone at this shape (bf16, causal) "
+                         "instead of pricing the train step")
+    ap.add_argument("--calls", type=int, default=24,
+                    help="timed calls per program of --standalone")
     ap.add_argument("--seqs", default="256,512",
                     help="comma list; each must divide the mesh seq axis")
     ap.add_argument("--warmup", type=int, default=3,
@@ -93,6 +162,9 @@ def main() -> int:
     ap.add_argument("--json", action="store_true",
                     help="machine-readable only: suppress stderr narration")
     args = ap.parse_args()
+    if args.standalone:
+        return standalone(tuple(int(x) for x in args.standalone.split(",")),
+                          args.calls)
 
     def say(msg: str) -> None:
         if not args.json:

@@ -13,8 +13,9 @@ the error and the run moves on, so one call shows every refusal.
 Covers: fused LayerNorm (fwd+grads), fused cross-entropy (fwd+grad),
 fused AdamW (vs optax), fused normalize, the quant_wire trio
 (amax/encode/decode vs the staged jnp expressions — the in-collective
-wire's arithmetic contract), blockwise attention (fwd+grads, causal and
-not), ring and ulysses attention oracle parity on one device.
+wire's arithmetic contract), blockwise attention's flash kernels
+(fwd+grads, causal and not, and deepseek-v2-lite's latent shape in
+bf16), ring and ulysses attention oracle parity on one device.
 
 Usage: python benchmarks/check_kernels_tpu.py [--only a,b,...]
 (exits 1 on any failure).  ``--only`` runs a named subset — sections:
@@ -264,29 +265,71 @@ def _check_quant_wire(jax, jnp, np, rng) -> None:
         )
 
 
-def _attention_parity(jax, jnp, name: str, fn, qkv, *, causal: bool) -> None:
+def _attention_parity(jax, jnp, name: str, fn, qkv, *, causal: bool,
+                      scale: float | None = None,
+                      tols=(("highest", 2e-4, 2e-3), ("default", 1e-2, 2e-2))) -> None:
     """fwd + grads of ``fn(q, k, v)`` vs the dense oracle, twice: at
     ``highest`` matmul precision (the algorithm — tight tolerances) and at
     the backend default, where a TPU runs f32 matmuls as bf16 passes and
     rows that attend to few keys do not average the rounding away (first
     chip run: 1.6e-3 forward on the causal variants) — that tolerance is
-    the precision's, not the kernel's."""
+    the precision's, not the kernel's.  The oracle always takes float32
+    copies of the inputs; differences are taken in float32."""
     from tpuframe.ops.ring_attention import attention_reference
 
     def ref(q, k, v):
-        return attention_reference(q, k, v, causal=causal)
+        # a batch row at a time: at 4096 keys one row's float32 scores
+        # are 1 GiB, and the backward holds four such
+        return jax.lax.map(
+            lambda row: attention_reference(
+                *(a[None] for a in row), causal=causal, scale=scale)[0],
+            (q, k, v))
 
-    for precision, ftol, gtol in (("highest", 2e-4, 2e-3), ("default", 1e-2, 2e-2)):
+    def sq(f):
+        return lambda *a: jnp.sum(f(*a).astype(jnp.float32) ** 2)
+
+    def gap(a, b):
+        return float(jnp.max(jnp.abs(a.astype(jnp.float32) - b)))
+
+    exact = tuple(a.astype(jnp.float32) for a in qkv)
+    for precision, ftol, gtol in tols:
         with jax.default_matmul_precision(precision):
-            got, want = jax.jit(fn)(*qkv), jax.jit(ref)(*qkv)
-            gk = jax.jit(jax.grad(lambda *a: jnp.sum(fn(*a) ** 2), (0, 1, 2)))(*qkv)
-            go = jax.jit(jax.grad(lambda *a: jnp.sum(ref(*a) ** 2), (0, 1, 2)))(*qkv)
-        record(f"{name}_fwd_{precision}", float(jnp.max(jnp.abs(got - want))), ftol)
-        record(
-            f"{name}_grads_{precision}",
-            max(float(jnp.max(jnp.abs(a - c))) for a, c in zip(gk, go)),
-            gtol,
-        )
+            got, want = jax.jit(fn)(*qkv), jax.jit(ref)(*exact)
+            gk = jax.jit(jax.grad(sq(fn), (0, 1, 2)))(*qkv)
+            go = jax.jit(jax.grad(sq(ref), (0, 1, 2)))(*exact)
+        record(f"{name}_fwd_{precision}", gap(got, want), ftol)
+        record(f"{name}_grads_{precision}",
+               max(gap(a, c) for a, c in zip(gk, go)), gtol)
+
+
+def _schedule_parity(jax, jnp, name: str, qkv, *, scale: float,
+                     ftol: float, gtol: float) -> None:
+    """The flash kernels against the scan schedule on the SAME inputs,
+    forward and gradients, as a share of each value's size (of 1 for
+    the smaller ones): two schedules of one arithmetic differ by the
+    order of their float32 sums and then by a rounding of the storage
+    dtype, 2^-8 of the value in bf16.  A tile-level mistake that the
+    float32 oracle's looser bound lets through does not pass here."""
+    from tpuframe.ops.blockwise_attention import (
+        blockwise_attention,
+        blockwise_attention_reference,
+    )
+
+    def gap(a, b):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return float(jnp.max(jnp.abs(a - b) / jnp.maximum(jnp.abs(b), 1.0)))
+
+    outs, grads = [], []
+    for fn in (blockwise_attention, blockwise_attention_reference):
+        def f(q, k, v, fn=fn):
+            return fn(q, k, v, causal=True, scale=scale)
+
+        outs.append(jax.jit(f)(*qkv))
+        grads.append(jax.jit(jax.grad(
+            lambda *a, f=f: jnp.sum(f(*a).astype(jnp.float32) ** 2), (0, 1, 2)))(*qkv))
+    record(f"{name}_fwd_vs_schedule", gap(*outs), ftol)
+    record(f"{name}_grads_vs_schedule",
+           max(gap(a, c) for a, c in zip(*grads)), gtol)
 
 
 def _qkv(jnp, rng):
@@ -314,6 +357,34 @@ def _check_blockwise(jax, jnp, np, rng) -> None:
                 q, k, v, causal=c, block_size=128),
             qkv, causal=causal,
         )
+    # the latent-attention shape of deepseek-v2-lite as the benchmark runs
+    # it: 192-wide queries and keys, 128-wide values, bf16, causal, its
+    # softmax scale (192^-0.5 x YaRN's temperature squared), the kernels'
+    # own tiles.  Against the float32 oracle bf16 operands bound the
+    # agreement (a bf16 output rounds at 2^-9 of its size, a gradient
+    # likewise; gradients here reach 7): read on the v5e 0.0068 forward
+    # and 0.027 gradients at most (PR 28), held to twice that.
+    def latent(b, l, h):
+        return tuple(
+            jnp.asarray(rng.standard_normal((b, l, h, w)) * 0.5, jnp.bfloat16)
+            for w in (192, 192, 128))
+
+    scale = 192 ** -0.5 * 1.260804 ** 2
+    _attention_parity(
+        jax, jnp, "blockwise_latent_bf16",
+        lambda q, k, v: blockwise_attention(q, k, v, causal=True, scale=scale),
+        latent(2, 4096, 16), causal=True, scale=scale,
+        tols=(("highest", 1.4e-2, 5.5e-2), ("default", 1.4e-2, 5.5e-2)),
+    )
+    # read on the v5e: 2^-11 forward, 0.0076 gradients (0.0039 at the
+    # long shape): two and one rounding of bf16 at a value's size
+    _schedule_parity(jax, jnp, "blockwise_latent_bf16", latent(2, 4096, 16),
+                     scale=scale, ftol=2 ** -9, gtol=2 ** -6)
+    # the longest sequence auto dispatch hands the kernels, an indivisible
+    # length under it: a head's dQ takes 96 of the 100 MiB of VMEM the
+    # backward kernel may ask for
+    _schedule_parity(jax, jnp, "blockwise_long_bf16", latent(1, 32768 - 200, 2),
+                     scale=scale, ftol=2 ** -9, gtol=2 ** -6)
 
 
 def _check_ring(jax, jnp, np, rng) -> None:

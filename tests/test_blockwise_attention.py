@@ -219,3 +219,260 @@ def test_auto_picks_blockwise_for_long_unsharded_seq(monkeypatch):
     calls.clear()
     model.apply(variables, short_toks, train=False)
     assert "full" in calls and "blockwise" not in calls
+
+
+# -- the Pallas flash kernels (interpret mode: the kernels' own code on CPU) --
+
+import importlib  # noqa: E402
+
+bw = importlib.import_module("tpuframe.ops.blockwise_attention")
+
+
+def _wide_qkv(l, d, dv, dtype, b=1, h=2, seed=0, scale=0.5):
+    rng = np.random.default_rng(seed)
+    mk = lambda w: jnp.asarray(  # noqa: E731
+        rng.standard_normal((b, l, h, w)) * scale, dtype
+    )
+    return mk(d), mk(d), mk(dv)
+
+
+def _f32(a):
+    return np.asarray(a, np.float32)
+
+
+def _kernel(causal, **kw):
+    return lambda q, k, v: blockwise_attention(
+        q, k, v, causal=causal, block_size=128, interpret=True, **kw
+    )
+
+
+def _grads(fn, q, k, v):
+    return jax.grad(
+        lambda *a: jnp.sum(fn(*a).astype(jnp.float32) ** 2), argnums=(0, 1, 2)
+    )(q, k, v)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("l", [256, 300], ids=["blocks", "indivisible"])
+@pytest.mark.parametrize("causal", [False, True], ids=["bidir", "causal"])
+@pytest.mark.parametrize("widths", [(192, 128), (128, 128), (64, 64)],
+                         ids=["192x128", "128x128", "64x64"])
+def test_kernel_matches_full_attention(widths, causal, l, dtype):
+    """Forward and all three gradients of the kernels against the dense
+    oracle in float32, at the head widths the kernels must take."""
+    d, dv = widths
+    q, k, v = _wide_qkv(l, d, dv, dtype)
+    exact = [a.astype(jnp.float32) for a in (q, k, v)]
+    ref = lambda q, k, v: attention_reference(q, k, v, causal=causal)  # noqa: E731
+    tol = dict(atol=2e-5, rtol=1e-5) if dtype == jnp.float32 else dict(atol=0.03, rtol=0.03)
+    got = _kernel(causal)(q, k, v)
+    assert got.dtype == dtype and got.shape == (1, l, 2, dv)
+    np.testing.assert_allclose(_f32(got), _f32(ref(*exact)), **tol)
+    gtol = dict(atol=5e-4) if dtype == jnp.float32 else dict(atol=0.06, rtol=0.06)
+    for a, b_, x in zip(_grads(_kernel(causal), q, k, v), _grads(ref, *exact), (q, k, v)):
+        assert a.dtype == x.dtype and a.shape == x.shape
+        np.testing.assert_allclose(_f32(a), _f32(b_), **gtol)
+
+
+@pytest.mark.parametrize("l", [256, 300], ids=["blocks", "indivisible"])
+@pytest.mark.parametrize("causal", [False, True], ids=["bidir", "causal"])
+def test_kernel_matches_scan_schedule(causal, l):
+    """The kernels and the scan schedule are two schedules of one
+    arithmetic: float32 round-off apart on the same inputs, forward and
+    backward (the registry's parity test)."""
+    q, k, v = _wide_qkv(l, 192, 128, jnp.float32, seed=1)
+    sched = lambda q, k, v: bw.blockwise_attention_reference(  # noqa: E731
+        q, k, v, causal=causal, block_size=128)
+    np.testing.assert_allclose(
+        _f32(_kernel(causal)(q, k, v)), _f32(sched(q, k, v)), atol=2e-6)
+    for a, b_ in zip(_grads(_kernel(causal), q, k, v), _grads(sched, q, k, v)):
+        np.testing.assert_allclose(_f32(a), _f32(b_), atol=2e-5)
+
+
+def test_kernel_bf16_follows_scan_schedule_at_equal_tiles():
+    """Same tiles, same order of the same operations: in bfloat16 the two
+    schedules agree within one rounding of the storage dtype, output and
+    gradients."""
+    q, k, v = _wide_qkv(384, 192, 128, jnp.bfloat16, seed=2)
+    sched = lambda q, k, v: bw.blockwise_attention_reference(  # noqa: E731
+        q, k, v, causal=True, block_size=128)
+    np.testing.assert_allclose(
+        _f32(_kernel(True)(q, k, v)), _f32(sched(q, k, v)), atol=2e-3, rtol=2**-7)
+    for a, b_ in zip(_grads(_kernel(True), q, k, v), _grads(sched, q, k, v)):
+        np.testing.assert_allclose(_f32(a), _f32(b_), atol=2e-3, rtol=2**-7)
+
+
+@pytest.mark.parametrize("l", [300, 512, 1000], ids=["padded", "blocks", "long"])
+@pytest.mark.parametrize("causal", [False, True], ids=["bidir", "causal"])
+def test_kernel_forward_tile_twice_the_backwards(monkeypatch, causal, l):
+    """The forward's tile may be twice the backward's (as 1024 and 512
+    are at 4096 positions), and the padding may reach over more than one
+    of the backward's K blocks (300 positions padded to 512, in tiles of
+    128): the diagonal, the clamped fetches and the padding mask follow."""
+    monkeypatch.setattr(bw, "_tiles", lambda l, block: (256, 128))
+    q, k, v = _wide_qkv(l, 64, 64, jnp.float32, seed=3)
+    ref = lambda q, k, v: attention_reference(q, k, v, causal=causal)  # noqa: E731
+    np.testing.assert_allclose(
+        _f32(_kernel(causal)(q, k, v)), _f32(ref(q, k, v)), atol=2e-5)
+    for a, b_ in zip(_grads(_kernel(causal), q, k, v), _grads(ref, q, k, v)):
+        np.testing.assert_allclose(_f32(a), _f32(b_), atol=5e-4)
+
+
+@pytest.mark.parametrize("l, block, want", [
+    (4096, None, (1024, 512)),   # the forward doubles: it pads no further
+    (1536, None, (512, 512)),
+    (8000, None, (1024, 512)),   # 8000 pads to 8192 either way
+    (300, None, (384, 384)),     # one tile of whole lanes
+    (300, 128, (128, 128)),      # an explicit block: every tile's side
+    (4096, 768, (768, 768)),
+    (4096, 100, (128, 128)),     # in whole lanes
+    (8192, 4096, (1024, 1024)),  # no side passes 1024
+])
+def test_kernel_tile_rule(l, block, want):
+    """The kernels' (forward, backward) tile sides follow L alone, or an
+    explicit ``block_size``; both divide L padded to the forward's."""
+    assert bw._tiles(l, block) == want
+    assert want[0] % want[1] == 0
+
+
+def test_kernel_tiles_ignore_the_schedules_knob(monkeypatch):
+    seen = []
+    real = bw._flash_call
+    monkeypatch.setattr(
+        bw, "_flash_call",
+        lambda *a, side, **kw: seen.append(side) or real(*a, side=side, **kw))
+    monkeypatch.setenv("TPUFRAME_KERNEL_ATTN_BLOCK", "128")
+    q, k, v = _wide_qkv(300, 64, 64, jnp.float32)
+    blockwise_attention(q, k, v, causal=True, interpret=True)
+    assert seen == [384]  # not TPUFRAME_KERNEL_ATTN_BLOCK: that is the scan schedule's
+
+
+@pytest.mark.parametrize("blocks", [1, 2], ids=["one_block", "two_blocks"])
+@pytest.mark.parametrize("block", [640, 768, 896])
+def test_kernel_gradients_at_blocks_no_power_of_two(block, blocks):
+    """Every tile side divides the padded length, so every grid covers
+    it: block sizes the knob's domain allows and 512 does not divide."""
+    q, k, v = _wide_qkv(block * blocks, 64, 64, jnp.float32, h=1, seed=6)
+    fn = lambda q, k, v: blockwise_attention(  # noqa: E731
+        q, k, v, causal=True, block_size=block, interpret=True)
+    ref = lambda q, k, v: attention_reference(q, k, v, causal=True)  # noqa: E731
+    np.testing.assert_allclose(_f32(fn(q, k, v)), _f32(ref(q, k, v)), atol=2e-5)
+    for a, b_ in zip(_grads(fn, q, k, v), _grads(ref, q, k, v)):
+        np.testing.assert_allclose(_f32(a), _f32(b_), atol=5e-4)
+
+
+def test_tiles_that_do_not_divide_are_refused():
+    """A grid is never floored: 768 positions unpadded in tiles of 512."""
+    q, k, v = (a.transpose(0, 2, 1, 3) for a in _wide_qkv(768, 64, 64, jnp.float32, h=1))
+    with pytest.raises(ValueError, match="do not divide"):
+        bw._flash_fwd(q, k, v, True, 1.0, 512, 768, True)
+
+
+def test_backward_is_one_kernel_and_long_sequences_take_the_schedule(monkeypatch):
+    """One backward kernel, a head's float32 dQ resident in VMEM; auto
+    dispatch leaves a sequence whose dQ would not fit to the scan
+    schedule, whatever the backend would run."""
+    import re
+
+    monkeypatch.setenv("TPUFRAME_PALLAS_INTERPRET", "1")
+
+    def kernels(l, d, interpret=None):
+        q = jax.ShapeDtypeStruct((1, l, 1, d), jnp.bfloat16)
+        jaxpr = jax.jit(jax.grad(lambda *a: jnp.sum(
+            blockwise_attention(*a, causal=True, interpret=interpret).astype(jnp.float32)
+        ), (0, 1, 2))).trace(q, q, q).jaxpr
+        return set(re.findall(r"name=(tpuframe_\w+)", str(jaxpr)))
+
+    both = {"tpuframe_flash_fwd", "tpuframe_flash_bwd"}
+    assert kernels(4096, 192) == both
+    assert kernels(32768, 192) == both  # 96 of 100 MiB
+    assert kernels(40960, 192) == set()
+    assert kernels(40960, 192, interpret=True) == both  # an explicit choice wins
+    assert kernels(65536, 64) == both
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["bidir", "causal"])
+def test_kernel_custom_scale_and_value_width(causal):
+    q, k, v = _wide_qkv(300, 192, 128, jnp.float32, seed=4)
+    scale = 192 ** -0.5 * 1.26 ** 2  # latent attention's: rotary temperature folded in
+    want = attention_reference(q, k, v, causal=causal, scale=scale)
+    np.testing.assert_allclose(
+        _f32(_kernel(causal, scale=scale)(q, k, v)), _f32(want), atol=2e-5)
+    ref = lambda q, k, v: attention_reference(q, k, v, causal=causal, scale=scale)  # noqa: E731
+    for a, b_ in zip(_grads(_kernel(causal, scale=scale), q, k, v), _grads(ref, q, k, v)):
+        np.testing.assert_allclose(_f32(a), _f32(b_), atol=5e-4)
+
+
+def test_kernel_large_logits_no_overflow():
+    q, k, v = _wide_qkv(256, 64, 64, jnp.float32, seed=5, scale=3.0)  # logits ~ +-200
+    got = _kernel(True)(q, k, v)
+    assert np.isfinite(_f32(got)).all()
+    np.testing.assert_allclose(
+        _f32(got), _f32(attention_reference(q, k, v, causal=True)), rtol=1e-4, atol=1e-3)
+    assert all(np.isfinite(_f32(g)).all() for g in _grads(_kernel(True), q, k, v))
+
+
+def test_kernel_verdict_event_and_auto_dispatch(monkeypatch, tmp_path):
+    """Auto dispatch asks the one rule every op asks: on this backend the
+    scan schedule, under TPUFRAME_PALLAS_INTERPRET the kernels, and the
+    decision is announced once a shape class."""
+    from tpuframe.ops import dispatch
+    from tpuframe.track import telemetry as T
+
+    calls = []
+    real = bw._flash_fwd
+    monkeypatch.setattr(bw, "_flash_fwd", lambda *a: calls.append(1) or real(*a))
+    monkeypatch.setenv("TPUFRAME_KERNELS", "auto")
+    monkeypatch.setenv("TPUFRAME_KERNEL_LEDGER_DIR", str(tmp_path / "empty"))
+    dispatch._reset_kernel_cache()
+    tele = T.configure(str(tmp_path / "events.jsonl"))
+    try:
+        q, k, v = _wide_qkv(256, 64, 64, jnp.float32)
+        want = attention_reference(q, k, v, causal=True)
+        np.testing.assert_allclose(
+            _f32(blockwise_attention(q, k, v, causal=True)), _f32(want), atol=2e-5)
+        assert not calls  # CPU, no interpret knob: the scan schedule
+        monkeypatch.setenv("TPUFRAME_PALLAS_INTERPRET", "1")
+        for _ in range(2):
+            np.testing.assert_allclose(
+                _f32(blockwise_attention(q, k, v, causal=True)), _f32(want), atol=2e-5)
+        assert len(calls) == 2
+        monkeypatch.setenv("TPUFRAME_DISABLE_PALLAS", "1")
+        blockwise_attention(q, k, v, causal=True)
+        assert len(calls) == 2
+        events = [e for e in tele.recent_events(50) if e["name"] == "ops/kernel_verdict"]
+        assert [(e["op"], e["shape_class"], e["enable"], e["source"]) for e in events] == [
+            ("blockwise_attention", "d64_l256", True, "default")]
+    finally:
+        T.reset()
+        dispatch._reset_kernel_cache()
+
+
+def test_lowered_latent_step_holds_the_flash_kernels(compiled_backend):
+    """A latent-attention TransformerLM's gradient, lowered for the TPU
+    with the backend's answer pinned to "compiled": the attention core is
+    the flash kernels and no loop is left under ``tpuframe/mla``."""
+    from tpuframe.models import TransformerLM
+
+    model = TransformerLM(
+        vocab_size=64, num_layers=1, num_heads=2, d_model=64, head_dim=128,
+        rope_dim=64, kv_lora_rank=32, v_head_dim=128, max_len=256,
+        norm="rms", attn_impl="blockwise",
+        dtype=jnp.bfloat16,
+    )
+    toks = jax.ShapeDtypeStruct((2, 256), jnp.int32)
+    variables = jax.eval_shape(
+        lambda: model.init({"params": jax.random.PRNGKey(0)},
+                           jnp.zeros((1, 16), jnp.int32), train=False))
+
+    def loss(params, toks):
+        return jnp.sum(model.apply({"params": params}, toks, train=False)
+                       .astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss)).trace(variables["params"], toks).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    for name in ("tpuframe_flash_fwd", "tpuframe_flash_bwd"):
+        assert f'kernel_name = "{name}"' in text
+    assert "tpuframe/mla" in text
+    loops = [line for line in text.splitlines() if "stablehlo.while" in line]
+    assert not [line for line in loops if "tpuframe/mla" in line], loops[:2]

@@ -9,8 +9,11 @@ family is the workload that exercises the ``seq`` mesh axis.  Design:
   (`tpuframe.ops.ring_attention`) whenever the current mesh shards the
   sequence axis — K/V rotate the ICI ring, scores never materialize
   globally; unsharded sequences of ``_BLOCKWISE_AUTO_LEN`` (4k) tokens
-  or more take the flash-style linear-memory blockwise path; short
-  unsharded sequences use plain XLA attention.
+  or more take the flash-style linear-memory blockwise path
+  (`tpuframe.ops.blockwise_attention`: its Pallas flash kernels on a
+  single-device TPU process or inside a manual region, its scan
+  schedule elsewhere); short unsharded sequences use plain XLA
+  attention.
 - Tensor-parallel ready: :func:`transformer_tp_rules` gives the
   ParallelPlan rules that split QKV/MLP projections over ``model``
   (Megatron-style column->row pairing; XLA inserts the all-reduces).

@@ -55,6 +55,7 @@ _LAZY = {
     "fused_layer_norm": "tpuframe.ops.layer_norm",
     "layer_norm_reference": "tpuframe.ops.layer_norm",
     "blockwise_attention": "tpuframe.ops.blockwise_attention",
+    "blockwise_attention_reference": "tpuframe.ops.blockwise_attention",
     "ulysses_attention": "tpuframe.ops.ulysses",
     "ulysses_attention_local": "tpuframe.ops.ulysses",
     "attention_reference": "tpuframe.ops.ring_attention",

@@ -3,35 +3,57 @@
 Ring attention (`tpuframe.ops.ring_attention`) spreads the sequence over
 chips; this is the within-one-shard counterpart for long context that
 FITS on a chip but whose (B, H, L, L) score matrix would not — forward
-AND backward:
+AND backward.  One arithmetic, two schedules of it:
 
-- **Forward**: an outer ``lax.scan`` over Q blocks runs the inner
-  online-softmax K/V scan (`ring_attention._block_update` — one
-  numerics implementation, ring and blockwise schedules share it) and
-  emits, besides the normalized output, each row's logsumexp.
+- **The Pallas flash kernels** (``tpuframe_flash_fwd`` and
+  ``tpuframe_flash_bwd``) wherever a kernel can run: a single-device
+  TPU process or a manual region, and anywhere in Pallas interpret mode
+  (``TPUFRAME_PALLAS_INTERPRET=1`` or ``interpret=True``).  The score
+  tile, the probabilities and the running (output, sum, max) state live
+  in VMEM; HBM sees q, k, v, the output and the rows' logsumexp, once
+  each.  The kernels are bound by the MXU, not by bandwidth.
+- **The scan schedule** (:func:`blockwise_attention_reference`)
+  everywhere else — CPU, ``TPUFRAME_DISABLE_PALLAS``,
+  ``TPUFRAME_KERNELS=off``, a multi-device jit without a mesh, a
+  sequence whose dQ of one head outgrows VMEM (`_bwd_vmem_bytes`: past
+  ~32k positions of 192-wide bf16 rows) — and as what the kernels are
+  held to.  It is made of
+  ``ring_attention._block_update`` / ``_tile_grads`` / ``_causal_skip``,
+  which ring attention's own sweep shares.
+
+Both:
+
+- **Forward**: for every Q block, an online-softmax sweep over the K/V
+  blocks emits, besides the normalized output, each row's logsumexp.
 - **Backward**: hand-written (``jax.custom_vjp``), the FlashAttention-2
   two-pass recipe.  Reverse-mode through the scan-of-scans stacked
   per-step residuals and re-ran the whole inner sweep per Q block —
   measured 107.6 ms fwd+bwd per layer at seq 8192 on v5e vs 13.0 ms
   forward (PERF.md r03).  Instead the VJP saves only Q/K/V, the output
   and the O(L) logsumexp, and recomputes probabilities one
-  (block x block) tile at a time: pass 1 scans Q blocks accumulating
-  dQ; pass 2 scans K/V blocks accumulating dK/dV.
+  (block x block) tile at a time: pass 1 sweeps the K/V blocks past
+  each Q block accumulating dQ; pass 2 sweeps the Q blocks past each
+  K/V block accumulating dK/dV.  The backward kernel makes it one
+  pass: pass 2's sweep, each tile's dS also adding into its rows of a
+  head's float32 dQ, which stays in VMEM.
 - Q/K/V keep their storage dtype end to end: the MXU multiplies bf16
   natively with f32 accumulation; only softmax state (and the gradient
   accumulators) are f32.
 - L pads up to a block multiple (padded keys are masked via ``kv_len``,
   padded query rows are sliced off) — one MXU-friendly compiled
-  schedule for any L, never a degenerate tiny-block divisor.
+  schedule for any L, never a degenerate tiny-block divisor.  The
+  schedule's block is the ``TPUFRAME_KERNEL_ATTN_BLOCK`` knob; the
+  kernels' tiles follow L alone (`_tiles`).
+- Causal: tiles entirely above the diagonal are *skipped at runtime*,
+  so the sweep executes only the tiles that meet the triangle; the
+  tiles the diagonal crosses mask element-wise.  The scan bodies branch
+  on the scalar block indices with ``lax.cond`` (a real XLA
+  Conditional, not a select); the kernels' skip is their own
+  (``pl.when``, and an index map that fetches nothing for a skipped
+  tile).
 
-Causal note: tiles entirely above the diagonal are *skipped at
-runtime* — the scan bodies branch on the scalar block indices with
-``lax.cond`` (a real XLA Conditional, not a select), so the causal
-sweep executes only the ~(n^2+n)/2 tiles that intersect the triangle
-while keeping one static schedule.  Diagonal tiles still mask
-element-wise.
-
-``TransformerLM(attn_impl="blockwise")`` selects it; composes with the
+``TransformerLM(attn_impl="blockwise")`` selects it, and ``"auto"``
+does from 4096 unsharded positions on; it composes with the
 ``seq``-sharded impls (they shard ACROSS devices, this blocks WITHIN
 one).
 """
@@ -44,11 +66,24 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from tpuframe.ops.ledger import attn_block
+from tpuframe.ops.dispatch import pad_to, resolve_interpret
+from tpuframe.ops.ledger import attn_block, shape_class
 from tpuframe.ops.ring_attention import _block_update, _causal_skip, _tile_grads
 
-__all__ = ["blockwise_attention"]
+__all__ = ["blockwise_attention", "blockwise_attention_reference"]
+
+_LANES = 128
+#: the kernels' largest tile side: a float32 (1024, 1024) score tile is
+#: 4 MiB, and the backward holds four such
+_MAX_TILE = 1024
+#: what the backward kernel's tiles may take of VMEM at that side
+#: (scores, probabilities, dP, dS; the double-buffered blocks; dK, dV)
+_TILE_VMEM_BYTES = 32 << 20
+#: what the backward kernel may ask of a core's 128 MiB of VMEM
+_VMEM_BYTES = 100 << 20
 
 
 def _to_blocks(a, n, block):
@@ -110,13 +145,318 @@ def _fwd_schedule(q_blocks, k_blocks, v_blocks, causal, scale, block, kv_len):
     return outs, lses  # (n, B, blk, H, D) storage dtype, (n, B, H, blk) f32
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _blockwise_padded(q, k, v, causal, block, kv_len, scale):
-    out, _ = _blockwise_padded_fwd(q, k, v, causal, block, kv_len, scale)
+# -- the Pallas flash kernels --------------------------------------------------
+#
+# Same tiles, same arithmetic as the schedule above, in the layout
+# (B, H, L, D): one head's (block, D) tile is then a contiguous row range
+# and a legal Mosaic block at any head width (192 and 64 are no lane
+# multiples, so no block of the model's (B, L, H*D) view can hold one
+# head).  Every kernel runs a grid (batch, head, held block, streamed
+# block), the last axis sequential: the held side's blocks stay in VMEM
+# while the other side streams past, and the float32 state (output
+# accumulator, row sum, row max; dQ; dK and dV) lives in VMEM scratch and
+# reaches HBM once a held block.  The causal skip is the kernels' own: a
+# tile above the diagonal runs nothing (``pl.when``) and fetches nothing
+# (the streamed ``index_map`` is clamped to the diagonal, so a skipped
+# step names the block that is already resident).  Row statistics travel
+# as (B, H, 1, L) rows: a (B, H, L, 1) column would be padded 128-fold in
+# HBM.
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T, the contraction on both last axes
+
+
+def _precision(a):
+    """Narrow operands multiply exactly on the MXU in one pass; Mosaic
+    takes no other precision for them (an ambient ``highest`` is for the
+    float32 operands it was set for)."""
+    return lax.Precision.DEFAULT if a.dtype.itemsize < 4 else None
+
+
+def _nt_dot(a, b):
+    return lax.dot_general(a, b, _NT, precision=_precision(a),
+                           preferred_element_type=jnp.float32)
+
+
+def _dot(a, b):
+    return jnp.dot(a, b, precision=_precision(a),
+                   preferred_element_type=jnp.float32)
+
+
+def _eye(n):
+    return (lax.broadcasted_iota(jnp.int32, (n, n), 0)
+            == lax.broadcasted_iota(jnp.int32, (n, n), 1))
+
+
+def _col_to_row(col):
+    """(n, 1) -> (1, n) by a masked sublane sum: once a held block, and
+    nothing but a select and a reduction for Mosaic to lay out."""
+    return jnp.sum(jnp.where(_eye(col.shape[0]), col, 0.0), axis=0, keepdims=True)
+
+
+def _valid(q_idx, k_idx, *, side, causal, kv_len, keys_first=False, **_):
+    """Which scores of tile (q_idx, k_idx) count: keys before ``kv_len``
+    and, if causal, not after their query.  (side, side) bool, queries
+    along the rows (``keys_first``: keys along the rows)."""
+    q_pos = q_idx * side + lax.broadcasted_iota(
+        jnp.int32, (side, side), int(keys_first))
+    k_pos = k_idx * side + lax.broadcasted_iota(
+        jnp.int32, (side, side), int(not keys_first))
+    valid = k_pos < kv_len
+    return valid & (k_pos <= q_pos) if causal else valid
+
+
+def _visit(q_idx, k_idx, update, *, causal, side, kv_len, l_pad, **_):
+    """``update(masked)`` on tile (q_idx, k_idx) if it holds a score that
+    counts; ``masked`` only where it also holds one to mask: the tiles
+    on the diagonal and the K blocks that reach into the padding."""
+    live, edges = [], []
+    if causal:
+        live.append(k_idx <= q_idx)
+        edges.append(k_idx == q_idx)
+    if kv_len < l_pad:
+        live.append(k_idx * side < kv_len)
+        edges.append((k_idx + 1) * side > kv_len)
+    if not edges:
+        update(False)
+        return
+    live = functools.reduce(jnp.logical_and, live)
+    masked = functools.reduce(jnp.logical_or, edges)
+    pl.when(live & masked)(lambda: update(True))
+    pl.when(live & jnp.logical_not(masked))(lambda: update(False))
+
+
+# No row of a tile sweep is ever wholly masked: key 0 lies before
+# ``kv_len`` and not after any query, and its block is the first a held
+# Q block meets.  So the running max is finite from the first update on
+# and every logsumexp is finite, and the guards `_block_update` and
+# `_tile_grads` carry for rows that have seen nothing yet (ring
+# attention hands them whole blocks of such rows) would select their
+# other branch nowhere; the kernels leave them out, bit for bit the same.
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, l_ref, m_ref, **tile):
+    """`_block_update` over the K/V blocks streaming past one Q block."""
+    q_idx, k_idx = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(k_idx == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+
+    def update(masked):
+        v = v_ref[...]
+        s = _nt_dot(q_ref[...], k_ref[...]) * tile["scale"]  # (queries, keys) f32
+        if masked:
+            s = jnp.where(_valid(q_idx, k_idx, **tile), s, -jnp.inf)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        correction = jnp.exp(m - m_new)
+        l_ref[...] = l_ref[...] * correction + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * correction + _dot(p.astype(v.dtype), v)
+        m_ref[...] = m_new
+
+    _visit(q_idx, k_idx, update, **tile)
+
+    @pl.when(k_idx == pl.num_programs(3) - 1)
+    def _():
+        lsum = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[...] = (acc_ref[...] / lsum).astype(o_ref.dtype)
+        lse_ref[...] = _col_to_row(m_ref[...] + jnp.log(lsum))
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc, **tile):
+    """`_tile_grads` and the three products over the Q blocks streaming
+    past one K/V block: the schedule's two passes in one.  The tile is
+    held keys-first (scores transposed): the row statistics then
+    broadcast along the rows as they arrive, and the dK/dV products
+    contract over the tile's last axis, nothing transposed.  Each tile's
+    dS also goes, transposed by the product itself, into its Q block's
+    rows of a dQ accumulator that spans the sequence and is written once
+    a head: five tile products and one ``exp`` where two passes take
+    seven and two."""
+    k_idx, q_idx = pl.program_id(2), pl.program_id(3)
+    last_k, last_q = pl.num_programs(2) - 1, pl.num_programs(3) - 1
+    side = tile["side"]
+
+    @pl.when((k_idx == 0) & (q_idx == 0))
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    @pl.when(q_idx == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def update(masked):
+        q, do, k = q_ref[...], do_ref[...], k_ref[...]
+        s = _nt_dot(k, q) * tile["scale"]  # (keys, queries) f32
+        if masked:
+            s = jnp.where(
+                _valid(q_idx, k_idx, keys_first=True, **tile),
+                s, -jnp.inf)
+        p = jnp.exp(s - lse_ref[...])
+        dv_acc[...] += _dot(p.astype(do.dtype), do)
+        dp = _nt_dot(v_ref[...], do)
+        ds = (p * (dp - delta_ref[...]) * tile["scale"]).astype(q.dtype)
+        dk_acc[...] += _dot(ds, q)
+        rows = pl.ds(pl.multiple_of(q_idx * side, side), side)
+        dq_acc[rows, :] += lax.dot_general(  # ds.T @ k
+            ds, k, (((0,), (0,)), ((), ())), precision=_precision(ds),
+            preferred_element_type=jnp.float32)
+
+    _visit(q_idx, k_idx, update, **tile)
+
+    @pl.when(q_idx == last_q)
+    def _():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when((k_idx == last_k) & (q_idx == last_q))
+    def _():
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+
+
+def _flash_call(kernel, name, operands, outs, scratch, *, streams, side,
+                causal, scale, kv_len, interpret, held_axis="parallel",
+                vmem_bytes=None):
+    """One kernel of the family over grid (batch, head, held, streamed),
+    in square tiles of ``side`` positions.
+
+    ``operands`` and ``outs`` are (array or ShapeDtypeStruct, role) pairs:
+    role ``"q"`` / ``"k"`` says which side's block index the array
+    follows (``"all"``: a head's whole sequence, resident); a
+    (B, H, 1, L) array is a row statistic.  ``scratch`` lists the shapes
+    of the float32 VMEM scratch.  ``streams`` names the side that moves
+    along the last grid axis; under a causal mask its index is clamped
+    to the diagonal, from above for keys (tiles past it) and from below
+    for queries (tiles before it), so a step that computes nothing
+    fetches nothing.  ``vmem_bytes`` replaces Mosaic's 16 MiB of scoped
+    VMEM."""
+    b, h, l_pad, _ = operands[0][0].shape
+    if l_pad % side:  # a floored grid would leave rows unvisited
+        raise ValueError(f"tiles of {side} do not divide {l_pad} padded positions")
+    clamp = {"k": jnp.minimum, "q": jnp.maximum}[streams]
+
+    def spec(a, role):
+        def at(b_, h_, held, streamed):
+            idx = held
+            if role == streams:
+                idx = clamp(streamed, held) if causal else streamed
+            return (b_, h_, 0, idx) if a.shape[2] == 1 else (b_, h_, idx, 0)
+
+        if role == "all":  # the whole sequence of one head, resident
+            return pl.BlockSpec((None, None, *a.shape[2:]),
+                                lambda b_, h_, held, streamed: (b_, h_, 0, 0))
+        if a.shape[2] == 1:
+            return pl.BlockSpec((None, None, 1, side), at)
+        return pl.BlockSpec((None, None, side, a.shape[3]), at)
+
+    return pl.pallas_call(
+        functools.partial(kernel, causal=causal, scale=scale, side=side,
+                          kv_len=kv_len, l_pad=l_pad),
+        grid=(b, h, l_pad // side, l_pad // side),
+        in_specs=[spec(a, role) for a, role in operands],
+        out_specs=tuple(spec(a, role) for a, role in outs),
+        out_shape=tuple(a for a, _ in outs),
+        scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in scratch],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", held_axis, "arbitrary"),
+            vmem_limit_bytes=vmem_bytes),
+        interpret=interpret,
+        name=name,
+    )(*(a for a, _ in operands))
+
+
+def _tiles(l, block):
+    """The side of the forward's square tiles and of the backward's for
+    ``l`` positions; the forward's is a multiple of the backward's, so
+    both divide ``l`` padded to it.  ``block`` None: the tiles follow
+    ``l`` alone, 512 a side, the forward's 1024 where that pads no
+    further: what it does once a tile beside the products (the row
+    maxima and sums across lanes, rescaling the accumulator) then weighs
+    half as much, which bought 1.5x at 4096 positions; the backward
+    carries no such state and ran alike from 512 up, half as fast at 256
+    (v5e, PR 28).  An explicit ``block`` is every tile's side, in whole
+    lanes and no more than `_MAX_TILE`."""
+    lanes = pad_to(l, _LANES)
+    if block is not None:
+        side = min(pad_to(block, _LANES), lanes, _MAX_TILE)
+        return side, side
+    side = min(512, lanes)
+    return (2 * side if pad_to(l, side) % _MAX_TILE == 0 else side), side
+
+
+def _bwd_vmem_bytes(l_pad, d, dtype):
+    """What the backward kernel holds in VMEM: its tiles, and a head's
+    dQ over the whole sequence as the float32 accumulator and, twice
+    (Mosaic double-buffers it), the output block."""
+    return _TILE_VMEM_BYTES + l_pad * pad_to(d, _LANES) * (
+        4 + 2 * jnp.dtype(dtype).itemsize)
+
+
+def _flash_fwd(q, k, v, causal, scale, side, kv_len, interpret):
+    """(B, H, L, D) q/k, (B, H, L, Dv) v -> out (B, H, L, Dv) in the
+    storage dtype and the rows' logsumexp (B, H, 1, L) float32."""
+    b, h, l_pad, _ = q.shape
+    dv = v.shape[-1]
+    return _flash_call(
+        _fwd_kernel, "tpuframe_flash_fwd",
+        [(q, "q"), (k, "k"), (v, "k")],
+        [(jax.ShapeDtypeStruct((b, h, l_pad, dv), q.dtype), "q"),
+         (jax.ShapeDtypeStruct((b, h, 1, l_pad), jnp.float32), "q")],
+        [(side, dv), (side, 1), (side, 1)],
+        streams="k", side=side,
+        causal=causal, scale=scale, kv_len=kv_len, interpret=interpret,
+    )
+
+
+def _flash_bwd(q, k, v, do, lse, delta, causal, scale, side, kv_len, interpret):
+    """dQ, dK, dV in the layout and dtypes of q, k, v.  ``lse`` and
+    ``delta`` (rowsum(dO . O)) are (B, H, 1, L) rows."""
+    l_pad, d, dv = q.shape[2], q.shape[-1], v.shape[-1]
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
+    dk, dv_, dq = _flash_call(
+        _bwd_kernel, "tpuframe_flash_bwd",
+        [(q, "q"), (k, "k"), (v, "k"), (do, "q"), (lse, "q"), (delta, "q")],
+        [(like(k), "k"), (like(v), "k"), (like(q), "all")],
+        [(side, d), (side, dv), (l_pad, d)], streams="q", side=side,
+        held_axis="arbitrary", vmem_bytes=_bwd_vmem_bytes(l_pad, d, q.dtype),
+        causal=causal, scale=scale, kv_len=kv_len, interpret=interpret,
+    )
+    return dq, dk, dv_
+
+
+def _heads_first(a):
+    """(B, L, H, D) <-> (B, H, L, D): the model's layout and the kernels'."""
+    return a.transpose(0, 2, 1, 3)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _blockwise_padded(q, k, v, causal, block, kv_len, scale, interpret):
+    out, _ = _blockwise_padded_fwd(q, k, v, causal, block, kv_len, scale, interpret)
     return out
 
 
-def _blockwise_padded_fwd(q, k, v, causal, block, kv_len, scale):
+def _blockwise_padded_fwd(q, k, v, causal, block, kv_len, scale, interpret):
+    """``interpret`` None: the scan schedule over blocks of ``block``;
+    else the kernels (True: in Pallas interpret mode), ``block`` their
+    (forward, backward) `_tiles`, both dividing the padded length.
+    Either way the residuals are q, k, v, the output and the rows'
+    logsumexp, each held once."""
+    if interpret is not None:
+        out, lse = _flash_fwd(
+            _heads_first(q), _heads_first(k), _heads_first(v),
+            causal, scale, block[0], kv_len, interpret,
+        )
+        out = _heads_first(out)
+        # kept with the heads folded into the rows, (B, L, H*D): whole
+        # lanes at any head width.  The kernels' layout pads a 192-wide
+        # row to 256 lanes in HBM (a 64-wide one to 128), and XLA lays a
+        # (B, L, H, D) array kept for the backward out the same way.
+        return out, (*(a.reshape(*a.shape[:2], -1) for a in (q, k, v, out)), lse)
     b, l_pad, h, d = q.shape
     n = l_pad // block
     outs, lses = _fwd_schedule(
@@ -127,8 +467,24 @@ def _blockwise_padded_fwd(q, k, v, causal, block, kv_len, scale):
     return out, (q, k, v, out, lses)
 
 
-def _blockwise_padded_bwd(causal, block, kv_len, scale, res, g):
+def _blockwise_padded_bwd(causal, block, kv_len, scale, interpret, res, g):
     q, k, v, out, lses = res
+    if interpret is not None:
+        # behind a barrier, or XLA shares the forward's transposes with
+        # these and the padded copies live from one pass to the other
+        q, k, v, out = (
+            a.reshape(*g.shape[:3], -1) for a in lax.optimization_barrier(res[:4])
+        )
+        # delta_i = rowsum(dO . O) — the softmax-normalization term of dS
+        delta = jnp.einsum(
+            "blhd,blhd->bhl", out.astype(jnp.float32), g.astype(jnp.float32)
+        )[:, :, None, :]
+        grads = _flash_bwd(
+            _heads_first(q), _heads_first(k), _heads_first(v),
+            _heads_first(g).astype(q.dtype), lses, delta,
+            causal, scale, block[1], kv_len, interpret,
+        )
+        return tuple(_heads_first(a) for a in grads)
     b, l_pad, h, d = q.shape
     n = l_pad // block
     do = g.astype(q.dtype)
@@ -225,7 +581,26 @@ def _blockwise_padded_bwd(causal, block, kv_len, scale, res, g):
 _blockwise_padded.defvjp(_blockwise_padded_fwd, _blockwise_padded_bwd)
 
 
-def blockwise_attention(
+def _check_shapes(q, k, v):
+    if k.shape != q.shape or v.shape[:3] != q.shape[:3]:
+        raise ValueError(
+            f"q/k/v shapes must match, got {q.shape}/{k.shape}/{v.shape}"
+        )
+
+
+def _padded_call(q, k, v, causal, block, scale, interpret):
+    l, d = q.shape[1], q.shape[-1]
+    l_pad = pad_to(l, block if interpret is None else block[0])
+    if l_pad != l:
+        pad = [(0, 0), (0, l_pad - l), (0, 0), (0, 0)]
+        q, k, v = (jnp.pad(a, pad) for a in (q, k, v))
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    out = _blockwise_padded(q, k, v, causal, block, l, float(scale), interpret)
+    return out[:, :l]
+
+
+def blockwise_attention_reference(
     q: jax.Array,
     k: jax.Array,
     v: jax.Array,
@@ -234,30 +609,50 @@ def blockwise_attention(
     block_size: int | None = None,
     scale: float | None = None,
 ) -> jax.Array:
+    """The scan schedule: what :func:`blockwise_attention` runs wherever
+    its kernels do not, and what they are held to."""
+    _check_shapes(q, k, v)
+    block = min(attn_block() if block_size is None else block_size, q.shape[1])
+    return _padded_call(q, k, v, causal, block, scale, None)
+
+
+def blockwise_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    *,
+    causal: bool = False,
+    block_size: int | None = None,
+    scale: float | None = None,
+    interpret: bool | None = None,
+) -> jax.Array:
     """Exact attention over (B, L, H, D) without materializing (.., L, L).
 
     ``scale`` replaces the default ``1/sqrt(D)``; ``v`` may have a width
     of its own (latent attention: 192-wide queries and keys, 128-wide
     values), which the output takes.
 
-    ``block_size`` defaults to the domain-clamped
-    ``TPUFRAME_KERNEL_ATTN_BLOCK`` knob (512) — the tile the kernel
-    ledger probes over its legal grid; an explicit value always wins.
+    ``block_size`` None: the scan schedule takes the domain-clamped
+    ``TPUFRAME_KERNEL_ATTN_BLOCK`` knob (512), the kernels tiles that
+    follow L alone (`_tiles`).  An explicit value is the schedule's
+    block and every kernel tile's side (rounded up to whole lanes, 1024
+    at most).
+
+    ``interpret``: None = auto (the kernels on a single-device TPU
+    process or inside a manual region if a head's dQ fits VMEM, the
+    scan schedule elsewhere); True runs the kernels in Pallas interpret
+    mode on any backend.
     """
-    if block_size is None:
-        block_size = attn_block()
+    _check_shapes(q, k, v)
     b, l, h, d = q.shape
-    if k.shape != q.shape or v.shape[:3] != q.shape[:3]:
-        raise ValueError(
-            f"q/k/v shapes must match, got {q.shape}/{k.shape}/{v.shape}"
+    tiles = _tiles(l, block_size)
+    fits = _bwd_vmem_bytes(pad_to(l, tiles[0]), d, q.dtype) <= _VMEM_BYTES
+    if fits or interpret is not None:
+        interpret = resolve_interpret(
+            interpret, shardable=False, op="blockwise_attention",
+            shape_class=shape_class(l=l, d=d),
         )
-    block = min(block_size, l)
-    n = -(-l // block)
-    l_pad = n * block
-    if l_pad != l:
-        pad = [(0, 0), (0, l_pad - l), (0, 0), (0, 0)]
-        q, k, v = (jnp.pad(a, pad) for a in (q, k, v))
-    if scale is None:
-        scale = 1.0 / math.sqrt(d)
-    out = _blockwise_padded(q, k, v, causal, block, l, float(scale))
-    return out[:, :l]
+    if interpret is None:
+        return blockwise_attention_reference(
+            q, k, v, causal=causal, block_size=block_size, scale=scale)
+    return _padded_call(q, k, v, causal, tiles, scale, interpret)

@@ -132,9 +132,12 @@ OPS_REGISTRY = {
     "blockwise_attention": {
         "module": "tpuframe.ops.blockwise_attention",
         "symbol": "blockwise_attention",
-        "reference": None,
-        "parity_test": "tests/test_blockwise_attention.py::test_matches_full_attention",
-        "tile_knobs": ("TPUFRAME_KERNEL_ATTN_BLOCK",),
+        "reference": "blockwise_attention_reference",
+        "parity_test":
+            "tests/test_blockwise_attention.py::test_kernel_matches_scan_schedule",
+        # the kernels' tiles follow L; TPUFRAME_KERNEL_ATTN_BLOCK is the
+        # block of the reference (the scan schedule) and prices nothing here
+        "tile_knobs": (),
     },
     "ring_attention": {
         "module": "tpuframe.ops.ring_attention",
@@ -213,8 +216,9 @@ def norm_tile_rows() -> int:
 
 
 def attn_block() -> int:
-    """Default block size for blockwise attention
-    (``TPUFRAME_KERNEL_ATTN_BLOCK``, default 512, lane-aligned)."""
+    """Default block size of blockwise attention's scan schedule
+    (``TPUFRAME_KERNEL_ATTN_BLOCK``, default 512, lane-aligned); its
+    kernels' tiles follow the sequence length."""
     return _tile("TPUFRAME_KERNEL_ATTN_BLOCK", 512, lo=128, hi=4096, step=128)
 
 
