@@ -61,11 +61,10 @@ def enable_compile_cache() -> str | None:
     return compile_cache.enable_from_env()
 
 
-def make_uint8_normalize_transform(plan, on_accel: bool):
-    """Batch transform for raw-uint8 input: fused on-device normalize
-    emitting the compute dtype directly, sharded like the trainer's own
-    normalize path (mesh/batch_axes keep GSPMD from gathering the full
-    batch onto every chip).  Shared by bench_e2e.py and
+def make_uint8_normalize_transform(on_accel: bool):
+    """Batch transform for raw-uint8 input: on-device normalize emitting
+    the compute dtype directly, the trainer's own normalize (elementwise
+    jnp that GSPMD shards with the batch).  Shared by bench_e2e.py and
     bench_tpu_experiments.py so the A/B and the e2e bench can never
     diverge on normalize semantics."""
     import jax.numpy as jnp
@@ -77,7 +76,6 @@ def make_uint8_normalize_transform(plan, on_accel: bool):
         b["image"] = normalize_images(
             b["image"], IMAGENET_MEAN, IMAGENET_STD,
             out_dtype=jnp.bfloat16 if on_accel else jnp.float32,
-            mesh=plan.mesh, batch_axes=tuple(plan.data_axes),
         )
         return b
 

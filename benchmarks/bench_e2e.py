@@ -333,7 +333,7 @@ def main() -> None:
     # raw bytes ride host->HBM; the fused normalize emits the compute
     # dtype directly (no f32 image tensor on chip)
     batch_transform = (
-        make_uint8_normalize_transform(plan, on_accel)
+        make_uint8_normalize_transform(on_accel)
         if args.uint8_input else None
     )
     step_fn = make_train_step(policy, batch_transform=batch_transform)

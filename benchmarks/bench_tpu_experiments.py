@@ -96,7 +96,7 @@ def run_config(name: str, cfg: dict, steps: int) -> dict:
 
     batch_transform = (
         headline_bench.make_uint8_normalize_transform(
-            plan, on_accel=jax.default_backend() != "cpu"
+            on_accel=jax.default_backend() != "cpu"
         )
         if uint8_input else None
     )

@@ -11,7 +11,7 @@ compiler refuses records one failed ``<section>_compile`` check with
 the error and the run moves on, so one call shows every refusal.
 
 Covers: fused LayerNorm (fwd+grads), fused cross-entropy (fwd+grad),
-fused AdamW (vs optax), fused normalize, the quant_wire trio
+the quant_wire trio
 (amax/encode/decode vs the staged jnp expressions — the in-collective
 wire's arithmetic contract), blockwise attention's flash kernels
 (fwd+grads, causal and not, and deepseek-v2-lite's latent shape in
@@ -19,8 +19,7 @@ bf16), ring and ulysses attention oracle parity on one device.
 
 Usage: python benchmarks/check_kernels_tpu.py [--only a,b,...]
 (exits 1 on any failure).  ``--only`` runs a named subset — sections:
-layer_norm, cross_entropy, adamw, normalize, quant_wire, blockwise,
-ring, ulysses.
+layer_norm, cross_entropy, quant_wire, blockwise, ring, ulysses.
 """
 
 from __future__ import annotations
@@ -46,8 +45,6 @@ def main() -> None:
     sections = {
         "layer_norm": _check_layer_norm,
         "cross_entropy": _check_cross_entropy,
-        "adamw": _check_adamw,
-        "normalize": _check_normalize,
         "quant_wire": _check_quant_wire,
         "blockwise": _check_blockwise,
         "ring": _check_ring,
@@ -160,55 +157,6 @@ def _check_cross_entropy(jax, jnp, np, rng) -> None:
                                   - gr2.astype(jnp.float32)))) * b,
             gtol,
         )
-
-
-def _check_adamw(jax, jnp, np, rng) -> None:
-    import optax
-
-    from tpuframe.ops.fused_adamw import fused_adamw
-
-    params = {"w": jnp.asarray(rng.standard_normal((1000, 257)), jnp.float32),
-              "b": jnp.asarray(rng.standard_normal((257,)), jnp.float32)}
-    grads = jax.tree.map(
-        lambda a: jnp.asarray(rng.standard_normal(a.shape), jnp.float32), params
-    )
-    txf, txo = fused_adamw(1e-3), optax.adamw(1e-3)
-    uf, _ = jax.jit(txf.update)(grads, txf.init(params), params)
-    uo, _ = jax.jit(txo.update)(grads, txo.init(params), params)
-    record(
-        "fused_adamw_update",
-        max(float(jnp.max(jnp.abs(a - c)))
-            for a, c in zip(jax.tree.leaves(uf), jax.tree.leaves(uo))),
-        1e-5,
-    )
-
-
-def _check_normalize(jax, jnp, np, rng) -> None:
-    from tpuframe.ops.normalize import normalize_images, normalize_images_reference
-
-    mean, std = (0.485, 0.456, 0.406), (0.229, 0.224, 0.225)
-    # the trainer's batch (u8 -> bf16 and -> f32), a small batch whose
-    # 48 rows are not a multiple of the 32-row uint8 tile, and a ragged
-    # one that is not lane-aligned
-    cases = (((128, 224, 224, 3), jnp.bfloat16, 2e-2),
-             ((64, 224, 224, 3), jnp.float32, 1e-5),
-             ((2, 32, 32, 3), jnp.float32, 1e-5),
-             ((3, 5, 7, 3), jnp.float32, 1e-5))
-    for shape, out_dtype, tol in cases:
-        raw = jnp.asarray(rng.integers(0, 256, shape), jnp.uint8)
-        want = normalize_images_reference(raw, mean, std, out_dtype=out_dtype)
-        # auto keeps an image batch in its own layout (plain jnp, what the
-        # Trainer's step runs); interpret=False is the kernel itself
-        for form, interpret in (("", None), ("_kernel", False)):
-            got = jax.jit(lambda r, d=out_dtype, i=interpret: normalize_images(
-                r, mean, std, out_dtype=d, interpret=i))(raw)
-            record(
-                f"normalize_images{form}_{'x'.join(map(str, shape))}"
-                f"_{jnp.dtype(out_dtype).name}",
-                float(jnp.max(jnp.abs(got.astype(jnp.float32)
-                                      - want.astype(jnp.float32)))),
-                tol,
-            )
 
 
 def _check_quant_wire(jax, jnp, np, rng) -> None:

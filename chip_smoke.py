@@ -13,8 +13,7 @@ first-step loss, the precompile report, telemetry events, the compiled
 step's HLO, the shardings) and exits non-zero if any check failed or a
 phase degraded: a lazy-jit fallback, an interpreted kernel or a jnp
 reference standing in for a kernel is a failed smoke, not an exit 0.
-(The image normalize is plain ops by its own rule, not a stand-in: an
-NHWC batch keeps its layout, and the step must hold no custom call for it.)
+(The image normalize is plain ops, not a stand-in: it has no kernel.)
 
 With no TPU it exits non-zero and prints no result — there is no CPU arm
 and no ``JAX_PLATFORMS`` override.  ``--rehearsal`` is the one explicit
@@ -45,10 +44,6 @@ _OUT_DIR = os.path.join(_ROOT, "chiprun_out", "chip_smoke")
 
 #: the Pallas kernels the train step must carry as Mosaic custom calls
 _STEP_KERNELS = ("tpuframe_ce_fwd", "tpuframe_ce_bwd")
-#: and the one it must not: an NHWC image batch is normalized in its own
-#: layout by plain ops (a custom call forces re-layouts around it that cost
-#: seventy times the kernel; ``ops/normalize.py``)
-_STEP_NOT_KERNELS = ("tpuframe_normalize",)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -186,12 +181,10 @@ def run(size: Size, checks: Checks, *, rehearsal: bool) -> dict:
     plan = ParallelPlan(mesh=rt.mesh)
 
     # -- what the step runs against the repo's own oracles, on a small input
-    # (the normalize in the form auto dispatch picks for an image batch)
     rng = np.random.default_rng(0)
     raw = jnp.asarray(rng.integers(0, 256, (8 * n_dev, 32, 32, 3)), jnp.uint8)
     got = jax.jit(lambda r: normalize_images(
-        r, IMAGENET_MEAN, IMAGENET_STD, out_dtype=jnp.bfloat16,
-        mesh=plan.mesh, batch_axes=tuple(plan.data_axes)))(raw)
+        r, IMAGENET_MEAN, IMAGENET_STD, out_dtype=jnp.bfloat16))(raw)
     want = normalize_images_reference(
         raw, IMAGENET_MEAN, IMAGENET_STD, out_dtype=jnp.bfloat16)
     diff = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32))))
@@ -287,16 +280,11 @@ def run(size: Size, checks: Checks, *, rehearsal: bool) -> dict:
     check("no_degraded_phase", not any(degraded.values()), json.dumps(degraded))
 
     verdicts = [e for e in events if e.get("name") == "ops/kernel_verdict"]
-    # cross entropy engages its kernel; the image batch's shape declines
-    # normalize's (source "layout"), whatever the ledger or the mode say
-    for op, want in (("normalize", {"enable": False, "source": "layout"}),
-                     ("cross_entropy", {"enable": True})):
-        mine = [e for e in verdicts if e.get("op") == op]
-        check(f"kernel_verdict_{op}",
-              bool(mine) and all(e.get(k) == v for e in mine
-                                 for k, v in want.items()),
-              json.dumps([{k: e.get(k) for k in ("shape_class", "enable", "source")}
-                          for e in mine]))
+    mine = [e for e in verdicts if e.get("op") == "cross_entropy"]
+    check("kernel_verdict_cross_entropy",
+          bool(mine) and all(e.get("enable") is True for e in mine),
+          json.dumps([{k: e.get(k) for k in ("shape_class", "enable", "source")}
+                      for e in mine]))
 
     compiled = next((c for (kind, _), c in trainer._compiled.items()
                      if kind == "train"), None)
@@ -308,15 +296,13 @@ def run(size: Size, checks: Checks, *, rehearsal: bool) -> dict:
         hlo = compiled.as_text()
         calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
         missing = [k for k in _STEP_KERNELS if not any(k in ln for ln in calls)]
-        unwanted = [k for k in _STEP_NOT_KERNELS if k in hlo]
-        if missing or unwanted:
+        if missing:
             os.makedirs(_OUT_DIR, exist_ok=True)
             with open(os.path.join(_OUT_DIR, "train_step.hlo.txt"), "w") as f:
                 f.write(hlo)
-        check("mosaic_custom_calls", not missing and not unwanted,
+        check("mosaic_custom_calls", not missing,
               f"{len(calls)} tpu_custom_call(s) in the compiled train step; "
-              f"missing kernels: {missing or 'none'}; "
-              f"kernels that should not be there: {unwanted or 'none'}")
+              f"missing kernels: {missing or 'none'}")
 
     # every local device holds a replica of the parameters and a shard of
     # the batch

@@ -54,14 +54,10 @@ def rng():
 @pytest.fixture()
 def compiled_backend(monkeypatch):
     """Pin the dispatch plane's view of the backend to what one TPU chip
-    says (kernels compile, one device) with the ledger bypassed, so a
-    test reads the path an op picks there off a traced or TPU-lowered
-    program.  Nothing that holds a compiled kernel can *run* under it."""
+    says (kernels compile, one device), so a test reads the path an op
+    picks there off a traced or TPU-lowered program.  Nothing that holds
+    a compiled kernel can *run* under it."""
     from tpuframe.ops import dispatch
 
     monkeypatch.setattr(dispatch, "pallas_mode", lambda: "compiled")
     monkeypatch.setattr(jax, "device_count", lambda *a: 1)
-    monkeypatch.setenv("TPUFRAME_KERNELS", "on")
-    dispatch._reset_kernel_cache()
-    yield
-    dispatch._reset_kernel_cache()

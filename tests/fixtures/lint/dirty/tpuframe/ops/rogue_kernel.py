@@ -1,5 +1,5 @@
 """OP001: a kernel module that never made it into OPS_REGISTRY —
-invisible to TPUFRAME_KERNELS dispatch and the pricing bench."""
+invisible to the doctor and the diagnosis's name map."""
 
 
 def fused_rogue(x):

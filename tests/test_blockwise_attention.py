@@ -335,16 +335,16 @@ def test_kernel_tile_rule(l, block, want):
     assert want[0] % want[1] == 0
 
 
-def test_kernel_tiles_ignore_the_schedules_knob(monkeypatch):
+def test_kernel_tiles_ignore_the_schedules_block(monkeypatch):
     seen = []
     real = bw._flash_call
     monkeypatch.setattr(
         bw, "_flash_call",
         lambda *a, side, **kw: seen.append(side) or real(*a, side=side, **kw))
-    monkeypatch.setenv("TPUFRAME_KERNEL_ATTN_BLOCK", "128")
+    monkeypatch.setattr(bw, "_SCAN_BLOCK", 128)
     q, k, v = _wide_qkv(300, 64, 64, jnp.float32)
     blockwise_attention(q, k, v, causal=True, interpret=True)
-    assert seen == [384]  # not TPUFRAME_KERNEL_ATTN_BLOCK: that is the scan schedule's
+    assert seen == [384]  # not _SCAN_BLOCK: that is the scan schedule's
 
 
 @pytest.mark.parametrize("blocks", [1, 2], ids=["one_block", "two_blocks"])
@@ -422,9 +422,7 @@ def test_kernel_verdict_event_and_auto_dispatch(monkeypatch, tmp_path):
     calls = []
     real = bw._flash_fwd
     monkeypatch.setattr(bw, "_flash_fwd", lambda *a: calls.append(1) or real(*a))
-    monkeypatch.setenv("TPUFRAME_KERNELS", "auto")
-    monkeypatch.setenv("TPUFRAME_KERNEL_LEDGER_DIR", str(tmp_path / "empty"))
-    dispatch._reset_kernel_cache()
+    dispatch._VERDICT_EMITTED.clear()
     tele = T.configure(str(tmp_path / "events.jsonl"))
     try:
         q, k, v = _wide_qkv(256, 64, 64, jnp.float32)
@@ -442,10 +440,12 @@ def test_kernel_verdict_event_and_auto_dispatch(monkeypatch, tmp_path):
         assert len(calls) == 2
         events = [e for e in tele.recent_events(50) if e["name"] == "ops/kernel_verdict"]
         assert [(e["op"], e["shape_class"], e["enable"], e["source"]) for e in events] == [
-            ("blockwise_attention", "d64_l256", True, "default")]
+            ("blockwise_attention", "d64_l256", False, "default"),
+            ("blockwise_attention", "d64_l256", True, "default"),
+            ("blockwise_attention", "d64_l256", False, "forced")]
     finally:
         T.reset()
-        dispatch._reset_kernel_cache()
+        dispatch._VERDICT_EMITTED.clear()
 
 
 def test_lowered_latent_step_holds_the_flash_kernels(compiled_backend):

@@ -51,7 +51,7 @@ def test_rehearsal_passes_on_two_virtual_devices():
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
     assert "FAIL" not in proc.stdout
     for name in ("pallas_mode", "compile_cache_dir", "first_loss_vs_reference",
-                 "precompile", "no_degraded_phase", "kernel_verdict_normalize",
+                 "precompile", "no_degraded_phase",
                  "kernel_verdict_cross_entropy", "params_replicated",
                  "batch_sharded"):
         assert f"PASS {name}" in proc.stdout, name

@@ -13,14 +13,12 @@ import jax.numpy as jnp
 import pytest
 
 # by module path: tpuframe.ops re-exports functions under some of these names
-ce, adamw, ln, norm, qw = (
+ce, ln, qw = (
     importlib.import_module(f"tpuframe.ops.{m}")
-    for m in ("cross_entropy", "fused_adamw", "layer_norm", "normalize",
-              "quant_wire")
+    for m in ("cross_entropy", "layer_norm", "quant_wire")
 )
 
 _F32 = jnp.float32
-_HP = dict(lr=1e-3, b1=0.9, b2=0.999, eps=1e-8, weight_decay=0.0)
 
 
 def _x(*shape, dtype=_F32):
@@ -28,11 +26,6 @@ def _x(*shape, dtype=_F32):
 
 
 KERNELS = {
-    "tpuframe_normalize": (
-        lambda flat: norm._pallas_normalize(
-            flat, (1.0, 1.0, 1.0), (0.0, 0.0, 0.0), 3, jnp.bfloat16, False),
-        (_x(8 * 32 * 32 * 3, dtype=jnp.uint8),),
-    ),
     "tpuframe_ce_fwd": (
         lambda lg, lb: ce._fwd_pallas(lg, lb, False),
         (_x(32, 1000), _x(32, dtype=jnp.int32)),
@@ -48,10 +41,6 @@ KERNELS = {
     "tpuframe_layer_norm_bwd": (
         lambda x, s, g: ln._bwd_pallas(x, s, g, 1e-6, False),
         (_x(64, 768), _x(768), _x(64, 768)),
-    ),
-    "tpuframe_fused_adamw": (
-        lambda t, p, g, m, v: adamw._pallas_update(t, p, g, m, v, _HP, False),
-        (_x(1, 1),) + (_x(64, 128),) * 4,
     ),
     "tpuframe_quant_amax": (
         lambda v: qw._pallas_bucket_abs_max(v, False), (_x(8, 2048),),

@@ -205,13 +205,13 @@ def test_ops_registry_rules(dirty):
     (op1,) = by["OP001"]
     assert "rogue_kernel" in op1.message
     assert op1.file == "tpuframe/ops/rogue_kernel.py"
-    # OP002/OP003 anchor at the stale registry row in ledger.py
+    # OP002/OP003 anchor at the stale registry row in registry.py
     (op2,) = by["OP002"]
     assert "test_listed.py" in op2.message
-    assert op2.file == "tpuframe/ops/ledger.py"
+    assert op2.file == "tpuframe/ops/registry.py"
     (op3,) = by["OP003"]
     assert "fused_listed" in op3.message
-    assert op3.file == "tpuframe/ops/ledger.py"
+    assert op3.file == "tpuframe/ops/registry.py"
 
 
 def test_hotpath_negatives_stay_quiet():

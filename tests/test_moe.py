@@ -177,19 +177,19 @@ class TestMoEGatingKernel:
                                        atol=1e-4)
 
     def test_kernels_off_forces_reference_path(self, monkeypatch):
-        from tpuframe.ops import dispatch
+        from tpuframe.ops import moe_gating
         from tpuframe.ops.moe_gating import moe_dispatch_combine
 
         *inputs, capacity = self._case(n=16)
-        monkeypatch.setenv("TPUFRAME_KERNELS", "off")
-        dispatch._reset_kernel_cache()
-        try:
-            off = moe_dispatch_combine(*inputs, capacity=capacity)
-            monkeypatch.setenv("TPUFRAME_KERNELS", "on")
-            dispatch._reset_kernel_cache()
-            on = moe_dispatch_combine(*inputs, capacity=capacity)
-        finally:
-            dispatch._reset_kernel_cache()
+        calls = []
+        real = moe_gating.moe_dispatch_combine_reference
+        monkeypatch.setattr(
+            moe_gating, "moe_dispatch_combine_reference",
+            lambda *a, **kw: calls.append(1) or real(*a, **kw))
+        off = moe_dispatch_combine(*inputs, capacity=capacity, fused=False)
+        assert calls == [1]
+        on = moe_dispatch_combine(*inputs, capacity=capacity)
+        assert calls == [1]  # the default is the fused form
         np.testing.assert_allclose(np.asarray(on), np.asarray(off), atol=1e-5)
 
 

@@ -528,6 +528,67 @@ class TestDeviceNormalize:
         assert np.isfinite(result.metrics["train_loss"])
 
 
+    def test_train_accum_and_eval_steps_normalize_alike(self):
+        """One transform feeds the train step, the grad-accum scan and the
+        eval step: the same raw batch reaches the model bit-equal through
+        all three, (B, ...) and (n_micro, micro, ...) alike."""
+        from flax import linen as nn
+
+        from tpuframe.ops import normalize_images_reference
+
+        seen: dict[str, list] = {}
+
+        class Probe(nn.Module):
+            tag: str
+
+            @nn.compact
+            def __call__(self, x, train: bool = False):
+                kind = f"{self.tag}/{'train' if train else 'eval'}"
+                if not self.is_initializing():
+                    jax.debug.callback(
+                        lambda a: seen.setdefault(kind, []).append(np.asarray(a)), x)
+                return nn.Dense(4)(x.reshape((x.shape[0], -1)))
+
+        mean, std = (0.4, 0.45, 0.5), (0.2, 0.25, 0.3)
+        rng = np.random.default_rng(14)
+        raw = rng.integers(0, 256, (16, 8, 8, 3), dtype=np.uint8)
+        labels = rng.integers(0, 4, (16,)).astype(np.int32)
+
+        class Arrays:
+            def __len__(self):
+                return 16
+
+            def __getitem__(self, i):
+                return raw[i], int(labels[i])
+
+        for tag, grad_accum in (("plain", 1), ("accum", 2)):
+            def loader():
+                return DataLoader(Arrays(), 16, shuffle=False,
+                                  process_index=0, process_count=1)
+
+            Trainer(
+                Probe(tag), train_dataloader=loader(), eval_dataloader=loader(),
+                max_duration="1ep", num_classes=4, log_interval=0,
+                grad_accum=grad_accum, normalize=(mean, std),
+                sample_input=np.zeros((1, 8, 8, 3), np.float32),
+            ).fit()
+            jax.effects_barrier()
+
+        def whole(kind):
+            # the scan hands the model one microbatch at a time, in order
+            got = np.concatenate(seen[kind])
+            assert got.shape == raw.shape and got.dtype == np.float32, kind
+            return got
+
+        plain = whole("plain/train")
+        for kind in ("plain/eval", "accum/train", "accum/eval"):
+            np.testing.assert_array_equal(whole(kind), plain, err_msg=kind)
+        assert len(seen["accum/train"]) == 2
+        np.testing.assert_allclose(
+            plain, np.asarray(normalize_images_reference(raw, mean, std)),
+            atol=8 * float(np.finfo(np.float32).eps), rtol=0)
+
+
 class TestMidEpochResume:
     @pytest.mark.slow
     def test_crash_resumes_with_next_batch_not_replay(self, tmp_path):

@@ -109,6 +109,60 @@ def test_lm_without_runtime_defaults_to_full():
         rt.reset_runtime()
 
 
+# (positions, runtime mesh, initializing) -> the form attn_impl="auto" takes.
+# The first two are the benchmark's cells by name: they pin the programs the
+# numbers in PERF_LEDGER.jsonl are for.
+_AUTO_RULE = {
+    "gpt2m_seq1024": (1024, None, False, "full"),
+    "dsv2lite_seq4096": (4096, None, False, "blockwise"),
+    "gpt2m_dp4_data_mesh": (1024, MeshSpec(data=-1), False, "full"),
+    "one_below_the_threshold": (None, None, False, "full"),
+    "at_the_threshold": (None, None, False, "blockwise"),
+    "long_on_a_data_mesh": (8192, MeshSpec(data=-1), False, "blockwise"),
+    "sequence_axis_sharded": (1024, MeshSpec(data=2, seq=2, model=2), False, "ring"),
+    "sequence_axis_sharded_long": (
+        4096, MeshSpec(data=2, seq=2, model=2), False, "ring"),
+    "initializing": (4096, None, True, "full"),
+    "initializing_sequence_axis_sharded": (
+        1024, MeshSpec(data=2, seq=2, model=2), True, "full"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_AUTO_RULE))
+def test_attend_auto_rule(case, monkeypatch):
+    """One rule behind ``attn_impl="auto"``: the sequence axis sharded ->
+    ring; else ``_BLOCKWISE_AUTO_LEN`` positions or more -> blockwise; else
+    full.  Read off which attention core ``_attend`` calls."""
+    import importlib
+
+    from tpuframe.models import transformer
+
+    length, spec, initializing, want = _AUTO_RULE[case]
+    if length is None:
+        length = transformer._BLOCKWISE_AUTO_LEN - (case == "one_below_the_threshold")
+    took = []
+    monkeypatch.setattr(transformer, "attention_reference",
+                        lambda q, k, v, **kw: took.append("full") or v)
+    monkeypatch.setattr(transformer, "ring_attention_local",
+                        lambda q, k, v, **kw: took.append("ring") or v)
+    monkeypatch.setattr(
+        # by module path: tpuframe.ops re-exports the function under this name
+        importlib.import_module("tpuframe.ops.blockwise_attention"),
+        "blockwise_attention",
+        lambda q, k, v, **kw: took.append("blockwise") or v)
+    rt.reset_runtime()
+    try:
+        if spec is not None:
+            rt.initialize(spec)
+        qkv = jnp.zeros((2, length, 2, 8), jnp.float32)
+        out = transformer._attend(qkv, qkv, qkv, impl="auto", causal=True,
+                                  num_heads=2, initializing=initializing)
+        assert out.shape == qkv.shape
+        assert took == [want]
+    finally:
+        rt.reset_runtime()
+
+
 class TestRemat:
     def test_remat_lm_identical_outputs_and_grads(self):
         """remat=True changes memory/compute scheduling, never numerics."""

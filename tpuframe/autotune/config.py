@@ -204,7 +204,6 @@ def all_env_domains() -> dict[str, dict]:
     from tpuframe.compile.cache import COMPILE_ENV_DOMAINS
     from tpuframe.core.workspace import PERF_ENV_DOMAINS
     from tpuframe.fault.health import HEALTH_ENV_DOMAINS
-    from tpuframe.ops.ledger import KERNEL_ENV_DOMAINS
     from tpuframe.parallel.comms_env import COMMS_ENV_DOMAINS
     from tpuframe.serve.admission import SERVE_ENV_DOMAINS
     from tpuframe.track.telemetry import OBSERVABILITY_ENV_DOMAINS
@@ -212,7 +211,7 @@ def all_env_domains() -> dict[str, dict]:
     out: dict[str, dict] = {}
     for d in (OBSERVABILITY_ENV_DOMAINS, COMPILE_ENV_DOMAINS,
               HEALTH_ENV_DOMAINS, SERVE_ENV_DOMAINS, PERF_ENV_DOMAINS,
-              COMMS_ENV_DOMAINS, AUTOTUNE_ENV_DOMAINS, KERNEL_ENV_DOMAINS):
+              COMMS_ENV_DOMAINS, AUTOTUNE_ENV_DOMAINS):
         out.update(d)
     return out
 

@@ -37,7 +37,7 @@ from __future__ import annotations
 import dataclasses
 
 from tpuframe.autotune.config import all_env_domains, clamp
-from tpuframe.ops.ledger import normalize_top_ops
+from tpuframe.ops.registry import normalize_top_ops
 
 __all__ = ["Diagnosis", "KnobMove", "diagnose"]
 
@@ -263,10 +263,10 @@ def diagnose(report: dict, *, gauges: dict | None = None) -> Diagnosis:
         # time, never a slower run.
         top = (report.get("device_time") or {}).get("top_ops")
         if top:
-            # the ledger's name map turns raw profiler names into
+            # the registry's name map turns raw profiler names into
             # actionable tpuframe ops: a detail row says
             # "cross_entropy", not "log_softmax_fusion" — an operator
-            # (and the kernel plane) can act on the former
+            # can act on the former
             top = normalize_top_ops(top[:5])
             detail["top_ops"] = top
             comms = report.get("comms") or {}
@@ -295,19 +295,8 @@ def diagnose(report: dict, *, gauges: dict | None = None) -> Diagnosis:
                 move("TPUFRAME_DISABLE_PALLAS", False,
                      f"compute-bound on fusable ops ({names}) — make sure "
                      "the Pallas kernel paths (layer_norm, cross_entropy, "
-                     "adamw, quant_wire) are engaged, not the staged jnp "
+                     "quant_wire) are engaged, not the staged jnp "
                      "references")
-            # rows the name map pins to dispatchable tpuframe ops are
-            # the kernel ledger's A/B territory: auto dispatch prices
-            # each kernel (and its tile grid) per shape class
-            priced = [op for op in top[:5] if op.get("op")]
-            if priced:
-                names = ",".join(op["op"] for op in priced[:3])
-                move("TPUFRAME_KERNELS", "auto",
-                     f"compute-bound on dispatchable ops ({names}) — let "
-                     "the kernel ledger A/B-price each kernel and its "
-                     "tile knobs for this shape class "
-                     "(benchmarks/bench_kernels.py persists verdicts)")
 
     # compile block rides along regardless of bound: a cold compile that
     # dominates the window says the cache/precompiler are off

@@ -623,7 +623,8 @@ class MDSDataset:
             image = self.transform(
                 image, item_rng(self.rng_seed, self.epoch, int(idx))
             )
-        return np.asarray(image), int(rec[self.label_key])
+        # int32: the loader sizes its label rows from the first sample
+        return np.asarray(image), np.int32(rec[self.label_key])
 
     def __getstate__(self):
         # handles, not bytes, cross the process boundary (SURVEY §3.2)

@@ -627,26 +627,12 @@ def autotune_section(devices: dict | None = None) -> dict:
 
 def kernels_section(devices: dict | None = None) -> dict:
     """State of the kernel dispatch plane (``tpuframe.ops``): which
-    Pallas execution mode the env + probed backend would pick, the
-    ``TPUFRAME_KERNELS`` dispatch mode, the live tile-knob values (as
-    the domain-clamped reads the kernels will actually use), every
-    registered dispatchable op, and this host's persisted A/B verdicts
-    with their shape classes — so a "kernels feel off" report says up
-    front what would dispatch and whose measurement decided it.
-    Stdlib-only reads (the ledger module never imports jax); the Pallas
-    mode is recomputed from env + the subprocess probe's backend rather
-    than calling ``ops.dispatch.pallas_mode()``, which needs jax."""
-    from tpuframe.ops.ledger import (
-        KERNEL_ENV_VARS,
-        OPS_REGISTRY,
-        attn_block,
-        ce_rows,
-        kernels_mode,
-        ledger_dir,
-        list_ledgers,
-        norm_tile_rows,
-    )
-    from tpuframe.autotune.config import default_host
+    Pallas execution mode the env + probed backend would pick, and every
+    registered dispatchable op.  Stdlib-only reads (the registry module
+    never imports jax); the mode is recomputed from env + the subprocess
+    probe's backend rather than calling ``ops.dispatch.pallas_mode()``,
+    which needs jax."""
+    from tpuframe.ops.registry import OPS_REGISTRY
 
     falsy = {"", "0", "false", "no", "off"}
     disabled = os.environ.get(
@@ -655,54 +641,17 @@ def kernels_section(devices: dict | None = None) -> dict:
         "TPUFRAME_PALLAS_INTERPRET", "").strip().lower() not in falsy
     backend = (devices or {}).get("backend")
     if disabled:
-        pallas = None
+        mode = None
     elif interpret:
-        pallas = "interpret"
+        mode = "interpret"
     elif backend is None:
-        pallas = "unprobed"  # backend probe failed; can't tell
+        mode = "unprobed"  # backend probe failed; can't tell
     else:
-        pallas = "compiled" if backend == "tpu" else None
-
-    host = default_host()
-    ledgers = []
-    for led in list_ledgers():
-        if led.host != host:
-            continue
-        ops = {}
-        for op, classes in sorted(led.verdicts.items()):
-            ops[op] = {
-                cls: {
-                    k: v for k, v in verdict.items()
-                    if k in ("enable", "choice", "env", "ratio")
-                }
-                for cls, verdict in sorted(classes.items())
-            }
-        ledgers.append({
-            "backend": led.backend,
-            "signature": led.signature,
-            "matches_probed_backend": (
-                None if backend is None else led.backend == backend
-            ),
-            "verdicts": ops,
-        })
+        mode = "compiled" if backend == "tpu" else None
     return {
-        "mode": kernels_mode(),
-        "pallas": pallas,
+        "mode": mode,
         "registry": sorted(OPS_REGISTRY),
-        "tiles": {
-            "TPUFRAME_KERNEL_CE_ROWS": ce_rows(),
-            "TPUFRAME_KERNEL_NORM_TILE_ROWS": norm_tile_rows(),
-            "TPUFRAME_KERNEL_ATTN_BLOCK": attn_block(),
-        },
-        "env": {
-            k: os.environ[k] for k in KERNEL_ENV_VARS if k in os.environ
-        },
-        "store": ledger_dir(),
-        "ledgers": ledgers,
-        # the paste-ready pair: how to (re)price this host's kernels and
-        # how to price the attention round
-        "price": "python benchmarks/bench_kernels.py --json",
-        "attention": "python benchmarks/bench_attention.py --json",
+        "attention": "python benchmarks/bench_attention.py --standalone B,L,H,D",
     }
 
 

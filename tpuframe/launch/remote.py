@@ -128,11 +128,11 @@ def all_env_vars() -> tuple[str, ...]:
     (``OBSERVABILITY_ENV_VARS``, ``COMPILE_ENV_VARS``,
     ``HEALTH_ENV_VARS``, ``SERVE_ENV_VARS``, ``PERF_ENV_VARS``,
     ``COMMS_ENV_VARS``, ``AUTOTUNE_ENV_VARS``, ``PROFILE_ENV_VARS``,
-    ``MEMORY_ENV_VARS``, ``KERNEL_ENV_VARS``);
+    ``MEMORY_ENV_VARS``);
     new spines add
     themselves HERE, and both consumers pick them up for free — the
     concrete first step toward the ROADMAP item-5 typed knob registry.
-    All ten source modules are
+    All nine source modules are
     stdlib-only imports (no jax), so this resolves on a wedged-backend
     doctor run too.  The invariant linter (``tpuframe.lint`` rule
     KN004) fails tier-1 if a knob list exists that this aggregate does
@@ -142,7 +142,6 @@ def all_env_vars() -> tuple[str, ...]:
     from tpuframe.compile.cache import COMPILE_ENV_VARS
     from tpuframe.core.workspace import PERF_ENV_VARS
     from tpuframe.fault.health import HEALTH_ENV_VARS
-    from tpuframe.ops.ledger import KERNEL_ENV_VARS
     from tpuframe.parallel.comms_env import COMMS_ENV_VARS
     from tpuframe.serve.admission import SERVE_ENV_VARS
     from tpuframe.track.device_time import PROFILE_ENV_VARS
@@ -151,8 +150,7 @@ def all_env_vars() -> tuple[str, ...]:
 
     return (OBSERVABILITY_ENV_VARS + COMPILE_ENV_VARS + HEALTH_ENV_VARS
             + SERVE_ENV_VARS + PERF_ENV_VARS + COMMS_ENV_VARS
-            + AUTOTUNE_ENV_VARS + PROFILE_ENV_VARS + MEMORY_ENV_VARS
-            + KERNEL_ENV_VARS)
+            + AUTOTUNE_ENV_VARS + PROFILE_ENV_VARS + MEMORY_ENV_VARS)
 
 
 class _Worker:

@@ -41,7 +41,7 @@ Rule families (catalog with fix hints in LINT.md):
   site is declared in ``fault.chaos.CHAOS_SITES`` and documented in
   FAULT.md, and every declared site is actually instrumented.
 - **OP** (``lint.ops_registry``) — kernel dispatch registry: every
-  ``ops/`` kernel module is declared in ``ops.ledger.OPS_REGISTRY``
+  ``ops/`` kernel module is declared in ``ops.registry.OPS_REGISTRY``
   with a resolvable entry point and an existing parity test, so a
   kernel can't ship undispatched or untested.
 

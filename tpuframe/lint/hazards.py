@@ -122,7 +122,7 @@ def _reachable(seeds, by_name, stop_modules=frozenset()) -> set[int]:
     a module that contractually cannot import jax or numpy holds no
     device arrays and no tracers, so neither hazard class can propagate
     through it — expanding past it only manufactures false positives
-    (e.g. a trace-time ledger read name-resolving into every
+    (e.g. a trace-time registry read name-resolving into every
     ``from_dict`` in the tree)."""
     seen: set[int] = set()
     work = list(seeds)

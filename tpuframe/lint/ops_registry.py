@@ -1,15 +1,14 @@
 """OP rules — the kernel dispatch registry vs the ``ops/`` modules.
 
-The kernel ledger dispatches by name: ``ops.ledger.OPS_REGISTRY`` is
-the closed list of dispatchable kernels, each with its entry-point
-symbol and the parity test that pins kernel == jnp oracle.  A kernel
-module absent from the registry is invisible to ``TPUFRAME_KERNELS``
-and the pricing bench (it ships un-A/B-able); a registry row whose
-parity test doesn't exist is an untested dispatch claim.  Rules:
+``ops.registry.OPS_REGISTRY`` is the closed list of dispatchable
+kernels, each with its entry-point symbol and the parity test that pins
+kernel == jnp oracle.  A kernel module absent from the registry is
+invisible to the doctor and the diagnosis's name map; a registry row
+whose parity test doesn't exist is an untested dispatch claim.  Rules:
 
 - **OP001** — an ``ops/`` kernel module missing from ``OPS_REGISTRY``
-  (the dispatch plumbing itself — ``dispatch``, ``ledger``, the package
-  ``__init__`` — is exempt).
+  (the dispatch plumbing itself — ``dispatch``, ``registry``, the
+  package ``__init__`` — is exempt).
 - **OP002** — a registry row whose ``parity_test``
   (``tests/file.py::[Class::]test_name``) points at a missing file or
   a test function that isn't defined there.
@@ -34,12 +33,12 @@ RULES = {
 }
 
 #: dispatch plumbing, not kernels — exempt from OP001
-_PLUMBING = ("dispatch", "ledger")
+_PLUMBING = ("dispatch", "registry")
 
 
-def _ledger_module(repo: Repo) -> str | None:
+def _registry_module(repo: Repo) -> str | None:
     for name in repo.files:
-        if name.endswith(".ops.ledger"):
+        if name.endswith(".ops.registry"):
             return name
     return None
 
@@ -51,7 +50,7 @@ def _const(node) -> object:
 def declared_ops(repo: Repo) -> dict[str, dict]:
     """op -> {field: value, "line": decl line}, from the OPS_REGISTRY
     dict literal (string/None fields only — tuples are skipped)."""
-    mod = _ledger_module(repo)
+    mod = _registry_module(repo)
     if mod is None:
         return {}
     out: dict[str, dict] = {}
@@ -93,12 +92,12 @@ def _defined_symbols(repo: Repo, module: str) -> set[str]:
 
 
 def _parity_test_finding(repo: Repo, op: str, entry: dict,
-                         ledger_rel: str) -> Finding | None:
+                         registry_rel: str) -> Finding | None:
     ref = entry.get("parity_test")
     line = entry["line"]
     if not isinstance(ref, str) or "::" not in ref:
         return Finding(
-            rule="OP002", file=ledger_rel, line=line,
+            rule="OP002", file=registry_rel, line=line,
             message=(
                 f"OPS_REGISTRY[{op!r}] parity_test must be "
                 "'tests/file.py::[Class::]test_name', got "
@@ -111,7 +110,7 @@ def _parity_test_finding(repo: Repo, op: str, entry: dict,
     abspath = os.path.join(repo.docs_root, path)
     if not os.path.exists(abspath):
         return Finding(
-            rule="OP002", file=ledger_rel, line=line,
+            rule="OP002", file=registry_rel, line=line,
             message=(
                 f"OPS_REGISTRY[{op!r}] parity test file {path!r} does "
                 "not exist"
@@ -125,7 +124,7 @@ def _parity_test_finding(repo: Repo, op: str, entry: dict,
         text = ""
     if f"def {test_name}" not in text:
         return Finding(
-            rule="OP002", file=ledger_rel, line=line,
+            rule="OP002", file=registry_rel, line=line,
             message=(
                 f"OPS_REGISTRY[{op!r}] names parity test "
                 f"{test_name!r} but {path} defines no such test"
@@ -136,10 +135,10 @@ def _parity_test_finding(repo: Repo, op: str, entry: dict,
 
 
 def check(repo: Repo) -> list[Finding]:
-    ledger_mod = _ledger_module(repo)
-    if ledger_mod is None:
+    registry_mod = _registry_module(repo)
+    if registry_mod is None:
         return []
-    ledger_src = repo.files[ledger_mod]
+    registry_src = repo.files[registry_mod]
     declared = declared_ops(repo)
     registered_modules = {
         e.get("module") for e in declared.values()
@@ -147,7 +146,7 @@ def check(repo: Repo) -> list[Finding]:
     findings: list[Finding] = []
 
     # OP001: every ops/ kernel module is in the registry
-    ops_pkg = ledger_mod.rsplit(".", 1)[0]  # "<package>.ops"
+    ops_pkg = registry_mod.rsplit(".", 1)[0]  # "<package>.ops"
     for module, src in sorted(repo.files.items()):
         if not module.startswith(ops_pkg + "."):
             continue
@@ -159,11 +158,11 @@ def check(repo: Repo) -> list[Finding]:
                 rule="OP001", file=src.rel, line=1,
                 message=(
                     f"ops kernel module {module!r} is not declared in "
-                    "ops.ledger.OPS_REGISTRY"
+                    "ops.registry.OPS_REGISTRY"
                 ),
                 hint=(
                     "add a registry row (module, symbol, reference, "
-                    "parity_test) so the op is dispatchable and priced"
+                    "parity_test) so the op is dispatchable and tested"
                 ),
             ))
 
@@ -172,7 +171,7 @@ def check(repo: Repo) -> list[Finding]:
         module = entry.get("module")
         if not isinstance(module, str) or module not in repo.files:
             findings.append(Finding(
-                rule="OP003", file=ledger_src.rel, line=line,
+                rule="OP003", file=registry_src.rel, line=line,
                 message=(
                     f"OPS_REGISTRY[{op!r}] module {module!r} is not in "
                     "the scanned tree"
@@ -187,14 +186,14 @@ def check(repo: Repo) -> list[Finding]:
                     continue  # reference=None: kernel is its own oracle
                 if sym not in symbols:
                     findings.append(Finding(
-                        rule="OP003", file=ledger_src.rel, line=line,
+                        rule="OP003", file=registry_src.rel, line=line,
                         message=(
                             f"OPS_REGISTRY[{op!r}] {field} {sym!r} is "
                             f"not defined in {module}"
                         ),
                         hint="fix the registry row or define the symbol",
                     ))
-        f = _parity_test_finding(repo, op, entry, ledger_src.rel)
+        f = _parity_test_finding(repo, op, entry, registry_src.rel)
         if f is not None:
             findings.append(f)
     return findings

@@ -180,9 +180,8 @@ class MoEMLP(nn.Module):
             # --- dispatch / expert MLPs / combine, capacity-truncated ----
             # tpuframe.ops.moe_gating owns the mechanics: the fused path
             # scatter-adds kept tokens straight into the (E, C, D) expert
-            # buffers (no (kN, E, C) one-hot tensor), the dense-einsum
-            # reference is the oracle, and the kernel ledger decides which
-            # runs (TPUFRAME_KERNELS / a priced per-shape verdict).
+            # buffers (no (kN, E, C) one-hot tensor); the dense-einsum
+            # reference is the oracle.
             if self.gated:
                 raise ValueError("gated experts run in the no-drop layer "
                                  "(capacity_factor=None)")

@@ -140,6 +140,23 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     return (x32 * cos[None, :, None, :] + rot * sin[None, :, None, :]).astype(x.dtype)
 
 
+def _resolve_impl(impl: str, length: int, mesh, initializing: bool) -> str:
+    """The attention form ``impl`` stands for, from what the call can
+    see: the one rule behind ``attn_impl="auto"``."""
+    if initializing:
+        # init traces with a sample batch that need not divide the mesh;
+        # attention has no params, so the full path initializes
+        # identically to ring.
+        return "full"
+    if impl != "auto":
+        return impl
+    if mesh is not None and mesh.shape.get(SEQUENCE_AXIS, 1) > 1:
+        return "ring"
+    # long unsharded context: the (B,H,L,L) score matrix is the memory
+    # hazard; take the flash-style linear-memory path
+    return "blockwise" if length >= _BLOCKWISE_AUTO_LEN else "full"
+
+
 def _attend(q, k, v, *, impl: str, causal: bool, num_heads: int,
             initializing: bool, scale: float | None = None) -> jax.Array:
     """The attention core every attention module dispatches to:
@@ -147,32 +164,8 @@ def _attend(q, k, v, *, impl: str, causal: bool, num_heads: int,
     ``scale`` (None: ``1/sqrt(D)``) and a value width of its own are
     taken by ``full`` and ``blockwise``; the sequence-sharded forms keep
     one head width and the default scale."""
-    l = q.shape[1]
     mesh = _mesh_or_none()
-    if initializing:
-        # init traces with a sample batch that need not divide the mesh;
-        # attention has no params, so the full path initializes
-        # identically to ring.
-        impl = "full"
-    elif impl == "auto":
-        seq_sharded = mesh is not None and mesh.shape.get(SEQUENCE_AXIS, 1) > 1
-        if seq_sharded:
-            impl = "ring"
-        else:
-            # measured first: the kernel ledger's priced verdict for
-            # this seq-length shape class (bench_attention persists
-            # them); the static memory-hazard heuristic is only the
-            # fallback when nothing has been measured here
-            from tpuframe.ops.ledger import attention_choice
-
-            impl = attention_choice(l)
-            if impl is None:
-                # long unsharded context: the (B,H,L,L) score matrix
-                # is the memory hazard; take the flash-style
-                # linear-memory path
-                impl = (
-                    "blockwise" if l >= _BLOCKWISE_AUTO_LEN else "full"
-                )
+    impl = _resolve_impl(impl, q.shape[1], mesh, initializing)
     widened = {} if scale is None else {"scale": scale}
     if impl in ("ring", "ulysses"):
         if mesh is None:

@@ -9,15 +9,11 @@ fallback and the correctness oracle for tests.
 
 - :func:`normalize_images` — uint8→float, scale, per-channel mean/std
   normalize in one pass (replaces torchvision's ToTensor+Normalize
-  chain, `/root/reference/utils/hf_dataset_utilities.py:58-81`): an
-  image batch in its own layout as one XLA fusion, a flat stream by the
-  kernel.
+  chain, `/root/reference/utils/hf_dataset_utilities.py:58-81`): plain
+  jnp on the batch in its own layout, one XLA fusion.
 - :func:`fused_cross_entropy` — softmax cross entropy with a custom VJP
   that recomputes the softmax in the backward kernel instead of
   materializing it in HBM.
-- :func:`fused_adamw` — one-kernel AdamW moment+param update (the
-  DeepSpeed "fused Adam" role, engaged via its ZeRO configs,
-  `/root/reference/02_deepspeed/deepspeed_config.py:28-40`).
 - :func:`grouped_matmul` — rows sorted by group times one weight a
   group, the expert product of the no-drop mixture-of-experts layer
   (``jax.lax.ragged_dot``: XLA's own tiled kernel on the TPU).
@@ -39,8 +35,6 @@ import types as _types
 
 _LAZY = {
     "use_pallas": "tpuframe.ops.dispatch",
-    "kernel_enabled": "tpuframe.ops.dispatch",
-    "kernels_mode": "tpuframe.ops.ledger",
     "grouped_matmul": "tpuframe.ops.grouped_matmul",
     "grouped_matmul_reference": "tpuframe.ops.grouped_matmul",
     "moe_dispatch_combine": "tpuframe.ops.moe_gating",
@@ -49,8 +43,6 @@ _LAZY = {
     "normalize_images_reference": "tpuframe.ops.normalize",
     "fused_cross_entropy": "tpuframe.ops.cross_entropy",
     "cross_entropy_reference": "tpuframe.ops.cross_entropy",
-    "fused_adamw": "tpuframe.ops.fused_adamw",
-    "fused_adamw_update": "tpuframe.ops.fused_adamw",
     "FusedLayerNorm": "tpuframe.ops.layer_norm",
     "fused_layer_norm": "tpuframe.ops.layer_norm",
     "layer_norm_reference": "tpuframe.ops.layer_norm",
@@ -89,9 +81,8 @@ def __dir__():
 
 
 class _OpsModule(_types.ModuleType):
-    """Four exports share their kernel module's name
-    (``blockwise_attention``, ``fused_adamw``, ``grouped_matmul``,
-    ``ring_attention``), and
+    """Three exports share their kernel module's name
+    (``blockwise_attention``, ``grouped_matmul``, ``ring_attention``), and
     importing such a submodule makes the import machinery rebind the
     module object over the package attribute of the same name — which
     would shadow the function for every later
@@ -109,8 +100,7 @@ def _shadow_proof(name):
     )
 
 
-for _name in ("blockwise_attention", "fused_adamw", "grouped_matmul",
-              "ring_attention"):
+for _name in ("blockwise_attention", "grouped_matmul", "ring_attention"):
     setattr(_OpsModule, _name, _shadow_proof(_name))
 
 _sys.modules[__name__].__class__ = _OpsModule

@@ -13,8 +13,8 @@ AND backward.  One arithmetic, two schedules of it:
   in VMEM; HBM sees q, k, v, the output and the rows' logsumexp, once
   each.  The kernels are bound by the MXU, not by bandwidth.
 - **The scan schedule** (:func:`blockwise_attention_reference`)
-  everywhere else — CPU, ``TPUFRAME_DISABLE_PALLAS``,
-  ``TPUFRAME_KERNELS=off``, a multi-device jit without a mesh, a
+  everywhere else — CPU, ``TPUFRAME_DISABLE_PALLAS``, a multi-device
+  jit without a mesh, a
   sequence whose dQ of one head outgrows VMEM (`_bwd_vmem_bytes`: past
   ~32k positions of 192-wide bf16 rows) — and as what the kernels are
   held to.  It is made of
@@ -42,8 +42,8 @@ Both:
 - L pads up to a block multiple (padded keys are masked via ``kv_len``,
   padded query rows are sliced off) — one MXU-friendly compiled
   schedule for any L, never a degenerate tiny-block divisor.  The
-  schedule's block is the ``TPUFRAME_KERNEL_ATTN_BLOCK`` knob; the
-  kernels' tiles follow L alone (`_tiles`).
+  schedule's block is ``_SCAN_BLOCK``; the kernels' tiles follow L
+  alone (`_tiles`).
 - Causal: tiles entirely above the diagonal are *skipped at runtime*,
   so the sweep executes only the tiles that meet the triangle; the
   tiles the diagonal crosses mask element-wise.  The scan bodies branch
@@ -70,12 +70,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tpuframe.ops.dispatch import pad_to, resolve_interpret
-from tpuframe.ops.ledger import attn_block, shape_class
+from tpuframe.ops.registry import shape_class
 from tpuframe.ops.ring_attention import _block_update, _causal_skip, _tile_grads
 
 __all__ = ["blockwise_attention", "blockwise_attention_reference"]
 
 _LANES = 128
+#: the scan schedule's block where the caller names none (lane-aligned)
+_SCAN_BLOCK = 512
 #: the kernels' largest tile side: a float32 (1024, 1024) score tile is
 #: 4 MiB, and the backward holds four such
 _MAX_TILE = 1024
@@ -612,7 +614,7 @@ def blockwise_attention_reference(
     """The scan schedule: what :func:`blockwise_attention` runs wherever
     its kernels do not, and what they are held to."""
     _check_shapes(q, k, v)
-    block = min(attn_block() if block_size is None else block_size, q.shape[1])
+    block = min(_SCAN_BLOCK if block_size is None else block_size, q.shape[1])
     return _padded_call(q, k, v, causal, block, scale, None)
 
 
@@ -632,11 +634,10 @@ def blockwise_attention(
     of its own (latent attention: 192-wide queries and keys, 128-wide
     values), which the output takes.
 
-    ``block_size`` None: the scan schedule takes the domain-clamped
-    ``TPUFRAME_KERNEL_ATTN_BLOCK`` knob (512), the kernels tiles that
-    follow L alone (`_tiles`).  An explicit value is the schedule's
-    block and every kernel tile's side (rounded up to whole lanes, 1024
-    at most).
+    ``block_size`` None: the scan schedule takes ``_SCAN_BLOCK`` (512),
+    the kernels tiles that follow L alone (`_tiles`).  An explicit value
+    is the schedule's block and every kernel tile's side (rounded up to
+    whole lanes, 1024 at most).
 
     ``interpret``: None = auto (the kernels on a single-device TPU
     process or inside a manual region if a head's dQ fits VMEM, the

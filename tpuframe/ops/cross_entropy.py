@@ -24,11 +24,11 @@ from jax.experimental import pallas as pl
 from jax.sharding import PartitionSpec as P
 
 from tpuframe.ops.dispatch import batch_sharding_info, pad_to, resolve_interpret
-from tpuframe.ops.ledger import ce_rows, shape_class
+from tpuframe.ops.registry import shape_class
 
-# rows per grid step: domain-clamped knob (TPUFRAME_KERNEL_CE_ROWS,
-# default 16, sublane-aligned) the kernel ledger probes per shape class
 _LANES = 128
+# rows per grid step (sublane-aligned)
+_ROWS = 16
 
 
 def cross_entropy_reference(logits: jax.Array, labels: jax.Array) -> jax.Array:
@@ -80,7 +80,7 @@ def _row_spec(rows, width):
 
 
 def _fwd_pallas(logits, labels, interpret):
-    rows = ce_rows()
+    rows = _ROWS
     logits_p, labels_p, b, k, bp, kp = _pad_inputs(logits, labels, rows)
     loss = pl.pallas_call(
         functools.partial(_fwd_kernel, n_classes=k),
@@ -95,7 +95,7 @@ def _fwd_pallas(logits, labels, interpret):
 
 
 def _bwd_pallas(logits, labels, g, interpret):
-    rows = ce_rows()
+    rows = _ROWS
     logits_p, labels_p, b, k, bp, kp = _pad_inputs(logits, labels, rows)
     g_p = jnp.pad(g.astype(jnp.float32), (0, bp - b))[:, None]
     grad = pl.pallas_call(

@@ -2,27 +2,21 @@
 
 Every op in tpuframe.ops has two implementations with identical
 semantics; tests assert they match (with ``interpret=True`` running the
-real kernel code on CPU).  Env knobs:
+real kernel code on CPU).  Which one runs is :func:`resolve_interpret`'s
+answer from what the process can observe (the backend, the device
+count, a manual region, the caller's ``shardable``, an explicit
+``interpret=``) plus the op's own shape rule where it has one, and
+nothing that lives outside the tree: a dispatch rule changes by a PR
+that a benchmark cell measures.  Env knobs:
 
 - ``TPUFRAME_DISABLE_PALLAS=1`` forces the reference path everywhere —
   the escape hatch when a kernel misbehaves on a new compiler version.
 - ``TPUFRAME_PALLAS_INTERPRET=1`` runs the kernels in Pallas interpret
   mode on any backend — how ``dryrun_multichip`` exercises the sharded
   kernel paths on virtual CPU devices.
-- ``TPUFRAME_KERNELS=auto|on|off`` is the measured layer above those
-  engage rules: ``auto`` (default) consults the persisted kernel ledger
-  (``ops/ledger.py`` — A/B-priced per backend + shape class, never
-  committed slower), ``on`` bypasses the ledger, ``off`` forces the
-  reference path everywhere.  Every distinct decision fires one loud
-  ``ops/kernel_verdict`` event, so a trace of a misdispatched run says
-  which verdict (and whose measurement) chose the path.
 
-An op may decline its own kernel from what it sees in its input before
-any of these is asked: ``normalize_images`` keeps an NHWC image batch in
-its own layout as plain jnp wherever a kernel could run (``source="layout"``
-on its verdict event), because the kernel's flat view of such an array is
-a physical re-layout on the chip that the ledger's stand-alone A/B does
-not price.
+Every distinct decision fires one ``ops/kernel_verdict`` event, so a
+run's JSONL says which form of each op its step took.
 
 Multi-chip: a ``pl.pallas_call`` lowers to a custom call the GSPMD
 partitioner cannot split, so ops invoke their kernels *per shard* under
@@ -61,51 +55,16 @@ def pallas_mode() -> str | None:
     return None
 
 
-def kernels_mode() -> str:
-    """``TPUFRAME_KERNELS``: ``"auto"`` | ``"on"`` | ``"off"``."""
-    from tpuframe.ops.ledger import kernels_mode as _mode
-
-    return _mode()
-
-
-#: (op, shape_class) pairs whose verdict event already fired — one loud
-#: event per distinct decision, not one per trace
+#: (op, shape class, decision, source) whose verdict event already
+#: fired — one loud event per distinct decision, not one per trace
 _VERDICT_EMITTED: set[tuple] = set()
-
-#: process cache for the persisted ledger: (dir, backend, signature) ->
-#: KernelLedger | None.  The store is consulted at trace time, so the
-#: read must be one dict lookup after the first call.
-_LEDGER_CACHE: dict[tuple, object] = {}
-
-
-def _reset_kernel_cache() -> None:
-    """Drop the per-process ledger/verdict caches (tests; call after
-    re-pricing so new verdicts take effect without a restart)."""
-    _VERDICT_EMITTED.clear()
-    _LEDGER_CACHE.clear()
-
-
-def _cached_ledger(*, backend: str | None = None, signature: str | None = None):
-    """The persisted :class:`~tpuframe.ops.ledger.KernelLedger` for this
-    (host, backend, signature), loaded once per process, or None."""
-    from tpuframe.ops import ledger as _ledger
-
-    b = backend or jax.default_backend()
-    sig = signature or _ledger.DEFAULT_SIGNATURE
-    key = (_ledger.ledger_dir(), b, sig)
-    if key not in _LEDGER_CACHE:
-        _LEDGER_CACHE[key] = _ledger.load_ledger(
-            _ledger.default_host(), b, sig)
-    return _LEDGER_CACHE[key]
 
 
 def _emit_verdict(op: str, shape_cls: str | None, *, enable: bool,
-                  source: str, **extra) -> None:
+                  source: str) -> None:
     """One ``ops/kernel_verdict`` event per distinct (op, shape class,
-    decision), plus the ledger hit/miss counters.  ``source="layout"`` is
-    an op that saw in its input's shape that its kernel's view of it is a
-    physical re-layout and kept the jnp form (``ops/normalize.py``): the
-    ledger was not asked, so neither counter moves."""
+    decision).  ``source`` is ``"forced"`` (an explicit ``interpret=``
+    or ``TPUFRAME_DISABLE_PALLAS``) or ``"default"`` (the engage rule)."""
     key = (op, shape_cls, enable, source)
     if key in _VERDICT_EMITTED:
         return
@@ -113,49 +72,12 @@ def _emit_verdict(op: str, shape_cls: str | None, *, enable: bool,
     try:
         from tpuframe.track.telemetry import get_telemetry
 
-        tele = get_telemetry()
-        if source != "layout":
-            tele.registry.counter(
-                "ops/ledger_hit" if source == "ledger" else "ops/ledger_miss"
-            ).inc()
-        tele.event(
+        get_telemetry().event(
             "ops/kernel_verdict", op=op, shape_class=shape_cls,
-            enable=bool(enable), source=source,
-            mode=kernels_mode(), **extra,
+            enable=bool(enable), source=source, mode=pallas_mode(),
         )
     except Exception:
         pass  # telemetry must never take dispatch down
-
-
-def kernel_enabled(op: str, shape_class: str | None = None) -> bool:
-    """Should ``op``'s kernel engage for this shape class?
-
-    ``TPUFRAME_KERNELS=off`` -> False everywhere; ``on`` -> True
-    (backend capability still gates via ``pallas_mode``); ``auto`` ->
-    the persisted ledger's A/B verdict when one exists for this
-    (backend, shape class), else True — pre-ledger behavior is the
-    default, the ledger only ever *removes* kernels it measured slower.
-    """
-    mode = kernels_mode()
-    if mode == "off":
-        _emit_verdict(op, shape_class, enable=False, source="forced")
-        return False
-    if mode == "on":
-        _emit_verdict(op, shape_class, enable=True, source="forced")
-        return True
-    led = _cached_ledger()
-    v = led.verdict(op, shape_class) if led is not None and shape_class \
-        else None
-    if v is None and led is not None and shape_class is None:
-        # shape-agnostic consult: any recorded verdict for the op
-        classes = getattr(led, "verdicts", {}).get(op) or {}
-        v = next(iter(classes.values()), None)
-    if v is not None and "enable" in v:
-        _emit_verdict(op, shape_class, enable=bool(v["enable"]),
-                      source="ledger")
-        return bool(v["enable"])
-    _emit_verdict(op, shape_class, enable=True, source="default")
-    return True
 
 
 def use_pallas() -> bool:
@@ -189,6 +111,22 @@ def effective_mesh(mesh):
     return None if inside_shard_map() else mesh
 
 
+def _engage(interpret: bool | None, shardable: bool) -> bool | None:
+    if interpret is not None:
+        return interpret
+    mode = pallas_mode()
+    if mode is None:
+        return None
+    if (
+        mode == "compiled"
+        and jax.device_count() > 1
+        and not shardable
+        and not inside_shard_map()
+    ):
+        return None
+    return mode == "interpret"
+
+
 def resolve_interpret(interpret: bool | None, shardable: bool, *,
                       op: str | None = None,
                       shape_class: str | None = None) -> bool | None:
@@ -202,27 +140,15 @@ def resolve_interpret(interpret: bool | None, shardable: bool, *,
     manual region — a bare pallas custom call inside a plain multi-device
     jit is the one placement that would force operand replication.
 
-    Ops that pass their ``op`` (and optionally a ``shape_class``) get
-    the measured layer on top: ``TPUFRAME_KERNELS=off`` forces the
-    reference, and ``auto`` consults the persisted ledger verdict via
-    :func:`kernel_enabled` — a kernel the ledger priced slower for this
-    shape class stays off.
+    Ops that pass their ``op`` (and optionally a ``shape_class``) leave
+    one ``ops/kernel_verdict`` event per distinct decision.
     """
-    if interpret is not None:
-        return interpret
-    if op is not None and not kernel_enabled(op, shape_class):
-        return None
-    mode = pallas_mode()
-    if mode is None:
-        return None
-    if (
-        mode == "compiled"
-        and jax.device_count() > 1
-        and not shardable
-        and not inside_shard_map()
-    ):
-        return None
-    return mode == "interpret"
+    decision = _engage(interpret, shardable)
+    if op is not None:
+        forced = interpret is not None or _env_truthy("TPUFRAME_DISABLE_PALLAS")
+        _emit_verdict(op, shape_class, enable=decision is not None,
+                      source="forced" if forced else "default")
+    return decision
 
 
 def batch_sharding_info(mesh, batch_axes, leading_size: int):

@@ -227,7 +227,7 @@ def fused_layer_norm(
             mesh, batch_axes, lead[0] if lead else 0
         )
         spec = P(axes, *([None] * (x.ndim - 1)))
-    from tpuframe.ops.ledger import shape_class
+    from tpuframe.ops.registry import shape_class
 
     interpret = resolve_interpret(
         interpret, shardable, op="layer_norm",

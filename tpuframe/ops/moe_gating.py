@@ -12,18 +12,16 @@ the data movement is O(kN·D) instead of O(kN·E·C), and XLA lowers the
 the VPU.  Bit-close, not bit-identical: the scatter accumulates token
 contributions in a different order than the einsum's reduction, so
 results agree to float tolerance (atol 1e-5 f32 — pinned by the parity
-test and the committed ``bench_kernels_cpu.json`` record).
+test).
 
 Routing semantics are shared (one ``_routing`` implementation): top-k
 choices fill expert buffers in choice-major order, a token's slot past
 ``capacity`` is dropped (combine weight zero), exactly the Switch
 behavior the reference implements.
 
-Dispatch: ``moe_dispatch_combine`` consults the kernel ledger
-(``kernel_enabled("moe_gating", ...)``) — ``TPUFRAME_KERNELS=off``
-pins the dense reference, a priced verdict can turn the fused path off
-per shape class, and the default is fused (it is pure XLA, so it
-engages on every backend).
+``moe_dispatch_combine`` runs the fused path (it is pure XLA, so it
+engages on every backend); ``fused=False`` is the dense reference, for
+tests.
 """
 
 from __future__ import annotations
@@ -32,9 +30,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-
-from tpuframe.ops.dispatch import kernel_enabled
-from tpuframe.ops.ledger import shape_class
 
 __all__ = ["moe_dispatch_combine", "moe_dispatch_combine_reference"]
 
@@ -96,7 +91,7 @@ def moe_dispatch_combine(
     *,
     capacity: int,
     act: Callable = jax.nn.gelu,
-    fused: bool | None = None,
+    fused: bool = True,
 ) -> jax.Array:
     """Top-k expert MLP: tokens -> gated mixture of expert outputs.
 
@@ -106,8 +101,7 @@ def moe_dispatch_combine(
       gate_idx: (N, k) chosen expert ids.
       w_in / w_out: (E, D, H) / (E, H, D) expert-stacked MLP weights.
       capacity: per-expert buffer slots; overflow slots are dropped.
-      fused: None = auto (the kernel ledger via
-        ``kernel_enabled("moe_gating", ...)``); True/False forces.
+      fused: False runs :func:`moe_dispatch_combine_reference` (tests).
 
     Returns (N, D) combined outputs (dropped tokens contribute zero).
     Differentiable end to end — the scatter/gather pair transposes
@@ -120,8 +114,6 @@ def moe_dispatch_combine(
             f"gate_vals/gate_idx must be (N, k), got {gate_vals.shape}/"
             f"{gate_idx.shape} for N={n}"
         )
-    if fused is None:
-        fused = kernel_enabled("moe_gating", shape_class(n=n, e=e))
     if not fused:
         return moe_dispatch_combine_reference(
             tokens, gate_vals, gate_idx, w_in, w_out,

@@ -414,30 +414,22 @@ class Trainer:
         else:
             self._norm_args = None
 
-        def image_transform(img, mesh):
-            from tpuframe.ops import normalize_images
-
-            mean, std, scale = self._norm_args
-            return normalize_images(
-                img, mean, std, scale=scale,
-                out_dtype=self.policy.compute_dtype, mesh=mesh,
-                batch_axes=tuple(self.plan.data_axes),
-            )
-
-        train_transform = eval_transform = None
+        # one transform for the train step, the grad-accum scan (per
+        # (micro, ...) microbatch), the eval step and predict(): elementwise
+        # jnp that GSPMD shards natively, whatever the leading dimensions
+        image_transform = batch_transform = None
         if normalize is not None:
-            # the mesh is for ``normalize_images``' kernel form (per shard
-            # over the plain (B, ...) layout), which no image batch takes:
-            # NHWC input is elementwise jnp that GSPMD shards natively.
-            # Grad-accum train batches are (n_micro, micro, ...) and are
-            # normalized per microbatch inside the scan (mesh=None there).
-            def train_transform(batch: dict) -> dict:
-                mesh = self.plan.mesh if self.grad_accum == 1 else None
-                batch["image"] = image_transform(batch["image"], mesh)
-                return batch
+            def image_transform(img):
+                from tpuframe.ops import normalize_images
 
-            def eval_transform(batch: dict) -> dict:
-                batch["image"] = image_transform(batch["image"], self.plan.mesh)
+                mean, std, scale = self._norm_args
+                return normalize_images(
+                    img, mean, std, scale=scale,
+                    out_dtype=self.policy.compute_dtype,
+                )
+
+            def batch_transform(batch: dict) -> dict:
+                batch["image"] = image_transform(batch["image"])
                 return batch
 
         if grad_accum > 1:
@@ -448,7 +440,7 @@ class Trainer:
             # and the compressed sync runs once per optimizer step.
             self._train_step = make_grad_accum_step(
                 grad_accum, self.policy, loss_fn, plan=self.plan,
-                batch_transform=train_transform,
+                batch_transform=batch_transform,
                 health=self.health,
                 grad_compression=self.comms_config,
                 grad_clip=self._step_grad_clip,
@@ -456,22 +448,16 @@ class Trainer:
         else:
             self._train_step = make_train_step(
                 self.policy, loss_fn, plan=self.plan,
-                batch_transform=train_transform,
+                batch_transform=batch_transform,
                 grad_compression=self.comms_config,
                 health=self.health,
                 grad_clip=self._step_grad_clip,
             )
         self._eval_step = make_eval_step(
-            self.policy, loss_fn, plan=self.plan, batch_transform=eval_transform
+            self.policy, loss_fn, plan=self.plan, batch_transform=batch_transform
         )
         self._predict = make_predict_fn(
-            self.policy,
-            input_transform=(
-                (lambda x: image_transform(x, self.plan.mesh))
-                if normalize is not None
-                else None
-            ),
-        )
+            self.policy, input_transform=image_transform)
 
     # -- wiring ------------------------------------------------------------
     def _resolve_lr(self, lr):
