@@ -5,14 +5,19 @@
 through the flash kernels, the scan schedule and ``attention_reference``,
 forward and forward + backward under one ``jax.grad``, with each
 compiled program's temporaries — the figures that size moving a shape
-onto the kernels (PERF.md section 7, ROADMAP S3).  On a non-TPU host the
-kernels run in interpret mode (the only way the kernel code runs here).
+onto the kernels (PERF.md section 6, PR 30).  ``--layers N`` chains N
+calls in one program, each call's output the next one's queries, and
+reports the time a call: one call of a short sequence takes less than
+the host takes to dispatch it (0.23 ms forward, 0.5 ms forward +
+backward on the v5e's host), so only a chain reads it.  On a non-TPU
+host the kernels run in interpret mode (the only way the kernel code
+runs here).
 
 Which form ``attn_impl="auto"`` takes is ``models/transformer.py``'s
 ``_resolve_impl``; a change to it is a PR that a benchmark cell
 measures, and this script only sizes the candidate.
 
-Usage: python benchmarks/bench_attention.py --standalone 4,1024,16,64
+Usage: python benchmarks/bench_attention.py --standalone 4,1024,16,64 [--layers 24]
 """
 
 from __future__ import annotations
@@ -25,9 +30,10 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), os.pardir))
 
 
-def standalone(shape: tuple[int, ...], calls: int) -> int:
-    """One JSON line per implementation: ms a call, forward and forward
-    + backward, and the MiB of temporaries of each compiled program."""
+def standalone(shape: tuple[int, ...], calls: int, layers: int = 1) -> int:
+    """One JSON line per implementation: ms a call (of ``layers`` chained
+    in a program), forward and forward + backward, and the MiB of
+    temporaries of each compiled program."""
     import time
 
     import jax
@@ -41,6 +47,8 @@ def standalone(shape: tuple[int, ...], calls: int) -> int:
 
     b, l, h, d = shape[:4]
     dv = shape[4] if len(shape) > 4 else d
+    if layers > 1 and dv != d:
+        raise SystemExit("--layers chains outputs into queries: one head width")
     interpret = None if jax.default_backend() == "tpu" else True
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
     qkv = tuple(jax.random.normal(k, (b, l, h, w), jnp.bfloat16) * 0.5
@@ -54,6 +62,13 @@ def standalone(shape: tuple[int, ...], calls: int) -> int:
             q, k, v, causal=True),
     }
 
+    def chained(f):
+        def run(q, k, v):
+            for _ in range(layers):
+                q = f(q, k, v)
+            return q
+        return run
+
     def ms_and_mib(fn):
         compiled = fn.lower(*qkv).compile()
         jax.block_until_ready(compiled(*qkv))
@@ -61,14 +76,15 @@ def standalone(shape: tuple[int, ...], calls: int) -> int:
         for _ in range(calls):
             out = compiled(*qkv)
         jax.block_until_ready(out)
-        return ((time.perf_counter() - t0) / calls * 1e3,
+        return ((time.perf_counter() - t0) / (calls * layers) * 1e3,
                 compiled.memory_analysis().temp_size_in_bytes / 2**20)
 
     dev = jax.devices()[0]
     for name, f in impls.items():
+        f = chained(f)
         rec = {"metric": "attention_standalone", "impl": name,
                "shape": [b, l, h, d, dv], "dtype": "bfloat16", "causal": True,
-               "calls": calls, "backend": dev.platform,
+               "calls": calls, "layers": layers, "backend": dev.platform,
                "device_kind": dev.device_kind,
                "pallas_interpret": bool(interpret)}
         try:
@@ -87,9 +103,11 @@ def main() -> int:
                     help="the shape to time the op at (bf16, causal)")
     ap.add_argument("--calls", type=int, default=24,
                     help="timed calls per program")
+    ap.add_argument("--layers", type=int, default=1,
+                    help="calls of the op chained in one program")
     args = ap.parse_args()
     return standalone(tuple(int(x) for x in args.standalone.split(",")),
-                      args.calls)
+                      args.calls, args.layers)
 
 
 if __name__ == "__main__":

@@ -14,8 +14,8 @@ Covers: fused LayerNorm (fwd+grads), fused cross-entropy (fwd+grad),
 the quant_wire trio
 (amax/encode/decode vs the staged jnp expressions — the in-collective
 wire's arithmetic contract), blockwise attention's flash kernels
-(fwd+grads, causal and not, and deepseek-v2-lite's latent shape in
-bf16), ring and ulysses attention oracle parity on one device.
+(fwd+grads, causal and not, deepseek-v2-lite's latent shape and
+gpt2-medium's heads in bf16), ring and ulysses attention oracle parity on one device.
 
 Usage: python benchmarks/check_kernels_tpu.py [--only a,b,...]
 (exits 1 on any failure).  ``--only`` runs a named subset — sections:
@@ -328,6 +328,24 @@ def _check_blockwise(jax, jnp, np, rng) -> None:
     # long shape): two and one rounding of bf16 at a value's size
     _schedule_parity(jax, jnp, "blockwise_latent_bf16", latent(2, 4096, 16),
                      scale=scale, ftol=2 ** -9, gtol=2 ** -6)
+    # gpt2-medium's heads as both GPT-2 cells run them since PR 30 (batch
+    # 4 a chip, 1024 positions, 16 heads of 64, bf16, causal), against
+    # the float32 oracle; beside them what those cells ran before,
+    # `attention_reference` on the same bf16 inputs (scores and softmax
+    # in bf16), so the record shows which of the two stands nearer.  Its
+    # limits are wide: it is here to be read, not held.
+    from tpuframe.ops.ring_attention import attention_reference
+
+    gpt2m = tuple(jnp.asarray(rng.standard_normal((4, 1024, 16, 64)) * 0.5,
+                              jnp.bfloat16) for _ in range(3))
+    for name, fn, tol in (
+        ("blockwise_gpt2m_bf16", blockwise_attention, (1.4e-2, 5.5e-2)),
+        ("full_gpt2m_bf16", attention_reference, (0.1, 0.5)),
+    ):
+        _attention_parity(
+            jax, jnp, name, lambda q, k, v, fn=fn: fn(q, k, v, causal=True),
+            gpt2m, causal=True, tols=(("default", *tol),),
+        )
     # the longest sequence auto dispatch hands the kernels, an indivisible
     # length under it: a head's dQ takes 96 of the 100 MiB of VMEM the
     # backward kernel may ask for

@@ -165,6 +165,9 @@ def test_dispatch_ignores_files_on_the_host(where, monkeypatch, tmp_path):
     assert dispatch.resolve_interpret(
         None, True, op="layer_norm", shape_class="d1024") is True
 
+    # on this backend without the interpret knob no kernel runs, and the
+    # rule's answer at 1024 positions is full whatever a ledger priced
+    monkeypatch.delenv("TPUFRAME_PALLAS_INTERPRET")
     took = []
     monkeypatch.setattr(transformer, "attention_reference",
                         lambda q, k, v, **kw: took.append("full") or v)

@@ -1,4 +1,5 @@
-"""The flash-attention kernels compiled for a described v5e: no chip, the
+"""The flash-attention kernels, and the placements ``attn_impl="auto"``
+gives them, compiled for a described v5e: no chip, the
 TPU's own compiler (Mosaic refuses here what it would refuse there: a
 tile that does not fit VMEM, a block it cannot lay out, a precision it
 does not take).  Nothing runs, so this says nothing of results or times.
@@ -7,16 +8,19 @@ The topology is described inside a fixture, never at import: only the
 worker that runs this file loads the TPU's library.  Keep every such
 compile in this one file."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
 
 from tpuframe.ops import blockwise_attention
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -29,9 +33,44 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def v5e_runtime(topo, monkeypatch):
+    """``runtime(chips)``: the described v5e as the process's runtime, a
+    data mesh over 1 or 4 of its chips, with the dispatch plane's view of
+    the backend pinned to what a TPU process of that many devices says."""
+    from tpuframe.core import MeshSpec
+    from tpuframe.core import runtime as rt
+    from tpuframe.ops import dispatch
+
+    def runtime(chips):
+        spec = MeshSpec(data=-1)
+        mesh = spec.build(list(topo.devices)[:chips])
+        monkeypatch.setattr(rt, "_CURRENT", rt.Runtime(
+            mesh=mesh, spec=spec, process_index=0, process_count=1, platform="tpu"))
+        monkeypatch.setattr(dispatch, "pallas_mode", lambda: "compiled")
+        monkeypatch.setattr(jax, "device_count", lambda *a: chips)
+        return mesh
+
+    return runtime
+
+
+def _kernel_calls(text, name):
+    return [line for line in text.splitlines()
+            if "custom-call(" in line and name in line]
+
+
+_COLLECTIVE = re.compile(
+    r" (all-gather|all-to-all|collective-permute|reduce-scatter|all-reduce)(-start)?\(")
 
 
 @pytest.mark.parametrize("shape, dtype, precision, block", [
@@ -64,3 +103,68 @@ def test_flash_kernels_compile_for_v5e(one_chip, shape, dtype, precision, block)
     scores = b * h * l * l * jnp.dtype(dtype).itemsize
     if l >= 1024:
         assert compiled.memory_analysis().temp_size_in_bytes < scores / 2
+
+
+def test_gpt2_heads_per_shard_compile_for_v5e_2x2(v5e_runtime):
+    """gpt2_dp4's attention call: 16 rows of 1024 positions over the
+    2x2 host's data axis, ``attn_impl="auto"``.  The rule places the
+    kernels per shard: every device runs one forward and one backward
+    kernel on its four rows, nothing is gathered, and no score matrix
+    is left."""
+    from tpuframe.models import transformer
+
+    mesh = v5e_runtime(4)
+    rows = NamedSharding(mesh, P(("data", "fsdp")))
+    q = jax.ShapeDtypeStruct((16, 1024, 16, 64), jnp.bfloat16, sharding=rows)
+
+    def loss(q, k, v):
+        out = transformer._attend(q, k, v, impl="auto", causal=True,
+                                  num_heads=16, initializing=False)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, q, q).compile()
+    text = compiled.as_text()
+    assert len(_kernel_calls(text, "tpuframe_flash_fwd")) == 1
+    assert len(_kernel_calls(text, "tpuframe_flash_bwd")) == 1
+    assert not _COLLECTIVE.findall(text)
+    assert "[4,16,1024,1024]" not in text
+    # a device's share of the scores alone would be 128 MiB
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("chips", [1, 4], ids=["gpt2m_seq1024", "gpt2m_dp4"])
+def test_gpt2_medium_step_holds_the_flash_kernels(v5e_runtime, chips):
+    """Two layers of ``TransformerLM`` at gpt2-medium's widths, batch 4 a
+    chip, the gradient of a loss over its logits: a forward and a
+    backward flash kernel a layer on every device, no (4, 16, 1024, 1024)
+    tensor, and on four chips the gradients' all-reduces and no other
+    collective."""
+    from tpuframe.models import TransformerLM
+
+    mesh = v5e_runtime(chips)
+    model = TransformerLM(vocab_size=50257, num_layers=2, num_heads=16, head_dim=64,
+                          max_len=1024, attn_impl="auto", dtype=jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda: model.init({"params": jax.random.PRNGKey(0)},
+                           jnp.zeros((1, 16), jnp.int32), train=False))["params"]
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=NamedSharding(mesh, P())),
+        params)
+    toks = jax.ShapeDtypeStruct((4 * chips, 1024), jnp.int32,
+                                sharding=NamedSharding(mesh, P(("data", "fsdp"))))
+
+    def loss(params, toks):
+        logits = model.apply({"params": params}, toks, train=True)
+        return jnp.mean(jax.nn.logsumexp(logits, axis=-1))
+
+    lowered = jax.jit(jax.grad(loss)).lower(params, toks)
+    if chips > 1:
+        # the layers call one jitted per-shard region: lowered once, not a
+        # region a layer (48 of them cost the 24-layer step 20 s to lower)
+        assert lowered.as_text().count('kernel_name = "tpuframe_flash_fwd"') == 1
+    text = lowered.compile().as_text()
+    assert len(_kernel_calls(text, "tpuframe_flash_fwd")) == 2
+    assert len(_kernel_calls(text, "tpuframe_flash_bwd")) == 2
+    assert "[4,16,1024,1024]" not in text
+    kinds = {kind for kind, _ in _COLLECTIVE.findall(text)}
+    assert kinds == ({"all-reduce"} if chips > 1 else set())

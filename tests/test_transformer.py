@@ -109,56 +109,104 @@ def test_lm_without_runtime_defaults_to_full():
         rt.reset_runtime()
 
 
-# (positions, runtime mesh, initializing) -> the form attn_impl="auto" takes.
-# The first two are the benchmark's cells by name: they pin the programs the
-# numbers in PERF_LEDGER.jsonl are for.
+# (positions, runtime mesh, initializing, the dispatch plane's view of the
+# backend, batch) -> the form attn_impl="auto" takes, and whether it runs per
+# shard under shard_map.  The first three are the benchmark's cells by name on
+# THIS backend (no kernel compiles on a CPU): they pin that tier-1's programs
+# are the ones they were before the flash kernels entered the rule.  The
+# ``_tpu`` cases pin the backend's answer to "compiled": the programs the
+# numbers in PERF_LEDGER.jsonl are for since PR 30.
 _AUTO_RULE = {
-    "gpt2m_seq1024": (1024, None, False, "full"),
-    "dsv2lite_seq4096": (4096, None, False, "blockwise"),
-    "gpt2m_dp4_data_mesh": (1024, MeshSpec(data=-1), False, "full"),
-    "one_below_the_threshold": (None, None, False, "full"),
-    "at_the_threshold": (None, None, False, "blockwise"),
-    "long_on_a_data_mesh": (8192, MeshSpec(data=-1), False, "blockwise"),
-    "sequence_axis_sharded": (1024, MeshSpec(data=2, seq=2, model=2), False, "ring"),
+    "gpt2m_seq1024": (1024, None, False, None, 4, "full", False),
+    "dsv2lite_seq4096": (4096, None, False, None, 2, "blockwise", False),
+    "gpt2m_dp4_data_mesh": (1024, MeshSpec(data=-1), False, None, 16, "full", False),
+    "one_below_the_threshold": (None, None, False, None, 2, "full", False),
+    "at_the_threshold": (None, None, False, None, 2, "blockwise", False),
+    "long_on_a_data_mesh": (8192, MeshSpec(data=-1), False, None, 8, "blockwise", False),
+    "sequence_axis_sharded": (
+        1024, MeshSpec(data=2, seq=2, model=2), False, None, 2, "ring", True),
     "sequence_axis_sharded_long": (
-        4096, MeshSpec(data=2, seq=2, model=2), False, "ring"),
-    "initializing": (4096, None, True, "full"),
+        4096, MeshSpec(data=2, seq=2, model=2), False, None, 2, "ring", True),
+    "initializing": (4096, None, True, None, 2, "full", False),
     "initializing_sequence_axis_sharded": (
-        1024, MeshSpec(data=2, seq=2, model=2), True, "full"),
+        1024, MeshSpec(data=2, seq=2, model=2), True, None, 2, "full", False),
+    "gpt2m_seq1024_tpu": (1024, None, False, "one_chip", 4, "blockwise", False),
+    "dsv2lite_seq4096_tpu": (4096, None, False, "one_chip", 2, "blockwise", False),
+    "gpt2m_dp4_data_mesh_tpu": (
+        1024, MeshSpec(data=-1), False, "compiled", 16, "blockwise", True),
+    "heads_over_the_model_axis_tpu": (
+        1024, MeshSpec(data=4, model=2), False, "compiled", 4, "blockwise", True),
+    "batch_does_not_divide_tpu": (
+        1024, MeshSpec(data=-1), False, "compiled", 12, "full", False),
+    "heads_do_not_divide_tpu": (
+        1024, MeshSpec(data=1, model=8), False, "compiled", 4, "full", False),
+    "long_batch_does_not_divide_tpu": (
+        4096, MeshSpec(data=-1), False, "compiled", 4, "blockwise", False),
+    "below_the_floor_tpu": ("floor-1", None, False, "one_chip", 4, "full", False),
+    "at_the_floor_tpu": ("floor", None, False, "one_chip", 4, "blockwise", False),
+    "below_the_floor_on_a_mesh_tpu": (
+        "floor-1", MeshSpec(data=-1), False, "compiled", 8, "full", False),
+    "many_devices_no_mesh_tpu": (1024, None, False, "compiled", 8, "full", False),
+    "disable_pallas_tpu": (1024, None, False, "disabled", 4, "full", False),
+    "disable_pallas_on_a_mesh_tpu": (
+        1024, MeshSpec(data=-1), False, "disabled", 16, "full", False),
+    "sequence_axis_sharded_tpu": (
+        1024, MeshSpec(data=2, seq=2, model=2), False, "compiled", 2, "ring", True),
+    "initializing_tpu": (1024, None, True, "one_chip", 4, "full", False),
+    "interpret_mode_on_a_mesh": (
+        1024, MeshSpec(data=-1), False, "interpret", 8, "blockwise", True),
 }
 
 
 @pytest.mark.parametrize("case", sorted(_AUTO_RULE))
 def test_attend_auto_rule(case, monkeypatch):
     """One rule behind ``attn_impl="auto"``: the sequence axis sharded ->
-    ring; else ``_BLOCKWISE_AUTO_LEN`` positions or more -> blockwise; else
-    full.  Read off which attention core ``_attend`` calls."""
+    ring; else from ``_FLASH_AUTO_LEN`` positions on, where the flash
+    kernels would run for the call -> blockwise (per shard on a mesh);
+    else ``_BLOCKWISE_AUTO_LEN`` positions or more -> blockwise (the scan
+    schedule, never under shard_map); else full.  Read off which
+    attention core ``_attend`` calls, and whether inside a manual region."""
     import importlib
 
     from tpuframe.models import transformer
+    from tpuframe.ops import dispatch
 
-    length, spec, initializing, want = _AUTO_RULE[case]
+    length, spec, initializing, backend, batch, want, per_shard = _AUTO_RULE[case]
     if length is None:
         length = transformer._BLOCKWISE_AUTO_LEN - (case == "one_below_the_threshold")
+    elif isinstance(length, str):
+        length = transformer._FLASH_AUTO_LEN - length.endswith("-1")
+    if backend == "disabled":  # a TPU with the off switch thrown
+        monkeypatch.setenv("TPUFRAME_DISABLE_PALLAS", "1")
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    elif backend == "interpret":
+        monkeypatch.setenv("TPUFRAME_PALLAS_INTERPRET", "1")
+    elif backend is not None:
+        monkeypatch.setattr(dispatch, "pallas_mode", lambda: "compiled")
+        if backend == "one_chip":
+            monkeypatch.setattr(jax, "device_count", lambda *a: 1)
     took = []
-    monkeypatch.setattr(transformer, "attention_reference",
-                        lambda q, k, v, **kw: took.append("full") or v)
-    monkeypatch.setattr(transformer, "ring_attention_local",
-                        lambda q, k, v, **kw: took.append("ring") or v)
+
+    def core(name):
+        return lambda q, k, v, **kw: took.append(
+            (name, dispatch.inside_shard_map())) or v
+
+    monkeypatch.setattr(transformer, "attention_reference", core("full"))
+    monkeypatch.setattr(transformer, "ring_attention_local", core("ring"))
     monkeypatch.setattr(
         # by module path: tpuframe.ops re-exports the function under this name
         importlib.import_module("tpuframe.ops.blockwise_attention"),
-        "blockwise_attention",
-        lambda q, k, v, **kw: took.append("blockwise") or v)
+        "blockwise_attention", core("blockwise"))
+    transformer._blockwise_per_shard.clear_cache()  # traced with the real op?
     rt.reset_runtime()
     try:
         if spec is not None:
             rt.initialize(spec)
-        qkv = jnp.zeros((2, length, 2, 8), jnp.float32)
+        qkv = jnp.zeros((batch, length, 4, 8), jnp.float32)
         out = transformer._attend(qkv, qkv, qkv, impl="auto", causal=True,
-                                  num_heads=2, initializing=initializing)
+                                  num_heads=4, initializing=initializing)
         assert out.shape == qkv.shape
-        assert took == [want]
+        assert took == [(want, per_shard)]
     finally:
         rt.reset_runtime()
 

@@ -5,15 +5,18 @@ anywhere), but long-context training is first-class in tpuframe: this
 family is the workload that exercises the ``seq`` mesh axis.  Design:
 
 - NHWC-free (B, L, D) layout; bf16-ready via ``dtype``.
-- Attention dispatch: ``attn_impl="auto"`` uses exact ring attention
-  (`tpuframe.ops.ring_attention`) whenever the current mesh shards the
-  sequence axis — K/V rotate the ICI ring, scores never materialize
-  globally; unsharded sequences of ``_BLOCKWISE_AUTO_LEN`` (4k) tokens
-  or more take the flash-style linear-memory blockwise path
-  (`tpuframe.ops.blockwise_attention`: its Pallas flash kernels on a
-  single-device TPU process or inside a manual region, its scan
-  schedule elsewhere); short unsharded sequences use plain XLA
-  attention.
+- Attention dispatch: ``attn_impl="auto"`` is one rule
+  (:func:`_resolve_impl`).  The mesh shards the sequence axis: exact
+  ring attention (`tpuframe.ops.ring_attention`) — K/V rotate the ICI
+  ring, scores never materialize globally.  Else, from
+  ``_FLASH_AUTO_LEN`` positions on, wherever the Pallas flash kernels
+  of `tpuframe.ops.blockwise_attention` would run for the call (a TPU:
+  one device, a manual region, or per shard on a mesh whose batch axes
+  divide the batch and whose model axis divides the heads): the
+  kernels, under ``shard_map`` on a mesh.  Else from
+  ``_BLOCKWISE_AUTO_LEN`` (4k) positions on the same op's scan
+  schedule, for its linear memory.  Else plain XLA attention
+  (``attention_reference``): the fallback, and the oracle.
 - Tensor-parallel ready: :func:`transformer_tp_rules` gives the
   ParallelPlan rules that split QKV/MLP projections over ``model``
   (Megatron-style column->row pairing; XLA inserts the all-reduces).
@@ -21,6 +24,8 @@ family is the workload that exercises the ``seq`` mesh axis.  Design:
 
 from __future__ import annotations
 
+import functools
+import importlib
 import math
 from typing import Any
 
@@ -38,14 +43,29 @@ from tpuframe.core.runtime import (
     SEQUENCE_AXIS,
     current_runtime,
 )
+from tpuframe.ops.dispatch import batch_sharding_info, effective_mesh
 from tpuframe.ops.ring_attention import attention_reference, ring_attention_local
 from tpuframe.ops.layer_norm import FusedLayerNorm
 from tpuframe.ops.ulysses import ulysses_attention_local
 
-#: attn_impl="auto" switches full -> blockwise at this unsharded sequence
-#: length: 4k tokens is a 64 MB f32 score matrix PER (batch, head) — the
-#: materialization, not the FLOPs, starts to dominate HBM there.
+# the module, by path: ``tpuframe.ops`` rebinds the name to the function
+_blockwise = importlib.import_module("tpuframe.ops.blockwise_attention")
+
+#: attn_impl="auto" takes blockwise attention's *scan schedule* (where
+#: its flash kernels do not run: a CPU, TPUFRAME_DISABLE_PALLAS, a batch
+#: that does not divide the mesh) from this unsharded sequence length
+#: on, for memory: 4k tokens is a 64 MB f32 score matrix PER (batch,
+#: head).  Below it the schedule loses to full attention (2.231 ms
+#: against 2.177 a layer at GPT-2-medium's shape on the v5e, PR 28).
 _BLOCKWISE_AUTO_LEN = 4096
+#: ... and its *flash kernels*, wherever they would run, from this
+#: length on: the shortest at which the kernels with the copies round
+#: them beat full attention, forward + backward, on the v5e at batch 4
+#: of 16 heads of 64 in bf16 (ms a layer, 24 chained, PR 30): 0.093 /
+#: 0.027 at 128 positions, 0.188 / 0.059 at 256, 0.371 / 0.242 at 512,
+#: 0.869 / 2.131 at 1024, 2.820 / 7.967 at 2048.  A shape rule like
+#: the kernels' VMEM cliff, not a knob.
+_FLASH_AUTO_LEN = 1024
 
 
 def transformer_tp_rules():
@@ -140,9 +160,43 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     return (x32 * cos[None, :, None, :] + rot * sin[None, :, None, :]).astype(x.dtype)
 
 
-def _resolve_impl(impl: str, length: int, mesh, initializing: bool) -> str:
+def _per_shard_spec(mesh, batch: int, num_heads: int):
+    """The ``shard_map`` spec that hands each device of ``mesh`` whole
+    (row, head) pairs of a (B, L, H, D) array whose sequence is not
+    sharded: rows over the batch axes, heads over the model axis.  None
+    where there is nothing to split (no mesh, one device, a manual
+    region already: the bare call is the per-shard one) or where the
+    batch or the heads do not divide."""
+    mesh = effective_mesh(mesh)
+    if mesh is None or mesh.size == 1:
+        return None
+    _, n_batch, _ = batch_sharding_info(mesh, None, batch)
+    n_model = mesh.shape.get(MODEL_AXIS, 1)
+    if batch % n_batch or num_heads % n_model:
+        return None
+    return P((DATA_AXIS, FSDP_AXIS), None,
+             MODEL_AXIS if n_model > 1 else None, None)
+
+
+@functools.partial(jax.jit, static_argnames=("mesh", "spec", "causal", "scale"))
+def _blockwise_per_shard(q, k, v, *, mesh, spec, causal, scale):
+    """``blockwise_attention`` on each device's shard of q, k, v.  A jit
+    of its own, so the layers of a model, which call it alike, trace and
+    lower one region and not one each: 48 separate regions took the
+    four-chip GPT-2-medium step 26 s to lower (PR 30)."""
+    return shard_map(
+        lambda q, k, v: _blockwise.blockwise_attention(
+            q, k, v, causal=causal, scale=scale),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
+    )(q, k, v)
+
+
+def _resolve_impl(impl: str, q, mesh, initializing: bool,
+                  per_shard: bool) -> str:
     """The attention form ``impl`` stands for, from what the call can
-    see: the one rule behind ``attn_impl="auto"``."""
+    see: the one rule behind ``attn_impl="auto"``.  ``per_shard``: the
+    call can be placed per shard on the mesh (`_per_shard_spec`)."""
     if initializing:
         # init traces with a sample batch that need not divide the mesh;
         # attention has no params, so the full path initializes
@@ -152,8 +206,14 @@ def _resolve_impl(impl: str, length: int, mesh, initializing: bool) -> str:
         return impl
     if mesh is not None and mesh.shape.get(SEQUENCE_AXIS, 1) > 1:
         return "ring"
+    length = q.shape[1]
+    # the flash kernels wherever they would run for this call and win:
+    # the scores stay in VMEM
+    if length >= _FLASH_AUTO_LEN and _blockwise.engage_kernels(
+            q, shardable=per_shard) is not None:
+        return "blockwise"
     # long unsharded context: the (B,H,L,L) score matrix is the memory
-    # hazard; take the flash-style linear-memory path
+    # hazard; take the linear-memory scan schedule
     return "blockwise" if length >= _BLOCKWISE_AUTO_LEN else "full"
 
 
@@ -165,7 +225,8 @@ def _attend(q, k, v, *, impl: str, causal: bool, num_heads: int,
     taken by ``full`` and ``blockwise``; the sequence-sharded forms keep
     one head width and the default scale."""
     mesh = _mesh_or_none()
-    impl = _resolve_impl(impl, q.shape[1], mesh, initializing)
+    per_shard = _per_shard_spec(mesh, q.shape[0], num_heads)
+    impl = _resolve_impl(impl, q, mesh, initializing, per_shard is not None)
     widened = {} if scale is None else {"scale": scale}
     if impl in ("ring", "ulysses"):
         if mesh is None:
@@ -197,9 +258,14 @@ def _attend(q, k, v, *, impl: str, causal: bool, num_heads: int,
             check_vma=False,
         )(q, k, v)
     if impl == "blockwise":
-        from tpuframe.ops.blockwise_attention import blockwise_attention
-
-        return blockwise_attention(q, k, v, causal=causal, **widened)
+        # a kernel is a custom call GSPMD cannot split: on a mesh it runs
+        # per shard, where each device holds whole rows and heads; the
+        # scan schedule is plain XLA and shards as it stands
+        if per_shard is not None and _blockwise.engage_kernels(
+                q, shardable=True) is not None:
+            return _blockwise_per_shard(
+                q, k, v, mesh=mesh, spec=per_shard, causal=causal, scale=scale)
+        return _blockwise.blockwise_attention(q, k, v, causal=causal, **widened)
     if impl == "full":
         return attention_reference(q, k, v, causal=causal, **widened)
     raise ValueError(
@@ -215,7 +281,8 @@ class SelfAttention(nn.Module):
     head_dim: int
     causal: bool = True
     #: "auto" picks ring attention when the mesh shards the sequence axis
-    #: (no head-count constraint), blockwise for unsharded sequences of
+    #: (no head-count constraint), blockwise where its flash kernels run
+    #: (_FLASH_AUTO_LEN+ tokens on a TPU) and for unsharded sequences of
     #: _BLOCKWISE_AUTO_LEN+ tokens, full otherwise; "ulysses" opts into
     #: the all-to-all form
     #: (tpuframe.ops.ulysses — one re-shard instead of N-1 ppermute hops,

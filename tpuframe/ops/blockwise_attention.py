@@ -7,7 +7,8 @@ AND backward.  One arithmetic, two schedules of it:
 
 - **The Pallas flash kernels** (``tpuframe_flash_fwd`` and
   ``tpuframe_flash_bwd``) wherever a kernel can run: a single-device
-  TPU process or a manual region, and anywhere in Pallas interpret mode
+  TPU process or a manual region (a caller's ``shard_map`` over the
+  batch and the heads), and anywhere in Pallas interpret mode
   (``TPUFRAME_PALLAS_INTERPRET=1`` or ``interpret=True``).  The score
   tile, the probabilities and the running (output, sum, max) state live
   in VMEM; HBM sees q, k, v, the output and the rows' logsumexp, once
@@ -53,9 +54,13 @@ Both:
   tile).
 
 ``TransformerLM(attn_impl="blockwise")`` selects it, and ``"auto"``
-does from 4096 unsharded positions on; it composes with the
-``seq``-sharded impls (they shard ACROSS devices, this blocks WITHIN
-one).
+does wherever the kernels would run (:func:`engage_kernels`, the one
+predicate this op and that rule ask) and, for memory, from 4096
+unsharded positions on whatever schedule runs.  On a mesh the caller
+places the op per shard under ``shard_map`` (whole rows and heads a
+device: ``models/transformer.py::_attend``), where the kernels engage
+as in any manual region; it composes with the ``seq``-sharded impls
+(they shard ACROSS devices, this blocks WITHIN one).
 """
 
 from __future__ import annotations
@@ -73,7 +78,8 @@ from tpuframe.ops.dispatch import pad_to, resolve_interpret
 from tpuframe.ops.registry import shape_class
 from tpuframe.ops.ring_attention import _block_update, _causal_skip, _tile_grads
 
-__all__ = ["blockwise_attention", "blockwise_attention_reference"]
+__all__ = ["blockwise_attention", "blockwise_attention_reference",
+           "engage_kernels"]
 
 _LANES = 128
 #: the scan schedule's block where the caller names none (lane-aligned)
@@ -618,6 +624,25 @@ def blockwise_attention_reference(
     return _padded_call(q, k, v, causal, block, scale, None)
 
 
+def engage_kernels(q, *, block_size: int | None = None,
+                   interpret: bool | None = None,
+                   shardable: bool = False) -> bool | None:
+    """Whether :func:`blockwise_attention` runs its flash kernels for
+    queries shaped like ``q`` (B, L, H, D): the interpret flag they run
+    with, or None for the scan schedule.  The op's shape rule (a head's
+    dQ fits VMEM) and then `resolve_interpret`; the one predicate the
+    op itself and ``attn_impl="auto"`` ask.  ``shardable``: the caller
+    runs the op per shard under ``shard_map``."""
+    l, d = q.shape[1], q.shape[-1]
+    l_pad = pad_to(l, _tiles(l, block_size)[0])
+    if interpret is None and _bwd_vmem_bytes(l_pad, d, q.dtype) > _VMEM_BYTES:
+        return None
+    return resolve_interpret(
+        interpret, shardable=shardable, op="blockwise_attention",
+        shape_class=shape_class(l=l, d=d),
+    )
+
+
 def blockwise_attention(
     q: jax.Array,
     k: jax.Array,
@@ -645,15 +670,9 @@ def blockwise_attention(
     mode on any backend.
     """
     _check_shapes(q, k, v)
-    b, l, h, d = q.shape
-    tiles = _tiles(l, block_size)
-    fits = _bwd_vmem_bytes(pad_to(l, tiles[0]), d, q.dtype) <= _VMEM_BYTES
-    if fits or interpret is not None:
-        interpret = resolve_interpret(
-            interpret, shardable=False, op="blockwise_attention",
-            shape_class=shape_class(l=l, d=d),
-        )
+    interpret = engage_kernels(q, block_size=block_size, interpret=interpret)
     if interpret is None:
         return blockwise_attention_reference(
             q, k, v, causal=causal, block_size=block_size, scale=scale)
-    return _padded_call(q, k, v, causal, tiles, scale, interpret)
+    return _padded_call(
+        q, k, v, causal, _tiles(q.shape[1], block_size), scale, interpret)
