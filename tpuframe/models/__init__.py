@@ -45,3 +45,6 @@ __all__ = [
 
 from tpuframe.models.moe import MoEMLP, moe_rules  # noqa: E402
 __all__ += ["MoEMLP", "moe_rules"]
+
+from tpuframe.models.block_diffusion import BlockDiffusionLM  # noqa: E402
+__all__ += ["BlockDiffusionLM"]

@@ -139,10 +139,15 @@ def rope_inv_freq(dim: int, theta: float, scaling: dict | None = None) -> np.nda
 
 
 def rope_tables(length: int, dim: int, theta: float,
-                scaling: dict | None = None) -> tuple[jax.Array, jax.Array]:
+                scaling: dict | None = None,
+                positions: np.ndarray | None = None) -> tuple[jax.Array, jax.Array]:
     """(cos, sin), each (length, dim) float32, rotate-half convention.
-    YaRN's factor on the tables, mscale / mscale_all_dim, is applied."""
-    ang = np.arange(length, dtype=np.float64)[:, None] * rope_inv_freq(dim, theta, scaling)
+    YaRN's factor on the tables, mscale / mscale_all_dim, is applied.
+    ``positions`` (static, ``length`` of them) are the rows' position
+    ids where they are not ``0 .. length - 1``."""
+    if positions is None:
+        positions = np.arange(length)
+    ang = np.asarray(positions, np.float64)[:, None] * rope_inv_freq(dim, theta, scaling)
     ang = np.concatenate([ang, ang], axis=-1)
     m = 1.0
     if scaling:
@@ -178,15 +183,15 @@ def _per_shard_spec(mesh, batch: int, num_heads: int):
              MODEL_AXIS if n_model > 1 else None, None)
 
 
-@functools.partial(jax.jit, static_argnames=("mesh", "spec", "causal", "scale"))
-def _blockwise_per_shard(q, k, v, *, mesh, spec, causal, scale):
+@functools.partial(jax.jit, static_argnames=("mesh", "spec", "causal", "scale", "mask"))
+def _blockwise_per_shard(q, k, v, *, mesh, spec, causal, scale, mask=None):
     """``blockwise_attention`` on each device's shard of q, k, v.  A jit
     of its own, so the layers of a model, which call it alike, trace and
     lower one region and not one each: 48 separate regions took the
     four-chip GPT-2-medium step 26 s to lower (PR 30)."""
     return shard_map(
         lambda q, k, v: _blockwise.blockwise_attention(
-            q, k, v, causal=causal, scale=scale),
+            q, k, v, causal=causal, scale=scale, mask=mask),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)
@@ -218,16 +223,26 @@ def _resolve_impl(impl: str, q, mesh, initializing: bool,
 
 
 def _attend(q, k, v, *, impl: str, causal: bool, num_heads: int,
-            initializing: bool, scale: float | None = None) -> jax.Array:
+            initializing: bool, scale: float | None = None,
+            mask=None, on_tiles=None) -> jax.Array:
     """The attention core every attention module dispatches to:
     (B, L, H, D) q/k and (B, L, H, Dv) v -> (B, L, H, Dv) by ``impl``.
-    ``scale`` (None: ``1/sqrt(D)``) and a value width of its own are
-    taken by ``full`` and ``blockwise``; the sequence-sharded forms keep
-    one head width and the default scale."""
+    ``scale`` (None: ``1/sqrt(D)``), a value width of its own, a
+    ``mask`` that is a rule on positions in ``causal``'s place
+    (`ops.ring_attention.BlockDiffusionMask`) and ``k``/``v`` of one head
+    a group of query heads are taken by ``full`` and ``blockwise``; the
+    sequence-sharded forms keep one head width, the default scale,
+    ``causal`` and as many key/value heads as query heads.  ``on_tiles``
+    is called with `blockwise_attention.tile_counts` of the call where
+    the blockwise form runs it under a mask rule, kernels or schedule as
+    decided here."""
     mesh = _mesh_or_none()
-    per_shard = _per_shard_spec(mesh, q.shape[0], num_heads)
+    # heads split over the model axis only in whole groups
+    per_shard = _per_shard_spec(mesh, q.shape[0], k.shape[2])
     impl = _resolve_impl(impl, q, mesh, initializing, per_shard is not None)
     widened = {} if scale is None else {"scale": scale}
+    if mask is not None:
+        widened["mask"] = mask
     if impl in ("ring", "ulysses"):
         if mesh is None:
             raise ValueError(
@@ -235,8 +250,9 @@ def _attend(q, k, v, *, impl: str, causal: bool, num_heads: int,
             )
         if widened or v.shape != q.shape:
             raise ValueError(
-                f"attn_impl={impl!r} takes one head width and the default "
-                "scale; latent attention runs full or blockwise"
+                f"attn_impl={impl!r} takes one head width, the default "
+                "scale, a causal or full mask and ungrouped heads; latent "
+                "and grouped attention and mask rules run full or blockwise"
             )
         if impl == "ulysses":
             # the all-to-all owns the head dim during attention, so no
@@ -261,10 +277,14 @@ def _attend(q, k, v, *, impl: str, causal: bool, num_heads: int,
         # a kernel is a custom call GSPMD cannot split: on a mesh it runs
         # per shard, where each device holds whole rows and heads; the
         # scan schedule is plain XLA and shards as it stands
-        if per_shard is not None and _blockwise.engage_kernels(
-                q, shardable=True) is not None:
+        kernels = _blockwise.engage_kernels(
+            q, shardable=per_shard is not None) is not None
+        if on_tiles is not None and mask is not None:
+            on_tiles(*_blockwise.tile_counts(mask, q.shape[1], kernels=kernels))
+        if kernels and per_shard is not None:
             return _blockwise_per_shard(
-                q, k, v, mesh=mesh, spec=per_shard, causal=causal, scale=scale)
+                q, k, v, mesh=mesh, spec=per_shard, causal=causal, scale=scale,
+                mask=mask)
         return _blockwise.blockwise_attention(q, k, v, causal=causal, **widened)
     if impl == "full":
         return attention_reference(q, k, v, causal=causal, **widened)
@@ -291,23 +311,49 @@ class SelfAttention(nn.Module):
     #: (tpuframe.ops.blockwise_attention) for long context on one chip.
     attn_impl: str = "auto"  # "auto" | "full" | "ring" | "ulysses" | "blockwise"
     dtype: Any = jnp.float32
+    #: key/value heads, one a group of ``num_heads / num_kv_heads`` query
+    #: heads (0: as many as query heads)
+    num_kv_heads: int = 0
+    #: RMSNorm with a learned scale on every query head and key head
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
+    #: a rule on positions in ``causal``'s place
+    #: (`ops.ring_attention.BlockDiffusionMask`)
+    mask: Any = None
 
     @nn.compact
-    def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
+    def __call__(self, x: jax.Array, train: bool = False, rope=None) -> jax.Array:
+        """``rope``: (cos, sin) tables at the rows' position ids, turned
+        into every query and key head over its whole width."""
         features = self.num_heads * self.head_dim
-        dense = lambda name: nn.Dense(  # noqa: E731
-            features, use_bias=False, dtype=self.dtype, name=name
+        kv_heads = self.num_kv_heads or self.num_heads
+        dense = lambda name, heads: nn.Dense(  # noqa: E731
+            heads * self.head_dim, use_bias=False, dtype=self.dtype, name=name
         )
         b, l, _ = x.shape
-        heads = (b, l, self.num_heads, self.head_dim)
-        q = dense("query")(x).reshape(heads)
-        k = dense("key")(x).reshape(heads)
-        v = dense("value")(x).reshape(heads)
+        q = dense("query", self.num_heads)(x).reshape(b, l, self.num_heads, self.head_dim)
+        k = dense("key", kv_heads)(x).reshape(b, l, kv_heads, self.head_dim)
+        v = dense("value", kv_heads)(x).reshape(b, l, kv_heads, self.head_dim)
+        if self.qk_norm:
+            q = RMSNorm(eps=self.norm_eps, dtype=self.dtype, name="q_norm")(q)
+            k = RMSNorm(eps=self.norm_eps, dtype=self.dtype, name="k_norm")(k)
+        if rope is not None:
+            q, k = apply_rope(q, *rope), apply_rope(k, *rope)
 
-        out = _attend(
-            q, k, v, impl=self.attn_impl, causal=self.causal,
-            num_heads=self.num_heads, initializing=self.is_initializing(),
-        )
+        def sow_tiles(visited, needed):
+            # static numbers a head and a row, times the rows and heads
+            for name, value in (("visited", visited), ("needed", needed)):
+                self.sow("counters", f"attention/tiles_{name}",
+                         jnp.float32(value * b * self.num_heads),
+                         reduce_fn=lambda old, new: new,
+                         init_fn=lambda: jnp.float32(0))
+
+        with jax.named_scope("tpuframe/attn"):
+            out = _attend(
+                q, k, v, impl=self.attn_impl, causal=self.causal,
+                num_heads=self.num_heads, initializing=self.is_initializing(),
+                mask=self.mask, on_tiles=sow_tiles,
+            )
         out = out.reshape(b, l, features)
         return nn.Dense(
             x.shape[-1], use_bias=False, dtype=self.dtype, name="attn_out"
@@ -419,6 +465,11 @@ class Block(nn.Module):
     mlp_gated: bool = False
     #: further arguments of the expert layer, as a tuple of (name, value)
     moe_kwargs: tuple = ()
+    #: multi-head attention's grouped heads, head norms and mask rule
+    #: (`SelfAttention`); its rotary positions arrive as ``rope``
+    num_kv_heads: int = 0
+    qk_norm: bool = False
+    mask: Any = None
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False, rope=None) -> jax.Array:
@@ -442,8 +493,10 @@ class Block(nn.Module):
         else:
             y = SelfAttention(
                 self.num_heads, self.head_dim, causal=self.causal,
-                attn_impl=self.attn_impl, dtype=self.dtype, name="attn",
-            )(y, train=train)
+                attn_impl=self.attn_impl, dtype=self.dtype,
+                num_kv_heads=self.num_kv_heads, qk_norm=self.qk_norm,
+                norm_eps=self.norm_eps, mask=self.mask, name="attn",
+            )(y, train=train, rope=rope)
         if self.dropout:
             y = nn.Dropout(self.dropout, deterministic=not train)(y)
         x = x + y
@@ -486,7 +539,10 @@ class TransformerLM(nn.Module):
     positions (with a config's YaRN ``rope_scaling``) in place of the
     position table; ``kv_lora_rank > 0`` latent attention, its heads
     ``head_dim + rope_dim`` wide for queries and keys and ``v_head_dim``
-    for values; ``mlp_gated`` a SiLU-gated MLP of width ``mlp_dim``;
+    for values (without it the rotary positions turn multi-head
+    attention's whole heads, ``rope_dim == head_dim``); ``num_kv_heads``
+    grouped heads and ``qk_norm`` an RMSNorm on every query and key
+    head; ``mlp_gated`` a SiLU-gated MLP of width ``mlp_dim``;
     ``moe_experts > 0`` the expert layer in every block from
     ``moe_first_dense`` on, with the dense MLP before it.
 
@@ -527,6 +583,9 @@ class TransformerLM(nn.Module):
     #: further arguments of the expert layer (``MoEMLP``): a dict, kept
     #: as sorted items
     moe_kwargs: Any = ()
+    #: 0: ``num_heads`` (multi-head attention)
+    num_kv_heads: int = 0
+    qk_norm: bool = False
 
     def __post_init__(self):
         # module attributes are hashed with the train state's treedef:
@@ -551,15 +610,23 @@ class TransformerLM(nn.Module):
 
     @nn.compact
     def __call__(self, tokens: jax.Array, train: bool = False) -> jax.Array:
+        return self._decode(tokens, train)
+
+    def _decode(self, tokens, train, *, positions=None, mask=None, head_len=None):
+        """Embedding, blocks, final norm and head over (B, L) tokens.  A
+        subclass that builds its own rows hands over what they need:
+        ``positions`` (static position ids where they are not ``0 .. L - 1``),
+        a ``mask`` rule for every block's attention in place of causal, and
+        ``head_len`` (logits for the first so many positions only)."""
         d_model = self.d_model or self.num_heads * self.head_dim
         x = nn.Embed(self.vocab_size, d_model, dtype=self.dtype, name="embed")(tokens)
         rope = None
         if self.rope_dim:
-            if not self.kv_lora_rank:
-                raise ValueError("rotary positions are built for latent "
-                                 "attention (kv_lora_rank > 0) only")
+            if not self.kv_lora_rank and self.rope_dim != self.head_dim:
+                raise ValueError("multi-head attention turns its whole heads: "
+                                 f"rope_dim {self.rope_dim} is not head_dim {self.head_dim}")
             rope = rope_tables(tokens.shape[1], self.rope_dim, self.rope_theta,
-                               dict(self.rope_scaling or ()) or None)
+                               dict(self.rope_scaling or ()) or None, positions)
         else:
             pos = nn.Embed(self.max_len, d_model, dtype=self.dtype, name="pos_embed")(
                 jnp.arange(tokens.shape[1])[None, :]
@@ -577,8 +644,11 @@ class TransformerLM(nn.Module):
                 rope_dim=self.rope_dim, v_head_dim=self.v_head_dim,
                 attn_scale=self.attn_scale(), mlp_dim=self.mlp_dim,
                 mlp_gated=self.mlp_gated, moe_kwargs=self.moe_kwargs,
+                num_kv_heads=self.num_kv_heads, qk_norm=self.qk_norm, mask=mask,
                 name=f"block{i}",
             )(x, train, rope)
+        if head_len is not None:
+            x = x[:, :head_len]
         if self.norm == "rms":
             x = RMSNorm(eps=self.norm_eps, dtype=self.dtype, name="ln_f")(x)
         else:

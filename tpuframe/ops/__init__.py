@@ -51,6 +51,7 @@ _LAZY = {
     "ulysses_attention": "tpuframe.ops.ulysses",
     "ulysses_attention_local": "tpuframe.ops.ulysses",
     "attention_reference": "tpuframe.ops.ring_attention",
+    "BlockDiffusionMask": "tpuframe.ops.ring_attention",
     "ring_attention": "tpuframe.ops.ring_attention",
     "ring_attention_local": "tpuframe.ops.ring_attention",
     "bucket_abs_max": "tpuframe.ops.quant_wire",

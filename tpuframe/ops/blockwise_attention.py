@@ -53,6 +53,18 @@ Both:
   (``pl.when``, and an index map that fetches nothing for a skipped
   tile).
 
+- A mask that is neither causal nor full is a rule on positions
+  (``mask=``: `ring_attention.BlockDiffusionMask`, two static integers):
+  `_tile_classes` sorts the tiles into dead, whole and masked once, at
+  trace time; the scan schedule skips by that table, and the kernels run
+  a grid that holds the live tiles alone (`_tile_plan`: per held block
+  the streamed blocks to visit, read by the ``index_map`` from scalar
+  memory), so a dead tile costs neither a fetch nor a grid step.
+- Grouped heads: ``k`` and ``v`` may hold one head a group of query
+  heads.  They stay that size in HBM; the kernels' ``index_map`` reads
+  head ``j // group``, and dK/dV come out a query head and are summed
+  over the group by one reduction after the backward kernel.
+
 ``TransformerLM(attn_impl="blockwise")`` selects it, and ``"auto"``
 does wherever the kernels would run (:func:`engage_kernels`, the one
 predicate this op and that rule ask) and, for memory, from 4096
@@ -70,16 +82,23 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tpuframe.ops.dispatch import pad_to, resolve_interpret
 from tpuframe.ops.registry import shape_class
-from tpuframe.ops.ring_attention import _block_update, _causal_skip, _tile_grads
+from tpuframe.ops.ring_attention import (
+    BlockDiffusionMask,
+    _block_update,
+    _causal_skip,
+    _repeat_kv,
+    _tile_grads,
+)
 
 __all__ = ["blockwise_attention", "blockwise_attention_reference",
-           "engage_kernels"]
+           "engage_kernels", "tile_counts"]
 
 _LANES = 128
 #: the scan schedule's block where the caller names none (lane-aligned)
@@ -104,11 +123,35 @@ def _from_blocks(a):
     return a.transpose(1, 0, 2, 3, 4).reshape(b, n * block, h, d)
 
 
+@functools.lru_cache(maxsize=64)
+def _tile_classes(rule, l_pad, side, kv_len):
+    """(n, n) numpy, [query tile, key tile]: 0 a tile with no score that
+    counts, 1 one with no other, 2 one to mask element-wise, for square
+    tiles of ``side`` over ``l_pad`` positions with keys up to ``kv_len``."""
+    lo = np.arange(l_pad // side) * side
+    hi = lo + side - 1
+    live, whole = rule.tiles(lo[:, None], hi[:, None], lo[None, :], hi[None, :])
+    live = live & (lo < kv_len)[None, :]
+    return np.where(live, np.where(whole & (hi < kv_len)[None, :], 1, 2), 0)
+
+
+def _tile_live(causal, n, block, kv_len):
+    """The scan schedule's skip: ``live(q_idx, k_idx)``, a scalar bool or
+    None (every tile runs)."""
+    if causal is True:
+        return lambda q_idx, k_idx: k_idx <= q_idx
+    if not causal:
+        return lambda q_idx, k_idx: None
+    table = jnp.asarray(_tile_classes(causal, n * block, block, kv_len) > 0)
+    return lambda q_idx, k_idx: table[q_idx, k_idx]
+
+
 def _fwd_schedule(q_blocks, k_blocks, v_blocks, causal, scale, block, kv_len):
     """Online-softmax forward over blocks -> (out_blocks, lse_blocks)."""
     n, b, _, h, _ = q_blocks.shape
     dv = v_blocks.shape[-1]  # the output takes the values' width
     block_pos = jnp.arange(block)
+    live = _tile_live(causal, n, block, kv_len)
 
     def q_body(q_blk, q_idx):
         q_pos = q_idx * block + block_pos
@@ -130,9 +173,7 @@ def _fwd_schedule(q_blocks, k_blocks, v_blocks, causal, scale, block, kv_len):
 
             # tiles entirely above the diagonal are SKIPPED at runtime,
             # not just masked — ~half the causal sweep never executes
-            carry = _causal_skip(
-                (k_idx <= q_idx) if causal else None, update, carry
-            )
+            carry = _causal_skip(live(q_idx, k_idx), update, carry)
             return carry, None
 
         (o, lsum, m), _ = lax.scan(
@@ -203,8 +244,15 @@ def _col_to_row(col):
 
 def _valid(q_idx, k_idx, *, side, causal, kv_len, keys_first=False, **_):
     """Which scores of tile (q_idx, k_idx) count: keys before ``kv_len``
-    and, if causal, not after their query.  (side, side) bool, queries
-    along the rows (``keys_first``: keys along the rows)."""
+    and, if causal, not after their query (a rule on positions: as it
+    says).  (side, side) bool, queries along the rows (``keys_first``:
+    keys along the rows)."""
+    if isinstance(causal, BlockDiffusionMask):
+        # each side coded along its own axis, a column and a row
+        along = lambda axis: lax.broadcasted_iota(  # noqa: E731
+            jnp.int32, (side, 1) if axis == 0 else (1, side), axis)
+        return causal.allowed(q_idx * side + along(int(keys_first)),
+                              k_idx * side + along(int(not keys_first)), kv_len)
     q_pos = q_idx * side + lax.broadcasted_iota(
         jnp.int32, (side, side), int(keys_first))
     k_pos = k_idx * side + lax.broadcasted_iota(
@@ -213,10 +261,15 @@ def _valid(q_idx, k_idx, *, side, causal, kv_len, keys_first=False, **_):
     return valid & (k_pos <= q_pos) if causal else valid
 
 
-def _visit(q_idx, k_idx, update, *, causal, side, kv_len, l_pad, **_):
+def _visit(q_idx, k_idx, update, kind=None, *, causal, side, kv_len, l_pad, **_):
     """``update(masked)`` on tile (q_idx, k_idx) if it holds a score that
     counts; ``masked`` only where it also holds one to mask: the tiles
-    on the diagonal and the K blocks that reach into the padding."""
+    on the diagonal and the K blocks that reach into the padding.  Under
+    a rule on positions the tile's ``kind`` says which (`_tile_classes`)."""
+    if kind is not None:
+        pl.when(kind == 2)(lambda: update(True))
+        pl.when(kind == 1)(lambda: update(False))
+        return
     live, edges = [], []
     if causal:
         live.append(k_idx <= q_idx)
@@ -240,13 +293,31 @@ def _visit(q_idx, k_idx, update, *, causal, side, kv_len, l_pad, **_):
 # `_tile_grads` carry for rows that have seen nothing yet (ring
 # attention hands them whole blocks of such rows) would select their
 # other branch nowhere; the kernels leave them out, bit for bit the same.
+# Under a rule on positions every row still has a key (a noised query
+# its own block, a clean one itself), so every logsumexp is finite; but
+# the first tile a held Q block meets may hold rows that see nothing in
+# it (a tile that straddles the two copies), and the forward's masked
+# update keeps the running max of such a row out of the exponent.
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, l_ref, m_ref, **tile):
+def _step(refs, width):
+    """One grid step's (refs, held block, streamed block, kind of tile).
+    Under a rule on positions the first two refs are the plan in scalar
+    memory (`_tile_plan`) and the last grid axis counts the held block's
+    live tiles; else it is the streamed block's own index."""
+    held, at = pl.program_id(2), pl.program_id(3)
+    if width is None:
+        return refs, held, at, None
+    order, kinds, *refs = refs
+    return refs, held, order[held * width + at], kinds[held * width + at]
+
+
+def _fwd_kernel(*refs, width, **tile):
     """`_block_update` over the K/V blocks streaming past one Q block."""
-    q_idx, k_idx = pl.program_id(2), pl.program_id(3)
+    (q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, l_ref, m_ref), q_idx, k_idx, kind = _step(
+        refs, width)
 
-    @pl.when(k_idx == 0)
+    @pl.when(pl.program_id(3) == 0)
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -259,23 +330,25 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, l_ref, m_ref, **ti
             s = jnp.where(_valid(q_idx, k_idx, **tile), s, -jnp.inf)
         m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        correction = jnp.exp(m - m_new)
+        m_exp = m_new
+        if masked and kind is not None:  # a row that has seen no key yet
+            m_exp = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+        p = jnp.exp(s - m_exp)
+        correction = jnp.exp(m - m_exp)
         l_ref[...] = l_ref[...] * correction + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[...] = acc_ref[...] * correction + _dot(p.astype(v.dtype), v)
         m_ref[...] = m_new
 
-    _visit(q_idx, k_idx, update, **tile)
+    _visit(q_idx, k_idx, update, kind, **tile)
 
-    @pl.when(k_idx == pl.num_programs(3) - 1)
+    @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
     def _():
         lsum = jnp.maximum(l_ref[...], 1e-30)
         o_ref[...] = (acc_ref[...] / lsum).astype(o_ref.dtype)
         lse_ref[...] = _col_to_row(m_ref[...] + jnp.log(lsum))
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc, **tile):
+def _bwd_kernel(*refs, width, **tile):
     """`_tile_grads` and the three products over the Q blocks streaming
     past one K/V block: the schedule's two passes in one.  The tile is
     held keys-first (scores transposed): the row statistics then
@@ -285,15 +358,16 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     rows of a dQ accumulator that spans the sequence and is written once
     a head: five tile products and one ``exp`` where two passes take
     seven and two."""
-    k_idx, q_idx = pl.program_id(2), pl.program_id(3)
-    last_k, last_q = pl.num_programs(2) - 1, pl.num_programs(3) - 1
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dq_ref,
+     dk_acc, dv_acc, dq_acc), k_idx, q_idx, kind = _step(refs, width)
+    first, last = pl.program_id(3) == 0, pl.program_id(3) == pl.num_programs(3) - 1
     side = tile["side"]
 
-    @pl.when((k_idx == 0) & (q_idx == 0))
+    @pl.when((k_idx == 0) & first)
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    @pl.when(q_idx == 0)
+    @pl.when(first)
     def _():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -315,14 +389,14 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds, k, (((0,), (0,)), ((), ())), precision=_precision(ds),
             preferred_element_type=jnp.float32)
 
-    _visit(q_idx, k_idx, update, **tile)
+    _visit(q_idx, k_idx, update, kind, **tile)
 
-    @pl.when(q_idx == last_q)
+    @pl.when(last)
     def _():
         dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
-    @pl.when((k_idx == last_k) & (q_idx == last_q))
+    @pl.when((k_idx == pl.num_programs(2) - 1) & last)
     def _():
         dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
@@ -341,41 +415,83 @@ def _flash_call(kernel, name, operands, outs, scratch, *, streams, side,
     along the last grid axis; under a causal mask its index is clamped
     to the diagonal, from above for keys (tiles past it) and from below
     for queries (tiles before it), so a step that computes nothing
-    fetches nothing.  ``vmem_bytes`` replaces Mosaic's 16 MiB of scoped
-    VMEM."""
+    fetches nothing; under a rule on positions the last grid axis counts
+    a held block's live tiles and the index comes from `_tile_plan`.  An
+    array with fewer heads than the first operand is read a group of
+    heads at a time (head ``j // group``).  ``vmem_bytes`` replaces
+    Mosaic's 16 MiB of scoped VMEM."""
     b, h, l_pad, _ = operands[0][0].shape
     if l_pad % side:  # a floored grid would leave rows unvisited
         raise ValueError(f"tiles of {side} do not divide {l_pad} padded positions")
     clamp = {"k": jnp.minimum, "q": jnp.maximum}[streams]
+    n = l_pad // side
+    plan, width = (), None
+    if isinstance(causal, BlockDiffusionMask):
+        *plan, width = _tile_plan(causal, l_pad, side, kv_len, streams)
 
     def spec(a, role):
-        def at(b_, h_, held, streamed):
+        group = h // a.shape[1]
+
+        def at(b_, h_, held, streamed, *plan):
             idx = held
             if role == streams:
-                idx = clamp(streamed, held) if causal else streamed
+                if plan:
+                    idx = plan[0][held * width + streamed]
+                else:
+                    idx = clamp(streamed, held) if causal else streamed
+            h_ = h_ if group == 1 else h_ // group
             return (b_, h_, 0, idx) if a.shape[2] == 1 else (b_, h_, idx, 0)
 
         if role == "all":  # the whole sequence of one head, resident
             return pl.BlockSpec((None, None, *a.shape[2:]),
-                                lambda b_, h_, held, streamed: (b_, h_, 0, 0))
+                                lambda b_, h_, held, streamed, *plan: (b_, h_, 0, 0))
         if a.shape[2] == 1:
             return pl.BlockSpec((None, None, 1, side), at)
         return pl.BlockSpec((None, None, side, a.shape[3]), at)
 
-    return pl.pallas_call(
-        functools.partial(kernel, causal=causal, scale=scale, side=side,
-                          kv_len=kv_len, l_pad=l_pad),
-        grid=(b, h, l_pad // side, l_pad // side),
+    grid = dict(
+        grid=(b, h, n, width or n),
         in_specs=[spec(a, role) for a, role in operands],
         out_specs=tuple(spec(a, role) for a, role in outs),
-        out_shape=tuple(a for a, _ in outs),
         scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in scratch],
+    )
+    if plan:
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(plan), **grid))
+    return pl.pallas_call(
+        functools.partial(kernel, causal=causal, scale=scale, side=side,
+                          kv_len=kv_len, l_pad=l_pad, width=width),
+        **grid,
+        out_shape=tuple(a for a, _ in outs),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", held_axis, "arbitrary"),
             vmem_limit_bytes=vmem_bytes),
         interpret=interpret,
         name=name,
-    )(*(a for a, _ in operands))
+    )(*(jnp.asarray(p) for p in plan), *(a for a, _ in operands))
+
+
+@functools.lru_cache(maxsize=64)
+def _tile_plan(rule, l_pad, side, kv_len, streams):
+    """(order, kinds, width) for a kernel's grid under a rule on
+    positions: per held block (a Q block if the keys stream, a K/V block
+    if the queries do) the ``width`` streamed blocks to visit, in rising
+    order, and the kind of each tile (`_tile_classes`); both flat int32,
+    ``[held * width + step]``.  A held block with fewer live tiles names
+    its last one again with kind 0: nothing fetched, nothing run."""
+    kinds = _tile_classes(rule, l_pad, side, kv_len)
+    if streams == "q":
+        kinds = kinds.T
+    width = max(int((kinds > 0).sum(axis=1).max()), 1)
+    order = np.zeros((len(kinds), width), np.int32)
+    kind = np.zeros((len(kinds), width), np.int32)
+    for held, row in enumerate(kinds):
+        live = np.flatnonzero(row)
+        if live.size:
+            order[held] = live[-1]
+            order[held, :live.size] = live
+            kind[held, :live.size] = row[live]
+    return order.reshape(-1), kind.reshape(-1), width
 
 
 def _tiles(l, block):
@@ -424,8 +540,9 @@ def _flash_fwd(q, k, v, causal, scale, side, kv_len, interpret):
 def _flash_bwd(q, k, v, do, lse, delta, causal, scale, side, kv_len, interpret):
     """dQ, dK, dV in the layout and dtypes of q, k, v.  ``lse`` and
     ``delta`` (rowsum(dO . O)) are (B, H, 1, L) rows."""
-    l_pad, d, dv = q.shape[2], q.shape[-1], v.shape[-1]
-    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
+    (b, h, l_pad, d), dv = q.shape, v.shape[-1]
+    # dK and dV a query head: a group's are summed after the kernel
+    like = lambda a: jax.ShapeDtypeStruct((b, h, *a.shape[2:]), a.dtype)  # noqa: E731
     dk, dv_, dq = _flash_call(
         _bwd_kernel, "tpuframe_flash_bwd",
         [(q, "q"), (k, "k"), (v, "k"), (do, "q"), (lse, "q"), (delta, "q")],
@@ -434,6 +551,11 @@ def _flash_bwd(q, k, v, do, lse, delta, causal, scale, side, kv_len, interpret):
         held_axis="arbitrary", vmem_bytes=_bwd_vmem_bytes(l_pad, d, q.dtype),
         causal=causal, scale=scale, kv_len=kv_len, interpret=interpret,
     )
+    if k.shape[1] != h:
+        dk, dv_ = (
+            jnp.sum(a.reshape(b, k.shape[1], -1, *a.shape[2:]), axis=2,
+                    dtype=jnp.float32).astype(a.dtype)
+            for a in (dk, dv_))
     return dq, dk, dv_
 
 
@@ -480,9 +602,12 @@ def _blockwise_padded_bwd(causal, block, kv_len, scale, interpret, res, g):
     if interpret is not None:
         # behind a barrier, or XLA shares the forward's transposes with
         # these and the padded copies live from one pass to the other
+        q, k, v, out = lax.optimization_barrier(res[:4])
+        kv_heads = k.shape[-1] * g.shape[2] // q.shape[-1]
         q, k, v, out = (
-            a.reshape(*g.shape[:3], -1) for a in lax.optimization_barrier(res[:4])
-        )
+            a.reshape(*g.shape[:2], heads, -1)
+            for a, heads in ((q, g.shape[2]), (k, kv_heads), (v, kv_heads),
+                             (out, g.shape[2])))
         # delta_i = rowsum(dO . O) — the softmax-normalization term of dS
         delta = jnp.einsum(
             "blhd,blhd->bhl", out.astype(jnp.float32), g.astype(jnp.float32)
@@ -509,6 +634,7 @@ def _blockwise_padded_bwd(causal, block, kv_len, scale, interpret, res, g):
     )  # (n, B, H, blk)
     block_pos = jnp.arange(block)
     idx = jnp.arange(n)
+    live = _tile_live(causal, n, block, kv_len)
 
     # Pass 1: dQ.  Outer scan over Q blocks (ys only), inner scan over
     # K/V blocks with a (B, blk, H, D) f32 accumulator.
@@ -528,7 +654,7 @@ def _blockwise_padded_bwd(causal, block, kv_len, scale, interpret, res, g):
                     preferred_element_type=jnp.float32,
                 )
 
-            dq = _causal_skip((k_idx <= q_idx) if causal else None, update, dq)
+            dq = _causal_skip(live(q_idx, k_idx), update, dq)
             return dq, None
 
         dq0 = jnp.zeros((b, block, h, d), jnp.float32)
@@ -563,9 +689,7 @@ def _blockwise_padded_bwd(causal, block, kv_len, scale, interpret, res, g):
                 )
                 return dk, dv
 
-            carry = _causal_skip(
-                (q_idx >= k_idx) if causal else None, update, carry
-            )
+            carry = _causal_skip(live(q_idx, k_idx), update, carry)
             return carry, None
 
         zero_k = jnp.zeros((b, block, h, d), jnp.float32)
@@ -589,11 +713,17 @@ def _blockwise_padded_bwd(causal, block, kv_len, scale, interpret, res, g):
 _blockwise_padded.defvjp(_blockwise_padded_fwd, _blockwise_padded_bwd)
 
 
-def _check_shapes(q, k, v):
-    if k.shape != q.shape or v.shape[:3] != q.shape[:3]:
+def _check_shapes(q, k, v, mask=None):
+    """q (B, L, H, D), k (B, L, Hkv, D), v (B, L, Hkv, Dv), ``Hkv``
+    dividing ``H``; a rule on positions spans the row."""
+    same = (0, 1, 3)
+    if (any(k.shape[i] != q.shape[i] for i in same) or v.shape[:3] != k.shape[:3]
+            or q.shape[2] % k.shape[2]):
         raise ValueError(
             f"q/k/v shapes must match, got {q.shape}/{k.shape}/{v.shape}"
         )
+    if mask is not None and 2 * mask.half != q.shape[1]:
+        raise ValueError(f"{mask} is no rule for a row of {q.shape[1]} positions")
 
 
 def _padded_call(q, k, v, causal, block, scale, interpret):
@@ -616,12 +746,16 @@ def blockwise_attention_reference(
     causal: bool = False,
     block_size: int | None = None,
     scale: float | None = None,
+    mask: BlockDiffusionMask | None = None,
 ) -> jax.Array:
     """The scan schedule: what :func:`blockwise_attention` runs wherever
-    its kernels do not, and what they are held to."""
-    _check_shapes(q, k, v)
+    its kernels do not, and what they are held to.  Grouped heads run as
+    multi-head attention on copies of ``k`` and ``v``."""
+    _check_shapes(q, k, v, mask)
+    k, v = _repeat_kv(q, k, v)
     block = min(_SCAN_BLOCK if block_size is None else block_size, q.shape[1])
-    return _padded_call(q, k, v, causal, block, scale, None)
+    return _padded_call(q, k, v, bool(causal) if mask is None else mask,
+                        block, scale, None)
 
 
 def engage_kernels(q, *, block_size: int | None = None,
@@ -643,6 +777,27 @@ def engage_kernels(q, *, block_size: int | None = None,
     )
 
 
+def tile_counts(mask: BlockDiffusionMask, length: int, *,
+                block_size: int | None = None, kernels: bool = True
+                ) -> tuple[float, float]:
+    """(visited, needed) for one head of one row under ``mask``, forward
+    and backward together, both in tiles of the backward's side: the
+    tiles the sweeps visit, and the area of the scores that count.
+    ``kernels``: the flash kernels' two sweeps in their own `_tiles`;
+    else the scan schedule's three (forward, dQ, dK/dV) in its block."""
+    if kernels:
+        sides = _tiles(length, block_size)
+        sweeps = (sides[0], 1), (sides[1], 1)
+    else:
+        sides = (min(_SCAN_BLOCK if block_size is None else block_size, length),) * 2
+        sweeps = ((sides[0], 3),)
+    l_pad, unit = pad_to(length, sides[0]), sides[1] ** 2
+    visited = sum(
+        times * int((_tile_classes(mask, l_pad, side, length) > 0).sum()) * side * side
+        for side, times in sweeps)
+    return visited / unit, sum(t for _, t in sweeps) * mask.area() / unit
+
+
 def blockwise_attention(
     q: jax.Array,
     k: jax.Array,
@@ -652,12 +807,15 @@ def blockwise_attention(
     block_size: int | None = None,
     scale: float | None = None,
     interpret: bool | None = None,
+    mask: BlockDiffusionMask | None = None,
 ) -> jax.Array:
     """Exact attention over (B, L, H, D) without materializing (.., L, L).
 
     ``scale`` replaces the default ``1/sqrt(D)``; ``v`` may have a width
     of its own (latent attention: 192-wide queries and keys, 128-wide
-    values), which the output takes.
+    values), which the output takes.  ``mask``, a rule on positions,
+    stands in ``causal``'s place; ``k`` and ``v`` may hold one head a
+    group of query heads.
 
     ``block_size`` None: the scan schedule takes ``_SCAN_BLOCK`` (512),
     the kernels tiles that follow L alone (`_tiles`).  An explicit value
@@ -669,10 +827,11 @@ def blockwise_attention(
     scan schedule elsewhere); True runs the kernels in Pallas interpret
     mode on any backend.
     """
-    _check_shapes(q, k, v)
+    _check_shapes(q, k, v, mask)
     interpret = engage_kernels(q, block_size=block_size, interpret=interpret)
     if interpret is None:
         return blockwise_attention_reference(
-            q, k, v, causal=causal, block_size=block_size, scale=scale)
+            q, k, v, causal=causal, block_size=block_size, scale=scale, mask=mask)
     return _padded_call(
-        q, k, v, causal, _tiles(q.shape[1], block_size), scale, interpret)
+        q, k, v, bool(causal) if mask is None else mask,
+        _tiles(q.shape[1], block_size), scale, interpret)

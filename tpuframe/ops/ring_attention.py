@@ -23,9 +23,11 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
@@ -33,24 +35,125 @@ from jax.sharding import PartitionSpec as P
 from tpuframe.core.runtime import DATA_AXIS, FSDP_AXIS, SEQUENCE_AXIS
 
 
+class BlockDiffusionMask(NamedTuple):
+    """The mask of a block-diffusion training row, as a rule on positions.
+
+    The row is the noised copy of a sequence, positions ``[0, half)``,
+    then its clean copy, ``[half, 2 * half)``, both in blocks of
+    ``block`` positions.  With ``blk(p)`` a position's block within its
+    copy, query ``i`` sees key ``j`` iff
+
+    - both are noised and ``blk(i) == blk(j)`` (the whole block), or
+    - ``i`` is noised, ``j`` clean and ``blk(j) < blk(i)``, or
+    - both are clean and ``blk(j) <= blk(i)``;
+
+    a clean query sees no noised key.  Positions past the row (padding
+    up to a tile) count as clean blocks after the last, so every query
+    has a key.  Two static integers, never an array in HBM: the oracle
+    builds the dense mask from `allowed`, the kernels mask a tile
+    element-wise with it, and `tiles` says which tiles to visit at all.
+    """
+
+    half: int
+    block: int
+
+    def _split(self, pos):
+        """(noised, block within its copy) of int32 positions."""
+        if self.half % self.block:
+            raise ValueError(f"blocks of {self.block} do not divide {self.half} positions")
+        noised = pos < self.half
+        shift = self.block.bit_length() - 1
+        if self.block == 1 << shift:  # no vector division in a kernel
+            blk = lax.shift_right_logical(pos, jnp.full_like(pos, shift))
+        else:
+            blk = lax.div(pos, jnp.full_like(pos, self.block))
+        return noised, jnp.where(noised, blk, blk - self.half // self.block)
+
+    def allowed(self, q_pos, k_pos, kv_len=None):
+        """Which scores count, for int32 positions that broadcast against
+        each other; keys at or past ``kv_len`` never do.  Two compares
+        and an ``or`` over the broadcast shape: each side is first coded
+        along its own axis (a noised query's clean keys end one block
+        before its own, and its noised keys are its own block's)."""
+        q_noised, q_blk = self._split(jnp.asarray(q_pos, jnp.int32))
+        k_pos = jnp.asarray(k_pos, jnp.int32)
+        k_noised, k_blk = self._split(k_pos)
+        k_clean = jnp.where(k_noised, jnp.iinfo(jnp.int32).max, k_blk)
+        k_same = jnp.where(k_noised, k_blk, -1)     # -1: no noised key
+        if kv_len is not None:
+            k_clean = jnp.where(k_pos < kv_len, k_clean, jnp.iinfo(jnp.int32).max)
+            k_same = jnp.where(k_pos < kv_len, k_same, -1)
+        q_clean_to = jnp.where(q_noised, q_blk - 1, q_blk)  # last clean block seen
+        q_same = jnp.where(q_noised, q_blk, -2)     # -2: a clean query matches none
+        return (k_clean <= q_clean_to) | (k_same == q_same)
+
+    def tiles(self, q_lo, q_hi, k_lo, k_hi):
+        """(live, whole) for tiles of queries ``[q_lo, q_hi]`` and keys
+        ``[k_lo, k_hi]`` (numpy ints, inclusive, broadcast): ``live``
+        holds a score that counts, ``whole`` holds no other.  A tile is
+        cut at ``half`` into its four quadrant parts, each judged by the
+        blocks its two ranges span."""
+        half, b = self.half, self.block
+        qn, qc = q_lo < half, q_hi >= half
+        kn, kc = k_lo < half, k_hi >= half
+        a0, a1 = q_lo // b, np.minimum(q_hi, half - 1) // b        # noised queries
+        c0, c1 = k_lo // b, np.minimum(k_hi, half - 1) // b        # noised keys
+        e0, e1 = (np.maximum(q_lo, half) - half) // b, (q_hi - half) // b
+        d0, d1 = (np.maximum(k_lo, half) - half) // b, (k_hi - half) // b
+        nn, nc, cc = qn & kn, qn & kc, qc & kc
+        live = ((nn & (a0 <= c1) & (c0 <= a1)) | (nc & (d0 < a1))
+                | (cc & (d0 <= e1)))
+        whole = (~(qc & kn)
+                 & (~nn | ((a0 == a1) & (c0 == c1) & (a0 == c0)))
+                 & (~nc | (d1 < a0)) & (~cc | (d1 <= e0)))
+        return live, live & whole
+
+    def area(self) -> int:
+        """Scores that count in one row: ``half^2 + half * block``."""
+        return self.half * (self.half + self.block)
+
+
+def _repeat_kv(q, k, v):
+    """Grouped heads as multi-head attention: each key/value head copied
+    to the query heads of its group (query head ``j`` uses ``j // group``)."""
+    group, rest = divmod(q.shape[2], k.shape[2])
+    if rest or v.shape[2] != k.shape[2]:
+        raise ValueError(
+            f"{k.shape[2]}/{v.shape[2]} key/value heads do not group {q.shape[2]} query heads")
+    if group == 1:
+        return k, v
+    return jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+
+
 def attention_reference(
     q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = False,
-    scale: float | None = None,
+    scale: float | None = None, mask: BlockDiffusionMask | None = None,
 ) -> jax.Array:
     """Full (unsharded) attention oracle, (B, L, H, D) layout.
 
     ``scale`` replaces the default ``1/sqrt(D)`` (latent attention folds
     its rotary scaling into it); ``v`` may be narrower or wider than
-    ``q``/``k``: the output takes ``v``'s width."""
+    ``q``/``k``: the output takes ``v``'s width.  ``mask`` is a rule on
+    positions in place of ``causal`` (the dense mask is built from it
+    here); ``k`` and ``v`` may hold fewer heads than ``q``, one a group."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    k, v = _repeat_kv(q, k, v)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-    if causal:
+    if mask is not None or causal:
         qi = jnp.arange(q.shape[1])[:, None]
         ki = jnp.arange(k.shape[1])[None, :]
-        scores = jnp.where(ki <= qi, scores, -jnp.inf)
+        seen = ki <= qi if mask is None else mask.allowed(qi, ki)
+        scores = jnp.where(seen, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def _seen(causal, q_pos, k_pos):
+    """(Lq, Lk) bool: ``causal`` True, or a rule on positions in its place."""
+    if causal is True:
+        return k_pos[None, :] <= q_pos[:, None]
+    return causal.allowed(q_pos[:, None], k_pos[None, :])
 
 
 def _block_update(q, k, v, o, l, m, q_pos, k_pos, causal, scale,
@@ -71,7 +174,7 @@ def _block_update(q, k, v, o, l, m, q_pos, k_pos, causal, scale,
         * scale
     )  # (B, H, Lq, Lk) f32
     if causal:
-        mask = k_pos[None, :] <= q_pos[:, None]  # (Lq, Lk)
+        mask = _seen(causal, q_pos, k_pos)  # (Lq, Lk)
         s = jnp.where(mask[None, None], s, -jnp.inf)
     if kv_len is not None:
         s = jnp.where((k_pos < kv_len)[None, None, None, :], s, -jnp.inf)
@@ -114,7 +217,7 @@ def _tile_grads(q_blk, k_blk, v_blk, do_blk, lse_blk, delta_blk,
     if kv_len is not None:
         valid = (k_pos < kv_len)[None, :]
     if causal:
-        cmask = k_pos[None, :] <= q_pos[:, None]
+        cmask = _seen(causal, q_pos, k_pos)
         valid = cmask if valid is None else (valid & cmask)
     if valid is not None:
         s = jnp.where(valid[None, None], s, -jnp.inf)

@@ -34,6 +34,8 @@ from tpuframe.train.step import (
     make_predict_fn,
     make_train_step,
     merge_metrics,
+    model_objective,
+    ModelObjective,
     summarize_metrics,
 )
 from tpuframe.train.trainer import FitResult, Trainer
@@ -64,6 +66,8 @@ __all__ = [
     "create_train_state",
     "param_count",
     "cross_entropy",
+    "model_objective",
+    "ModelObjective",
     "make_eval_step",
     "make_grad_accum_step",
     "make_predict_fn",

@@ -30,6 +30,28 @@ from tpuframe.train.state import TrainState
 LossFn = Callable[[jax.Array, jax.Array], jax.Array]
 
 
+class ModelObjective:
+    """An objective a model brings with it, as a ``loss_fn``: called with
+    the model's output and the whole batch (not the labels alone), it
+    returns one loss a row.  What the labels cannot score the step does
+    not score: ``correct`` stays 0, and ``count`` is the rows, so
+    ``loss_sum / count`` is the mean objective a row."""
+
+    def __init__(self, fn: Callable[[jax.Array, Mapping[str, jax.Array]], jax.Array]):
+        self.fn = fn
+
+    def __call__(self, output, batch):
+        return self.fn(output, batch)
+
+
+def model_objective(model) -> ModelObjective | None:
+    """The objective ``model`` brings (a method ``objective(output, batch)``
+    -> per-row losses), or None: then the caller's ``loss_fn`` stands,
+    `cross_entropy` by default."""
+    fn = getattr(model, "objective", None)
+    return ModelObjective(fn) if callable(fn) else None
+
+
 def cross_entropy(
     logits: jax.Array,
     labels: jax.Array,
@@ -97,6 +119,9 @@ def _forward(state: TrainState, params: Any, batch: Mapping[str, jax.Array],
     """Shared forward: handles batch_stats mutability, dropout rngs, and
     auxiliary losses (``aux_loss`` collection — MoE load balancing).
 
+    A `ModelObjective` takes the model's output and the whole batch, and
+    its output is then scored no further (``logits`` comes back None).
+
     Returns (losses, logits, new_stats, aux, model_stats) where ``aux`` is
     the summed auxiliary loss (0.0 when the model sows none); train steps
     add it to the objective so e.g. MoE routers actually feel their
@@ -136,6 +161,10 @@ def _forward(state: TrainState, params: Any, batch: Mapping[str, jax.Array],
         new_stats = state.batch_stats
         model_stats = {}
     logits = policy.cast_outputs(logits)
+    if isinstance(loss_fn, ModelObjective):
+        # under "input" what the model was given, whichever key held it
+        losses = loss_fn(logits, {**batch, "input": x})
+        return losses, None, new_stats, aux, model_stats
     losses = loss_fn(logits, batch["label"])
     return losses, logits, new_stats, aux, model_stats
 
@@ -162,6 +191,9 @@ def _train_metrics(loss, logits, labels) -> dict:
     """The summed train-metrics triple every train-step flavor reports
     (mean is taken by whoever logs).  One definition — grad-accum adds
     across microbatches, the compressed step psums across shards."""
+    if logits is None:  # a model's own objective: rows, nothing to score
+        n = jnp.asarray(labels.shape[0], jnp.float32)
+        return {"loss_sum": loss * n, "correct": jnp.zeros(()), "count": n}
     hard = jnp.argmax(labels, -1) if labels.ndim == logits.ndim else labels
     n = jnp.asarray(hard.size, jnp.float32)  # tokens for LM, images for vision
     return {
@@ -866,18 +898,21 @@ def make_eval_step(
             state, state.params, batch, policy, False, None, loss_fn
         )
         labels = batch["label"]
-        hard = jnp.argmax(labels, -1) if labels.ndim == logits.ndim else labels
         weight = batch.get("weight")
         if weight is None:
             weight = jnp.ones_like(losses)
         weight = weight.astype(jnp.float32)
         if weight.ndim < losses.ndim:  # per-example mask over per-token losses
             weight = weight.reshape(weight.shape + (1,) * (losses.ndim - weight.ndim))
+        if logits is None:  # a model's own objective scores nothing else
+            correct = jnp.zeros(())
+        else:
+            hard = jnp.argmax(labels, -1) if labels.ndim == logits.ndim else labels
+            correct = jnp.sum(
+                (jnp.argmax(logits, -1) == hard).astype(jnp.float32) * weight)
         return {
             "loss_sum": jnp.sum(losses * weight),
-            "correct": jnp.sum(
-                (jnp.argmax(logits, -1) == hard).astype(jnp.float32) * weight
-            ),
+            "correct": correct,
             "count": jnp.sum(weight),
         }
 

@@ -67,6 +67,7 @@ from tpuframe.train.step import (
     make_predict_fn,
     make_train_step,
     merge_metrics,
+    model_objective,
     summarize_metrics,
 )
 
@@ -207,7 +208,7 @@ class Trainer:
         loggers: Sequence[Any] = (),
         plan: ParallelPlan | None = None,
         precision: str | Policy | None = None,
-        loss_fn: Callable = cross_entropy,
+        loss_fn: Callable | None = None,
         seed: int = 0,
         num_classes: int | None = None,
         sample_input: np.ndarray | None = None,
@@ -257,6 +258,9 @@ class Trainer:
                 if env_profiler is not None:
                     self.callbacks.append(env_profiler)
         self.loggers = list(loggers)
+        # None: the objective the model brings, if it brings one
+        # (``model.objective(output, batch)``), else cross entropy
+        loss_fn = loss_fn or model_objective(model) or cross_entropy
         self.loss_fn = loss_fn
         self.seed = seed
         self.checkpointer = checkpointer
