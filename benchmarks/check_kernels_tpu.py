@@ -15,11 +15,14 @@ the quant_wire trio
 (amax/encode/decode vs the staged jnp expressions — the in-collective
 wire's arithmetic contract), blockwise attention's flash kernels
 (fwd+grads, causal and not, deepseek-v2-lite's latent shape and
-gpt2-medium's heads in bf16), ring and ulysses attention oracle parity on one device.
+gpt2-medium's heads in bf16), ring and ulysses attention oracle parity on one device,
+the no-drop expert layer's bounded slot buffers (one window, and a router that
+overflows the bound into several: XLA's ragged-dot kernel leaves the tiles it
+does not visit unwritten on the chip, which no CPU run shows).
 
 Usage: python benchmarks/check_kernels_tpu.py [--only a,b,...]
 (exits 1 on any failure).  ``--only`` runs a named subset — sections:
-layer_norm, cross_entropy, quant_wire, blockwise, ring, ulysses.
+layer_norm, cross_entropy, quant_wire, blockwise, ring, ulysses, moe_windows.
 """
 
 from __future__ import annotations
@@ -49,6 +52,7 @@ def main() -> None:
         "blockwise": _check_blockwise,
         "ring": _check_ring,
         "ulysses": _check_ulysses,
+        "moe_windows": _check_moe_windows,
     }
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
@@ -351,6 +355,53 @@ def _check_blockwise(jax, jnp, np, rng) -> None:
     # backward kernel may ask for
     _schedule_parity(jax, jnp, "blockwise_long_bf16", latent(1, 32768 - 200, 2),
                      scale=scale, ftol=2 ** -9, gtol=2 ** -6)
+
+
+def _check_moe_windows(jax, jnp, np, rng) -> None:
+    """4,096 tokens x 4 choices over 32 experts of which 4 are held: 16,384
+    pairs through buffers of 4,096 slots, against every token through every
+    held expert; a fair router (one window) and one that sends most pairs
+    here (several).  Relative error of the output and of every gradient."""
+    from tpuframe.models.moe import MoEMLP, slot_bound
+
+    n, k, e, held, d, h = 4096, 4, 32, 4, 256, 128
+    cap = slot_bound(n * k, held, e)
+    layer = MoEMLP(num_experts=e, top_k=k, expert_dim=h, held=(0, held), gated=True,
+                   capacity_factor=None)
+    f32 = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)  # noqa: E731
+    x, co = f32(n, d).at[:, 0].set(1.0), f32(n, d)
+    base = {"w_gate": f32(held, d, h) / 16, "w_in": f32(held, d, h) / 16,
+            "w_out": f32(held, h, d) / 11}
+
+    def oracle(p, x):
+        gates, chosen = jax.lax.top_k(jax.nn.softmax(x @ p["router"]["kernel"], -1), k)
+        gates = gates / jnp.sum(gates, -1, keepdims=True)
+        out = 0.0
+        for i in range(held):
+            w = jnp.sum(jnp.where(chosen == i, gates, 0.0), -1, keepdims=True)
+            out = out + w * ((jax.nn.silu(x @ p["w_gate"][i]) * (x @ p["w_in"][i])) @ p["w_out"][i])
+        return out
+
+    def program(p, x):
+        return layer.apply({"params": p}, x, mutable=["counters", "gauges", "aux_loss"])
+
+    rel = lambda a, b: float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))  # noqa: E731
+    for name, lift in (("fair", 0.0), ("overflowing", 0.12)):
+        # the lift raises the held experts' logits for every token
+        router = (f32(d, e) / 16).at[0, :held].add(lift * 16)
+        p = {**base, "router": {"kernel": router}}
+        out, upd = jax.jit(program)(p, x)
+        here, rows = (float(upd["counters"][c]) for c in ("moe/assignments_here", "moe/slot_rows"))
+        print(json.dumps({"check": f"moe_windows_{name}", "routed_here": here, "slot_rows": rows,
+                          "bound": cap, "overflow_calls": float(upd["counters"]["moe/overflow_calls"])}),
+              flush=True)
+        record(f"moe_windows_{name}_windows", abs(rows / cap - max(1, -(-here // cap))), 0.5)
+        record(f"moe_windows_{name}_fwd", rel(out, jax.jit(oracle)(p, x)), 2e-2)
+        got = jax.jit(jax.grad(lambda p, x: jnp.sum(program(p, x)[0] * co), argnums=(0, 1)))(p, x)
+        want = jax.jit(jax.grad(lambda p, x: jnp.sum(oracle(p, x) * co), argnums=(0, 1)))(p, x)
+        for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(got)[0], jax.tree.leaves(want)):
+            leaf = jax.tree_util.keystr(path).replace("'", "")
+            record(f"moe_windows_{name}_grad{leaf}", rel(g, w), 3e-2)
 
 
 def _check_ring(jax, jnp, np, rng) -> None:

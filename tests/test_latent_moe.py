@@ -15,7 +15,7 @@ import pytest
 from chipbench import correct
 from tpuframe.models import TransformerLM
 from tpuframe.models import transformer as tr
-from tpuframe.models.moe import MoEMLP
+from tpuframe.models.moe import MoEMLP, slot_bound
 from tpuframe.ops.blockwise_attention import blockwise_attention
 from tpuframe.ops.grouped_matmul import (
     grouped_matmul,
@@ -103,6 +103,8 @@ class TestProgramAgainstReference:
             here = float(c["moe/assignments_here"])
             assert 0 < here < pairs
             assert float(c["moe/rows_computed"]) >= here
+            # 4 of 8 experts held: twice the balanced share is every slot
+            assert float(c["moe/slot_rows"]) == pairs and float(c["moe/overflow_calls"]) == 0.0
             assert float(upd["gauges"][f"block{i}"]["moe"]["moe/expert_load_max_over_mean"]) >= 1.0
         assert "block0" not in upd["counters"]  # the leading dense layer
 
@@ -175,6 +177,146 @@ class TestNoTokenDropped:
     def test_held_experts_need_the_no_drop_layer(self):
         with pytest.raises(ValueError, match="no-drop"):
             MoEMLP(num_experts=8, held=(0, 2)).init(jax.random.PRNGKey(0), jnp.ones((1, 4, 4)))
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its inner jaxprs too."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+class TestSlotBuffersBoundedToTheRowsRoutedHere:
+    """2,048 tokens x 2 choices over 16 experts of which 2 are held: 4,096
+    (token, choice) pairs, buffers of 1,024 slots, against a dense oracle."""
+
+    E, K, D, H, N, HELD, PAIRS, CAP = 16, 2, 8, 16, 2048, 2, 4096, 1024
+
+    def _layer(self, held=(0, 2), **kw):
+        return MoEMLP(num_experts=self.E, top_k=self.K, expert_dim=self.H, held=held,
+                      gated=True, capacity_factor=None, **kw)
+
+    def _params(self, router=None):
+        k = jax.random.split(jax.random.PRNGKey(0), 4)
+        n = lambda key, *s: 0.3 * jax.random.normal(key, s, jnp.float32)  # noqa: E731
+        return {"router": {"kernel": n(k[0], self.D, self.E) if router is None else router},
+                "w_gate": n(k[1], self.HELD, self.D, self.H), "w_in": n(k[2], self.HELD, self.D, self.H),
+                "w_out": n(k[3], self.HELD, self.H, self.D)}
+
+    def _tokens(self):
+        return jax.random.normal(jax.random.PRNGKey(4), (self.N, self.D), jnp.float32)
+
+    def _router_sending(self, here):
+        """A router under which exactly ``here`` of the 4,096 pairs choose a held
+        expert: the first ``here`` tokens (their first feature set to +1) put
+        expert 0 first and expert 2 second, the others experts 2 and 3."""
+        x = self._tokens().at[:, 0].set(-1.0).at[:here, 0].set(1.0)
+        router = jnp.zeros((self.D, self.E)).at[0, 0].set(8.0).at[0, 3].set(-4.0)
+        return x.at[:, 1].set(1.0), router.at[1, 2].set(2.0).at[1, 3].set(1.0)
+
+    def _oracle(self, p, x):
+        """Every token through every held expert, weighed by its renormalised gate."""
+        gates, chosen = jax.lax.top_k(jax.nn.softmax(x @ p["router"]["kernel"], -1), self.K)
+        gates = gates / jnp.sum(gates, -1, keepdims=True)
+        out = 0.0
+        for e in range(self.HELD):
+            weight = jnp.sum(jnp.where(chosen == e, gates, 0.0), -1, keepdims=True)
+            out = out + weight * ((jax.nn.silu(x @ p["w_gate"][e]) * (x @ p["w_in"][e])) @ p["w_out"][e])
+        return out
+
+    def _agrees_with_the_oracle(self, p, x):
+        layer = self._layer()
+        out, upd = layer.apply({"params": p}, x, mutable=["counters", "gauges", "aux_loss"])
+        np.testing.assert_allclose(np.asarray(out), np.asarray(self._oracle(p, x)), rtol=2e-5, atol=2e-6)
+        co = jax.random.normal(jax.random.PRNGKey(5), out.shape)
+        got = jax.grad(lambda p, x: jnp.sum(layer.apply({"params": p}, x) * co), argnums=(0, 1))(p, x)
+        want = jax.grad(lambda p, x: jnp.sum(self._oracle(p, x) * co), argnums=(0, 1))(p, x)
+        for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(got)[0], jax.tree.leaves(want)):
+            assert float(jnp.linalg.norm(w)) > 0, jax.tree_util.keystr(path)
+            err = float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w))
+            assert err < 2e-5, (jax.tree_util.keystr(path), err)
+        return {k: float(v) for k, v in upd["counters"].items()}
+
+    @pytest.mark.parametrize("pairs, count, experts, want", [
+        (8192 * 6, 8, 64, 12288),     # dsv2lite_seq4096: a quarter of 49,152
+        (8192 * 8, 16, 128, 16384),   # sdar_blockdiff_seq4096: a quarter of 65,536
+        (4096, 2, 16, 1024),          # this class
+        (4096, 16, 16, 4096),         # all experts held: a slot a pair
+        (4096, 9, 16, 4096),          # more than half of them: the same
+        (30, 2, 8, 30),               # small shapes: a tile is more than the pairs
+        (5000, 1, 16, 1024),          # 2 x 313 = 626 rows, in whole tiles of 512
+        (4096, 1, 16, 512),
+    ])
+    def test_the_bound_is_twice_the_balanced_share_in_whole_tiles(self, pairs, count, experts, want):
+        assert slot_bound(pairs, count, experts) == want
+
+    def test_bounded_path_output_and_every_gradient_leaf(self):
+        counters = self._agrees_with_the_oracle(self._params(), self._tokens())
+        assert 0 < counters["moe/assignments_here"] < self.CAP
+        assert counters["moe/slot_rows"] == self.CAP
+        assert counters["moe/overflow_calls"] == 0.0
+
+    def test_a_router_that_overflows_the_bound_runs_a_second_window(self):
+        x, router = self._router_sending(1500)
+        counters = self._agrees_with_the_oracle(self._params(router), x)
+        assert counters["moe/assignments_here"] == 1500.0
+        assert counters["moe/slot_rows"] == 2 * self.CAP
+        assert counters["moe/overflow_calls"] == 1.0
+
+    def test_every_pair_routed_here(self):
+        router = jnp.zeros((self.D, self.E)).at[:, 0].set(3.0).at[:, 1].set(2.7)
+        counters = self._agrees_with_the_oracle(self._params(router), jnp.abs(self._tokens()) + 0.1)
+        assert counters["moe/assignments_here"] == self.PAIRS
+        assert counters["moe/slot_rows"] == self.PAIRS and counters["moe/overflow_calls"] == 1.0
+
+    @pytest.mark.parametrize("here, rows, overflow", [
+        (1024, 1024, 0.0), (1025, 2048, 1.0), (2048, 2048, 1.0)])
+    def test_exactly_the_bound_fits_and_one_more_does_not(self, here, rows, overflow):
+        x, router = self._router_sending(here)
+        counters = self._agrees_with_the_oracle(self._params(router), x)
+        assert counters["moe/assignments_here"] == here
+        assert counters["moe/slot_rows"] == rows and counters["moe/overflow_calls"] == overflow
+
+    def _grad_jaxpr(self, layer, p, x):
+        return jax.make_jaxpr(jax.value_and_grad(
+            lambda p, x: jnp.sum(layer.apply({"params": p}, x) ** 2), argnums=(0, 1)))(p, x).jaxpr
+
+    @pytest.mark.parametrize("held, tokens", [(None, 2048), ((0, 9), 2048), ((0, 2), 15)])
+    def test_where_no_bound_applies_the_program_has_no_branch_or_loop(self, held, tokens):
+        count = self.E if held is None else held[1]
+        p = {**self._params(), **{w: jnp.ones((count,) + s) for w, s in (
+            ("w_gate", (self.D, self.H)), ("w_in", (self.D, self.H)), ("w_out", (self.H, self.D)))}}
+        x = self._tokens()[:tokens]
+        assert slot_bound(tokens * self.K, count, self.E) == tokens * self.K
+        names = {e.primitive.name for e in _eqns(self._grad_jaxpr(self._layer(held), p, x))}
+        assert not names & {"cond", "while"} and "gather" in names
+        _, upd = self._layer(held).apply({"params": p}, x, mutable=["counters", "gauges", "aux_loss"])
+        assert float(upd["counters"]["moe/slot_rows"]) == tokens * self.K
+        assert float(upd["counters"]["moe/overflow_calls"]) == 0.0
+
+    def test_no_array_but_the_unsort_has_a_row_for_every_slot(self):
+        """What crosses from the forward to the backward pass is 1,024 rows long
+        (differentiating a plain ``cond`` between buffers of 1,024 and of 4,096
+        slots would save both branches' arrays).  On the way the un-sort alone
+        reads a row a pair out of those buffers, the choices in front, and sums
+        them at once: a gather and its mask, nothing an expert computed."""
+        layer, p, x = self._layer(), self._params(), self._tokens()
+        _, pullback = jax.vjp(lambda p, x: layer.apply({"params": p}, x), p, x)
+        saved = [tuple(a.shape) for a in jax.tree.leaves(pullback) if hasattr(a, "shape")]
+        assert saved.count((self.CAP, self.D)) == 2 and saved.count((self.CAP, self.H)) == 3
+        wide = lambda shape: (len(shape) >= 2 and shape[-1] > 1  # noqa: E731
+                              and int(np.prod(shape[:-1])) >= self.PAIRS)
+        assert not [s for s in saved if wide(s)]
+        made = {(e.primitive.name, tuple(v.aval.shape)) for e in _eqns(self._grad_jaxpr(layer, p, x))
+                for v in e.outvars if wide(v.aval.shape)}
+        assert {shape for _, shape in made} == {(self.K, self.N, self.D)}, made
+        assert {name for name, _ in made} <= {  # "jit" is jnp.where's own
+            "gather", "select_n", "broadcast_in_dim", "convert_element_type", "jit"}, made
+        # the layer that holds every expert does gather a row a slot, as it always has
+        everywhere = self._grad_jaxpr(self._layer(None), {**p, **{
+            w: jnp.ones((self.E,) + p[w].shape[1:]) for w in ("w_gate", "w_in", "w_out")}}, x)
+        assert any(wide(v.aval.shape) for e in _eqns(everywhere) for v in e.outvars)
 
 
 class TestGroupedMatmul:
@@ -297,6 +439,7 @@ class TestModelStatsRideTheMetricsWindow:
 
         reg = get_telemetry().registry
         before = reg.counter("moe/assignments_here").value
+        slots_before = reg.counter("moe/slot_rows").value
         trainer = Trainer(TransformerLM(**cfg["model"]["kwargs"]),
                           train_dataloader=DataLoader(Rows(), batch_size=8, shuffle=False),
                           optimizer="sgd", lr=1e-3, max_duration="4ba", log_interval=2,
@@ -306,6 +449,7 @@ class TestModelStatsRideTheMetricsWindow:
         here = reg.counter("moe/assignments_here").value - before
         pairs = 4 * 8 * cfg["seq_len"] * cfg["num_experts_per_tok"] * 2   # steps x rows x tokens x k x layers
         assert 0 < here < pairs
+        assert reg.counter("moe/slot_rows").value - slots_before == pairs
         assert reg.counter("moe/rows_computed").value >= here
         assert reg.gauge("moe/expert_load_max_over_mean").value >= 1.0
 

@@ -13,8 +13,9 @@ Components:
   top-k softmax gating and a balance loss exposed via the ``"aux_loss"``
   mutable collection; either capacity-factor truncation (GShard dense
   dispatch) or, with ``capacity_factor=None``, no token dropped: sorted
-  assignments through ``ops.grouped_matmul``, the experts ``held`` here
-  out of all the router scores, and a shared MLP beside them.
+  assignments through ``ops.grouped_matmul`` in buffers bounded to the
+  rows routed here, the experts ``held`` here out of all the router
+  scores, and a shared MLP beside them.
 - :func:`moe_rules` — ParallelPlan rules placing expert weights on the
   ``expert`` axis (compose with the TP/fsdp rules).
 """
@@ -42,15 +43,23 @@ def moe_rules():
 
 
 def _sum_choices_impl(rows, inv, n):
-    return rows[inv].reshape(n, -1, rows.shape[-1]).sum(axis=1)
+    if rows.shape[0] == inv.shape[0]:
+        return rows[inv].reshape(n, -1, rows.shape[-1]).sum(axis=1)
+    # a window of the slots: a slot outside it reads as zero.  Gathered
+    # with the choices in front, (k, n, d): on the TPU an (n, 6, d) array
+    # is re-tiled and costs twice as much (eight the same; PERF.md, PR 32)
+    slots = inv.reshape(n, -1).T
+    inside = (slots >= 0) & (slots < rows.shape[0])
+    picked = jnp.where(inside[..., None], rows[jnp.where(inside, slots, 0)], 0)
+    return picked.sum(axis=0, dtype=jnp.float32).astype(rows.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _take_tokens(tokens, tok, inv, n):
-    """``rows[a] = tokens[tok[a]]`` for the sorted assignments.  Its
-    transpose is written as a gather too (un-sort by ``inv``, then sum
-    each token's choices): the scatter-add autodiff would emit is the
-    slow form on the TPU."""
+    """``rows[a] = tokens[tok[a]]`` for the sorted assignments ``tok``
+    names (all of them, or a window).  Its transpose is written as a
+    gather too (un-sort by ``inv``, then sum each token's choices): the
+    scatter-add autodiff would emit is the slow form on the TPU."""
     return tokens[tok]
 
 
@@ -69,7 +78,9 @@ _take_tokens.defvjp(_take_tokens_fwd, _take_tokens_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _sum_choices(rows, tok, inv, n):
     """``out[t] = sum of the rows assigned from token t``: the transpose
-    of :func:`_take_tokens`, again a gather."""
+    of :func:`_take_tokens`, again a gather.  ``rows`` may be a window of
+    the slots (those ``tok`` names, ``inv`` counted from its first); a
+    slot outside it adds zero."""
     return _sum_choices_impl(rows, inv, n)
 
 
@@ -85,6 +96,116 @@ def _sum_choices_bwd(n, res, g):
 _sum_choices.defvjp(_sum_choices_fwd, _sum_choices_bwd)
 
 
+def slot_bound(pairs: int, count: int, experts: int) -> int:
+    """Rows of the no-drop layer's slot buffers: twice the share of the
+    ``pairs`` (token, choice) pairs that a balanced router sends to
+    ``count`` held experts of ``experts``, in whole row tiles of the
+    grouped product, and never more than a slot a pair."""
+    balanced = -(-pairs * count // experts)
+    return min(pairs, -(-2 * balanced // TILE_ROWS) * TILE_ROWS)
+
+
+def _scale_rows(y, weight):
+    return y * weight[:, None].astype(y.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(9, 10))
+def _expert_parts(tokens, w_gate, w_in, w_out, weight, tok, inv, sizes, lo, cap, act):
+    """``sum p_e E_e(x)`` over the sorted slots ``[lo, lo + cap)``, and
+    the arrays its backward pass reads.  ``tok`` and ``weight`` are whole
+    windows long.  Jitted, like :func:`_window_bwd`: the layers of a
+    model and a layer's first and further windows are then one traced
+    and lowered function the step calls, not a copy each (seconds of a
+    job's first step)."""
+    n = tokens.shape[0]
+    if cap < tok.shape[0]:
+        ends = jnp.cumsum(sizes)
+        sizes = jnp.clip(ends, lo, lo + cap) - jnp.clip(ends - sizes, lo, lo + cap)
+        tok = jax.lax.dynamic_slice_in_dim(tok, lo, cap)
+        weight = jax.lax.dynamic_slice_in_dim(weight, lo, cap)
+        inv = inv - lo
+    rows = _take_tokens(tokens, tok, inv, n)
+    pre = grouped_matmul(rows, w_in, sizes)
+    if w_gate is not None:
+        gate = grouped_matmul(rows, w_gate, sizes)
+        hid = act(gate) * pre
+    else:
+        gate, hid = None, act(pre)
+    y = grouped_matmul(hid, w_out, sizes)
+    out = _sum_choices(_scale_rows(y, weight), tok, inv, n)
+    return out, (rows, gate, pre, hid, y, weight, tok, inv, sizes)
+
+
+def _windows(sizes, cap):
+    """Windows of ``cap`` slots that hold the pairs routed here."""
+    return jnp.maximum(-(-jnp.sum(sizes) // cap), 1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _experts_windowed(tokens, w_gate, w_in, w_out, weight, tok, inv, sizes, cap, act):
+    """The held experts' MLPs through buffers of ``cap`` slots: the
+    window of the sorted slots that holds every pair routed here unless
+    the router sent more than ``cap``, and then window after window until
+    all are done.  Nothing is dropped, and no buffer has a slot a pair.
+
+    A ``custom_vjp``: what the first window computed is saved for the
+    backward pass, ``cap`` rows long; a further window saves nothing and
+    computes its forward pass again there."""
+    return _experts_windowed_fwd(tokens, w_gate, w_in, w_out, weight, tok, inv,
+                                 sizes, cap, act)[0]
+
+
+def _experts_windowed_fwd(tokens, w_gate, w_in, w_out, weight, tok, inv, sizes, cap, act):
+    # the gate weights and the slots' tokens in whole windows
+    pad = (0, -tok.shape[0] % cap)
+    padded = jnp.pad(weight, pad)
+    args = (tokens, w_gate, w_in, w_out, padded, jnp.pad(tok, pad), inv, sizes)
+    out, parts = _expert_parts(*args, jnp.int32(0), cap, act)
+    out = jax.lax.fori_loop(
+        1, _windows(sizes, cap),
+        lambda i, acc: acc + _expert_parts(*args, i * cap, cap, act)[0], out)
+    return out, (parts, args)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5))
+def _window_bwd(parts, w_gate, w_in, w_out, n, act, g):
+    """The transposes of :func:`_expert_parts`' lines, last to first, each
+    from the arrays the forward pass made: cotangents of the tokens, the
+    three weights and the window's gate weights."""
+    rows, gate, pre, hid, y, weight, tok, inv, sizes = parts
+    product = lambda r, w: grouped_matmul(r, w, sizes)  # noqa: E731
+    d_y, d_weight = jax.vjp(_scale_rows, y, weight)[1](_take_tokens(g, tok, inv, n))
+    d_hid, d_out = jax.vjp(product, hid, w_out)[1](d_y)
+    if w_gate is not None:
+        d_gate, d_pre = jax.vjp(lambda a, b: act(a) * b, gate, pre)[1](d_hid)
+        d_rows, d_wg = jax.vjp(product, rows, w_gate)[1](d_gate)
+    else:
+        (d_pre,), d_rows, d_wg = jax.vjp(act, pre)[1](d_hid), 0, None
+    d_more, d_in = jax.vjp(product, rows, w_in)[1](d_pre)
+    return _sum_choices(d_rows + d_more, tok, inv, n), d_wg, d_in, d_out, d_weight
+
+
+def _experts_windowed_bwd(cap, act, res, g):
+    parts, args = res
+    tokens, w_gate, w_in, w_out, padded, _, inv, sizes = args
+    n, pairs = tokens.shape[0], inv.shape[0]
+
+    def window(parts, lo):
+        *d, d_weight = _window_bwd(parts, w_gate, w_in, w_out, n, act, g)
+        return (*d, jax.lax.dynamic_update_slice_in_dim(
+            jnp.zeros_like(padded), d_weight, lo, 0))
+
+    def further(i, acc):
+        again = _expert_parts(*args, i * cap, cap, act)[1]
+        return jax.tree.map(jnp.add, acc, window(again, i * cap))
+
+    *d, d_weight = jax.lax.fori_loop(1, _windows(sizes, cap), further, window(parts, 0))
+    return (*d, d_weight[:pairs], None, None, None)
+
+
+_experts_windowed.defvjp(_experts_windowed_fwd, _experts_windowed_bwd)
+
+
 class MoEMLP(nn.Module):
     """Top-k gated mixture of expert MLPs: the one expert layer.
 
@@ -96,10 +217,16 @@ class MoEMLP(nn.Module):
       capacity_factor: per-expert slots = ceil(top_k * N / E * factor);
         overflow tokens are dropped (their combine weight is zero), the
         standard Switch behavior, through ``ops.moe_dispatch_combine``.
-        ``None`` drops nothing: assignments are sorted by expert into a
-        buffer with a slot for every (token, choice) pair and the expert
+        ``None`` drops nothing: the (token, choice) pairs are sorted by
+        expert, those routed to held experts in front, and the expert
         matmuls are ``ops.grouped_matmul`` over whatever group sizes the
-        router made.
+        router made.  The buffers round them hold :func:`slot_bound`
+        rows: twice the share of the pairs a balanced router sends to
+        the held experts (a quarter of the pairs where an eighth of the
+        experts is held; a slot a pair where all are, or at small
+        shapes).  A call whose router sends more runs further windows
+        of that many slots, one after another, so it is slower and
+        still exact.  The bound follows from the shapes; nothing sets it.
       held: ``(first, count)``: the experts this layer holds out of
         ``num_experts`` (expert parallelism's share; ``None`` = all).
         Every token is still routed over all E; the layer computes
@@ -118,9 +245,10 @@ class MoEMLP(nn.Module):
         ``aux_loss`` mutable collection for the train step to pick up.
 
     With ``capacity_factor=None`` the layer also sows, for the step's
-    metrics window, the counters ``moe/assignments_here`` and
-    ``moe/rows_computed`` and the gauge
-    ``moe/expert_load_max_over_mean`` (OBSERVABILITY.md).
+    metrics window, the counters ``moe/assignments_here``,
+    ``moe/rows_computed``, ``moe/slot_rows`` (rows its buffers carried)
+    and ``moe/overflow_calls`` (calls that ran more than one window) and
+    the gauge ``moe/expert_load_max_over_mean`` (OBSERVABILITY.md).
     """
 
     num_experts: int = 8
@@ -240,20 +368,27 @@ class MoEMLP(nn.Module):
             tok = order // k
             weight = (gate_vals.reshape(-1) * here)[order]
         with jax.named_scope("tpuframe/moe/experts"):
-            rows = _take_tokens(tokens.astype(self.dtype), tok, inv, n)
-            hid = grouped_matmul(rows, w_in, sizes)
-            hid = (act(grouped_matmul(rows, w_gate, sizes)) * hid
-                   if w_gate is not None else act(hid))
-            y = grouped_matmul(hid, w_out, sizes)
-            out = _sum_choices(y * weight[:, None].astype(y.dtype), tok, inv, n)
+            # the buffers hold the pairs routed here, not a slot a pair:
+            # twice a balanced router's share, and a call that gets more
+            # runs window after window of them (_experts_windowed)
+            pairs = n * k
+            cap = slot_bound(pairs, count, self.num_experts)
+            args = (tokens.astype(self.dtype), w_gate, w_in, w_out, weight,
+                    tok, inv, sizes)
+            out = (_expert_parts(*args, 0, pairs, act)[0] if cap == pairs
+                   else _experts_windowed(*args, cap, act))
+            windows = _windows(sizes, cap)
         f32 = jnp.float32
         load = sizes.astype(f32)
-        self.sow("counters", "moe/assignments_here", jnp.sum(load),
-                 reduce_fn=lambda a, b: b, init_fn=lambda: f32(0))
-        self.sow("counters", "moe/rows_computed",
-                 (tiles_visited(sizes) * TILE_ROWS).astype(f32),
-                 reduce_fn=lambda a, b: b, init_fn=lambda: f32(0))
-        self.sow("gauges", "moe/expert_load_max_over_mean",
-                 jnp.max(load) / jnp.maximum(jnp.mean(load), 1.0),
-                 reduce_fn=lambda a, b: b, init_fn=lambda: f32(0))
+
+        def sow(collection, name, value):
+            self.sow(collection, name, value.astype(f32),
+                     reduce_fn=lambda a, b: b, init_fn=lambda: f32(0))
+
+        sow("counters", "moe/assignments_here", jnp.sum(load))
+        sow("counters", "moe/rows_computed", tiles_visited(sizes) * TILE_ROWS)
+        sow("counters", "moe/slot_rows", windows * cap)
+        sow("counters", "moe/overflow_calls", windows > 1)
+        sow("gauges", "moe/expert_load_max_over_mean",
+            jnp.max(load) / jnp.maximum(jnp.mean(load), 1.0))
         return out
