@@ -22,7 +22,8 @@ does not visit unwritten on the chip, which no CPU run shows).
 
 Usage: python benchmarks/check_kernels_tpu.py [--only a,b,...]
 (exits 1 on any failure).  ``--only`` runs a named subset — sections:
-layer_norm, cross_entropy, quant_wire, blockwise, ring, ulysses, moe_windows.
+layer_norm, cross_entropy, quant_wire, blockwise, ring, ulysses, moe_windows,
+short_conv.
 """
 
 from __future__ import annotations
@@ -53,6 +54,7 @@ def main() -> None:
         "ring": _check_ring,
         "ulysses": _check_ulysses,
         "moe_windows": _check_moe_windows,
+        "short_conv": _check_short_conv,
     }
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
@@ -402,6 +404,48 @@ def _check_moe_windows(jax, jnp, np, rng) -> None:
         for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(got)[0], jax.tree.leaves(want)):
             leaf = jax.tree_util.keystr(path).replace("'", "")
             record(f"moe_windows_{name}_grad{leaf}", rel(g, w), 3e-2)
+
+
+def _check_short_conv(jax, jnp, np, rng) -> None:
+    """The short-convolution kernel pair against its oracle, output and both
+    gradients: float32 at a length that is no tile multiple, and bfloat16 at
+    ``lfm2-8b-a1b``'s shape (2 rows of 4096 positions, 2048 wide, 3 taps),
+    where both forms are also timed, forward + backward a call (a line of
+    its own; the time passes or fails nothing)."""
+    import time
+
+    from tpuframe.ops.short_conv import short_conv, short_conv_reference
+
+    def both(op):
+        def run(x, w, g):
+            y, vjp = jax.vjp(op, x, w)
+            return (y,) + vjp(g)
+        return jax.jit(run)
+
+    kernel = both(lambda x, w: short_conv(x, w, interpret=False))
+    oracle = both(short_conv_reference)
+    rel = lambda a, b: float(jnp.linalg.norm((a - b).astype(jnp.float32))  # noqa: E731
+                             / jnp.linalg.norm(b.astype(jnp.float32)))
+    for name, (b, l, d, k), dtype, tol in (("f32_ragged", (2, 600, 256, 3), jnp.float32, 1e-5),
+                                           ("bf16_lfm2", (2, 4096, 2048, 3), jnp.bfloat16, 2e-2)):
+        x = jnp.asarray(rng.standard_normal((b, l, 3 * d)), dtype)
+        w = jnp.asarray(rng.standard_normal((k, d)), dtype)
+        g = jnp.asarray(rng.standard_normal((b, l, d)), dtype)
+        for part, got, want in zip(("out", "dx", "dw"), kernel(x, w, g), oracle(x, w, g)):
+            record(f"short_conv_{name}_{part}", rel(got, want), tol)
+    times = {}
+    for form, fn in (("kernels", kernel), ("oracle", oracle)):
+        jax.block_until_ready(fn(x, w, g))
+        laps = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            for _ in range(20):
+                out = fn(x, w, g)
+            jax.block_until_ready(out)
+            laps.append((time.perf_counter() - t0) / 20)
+        times[form] = 1e3 * sorted(laps)[len(laps) // 2]
+    print(json.dumps({"check": "short_conv_ms_a_call_fwd_bwd", "shape": [b, l, d, k],
+                      **times}), flush=True)
 
 
 def _check_ring(jax, jnp, np, rng) -> None:

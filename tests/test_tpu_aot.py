@@ -136,6 +136,32 @@ def test_masked_grouped_kernels_compile_for_v5e(one_chip, shape, half, block, ti
         assert compiled.memory_analysis().temp_size_in_bytes < b * h * l * l * 2 / 8
 
 
+@pytest.mark.parametrize("shape, dtype", [
+    ((2, 4096, 2048, 3), jnp.bfloat16),   # lfm2-8b-a1b's conv layers: 8192 x 2048 a call
+    ((2, 600, 256, 3), jnp.float32),      # check_kernels_tpu's: a length that is no tile multiple
+    ((1, 4096, 1024, 4), jnp.bfloat16),   # four taps, another width
+], ids=["lfm2", "f32_ragged", "four_taps"])
+def test_short_conv_kernels_compile_for_v5e(one_chip, shape, dtype):
+    """The short-convolution pair: full-width blocks of 256 rows, the
+    16-row neighbour views, sublane rolls and the taps' gradient resident
+    across the grid, inside the VMEM the kernels ask for."""
+    from tpuframe.ops.short_conv import short_conv
+
+    b, l, d, k = shape
+    x = jax.ShapeDtypeStruct((b, l, 3 * d), dtype, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((k, d), dtype, sharding=one_chip)
+
+    def loss(x, w):
+        return jnp.sum(short_conv(x, w, interpret=False).astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1))).lower(x, w).compile()
+    text = compiled.as_text()
+    assert len(_kernel_calls(text, "tpuframe_short_conv_fwd")) == 1
+    assert len(_kernel_calls(text, "tpuframe_short_conv_bwd")) == 1
+    # nothing but the result, its square's gradient and the partial sums of the taps
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * b * l * d * jnp.dtype(dtype).itemsize
+
+
 def test_gpt2_heads_per_shard_compile_for_v5e_2x2(v5e_runtime):
     """gpt2_dp4's attention call: 16 rows of 1024 positions over the
     2x2 host's data axis, ``attn_impl="auto"``.  The rule places the

@@ -36,9 +36,11 @@ from tpuframe.ops.moe_gating import moe_dispatch_combine
 
 
 def moe_rules():
-    """ParallelPlan rules: expert-stacked weights shard over ``expert``."""
+    """ParallelPlan rules: expert-stacked weights shard over ``expert``.
+    The router's kernel and the selection bias (``expert_bias``) score
+    every expert for every token and stay whole: no rule names them."""
     return (
-        (r"(^|/)(w_in|w_out)$", P(EXPERT_AXIS, None, None)),
+        (r"(^|/)(w_gate|w_in|w_out)$", P(EXPERT_AXIS, None, None)),
     )
 
 
@@ -238,6 +240,14 @@ class MoEMLP(nn.Module):
         through (shared experts, built as one MLP).
       renormalize: chosen gates are rescaled to sum to 1 (GShard);
         False keeps the router's probabilities as they are.
+      scoring: ``"softmax"`` over the router's outputs, or ``"sigmoid"``
+        of each on its own: the chosen scores are then divided by their
+        sum + 1e-6 (``renormalize``) and multiplied by ``routed_scale``.
+      select_bias: sigmoid scoring chooses by score + a bias an expert
+        (the float32 leaf ``expert_bias``) and weighs by the score alone:
+        the bias moves choices, never weights, so its gradient is zero
+        and a step leaves it as it is (its update rule is a balancing
+        heuristic outside the objective, not built here).
       seq_aux: the balance loss is taken per sequence over all top-k
         choices (``f_be = count * E / (k T)``, ``P_be = mean_t p``,
         ``mean_b sum_e f P``); else Switch's top-1 form over the batch.
@@ -248,7 +258,10 @@ class MoEMLP(nn.Module):
     metrics window, the counters ``moe/assignments_here``,
     ``moe/rows_computed``, ``moe/slot_rows`` (rows its buffers carried)
     and ``moe/overflow_calls`` (calls that ran more than one window) and
-    the gauge ``moe/expert_load_max_over_mean`` (OBSERVABILITY.md).
+    the gauge ``moe/expert_load_max_over_mean``; with ``select_bias`` the
+    counters ``moe/bias_choices`` (the pairs it chose) and
+    ``moe/bias_moved_choices`` (those whose expert is not among the
+    ``top_k`` by score alone) (OBSERVABILITY.md).
     """
 
     num_experts: int = 8
@@ -263,6 +276,9 @@ class MoEMLP(nn.Module):
     shared_dim: int = 0
     renormalize: bool = True
     seq_aux: bool = False
+    scoring: str = "softmax"
+    select_bias: bool = False
+    routed_scale: float = 1.0
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
@@ -285,14 +301,22 @@ class MoEMLP(nn.Module):
             logits = nn.Dense(
                 e, use_bias=False, dtype=jnp.float32, name="router"
             )(tokens.astype(jnp.float32))
-            probs = jax.nn.softmax(logits, axis=-1)  # (N, E)
-            # top-k expert choices per token
-            gate_vals, gate_idx = jax.lax.top_k(probs, k)  # (N, k)
-            if self.renormalize:
-                # chosen gates sum to 1 (GShard convention)
-                gate_vals = gate_vals / jnp.maximum(
-                    jnp.sum(gate_vals, -1, keepdims=True), 1e-9
-                )
+            if self.scoring not in ("softmax", "sigmoid"):
+                raise ValueError(f"scoring={self.scoring!r}: softmax or sigmoid")
+            if self.select_bias and self.scoring != "sigmoid":
+                raise ValueError("select_bias is sigmoid scoring's")
+            if self.scoring == "sigmoid":
+                probs = None
+                gate_vals, gate_idx = self._sigmoid_choices(logits, k)
+            else:
+                probs = jax.nn.softmax(logits, axis=-1)  # (N, E)
+                # top-k expert choices per token
+                gate_vals, gate_idx = jax.lax.top_k(probs, k)  # (N, k)
+                if self.renormalize:
+                    # chosen gates sum to 1 (GShard convention)
+                    gate_vals = gate_vals / jnp.maximum(
+                        jnp.sum(gate_vals, -1, keepdims=True), 1e-9
+                    )
 
         h = self.expert_dim or d * self.mlp_ratio
         init = nn.initializers.lecun_normal()
@@ -334,6 +358,10 @@ class MoEMLP(nn.Module):
                 out = out + dense(d, "shared_out")(hid).astype(out.dtype)
 
         # --- load-balance aux loss ---------------------------------------
+        if probs is None:
+            # sigmoid scores are no distribution over the experts: the
+            # models that score so balance by the selection bias
+            return out.reshape(*lead, d).astype(x.dtype)
         if self.seq_aux:
             # per sequence, over all k choices (DeepSeek-V2 eq. 12-14)
             b = lead[0] if len(lead) > 1 else 1
@@ -351,6 +379,30 @@ class MoEMLP(nn.Module):
         self.sow("aux_loss", "moe", aux)
 
         return out.reshape(*lead, d).astype(x.dtype)
+
+    def _sigmoid_choices(self, logits, k):
+        """(weights, experts), each (N, k): every expert scored on its
+        own; chosen by score (+ the selection bias), weighed by score."""
+        if self.aux_loss_weight:
+            raise ValueError("sigmoid scoring takes aux_loss_weight=0: no "
+                             "balance loss is defined on its scores")
+        scores = jax.nn.sigmoid(logits)
+        if self.select_bias:
+            bias = self.param("expert_bias", nn.initializers.zeros,
+                              (logits.shape[-1],), jnp.float32)
+            _, gate_idx = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+            gate_vals = jnp.take_along_axis(scores, gate_idx, axis=-1)
+            unmoved = gate_idx[:, :, None] == jax.lax.top_k(scores, k)[1][:, None, :]
+            for name, value in (
+                    ("moe/bias_choices", gate_idx.size),
+                    ("moe/bias_moved_choices", jnp.sum(~jnp.any(unmoved, axis=-1)))):
+                self.sow("counters", name, jnp.float32(value),
+                         reduce_fn=lambda a, b: b, init_fn=lambda: jnp.float32(0))
+        else:
+            gate_vals, gate_idx = jax.lax.top_k(scores, k)
+        if self.renormalize:
+            gate_vals = gate_vals / (jnp.sum(gate_vals, -1, keepdims=True) + 1e-6)
+        return gate_vals * self.routed_scale, gate_idx
 
     def _no_drop(self, tokens, gate_vals, gate_idx, first, count,
                  w_gate, w_in, w_out, act):

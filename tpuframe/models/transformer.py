@@ -46,6 +46,7 @@ from tpuframe.core.runtime import (
 from tpuframe.ops.dispatch import batch_sharding_info, effective_mesh
 from tpuframe.ops.ring_attention import attention_reference, ring_attention_local
 from tpuframe.ops.layer_norm import FusedLayerNorm
+from tpuframe.ops.short_conv import short_conv, short_conv_reference
 from tpuframe.ops.ulysses import ulysses_attention_local
 
 # the module, by path: ``tpuframe.ops`` rebinds the name to the function
@@ -76,6 +77,11 @@ def transformer_tp_rules():
         (r"attn_out/kernel", P(MODEL_AXIS, None)),
         (r"mlp_in/kernel", P(None, MODEL_AXIS)),
         (r"mlp_out/kernel", P(MODEL_AXIS, None)),
+        # the short-convolution operator is elementwise between its
+        # projections: [B | C | h] split by columns would part a column's
+        # three, so the input projection stays whole and the output
+        # projection splits its output columns
+        (r"conv/out_proj/kernel", P(None, MODEL_AXIS)),
         (r"embed/embedding", P(None, MODEL_AXIS)),
         (r"lm_head/kernel", P(None, MODEL_AXIS)),
     )
@@ -375,6 +381,32 @@ class GatedMLP(nn.Module):
         return dense(x.shape[-1], "out")(h)
 
 
+class ShortConv(nn.Module):
+    """LFM2's double-gated short convolution, a mixer that is no
+    attention: ``[B | C | h] = x W_in``, a ``taps``-tap causal depthwise
+    convolution of ``B * h`` along the sequence, gated by ``C``, then
+    ``W_out``; no bias, no activation (`tpuframe.ops.short_conv`)."""
+
+    taps: int = 3
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        d = x.shape[-1]
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, use_bias=False, dtype=self.dtype, name=name
+        )
+        with jax.named_scope("tpuframe/shortconv"):
+            bch = dense(3 * d, "in_proj")(x)
+            w = self.param("w", nn.initializers.lecun_normal(), (self.taps, d))
+            if self.is_initializing():
+                # init's sample batch need not divide the mesh
+                y = short_conv_reference(bch, w)
+            else:
+                y = short_conv(bch, w, mesh=_mesh_or_none())
+            return dense(d, "out_proj")(y)
+
+
 class LatentAttention(nn.Module):
     """Multi-head latent attention (MLA) without a query latent.
 
@@ -438,6 +470,8 @@ class Block(nn.Module):
     ``norm="rms"``, ``kv_lora_rank > 0`` (latent attention, rotary
     positions given as ``rope``) and ``mlp_gated`` (SiLU-gated MLP, no
     bias) are the other kinds of layer; the defaults are GPT-2's.
+    ``mixer="conv"`` puts the short-convolution operator
+    (:class:`ShortConv`) in attention's place.
     """
 
     num_heads: int
@@ -470,6 +504,8 @@ class Block(nn.Module):
     num_kv_heads: int = 0
     qk_norm: bool = False
     mask: Any = None
+    mixer: str = "full_attention"  # | "conv"
+    conv_taps: int = 3
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False, rope=None) -> jax.Array:
@@ -483,7 +519,12 @@ class Block(nn.Module):
                 dtype=self.dtype, use_mesh=self.ln_use_mesh, name=name
             )
         y = ln("ln1")(x)
-        if self.kv_lora_rank:
+        if self.mixer == "conv":
+            y = ShortConv(self.conv_taps, dtype=self.dtype, name="conv")(y)
+        elif self.mixer != "full_attention":
+            raise ValueError(f"unknown mixer {self.mixer!r}; known: "
+                             "full_attention, conv")
+        elif self.kv_lora_rank:
             y = LatentAttention(
                 self.num_heads, self.head_dim, self.rope_dim, self.v_head_dim,
                 self.kv_lora_rank, scale=self.attn_scale,
@@ -544,7 +585,10 @@ class TransformerLM(nn.Module):
     grouped heads and ``qk_norm`` an RMSNorm on every query and key
     head; ``mlp_gated`` a SiLU-gated MLP of width ``mlp_dim``;
     ``moe_experts > 0`` the expert layer in every block from
-    ``moe_first_dense`` on, with the dense MLP before it.
+    ``moe_first_dense`` on, with the dense MLP before it;
+    ``layer_types`` a mixer for each layer, as a config publishes them:
+    ``"full_attention"`` (the attention the other sizes describe) or
+    ``"conv"`` (the short-convolution operator of ``conv_taps`` taps).
 
     ``remat=True`` rematerializes each block in the backward pass
     (``jax.checkpoint`` via ``nn.remat``): activation memory drops from
@@ -586,6 +630,10 @@ class TransformerLM(nn.Module):
     #: 0: ``num_heads`` (multi-head attention)
     num_kv_heads: int = 0
     qk_norm: bool = False
+    #: one mixer a layer, ``"full_attention"`` | ``"conv"`` (empty: attention
+    #: in every layer); a list, kept as a tuple
+    layer_types: Any = ()
+    conv_taps: int = 3
 
     def __post_init__(self):
         # module attributes are hashed with the train state's treedef:
@@ -595,7 +643,7 @@ class TransformerLM(nn.Module):
                 return tuple(sorted((k, frozen(x)) for k, x in v.items()))
             return tuple(frozen(x) for x in v) if isinstance(v, list) else v
 
-        for name in ("rope_scaling", "moe_kwargs"):
+        for name in ("rope_scaling", "moe_kwargs", "layer_types"):
             object.__setattr__(self, name, frozen(getattr(self, name)))
         super().__post_init__()
 
@@ -633,6 +681,10 @@ class TransformerLM(nn.Module):
             )
             x = x + pos
         block_cls = RematBlock if self.remat else Block
+        mixers = self.layer_types or ("full_attention",) * self.num_layers
+        if len(mixers) != self.num_layers:
+            raise ValueError(f"layer_types names {len(mixers)} layers of "
+                             f"{self.num_layers}")
         for i in range(self.num_layers):
             sparse = self.moe_experts and i >= self.moe_first_dense
             x = block_cls(
@@ -645,7 +697,7 @@ class TransformerLM(nn.Module):
                 attn_scale=self.attn_scale(), mlp_dim=self.mlp_dim,
                 mlp_gated=self.mlp_gated, moe_kwargs=self.moe_kwargs,
                 num_kv_heads=self.num_kv_heads, qk_norm=self.qk_norm, mask=mask,
-                name=f"block{i}",
+                mixer=mixers[i], conv_taps=self.conv_taps, name=f"block{i}",
             )(x, train, rope)
         if head_len is not None:
             x = x[:, :head_len]

@@ -17,6 +17,9 @@ fallback and the correctness oracle for tests.
 - :func:`grouped_matmul` — rows sorted by group times one weight a
   group, the expert product of the no-drop mixture-of-experts layer
   (``jax.lax.ragged_dot``: XLA's own tiled kernel on the TPU).
+- :func:`short_conv` — LFM2's double-gated short convolution between
+  its two projections (gate, a few causal depthwise taps along the
+  sequence, gate) in one pass each way.
 - :func:`quant_encode` / :func:`quant_decode` — the compressed gradient
   wire's amax/scale/round/pack stages in one VMEM pass each
   (``parallel.compression`` calls them for the bucketed transport).
@@ -54,6 +57,8 @@ _LAZY = {
     "BlockDiffusionMask": "tpuframe.ops.ring_attention",
     "ring_attention": "tpuframe.ops.ring_attention",
     "ring_attention_local": "tpuframe.ops.ring_attention",
+    "short_conv": "tpuframe.ops.short_conv",
+    "short_conv_reference": "tpuframe.ops.short_conv",
     "bucket_abs_max": "tpuframe.ops.quant_wire",
     "bucket_abs_max_reference": "tpuframe.ops.quant_wire",
     "quant_encode": "tpuframe.ops.quant_wire",
@@ -82,8 +87,9 @@ def __dir__():
 
 
 class _OpsModule(_types.ModuleType):
-    """Three exports share their kernel module's name
-    (``blockwise_attention``, ``grouped_matmul``, ``ring_attention``), and
+    """Four exports share their kernel module's name
+    (``blockwise_attention``, ``grouped_matmul``, ``ring_attention``,
+    ``short_conv``), and
     importing such a submodule makes the import machinery rebind the
     module object over the package attribute of the same name — which
     would shadow the function for every later
@@ -101,7 +107,7 @@ def _shadow_proof(name):
     )
 
 
-for _name in ("blockwise_attention", "grouped_matmul", "ring_attention"):
+for _name in ("blockwise_attention", "grouped_matmul", "ring_attention", "short_conv"):
     setattr(_OpsModule, _name, _shadow_proof(_name))
 
 _sys.modules[__name__].__class__ = _OpsModule

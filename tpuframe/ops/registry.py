@@ -91,6 +91,12 @@ OPS_REGISTRY = {
         "parity_test":
             "tests/test_latent_moe.py::TestGroupedMatmul::test_forward_and_both_gradients_with_empty_groups",
     },
+    "short_conv": {
+        "module": "tpuframe.ops.short_conv",
+        "symbol": "short_conv",
+        "reference": "short_conv_reference",
+        "parity_test": "tests/test_lfm2.py::TestShortConvOp::test_kernels_match_the_oracle",
+    },
     "moe_gating": {
         "module": "tpuframe.ops.moe_gating",
         "symbol": "moe_dispatch_combine",
@@ -114,6 +120,7 @@ OP_NAME_TOKENS = (
     ("normalize", ("normalize", "per_image_standard")),
     ("quant_wire", ("quant", "dequant", "stochastic_round")),
     ("attention", ("attention", "flash", "fmha", "scaled_dot_product")),
+    ("short_conv", ("short_conv",)),
     ("grouped_matmul", ("ragged-dot", "ragged_dot", "grouped_matmul")),
     ("moe_gating", ("top_k_gating", "moe", "expert_dispatch")),
 )
