@@ -18,17 +18,20 @@ wire's arithmetic contract), blockwise attention's flash kernels
 gpt2-medium's heads in bf16), ring and ulysses attention oracle parity on one device,
 the no-drop expert layer's bounded slot buffers (one window, and a router that
 overflows the bound into several: XLA's ragged-dot kernel leaves the tiles it
-does not visit unwritten on the chip, which no CPU run shows).
+does not visit unwritten on the chip, which no CPU run shows), the short
+convolution, and the head norms with rotary positions (both timed beside
+their oracles' XLA fusions).
 
 Usage: python benchmarks/check_kernels_tpu.py [--only a,b,...]
 (exits 1 on any failure).  ``--only`` runs a named subset — sections:
 layer_norm, cross_entropy, quant_wire, blockwise, ring, ulysses, moe_windows,
-short_conv.
+short_conv, head_norm_rope.
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -55,6 +58,7 @@ def main() -> None:
         "ulysses": _check_ulysses,
         "moe_windows": _check_moe_windows,
         "short_conv": _check_short_conv,
+        "head_norm_rope": _check_head_norm_rope,
     }
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
@@ -446,6 +450,71 @@ def _check_short_conv(jax, jnp, np, rng) -> None:
         times[form] = 1e3 * sorted(laps)[len(laps) // 2]
     print(json.dumps({"check": "short_conv_ms_a_call_fwd_bwd", "shape": [b, l, d, k],
                       **times}), flush=True)
+
+
+def _check_head_norm_rope(jax, jnp, np, rng) -> None:
+    """The head-norm-and-rotary kernel pair against its oracle, output and
+    both gradients: float32 at a length that is no tile multiple, and
+    bfloat16 at the query and key projections of ``sdar-30b-a3b-chat`` (one
+    row of 8192 positions, 32 and 4 heads of 128, each position id twice) and
+    of ``lfm2-8b-a1b`` (2 x 4096, 32 and 8 heads of 64), where both forms are
+    also timed, forward + backward a call (20 calls chained in one program,
+    the median of 5 laps), with the share of the HBM bandwidth the pair's
+    five passes reach (a line of its own; the time passes or fails nothing)."""
+    import time
+
+    from tpuframe.models.transformer import rope_tables
+    from tpuframe.ops.head_norm_rope import head_norm_rope, head_norm_rope_reference
+
+    def both(op, h, eps, chain=1):
+        """``chain`` calls forward + backward in one program, each fed by the
+        one before (its input gradient as input, its output as cotangent), so
+        that a lap is the device's time and not the host's dispatch."""
+        def run(x, s, cos, sin, g):
+            for _ in range(chain):
+                y, vjp = jax.vjp(lambda x, s: op(x, s, cos, sin, num_heads=h, eps=eps), x, s)
+                dx, ds = vjp(g)
+                x, g, s = dx, y, s + 0 * ds
+            return y, dx, ds
+        return jax.jit(run)
+
+    rel = lambda a, b: float(jnp.linalg.norm((a - b).astype(jnp.float32))  # noqa: E731
+                             / jnp.linalg.norm(b.astype(jnp.float32)))
+    forms = (("kernels", functools.partial(head_norm_rope, interpret=False)),
+             ("oracle", head_norm_rope_reference))
+    for name, (b, l, h, d), dtype, eps, tol in (
+            ("f32_ragged", (2, 600, 4, 128), jnp.float32, 1e-6, 1e-5),
+            ("f32_ragged_64", (2, 600, 8, 64), jnp.float32, 1e-5, 1e-5),
+            ("sdar_q", (1, 8192, 32, 128), jnp.bfloat16, 1e-6, 2e-2),
+            ("sdar_k", (1, 8192, 4, 128), jnp.bfloat16, 1e-6, 2e-2),
+            ("lfm2_q", (2, 4096, 32, 64), jnp.bfloat16, 1e-5, 2e-2),
+            ("lfm2_k", (2, 4096, 8, 64), jnp.bfloat16, 1e-5, 2e-2)):
+        x = jnp.asarray(rng.standard_normal((b, l, h * d)), dtype)
+        g = jnp.asarray(rng.standard_normal((b, l, h * d)), dtype)
+        s = jnp.asarray(1 + 0.3 * rng.standard_normal((d,)), jnp.float32)
+        cos, sin = rope_tables(l, d, 1e6, None, np.arange(l) // 2)
+        args = (x, s, cos, sin, g)
+        got, want = (both(op, h, eps)(*args) for _, op in forms)
+        for part, a, c in zip(("out", "dx", "dscale"), got, want):
+            record(f"head_norm_rope_{name}_{part}", rel(a, c), tol)
+        if dtype != jnp.bfloat16:
+            continue
+        times = {}
+        for form, op in forms:
+            fn = both(op, h, eps, chain=20)
+            jax.block_until_ready(fn(*args))
+            laps = []
+            for _ in range(5):
+                t0 = time.perf_counter()
+                jax.block_until_ready(fn(*args))
+                laps.append((time.perf_counter() - t0) / 20)
+            times[form] = 1e3 * sorted(laps)[len(laps) // 2]
+        # two passes forward, three backward, and the two tables each way
+        moved = 5 * x.size * x.dtype.itemsize + 4 * l * max(d, 128) * 4
+        print(json.dumps({"check": "head_norm_rope_ms_a_call_fwd_bwd", "name": name,
+                          "shape": [b, l, h, d], **times,
+                          "kernels_pct_of_819_gb_s": 100 * moved / (times["kernels"] * 1e-3 * 819e9)}),
+              flush=True)
 
 
 def _check_ring(jax, jnp, np, rng) -> None:

@@ -255,16 +255,20 @@ class TestSigmoidRouter:
 
     @pytest.mark.parametrize("name, digest", [
         ("deepseek-v2-lite", "9ef30ed59827517aa2315c8e75be0c17f7f6f5cd4032f09b37db003e27ff2962"),
-        ("sdar-30b-a3b-chat", "697480dc061d32a73237e5579abf50f6f1268873956b6c96cbcbfab13df974e6"),
+        ("sdar-30b-a3b-chat", "5a066652d33734142496011d03f96dc354baf69630b1b3f597b7eadb966e5386"),
     ])
     def test_softmax_scoring_lowers_to_the_program_before_the_sigmoid_router(self, name, digest):
         """The two softmax configurations' models at their rehearsal sizes,
         loss and every gradient, lower to the StableHLO that the commit before
         the sigmoid router (PR 32's) lowers them to, byte for byte: the new
         scoring, bias and mixers touch nothing of theirs.  The digests were
-        taken on that commit with this very function under jax 0.9.0 and this
-        directory's conftest; a change
-        that means to alter those models' program takes them anew."""
+        taken with this very function under jax 0.9.0 and this directory's
+        conftest: ``deepseek-v2-lite``'s on PR 32's commit, and it has held
+        since (PR 34 touched neither latent attention nor the expert layer);
+        ``sdar-30b-a3b-chat``'s anew on PR 34's commit, whose attention calls
+        `ops.head_norm_rope`'s oracle where it ran ``RMSNorm`` and
+        ``apply_rope`` (on PR 32's commit it read ``697480dc...74e6``).  A
+        change that means to alter those models' program takes them anew."""
         cfg = _config(name)
         model = getattr(models, cfg["model"]["class"])(**cfg["model"]["kwargs"])
         shape = (2, cfg["seq_len"], 3) if cfg["sample"] == "blockdiff" else (2, cfg["seq_len"])

@@ -1,5 +1,5 @@
-"""The flash-attention kernels, and the placements ``attn_impl="auto"``
-gives them, compiled for a described v5e: no chip, the
+"""The flash-attention kernels, the placements ``attn_impl="auto"``
+gives them, and the short-convolution and head-norm-and-rotary pairs, compiled for a described v5e: no chip, the
 TPU's own compiler (Mosaic refuses here what it would refuse there: a
 tile that does not fit VMEM, a block it cannot lay out, a precision it
 does not take).  Nothing runs, so this says nothing of results or times.
@@ -160,6 +160,37 @@ def test_short_conv_kernels_compile_for_v5e(one_chip, shape, dtype):
     assert len(_kernel_calls(text, "tpuframe_short_conv_bwd")) == 1
     # nothing but the result, its square's gradient and the partial sums of the taps
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * b * l * d * jnp.dtype(dtype).itemsize
+
+
+@pytest.mark.parametrize("shape, dtype", [
+    ((1, 8192, 32, 128), jnp.bfloat16),   # sdar-30b-a3b-chat's query projection: a head a vreg column
+    ((1, 8192, 4, 128), jnp.bfloat16),    # ... and its key projection
+    ((2, 4096, 32, 64), jnp.bfloat16),    # lfm2-8b-a1b's: two heads side by side in 128 lanes
+    ((2, 4096, 8, 64), jnp.bfloat16),
+    ((2, 600, 4, 128), jnp.float32),      # check_kernels_tpu's: a length that is no tile multiple
+], ids=["sdar_q", "sdar_k", "lfm2_q", "lfm2_k", "f32_ragged"])
+def test_head_norm_rope_kernels_compile_for_v5e(one_chip, shape, dtype):
+    """The head-norm-and-rotary pair: whole-row blocks of 256 positions, a
+    head (or two) a chunk of whole lanes, lane rolls and lane sums within a
+    head, the scale's gradient resident across the grid; and nothing kept
+    for the backward pass but the input in its own dtype."""
+    from tpuframe.ops.head_norm_rope import head_norm_rope
+
+    b, l, h, d = shape
+    x = jax.ShapeDtypeStruct((b, l, h * d), dtype, sharding=one_chip)
+    scale = jax.ShapeDtypeStruct((d,), jnp.float32, sharding=one_chip)
+    table = jax.ShapeDtypeStruct((l, d), jnp.float32, sharding=one_chip)
+
+    def loss(x, scale, cos, sin):
+        out = head_norm_rope(x, scale, cos, sin, num_heads=h, eps=1e-6, interpret=False)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1))).lower(x, scale, table, table).compile()
+    text = compiled.as_text()
+    assert len(_kernel_calls(text, "tpuframe_head_norm_rope_fwd")) == 1
+    assert len(_kernel_calls(text, "tpuframe_head_norm_rope_bwd")) == 1
+    # no float32 array of the input's size: less than the input in its own dtype
+    assert compiled.memory_analysis().temp_size_in_bytes < b * l * h * d * jnp.dtype(dtype).itemsize
 
 
 def test_gpt2_heads_per_shard_compile_for_v5e_2x2(v5e_runtime):

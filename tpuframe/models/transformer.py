@@ -44,6 +44,7 @@ from tpuframe.core.runtime import (
     current_runtime,
 )
 from tpuframe.ops.dispatch import batch_sharding_info, effective_mesh
+from tpuframe.ops.head_norm_rope import head_norm_rope, head_norm_rope_reference
 from tpuframe.ops.ring_attention import attention_reference, ring_attention_local
 from tpuframe.ops.layer_norm import FusedLayerNorm
 from tpuframe.ops.short_conv import short_conv, short_conv_reference
@@ -97,8 +98,10 @@ def _mesh_or_none():
 class RMSNorm(nn.Module):
     """``x * rsqrt(mean(x^2) + eps) * scale``, statistics in float32.
 
-    Plain jnp on purpose: XLA fuses it into its neighbours, and a kernel
-    of its own would cost layout copies around it (PERF.md, PR 25)."""
+    Plain jnp on purpose: on a block's (B, L, D) rows XLA fuses it into
+    its neighbours, and a kernel of its own would cost layout copies
+    around it (PERF.md, PR 25).  A norm on every head before rotary
+    positions is `HeadNormRope`'s."""
 
     eps: float = 1e-6
     dtype: Any = jnp.float32
@@ -169,6 +172,28 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     x32 = x.astype(jnp.float32)
     rot = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
     return (x32 * cos[None, :, None, :] + rot * sin[None, :, None, :]).astype(x.dtype)
+
+
+class HeadNormRope(nn.Module):
+    """An RMSNorm on every head of a projection's (B, L, H * D) rows,
+    then the rotary turn, in one pass each way
+    (`tpuframe.ops.head_norm_rope`: XLA makes of the two, on a
+    (B, L, H, D) view, a handful of float32 passes and re-tiled
+    residuals).  The scale is where `RMSNorm` keeps it in the tree."""
+
+    num_heads: int
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x: jax.Array, rope) -> jax.Array:
+        scale = self.param("scale", nn.initializers.ones,
+                           (x.shape[-1] // self.num_heads,))
+        if self.is_initializing():
+            # init's sample batch need not divide the mesh
+            return head_norm_rope_reference(
+                x, scale, *rope, num_heads=self.num_heads, eps=self.eps)
+        return head_norm_rope(x, scale, *rope, num_heads=self.num_heads,
+                              eps=self.eps, mesh=_mesh_or_none())
 
 
 def _per_shard_spec(mesh, batch: int, num_heads: int):
@@ -337,13 +362,22 @@ class SelfAttention(nn.Module):
             heads * self.head_dim, use_bias=False, dtype=self.dtype, name=name
         )
         b, l, _ = x.shape
-        q = dense("query", self.num_heads)(x).reshape(b, l, self.num_heads, self.head_dim)
-        k = dense("key", kv_heads)(x).reshape(b, l, kv_heads, self.head_dim)
-        v = dense("value", kv_heads)(x).reshape(b, l, kv_heads, self.head_dim)
-        if self.qk_norm:
+        # head norms before rotary positions: one op on the projection's rows
+        fused = self.qk_norm and rope is not None
+
+        def project(name, heads, norm=None):
+            y = dense(name, heads)(x)
+            if fused and norm:
+                y = HeadNormRope(heads, self.norm_eps, name=norm)(y, rope)
+            return y.reshape(b, l, heads, self.head_dim)
+
+        q = project("query", self.num_heads, "q_norm")
+        k = project("key", kv_heads, "k_norm")
+        v = project("value", kv_heads)
+        if self.qk_norm and not fused:
             q = RMSNorm(eps=self.norm_eps, dtype=self.dtype, name="q_norm")(q)
             k = RMSNorm(eps=self.norm_eps, dtype=self.dtype, name="k_norm")(k)
-        if rope is not None:
+        elif rope is not None and not fused:
             q, k = apply_rope(q, *rope), apply_rope(k, *rope)
 
         def sow_tiles(visited, needed):

@@ -20,6 +20,10 @@ fallback and the correctness oracle for tests.
 - :func:`short_conv` — LFM2's double-gated short convolution between
   its two projections (gate, a few causal depthwise taps along the
   sequence, gate) in one pass each way.
+- :func:`head_norm_rope` — an RMSNorm on every head of a query or key
+  projection's rows and the rotary turn behind it, in one pass each way
+  (XLA makes of the two a handful of float32 passes over a
+  (B, L, H, D) view).
 - :func:`quant_encode` / :func:`quant_decode` — the compressed gradient
   wire's amax/scale/round/pack stages in one VMEM pass each
   (``parallel.compression`` calls them for the bucketed transport).
@@ -59,6 +63,8 @@ _LAZY = {
     "ring_attention_local": "tpuframe.ops.ring_attention",
     "short_conv": "tpuframe.ops.short_conv",
     "short_conv_reference": "tpuframe.ops.short_conv",
+    "head_norm_rope": "tpuframe.ops.head_norm_rope",
+    "head_norm_rope_reference": "tpuframe.ops.head_norm_rope",
     "bucket_abs_max": "tpuframe.ops.quant_wire",
     "bucket_abs_max_reference": "tpuframe.ops.quant_wire",
     "quant_encode": "tpuframe.ops.quant_wire",
@@ -87,9 +93,9 @@ def __dir__():
 
 
 class _OpsModule(_types.ModuleType):
-    """Four exports share their kernel module's name
-    (``blockwise_attention``, ``grouped_matmul``, ``ring_attention``,
-    ``short_conv``), and
+    """Five exports share their kernel module's name
+    (``blockwise_attention``, ``grouped_matmul``, ``head_norm_rope``,
+    ``ring_attention``, ``short_conv``), and
     importing such a submodule makes the import machinery rebind the
     module object over the package attribute of the same name — which
     would shadow the function for every later
@@ -107,7 +113,8 @@ def _shadow_proof(name):
     )
 
 
-for _name in ("blockwise_attention", "grouped_matmul", "ring_attention", "short_conv"):
+for _name in ("blockwise_attention", "grouped_matmul", "head_norm_rope", "ring_attention",
+              "short_conv"):
     setattr(_OpsModule, _name, _shadow_proof(_name))
 
 _sys.modules[__name__].__class__ = _OpsModule

@@ -97,6 +97,12 @@ OPS_REGISTRY = {
         "reference": "short_conv_reference",
         "parity_test": "tests/test_lfm2.py::TestShortConvOp::test_kernels_match_the_oracle",
     },
+    "head_norm_rope": {
+        "module": "tpuframe.ops.head_norm_rope",
+        "symbol": "head_norm_rope",
+        "reference": "head_norm_rope_reference",
+        "parity_test": "tests/test_head_norm_rope.py::TestKernels::test_kernels_match_the_oracle",
+    },
     "moe_gating": {
         "module": "tpuframe.ops.moe_gating",
         "symbol": "moe_dispatch_combine",
@@ -121,6 +127,7 @@ OP_NAME_TOKENS = (
     ("quant_wire", ("quant", "dequant", "stochastic_round")),
     ("attention", ("attention", "flash", "fmha", "scaled_dot_product")),
     ("short_conv", ("short_conv",)),
+    ("head_norm_rope", ("head_norm_rope",)),
     ("grouped_matmul", ("ragged-dot", "ragged_dot", "grouped_matmul")),
     ("moe_gating", ("top_k_gating", "moe", "expert_dispatch")),
 )
