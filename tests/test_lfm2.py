@@ -255,7 +255,7 @@ class TestSigmoidRouter:
 
     @pytest.mark.parametrize("name, digest", [
         ("deepseek-v2-lite", "9ef30ed59827517aa2315c8e75be0c17f7f6f5cd4032f09b37db003e27ff2962"),
-        ("sdar-30b-a3b-chat", "5a066652d33734142496011d03f96dc354baf69630b1b3f597b7eadb966e5386"),
+        ("sdar-30b-a3b-chat", "b2e5bddd8e0a687f4322a5b8a1cc3fdb827518dec58ab48555100aab35b03ad0"),
     ])
     def test_softmax_scoring_lowers_to_the_program_before_the_sigmoid_router(self, name, digest):
         """The two softmax configurations' models at their rehearsal sizes,
@@ -265,10 +265,13 @@ class TestSigmoidRouter:
         taken with this very function under jax 0.9.0 and this directory's
         conftest: ``deepseek-v2-lite``'s on PR 32's commit, and it has held
         since (PR 34 touched neither latent attention nor the expert layer);
-        ``sdar-30b-a3b-chat``'s anew on PR 34's commit, whose attention calls
+        ``sdar-30b-a3b-chat``'s on PR 34's commit, whose attention calls
         `ops.head_norm_rope`'s oracle where it ran ``RMSNorm`` and
-        ``apply_rope`` (on PR 32's commit it read ``697480dc...74e6``).  A
-        change that means to alter those models' program takes them anew."""
+        ``apply_rope`` (on PR 32's commit it read ``697480dc...74e6``), and anew
+        on PR 35's, which took four counters nobody read out of the model's
+        outputs (``5a066652...5386`` before; loss and gradients alone lower to
+        the same text on both commits).  A change that means to alter those
+        models' program takes them anew."""
         cfg = _config(name)
         model = getattr(models, cfg["model"]["class"])(**cfg["model"]["kwargs"])
         shape = (2, cfg["seq_len"], 3) if cfg["sample"] == "blockdiff" else (2, cfg["seq_len"])

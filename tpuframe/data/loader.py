@@ -699,7 +699,7 @@ class DataLoader:
         def assembled(sl: slice, b: int) -> tuple:
             # the span takes its train step from the prefetcher's
             # data/prefetch_fetch span around this pull, where there is one
-            with tele.span("data/assemble", batch=b) as sp:
+            with tele.span("data/assemble", cpu=True, batch=b) as sp:
                 allocs0 = self._pool.allocs
                 out = assemble(*screen(fetch(indices[sl]), genuine[sl], b))
                 # did the ring have to allocate (steady state: never)?
@@ -850,7 +850,7 @@ class DevicePrefetcher:
                         if self.track_loader is not None
                         else None
                     )
-                    with tele.span("data/h2d", batch=n, step=step):
+                    with tele.span("data/h2d", cpu=True, batch=n, step=step):
                         device_batch = self._put(batch)
                         # wait for the copy itself (NOT any consumer
                         # compute): after this the host buffers are free

@@ -83,9 +83,6 @@ class BlockDiffusionLM(TransformerLM):
             x0, masked, _ = forward_process(inputs, **self._process())
             xt = jnp.where(masked, self.mask_token, x0)
             row = jnp.concatenate([xt, x0], axis=1)
-        for name, value in (("masked", jnp.sum(masked)), ("noised", masked.size)):
-            self.sow("counters", f"blockdiff/positions_{name}", jnp.float32(value),
-                     reduce_fn=lambda a, b: b, init_fn=lambda: jnp.float32(0))
         return self._decode(
             row, train, positions=np.tile(np.arange(length), 2),
             mask=BlockDiffusionMask(length, self.block_length), head_len=length)

@@ -49,6 +49,7 @@ from tpuframe.ops.ring_attention import attention_reference, ring_attention_loca
 from tpuframe.ops.layer_norm import FusedLayerNorm
 from tpuframe.ops.short_conv import short_conv, short_conv_reference
 from tpuframe.ops.ulysses import ulysses_attention_local
+from tpuframe.track.telemetry import get_telemetry
 
 # the module, by path: ``tpuframe.ops`` rebinds the name to the function
 _blockwise = importlib.import_module("tpuframe.ops.blockwise_attention")
@@ -380,19 +381,20 @@ class SelfAttention(nn.Module):
         elif rope is not None and not fused:
             q, k = apply_rope(q, *rope), apply_rope(k, *rope)
 
-        def sow_tiles(visited, needed):
-            # static numbers a head and a row, times the rows and heads
+        def count_tiles(visited, needed):
+            # static numbers a head and a row, times the rows and heads:
+            # counted here on the host, once a trace, where their reader
+            # takes the ratio; nothing of them rides the step
+            registry = get_telemetry().registry
             for name, value in (("visited", visited), ("needed", needed)):
-                self.sow("counters", f"attention/tiles_{name}",
-                         jnp.float32(value * b * self.num_heads),
-                         reduce_fn=lambda old, new: new,
-                         init_fn=lambda: jnp.float32(0))
+                registry.counter(f"attention/tiles_{name}").inc(
+                    value * b * self.num_heads)
 
         with jax.named_scope("tpuframe/attn"):
             out = _attend(
                 q, k, v, impl=self.attn_impl, causal=self.causal,
                 num_heads=self.num_heads, initializing=self.is_initializing(),
-                mask=self.mask, on_tiles=sow_tiles,
+                mask=self.mask, on_tiles=count_tiles,
             )
         out = out.reshape(b, l, features)
         return nn.Dense(

@@ -1687,7 +1687,9 @@ def fleet_allgather(value: float) -> list[float]:
         return [float(value)]
     if jax.process_count() == 1 or jax.default_backend() == "cpu":
         return [float(value)]
-    return _bounded_gather(float(value))
+    # a collective at a step boundary: a dispatch and a wait, under a name
+    with get_telemetry().span("fleet/allgather", emit=False):
+        return _bounded_gather(float(value))
 
 
 class StragglerMonitor:

@@ -17,6 +17,7 @@ not just the Run logger path.  ``run=None`` runs the monitor registry-only.
 from __future__ import annotations
 
 import os
+import resource
 import threading
 import time
 
@@ -36,6 +37,25 @@ def _rss_mb() -> float:
     except OSError:
         pass
     return 0.0
+
+
+def machine_counters() -> dict[str, float]:
+    """What the machine has done to this process so far, for deltas over a
+    window (the Trainer's drain): involuntary and voluntary context
+    switches, major faults and CPU seconds of all its threads, the
+    runtime's included (one ``getrusage``), and ``psi_cpu_some_us``, the
+    microseconds some task on the host stood runnable without a CPU
+    (``/proc/pressure/cpu``; left out where the file cannot be read)."""
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    out = {"nivcsw": ru.ru_nivcsw, "nvcsw": ru.ru_nvcsw,
+           "majflt": ru.ru_majflt, "cpu_s": ru.ru_utime + ru.ru_stime}
+    try:
+        with open("/proc/pressure/cpu") as f:
+            some = f.readline()
+        out["psi_cpu_some_us"] = int(some.rsplit("total=", 1)[1])
+    except (OSError, IndexError, ValueError):
+        pass
+    return out
 
 
 def device_memory_stats() -> dict[str, float]:

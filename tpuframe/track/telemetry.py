@@ -361,21 +361,29 @@ class Span:
     clock for every thread of the process); ``elapsed`` (seconds) is valid
     after the ``with`` block exits.  ``parent_id`` is the enclosing span of
     the same thread, ``step`` the train step the span feeds (its own, or
-    its parent's).  ``attrs`` is held by reference: what the region writes
-    into it while open (``sp.attrs["fresh_alloc"] = True``) is in the log
-    and on the JSONL line."""
+    its parent's).  ``cpu_ns`` is None unless the span was opened with
+    ``cpu=True``: then it is the CPU time its thread burned inside the
+    region (``time.thread_time_ns()``, read inside the two clock readings,
+    so never more than the duration).  A blocked thread burns none:
+    ``cpu_ns`` is the region's work and the rest of its duration is wait,
+    whichever statement the wait fell in.  ``attrs`` is held by reference:
+    what the region writes into it while open
+    (``sp.attrs["fresh_alloc"] = True``) is in the log and on the JSONL
+    line."""
 
     __slots__ = ("id", "parent_id", "name", "thread", "start_ns", "end_ns",
-                 "step", "attrs", "stack", "elapsed", "ok", "error", "emit",
-                 "_tele", "_ident", "_ann")
+                 "cpu_ns", "step", "attrs", "stack", "elapsed", "ok", "error",
+                 "emit", "_tele", "_ident", "_ann", "_cpu0")
 
     def __init__(self, tele: "Telemetry", name: str, attrs: dict,
-                 step: int | None = None, emit: bool = True):
+                 step: int | None = None, emit: bool = True,
+                 cpu: bool = False):
         self.id = 0
         self.parent_id: int | None = None
         self.name = name
         self.thread = ""
         self.start_ns = self.end_ns = 0
+        self.cpu_ns: int | None = 0 if cpu else None
         self.step = step
         self.attrs = attrs
         self.stack: list[str] = [name]
@@ -411,9 +419,13 @@ class Span:
             self._ann = _annotate(self.name, self.step)
             self._ann.__enter__()
         self.start_ns = time.perf_counter_ns()
+        if self.cpu_ns is not None:
+            self._cpu0 = time.thread_time_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self.cpu_ns is not None:
+            self.cpu_ns = time.thread_time_ns() - self._cpu0
         self.end_ns = time.perf_counter_ns()
         self.elapsed = (self.end_ns - self.start_ns) / 1e9
         if self._ann is not None:
@@ -529,7 +541,7 @@ class Telemetry:
 
     # -- spans ---------------------------------------------------------------
     def span(self, name: str, *, emit: bool = True, step: int | None = None,
-             **attrs: Any) -> Span:
+             cpu: bool = False, **attrs: Any) -> Span:
         """Time a region; nestable, exception-transparent.
 
         ``step`` is the train step the region feeds (the value
@@ -537,9 +549,11 @@ class Telemetry:
         given none takes its parent's.  ``emit=False`` keeps the
         histogram, the live-stack visibility and the span-log record but
         skips the JSONL event — for per-batch inner regions where one
-        event per occurrence would dominate the log.
+        event per occurrence would dominate the log.  ``cpu=True`` also
+        takes the thread's CPU time over the region (``Span.cpu_ns``;
+        ``cpu_ms`` on the JSONL line): work apart from wait.
         """
-        return Span(self, name, attrs, step, emit)
+        return Span(self, name, attrs, step, emit, cpu)
 
     def _close_span(self, sp: Span) -> None:
         with self._lock:
@@ -636,6 +650,8 @@ class Telemetry:
             rec["error"] = sp.error
         if sp.step is not None:
             rec["step"] = sp.step
+        if sp.cpu_ns is not None:
+            rec["cpu_ms"] = round(sp.cpu_ns / 1e6, 3)
         if sp.attrs:
             rec["attrs"] = sp.attrs
         return self._envelope(rec, sp.end_ns, sp.thread)

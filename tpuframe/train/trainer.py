@@ -22,7 +22,9 @@ interval, not per batch.
 
 from __future__ import annotations
 
+import collections
 import os
+import statistics
 import threading
 import time
 from typing import Any, Callable, Mapping, Sequence
@@ -52,6 +54,7 @@ from tpuframe.track import memory as _memory
 # jax.profiler trace (it installs the spans' TraceAnnotation factory)
 from tpuframe.track import profiler as _profiler
 from tpuframe.track.analyze import StragglerMonitor
+from tpuframe.track.system_metrics import machine_counters
 from tpuframe.track.telemetry import get_telemetry
 from tpuframe.parallel.precision import Policy, align_model_dtype, get_policy
 from tpuframe.parallel.sharding import ParallelPlan
@@ -300,6 +303,9 @@ class Trainer:
         # on-device bad-step flags (run-scoped like the straggler)
         self.health = _health.resolve_policy(health)
         self._health_flags: list = []
+        # seconds a step of the windows drained so far, newest last: what a
+        # window is held against before it is called slow (run-scoped)
+        self._window_step_s: collections.deque = collections.deque(maxlen=64)
         self._comms_gauge_set = False
         self._pp_gauge_set = False
 
@@ -761,6 +767,36 @@ class Trainer:
                 loss_ewma=hs.get("loss_ewma"),
                 policy=self.health,
             )
+
+    def _slow_window(self, first_step: int, steps: int, step_s: float,
+                     median_s: float, record: Mapping[str, float]) -> None:
+        """One ``train/slow_window`` event for a window that took more than
+        1.5x the median of those before it, with what tells its causes
+        apart: the longest pull, assembly and copy in it (input), the
+        machine's ``record`` over it (host, machine), the shallowest
+        queue a dispatch found after the window's first (which follows a
+        drain and finds none); none of them, and the device or its
+        runtime held the step.  Evidence only: no metric leaves the
+        window out."""
+        tele = get_telemetry()
+        last = first_step + steps - 1
+        longest = dict.fromkeys(
+            ("train/data_wait", "data/assemble", "data/h2d"), 0.0)
+        depths = []
+        for r in tele.span_log((*longest, "train/step")):
+            if r.step is None or not first_step <= r.step <= last:
+                continue
+            if r.name != "train/step":
+                longest[r.name] = max(longest[r.name], r.elapsed)
+            elif r.step > first_step:
+                depths.append(r.attrs["steps_in_flight"])
+        tele.event(
+            "train/slow_window", first_step=first_step, step=last,
+            steps=steps, window_s=round(step_s * steps, 6),
+            median_s=round(median_s * steps, 6),
+            **{f"{name.split('/')[1]}_max_s": round(s, 6)
+               for name, s in longest.items()},
+            steps_in_flight_min=min(depths, default=None), **record)
 
     def _health_stamp(self) -> dict | None:
         """The health record stamped into every save's meta JSON (next
@@ -1359,7 +1395,7 @@ class Trainer:
 
         def drain(window, first_step):
             """Materialize the device-side window (the only host sync)."""
-            nonlocal host_block
+            nonlocal host_block, machine0, window_t0
             # the drained window: first_step .. step
             with tele.span("train/host_block", emit=False,
                            step=self.batches_seen, first_step=first_step) as sp:
@@ -1383,16 +1419,34 @@ class Trainer:
                     out.update(
                         _health.unpack_health_stats(window["health_stats"])
                     )
+                # what the machine did since the drain before: the record
+                # that tells a descheduled thread from a device that paused
+                machine = machine_counters()
+                record = {k: round(v - machine0[k], 6)
+                          for k, v in machine.items() if k in machine0}
+                sp.attrs.update(record)
             host_block += sp.elapsed
+            step_s = (sp.end_ns - window_t0) / 1e9 / steps
+            seen = self._window_step_s
+            median_s = statistics.median(seen) if len(seen) >= 3 else step_s
+            if step_s > 1.5 * median_s:
+                self._slow_window(first_step, steps, step_s, median_s, record)
+            seen.append(step_s)
+            machine0, window_t0 = machine, sp.end_ns
             return out
 
         batches = iter(self._device_batches(self.train_dataloader, train=True))
         empty_queue = tele.registry.counter("train/empty_queue_dispatches")
-        prev_out = None  # one leaf of the previous step's metrics
+        depth_hist = tele.registry.histogram("train/steps_in_flight")
+        # one output leaf a dispatched step, oldest first: the device's queue
+        in_flight: collections.deque = collections.deque()
         window_first = 0  # the first step summed into ``window``
         # straggler boundary: the gap back to the previous epoch (eval,
         # epoch-end checkpoint) must not read as one slow step
         self._straggler.mark()
+        # a window runs from the close of the drain before it (here: from
+        # the epoch's start) to the close of its own
+        machine0, window_t0 = machine_counters(), time.perf_counter_ns()
         while True:
             # chaos site: a scheduled loader fault raises here, exactly
             # where a real worker-pool / shard-fetch failure surfaces
@@ -1400,7 +1454,7 @@ class Trainer:
             # one parent per iteration, tagged with the step it feeds (its
             # children inherit the tag): what no child covers is the
             # loop's own time
-            with tele.span("train/iter", emit=False,
+            with tele.span("train/iter", emit=False, cpu=True,
                            step=self.batches_seen + 1) as it:
                 with tele.span("train/data_wait", emit=False) as sp:
                     batch = next(batches, _epoch_end)
@@ -1425,14 +1479,20 @@ class Trainer:
                     with tele.span("train/step", batch=self.batches_seen,
                                    data_wait_s=round(wait_s, 6)) as sp, \
                             tele.guard("train/step"):
-                        if prev_out is not None:
-                            # the previous step already complete means
-                            # nothing is queued: the device sits idle until
-                            # this dispatch lands (no sync, no dispatch)
-                            idle = prev_out.is_ready()
-                            sp.attrs["device_idle_at_dispatch"] = idle
-                            if idle:
-                                empty_queue.inc()
+                        # steps dispatched and not yet complete (no sync,
+                        # no dispatch); none means nothing is queued: the
+                        # device sits idle until this dispatch lands
+                        complete = 0
+                        for leaf in in_flight:
+                            if not leaf.is_ready():
+                                break
+                            complete += 1
+                        depth = len(in_flight) - complete
+                        sp.attrs["steps_in_flight"] = depth
+                        sp.attrs["device_idle_at_dispatch"] = depth == 0
+                        depth_hist.observe(depth)
+                        if depth == 0:
+                            empty_queue.inc()
                         self.state, metrics = self._step_call(
                             "train", self._train_step, self.state, batch
                         )
@@ -1445,7 +1505,12 @@ class Trainer:
                                             step=self.batches_seen)
                     raise
                 dispatch += sp.elapsed
-                prev_out = jax.tree.leaves(metrics)[0]
+                in_flight.append(jax.tree.leaves(metrics)[0])
+                # dropped after the dispatch, not before it: each frees a
+                # device buffer (3-4 us), and after a drain the device
+                # waits meanwhile
+                for _ in range(complete):
+                    in_flight.popleft()
                 self.batches_seen += 1
                 self.samples_seen += self.train_dataloader.global_batch_size
                 self._meter_comms(tele)
@@ -1478,18 +1543,21 @@ class Trainer:
                         # state + the consumer-true loader position, so a
                         # crash resumes with the very next batch (no replayed
                         # or skipped samples)
-                        self._intra_checkpointer().save(
-                            self.state,
-                            meta={
-                                "epoch": self.epoch,
-                                "batches_seen": self.batches_seen,
-                                "samples_seen": self.samples_seen,
-                                "loader_state": snap,
-                                "global_batch": self.train_dataloader.global_batch_size,
-                            },
-                            plan=self.plan,
-                            health=self._health_stamp(),
-                        )
+                        # (the health stamp is a device_get: a wait on the
+                        # step just dispatched, named with the save it is for)
+                        with tele.span("train/snapshot", emit=False):
+                            self._intra_checkpointer().save(
+                                self.state,
+                                meta={
+                                    "epoch": self.epoch,
+                                    "batches_seen": self.batches_seen,
+                                    "samples_seen": self.samples_seen,
+                                    "loader_state": snap,
+                                    "global_batch": self.train_dataloader.global_batch_size,
+                                },
+                                plan=self.plan,
+                                health=self._health_stamp(),
+                            )
                 # step boundary = the preemption exit point: the step is the
                 # atomic unit of progress, so a SIGTERM/maintenance notice is
                 # acted on here — last-chance checkpoint, then Preempted out
@@ -1500,7 +1568,11 @@ class Trainer:
                 if window is None:
                     window, window_first = metrics, self.batches_seen
                 else:
-                    window = jax.tree.map(jnp.add, window, metrics)
+                    # one eager add a leaf: each a dispatch, and the place
+                    # the loop waits where the device's queue is full
+                    with tele.span("train/metrics_window", emit=False,
+                                   leaves=len(jax.tree.leaves(metrics))):
+                        window = jax.tree.map(jnp.add, window, metrics)
                 self._emit("on_step_end")
                 if self.log_interval and self.batches_seen % self.log_interval == 0:
                     w = drain(window, window_first)
