@@ -24,8 +24,8 @@ their oracles' XLA fusions).
 
 Usage: python benchmarks/check_kernels_tpu.py [--only a,b,...]
 (exits 1 on any failure).  ``--only`` runs a named subset — sections:
-layer_norm, cross_entropy, quant_wire, blockwise, ring, ulysses, moe_windows,
-short_conv, head_norm_rope.
+layer_norm, cross_entropy, quant_wire, blockwise, flash_layout, ring, ulysses,
+moe_windows, short_conv, head_norm_rope.
 """
 
 from __future__ import annotations
@@ -54,6 +54,7 @@ def main() -> None:
         "cross_entropy": _check_cross_entropy,
         "quant_wire": _check_quant_wire,
         "blockwise": _check_blockwise,
+        "flash_layout": _check_flash_layout,
         "ring": _check_ring,
         "ulysses": _check_ulysses,
         "moe_windows": _check_moe_windows,
@@ -260,8 +261,8 @@ def _attention_parity(jax, jnp, name: str, fn, qkv, *, causal: bool,
                max(gap(a, c) for a, c in zip(gk, go)), gtol)
 
 
-def _schedule_parity(jax, jnp, name: str, qkv, *, scale: float,
-                     ftol: float, gtol: float) -> None:
+def _schedule_parity(jax, jnp, name: str, qkv, *, ftol: float, gtol: float,
+                     **how) -> None:
     """The flash kernels against the scan schedule on the SAME inputs,
     forward and gradients, as a share of each value's size (of 1 for
     the smaller ones): two schedules of one arithmetic differ by the
@@ -280,7 +281,7 @@ def _schedule_parity(jax, jnp, name: str, qkv, *, scale: float,
     outs, grads = [], []
     for fn in (blockwise_attention, blockwise_attention_reference):
         def f(q, k, v, fn=fn):
-            return fn(q, k, v, causal=True, scale=scale)
+            return fn(q, k, v, **{"causal": True, **how})
 
         outs.append(jax.jit(f)(*qkv))
         grads.append(jax.jit(jax.grad(
@@ -361,6 +362,31 @@ def _check_blockwise(jax, jnp, np, rng) -> None:
     # backward kernel may ask for
     _schedule_parity(jax, jnp, "blockwise_long_bf16", latent(1, 32768 - 200, 2),
                      scale=scale, ftol=2 ** -9, gtol=2 ** -6)
+
+
+def _check_flash_layout(jax, jnp, np, rng) -> None:
+    """The layouts the flash kernels' chunk rule makes, each at the shape
+    of the cell that runs it, against the scan schedule on the same bf16
+    inputs: every array in the model's rows (sdar-30b-a3b-chat: 32 heads
+    over 4 of 128 under its block mask), 64-wide heads (gpt2-medium's 16;
+    lfm2-8b-a1b's 32 over 8), and a call that mixes rows and heads-first
+    copies (deepseek-v2-lite: 192-wide q and k, 128-wide v)."""
+    from tpuframe.ops import BlockDiffusionMask
+
+    def qkv(b, l, h, kv_heads, d, dv):
+        return tuple(
+            jnp.asarray(rng.standard_normal((b, l, heads, w)) * 0.5, jnp.bfloat16)
+            for heads, w in ((h, d), (kv_heads, d), (kv_heads, dv)))
+
+    for name, shape, how in (
+        ("flash_layout_rows_sdar", (1, 8192, 32, 4, 128, 128),
+         {"causal": False, "mask": BlockDiffusionMask(4096, 4)}),
+        ("flash_layout_d64_gpt2m", (4, 1024, 16, 16, 64, 64), {}),
+        ("flash_layout_d64_grouped_lfm2", (2, 4096, 32, 8, 64, 64), {}),
+        ("flash_layout_mixed_latent", (2, 4096, 16, 16, 192, 128), {}),
+        ("flash_layout_rows_padded", (2, 1000, 8, 2, 128, 128), {}),
+    ):
+        _schedule_parity(jax, jnp, name, qkv(*shape), ftol=2 ** -9, gtol=2 ** -6, **how)
 
 
 def _check_moe_windows(jax, jnp, np, rng) -> None:

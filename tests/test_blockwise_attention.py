@@ -274,6 +274,44 @@ def test_kernel_matches_full_attention(widths, causal, l, dtype):
         np.testing.assert_allclose(_f32(a), _f32(b_), **gtol)
 
 
+@pytest.mark.parametrize("shape, how, layout", [
+    ((1, 512, 8, 2, 128, 128), "mask", (4, 11)),    # sdar's: four heads a block, one K/V head
+    ((2, 256, 4, 4, 64, 64), "causal", (2, 11)),    # gpt2's: two heads a block of lanes
+    ((1, 256, 8, 2, 64, 64), "causal", (2, 11)),    # lfm2's: a group shares half a K/V block
+    ((1, 256, 4, 4, 32, 32), "bidir", (4, 11)),     # four heads a block
+    ((1, 256, 2, 2, 192, 128), "causal", (1, 5)),   # deepseek's: rows and copies in one call
+    ((1, 256, 3, 3, 64, 64), "causal", (1, 0)),     # heads in no whole blocks: copies
+    ((1, 256, 6, 6, 128, 128), "causal", (2, 11)),  # whole-lane heads two a block, each its K/V
+    ((2, 768, 8, 2, 128, 128), "causal", (4, 11)),  # a length that pads (tiles of 512)
+    ((1, 768, 4, 2, 64, 64), "mask", (2, 11)),      # ... in blocks of two heads, a group of two
+], ids=["rows", "two_heads", "grouped_half", "four_heads", "mixed", "odd_heads",
+        "rows_pairs", "rows_padded", "two_heads_padded"])
+def test_kernel_layouts_match_scan_schedule(shape, how, layout):
+    """The layouts the chunk rule makes, each against the scan schedule on
+    the same float32 inputs: output and all three gradients, and the rule's
+    own answer for the shape (heads a block of lanes, arrays in place)."""
+    from tpuframe.ops import BlockDiffusionMask
+
+    b, l, h, kv_heads, d, dv = shape
+    rng = np.random.default_rng(11)
+    q, k, v = (jnp.asarray(rng.standard_normal((b, l, heads, w)) * 0.5, jnp.float32)
+               for heads, w in ((h, d), (kv_heads, d), (kv_heads, dv)))
+    assert (bw._chunk_heads(h, kv_heads, d, dv, l),
+            bw.layout_counts(h, kv_heads, d, dv)[0]) == layout
+    kw = {"mask": BlockDiffusionMask(l // 2, 4)} if how == "mask" else {"causal": how == "causal"}
+    block = None if l == 768 else 128
+    fn = lambda q, k, v: blockwise_attention(  # noqa: E731
+        q, k, v, block_size=block, interpret=True, **kw)
+    sched = lambda q, k, v: bw.blockwise_attention_reference(  # noqa: E731
+        q, k, v, block_size=128, **kw)
+    got = fn(q, k, v)
+    assert got.shape == (b, l, h, dv)
+    np.testing.assert_allclose(_f32(got), _f32(sched(q, k, v)), atol=5e-6)
+    for a, c, x in zip(_grads(fn, q, k, v), _grads(sched, q, k, v), (q, k, v)):
+        assert a.shape == x.shape
+        np.testing.assert_allclose(_f32(a), _f32(c), atol=5e-5)
+
+
 @pytest.mark.parametrize("l", [256, 300], ids=["blocks", "indivisible"])
 @pytest.mark.parametrize("causal", [False, True], ids=["bidir", "causal"])
 def test_kernel_matches_scan_schedule(causal, l):
@@ -336,6 +374,7 @@ def test_kernel_tile_rule(l, block, want):
 
 
 def test_kernel_tiles_ignore_the_schedules_block(monkeypatch):
+    jax.clear_caches()  # the kernels are jitted: trace this call anew
     seen = []
     real = bw._flash_call
     monkeypatch.setattr(
@@ -363,7 +402,7 @@ def test_kernel_gradients_at_blocks_no_power_of_two(block, blocks):
 
 def test_tiles_that_do_not_divide_are_refused():
     """A grid is never floored: 768 positions unpadded in tiles of 512."""
-    q, k, v = (a.transpose(0, 2, 1, 3) for a in _wide_qkv(768, 64, 64, jnp.float32, h=1))
+    q, k, v = _wide_qkv(768, 64, 64, jnp.float32, h=1)
     with pytest.raises(ValueError, match="do not divide"):
         bw._flash_fwd(q, k, v, True, 1.0, 512, 768, True)
 
@@ -443,6 +482,10 @@ def test_kernel_verdict_event_and_auto_dispatch(monkeypatch, tmp_path):
             ("blockwise_attention", "d64_l256", False, "default"),
             ("blockwise_attention", "d64_l256", True, "default"),
             ("blockwise_attention", "d64_l256", False, "forced")]
+        # how many of the kernels' eleven arrays stay in the model's rows:
+        # said where the kernels engage, and nowhere else
+        assert [(e.get("operands_in_place"), e.get("operands_copied")) for e in events] == [
+            (None, None), (11, 0), (None, None)]  # two 64-wide heads a block of lanes
     finally:
         T.reset()
         dispatch._VERDICT_EMITTED.clear()
@@ -530,12 +573,15 @@ def test_attend_per_shard_matches_full_attention(mesh_spec, causal, monkeypatch,
         rt.reset_runtime()
         T.reset()
         dispatch._VERDICT_EMITTED.clear()
-    # every kernel call saw one shard's rows and heads, in the kernels' layout
+    # every kernel call saw one shard's rows and heads, in the model's layout
     shards = runtime.mesh.shape
-    local = (b // shards["data"], h // shards["model"], 256, d)
+    local = (b // shards["data"], 256, h // shards["model"], d)
     assert placed and set(placed) == {(True, local)}
-    assert [(e["op"], e["shape_class"], e["enable"], e["source"]) for e in events] == [
-        ("blockwise_attention", "d16_l256", True, "default")]
+    # announced by the op's own call, which knows the values' width: the
+    # rule's question with q alone leaves kernels that engage to it
+    assert [(e["op"], e["shape_class"], e["enable"], e["source"],
+             e["operands_in_place"], e["operands_copied"]) for e in events] == [
+        ("blockwise_attention", "d16_l256", True, "default", 0, 11)]
     np.testing.assert_allclose(_f32(got), _f32(full(q, k, v)), atol=2e-5)
     for a, c in zip(grads, jax.grad(loss(full), (0, 1, 2))(q, k, v)):
         np.testing.assert_allclose(_f32(a), _f32(c), atol=5e-5)

@@ -8,6 +8,7 @@ The topology is described inside a fixture, never at import: only the
 worker that runs this file loads the TPU's library.  Keep every such
 compile in this one file."""
 
+import math
 import re
 
 import jax
@@ -136,6 +137,60 @@ def test_masked_grouped_kernels_compile_for_v5e(one_chip, shape, half, block, ti
         assert compiled.memory_analysis().temp_size_in_bytes < b * h * l * l * 2 / 8
 
 
+def _materialized(text):
+    """(name, opcode, dtype, elements) of every array the compiled step
+    writes: the instructions of its entry computation (what a fusion
+    computes inside stays in registers)."""
+    found = []
+    for line in text[text.index("\nENTRY "):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(", line)
+        if m:
+            dims = [int(x) for x in m.group(3).split(",") if x]
+            found.append((m.group(1), m.group(4), m.group(2),
+                          math.prod(dims)))
+    return found
+
+
+@pytest.mark.parametrize("shape, mask", [
+    ((1, 8192, 32, 4, 128), (4096, 4)),   # sdar-30b-a3b-chat's call: four heads a block of lanes
+    ((4, 1024, 16, 16, 64), None),        # gpt2-medium's: two heads a block
+    ((2, 4096, 32, 8, 64), None),         # lfm2-8b-a1b's: a group of four over half a K/V block
+], ids=["sdar", "gpt2m", "lfm2"])
+def test_flash_kernels_take_the_models_rows(one_chip, shape, mask):
+    """The call as the model makes it (the projections' (B, L, H*D) rows
+    in, the rows of the output out): the kernels read and write those rows,
+    so the compiled text holds one forward and one backward kernel, no copy
+    or transpose of an array the size of q, the output, k or v, and no
+    float32 array that size (``delta`` is taken from the rows as they lie)."""
+    from tpuframe.ops import BlockDiffusionMask
+
+    b, l, h, kv_heads, d = shape
+    rows = lambda heads: jax.ShapeDtypeStruct(  # noqa: E731
+        (b, l, heads * d), jnp.bfloat16, sharding=one_chip)
+    how = {"causal": True} if mask is None else {"mask": BlockDiffusionMask(*mask)}
+
+    def loss(q, k, v, w):
+        out = blockwise_attention(
+            q.reshape(b, l, h, d), k.reshape(b, l, kv_heads, d), v.reshape(b, l, kv_heads, d),
+            interpret=False, **how)
+        return jnp.sum((out.reshape(b, l, h * d) * w).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        rows(h), rows(kv_heads), rows(kv_heads), rows(h)).compile().as_text()
+    assert len(_kernel_calls(text, "tpuframe_flash_fwd")) == 1
+    assert len(_kernel_calls(text, "tpuframe_flash_bwd")) == 1
+    arrays = _materialized(text)
+    assert len(arrays) > 4
+    sizes = {b * l * h * d, b * l * kv_heads * d}
+    # a layout change is a copy or a transpose, alone or as the fusion XLA
+    # names after it; copy-start / copy-done move an array as it lies
+    moved = [a for a in arrays if a[3] in sizes and a[1] not in ("copy-start", "copy-done")
+             and re.search("copy|transpose", a[0] + " " + a[1])]
+    assert not moved, moved
+    wide = [a for a in arrays if a[2] == "f32" and a[3] >= b * l * h * d]
+    assert not wide, wide
+
+
 @pytest.mark.parametrize("shape, dtype", [
     ((2, 4096, 2048, 3), jnp.bfloat16),   # lfm2-8b-a1b's conv layers: 8192 x 2048 a call
     ((2, 600, 256, 3), jnp.float32),      # check_kernels_tpu's: a length that is no tile multiple
@@ -246,10 +301,11 @@ def test_gpt2_medium_step_holds_the_flash_kernels(v5e_runtime, chips):
         return jnp.mean(jax.nn.logsumexp(logits, axis=-1))
 
     lowered = jax.jit(jax.grad(loss)).lower(params, toks)
-    if chips > 1:
-        # the layers call one jitted per-shard region: lowered once, not a
-        # region a layer (48 of them cost the 24-layer step 20 s to lower)
-        assert lowered.as_text().count('kernel_name = "tpuframe_flash_fwd"') == 1
+    # the layers call one jitted per-shard region, and on one chip the jitted
+    # kernels themselves: lowered once, not once a layer (48 regions cost the
+    # 24-layer step 20 s to lower; 48 kernels of two heads each 14 s, PR 40)
+    for kernel in ("tpuframe_flash_fwd", "tpuframe_flash_bwd"):
+        assert lowered.as_text().count(f'kernel_name = "{kernel}"') == 1
     text = lowered.compile().as_text()
     assert len(_kernel_calls(text, "tpuframe_flash_fwd")) == 2
     assert len(_kernel_calls(text, "tpuframe_flash_bwd")) == 2

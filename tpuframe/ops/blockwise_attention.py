@@ -12,7 +12,10 @@ AND backward.  One arithmetic, two schedules of it:
   (``TPUFRAME_PALLAS_INTERPRET=1`` or ``interpret=True``).  The score
   tile, the probabilities and the running (output, sum, max) state live
   in VMEM; HBM sees q, k, v, the output and the rows' logsumexp, once
-  each.  The kernels are bound by the MXU, not by bandwidth.
+  each, in the model's own (B, L, H*D) rows wherever heads make a block
+  of their lanes (128-wide heads several a block, 64- or 32-wide ones
+  two or four in 128 lanes; a 192-wide head goes through a heads-first
+  copy).  The kernels are bound by the MXU, not by bandwidth.
 - **The scan schedule** (:func:`blockwise_attention_reference`)
   everywhere else — CPU, ``TPUFRAME_DISABLE_PALLAS``, a multi-device
   jit without a mesh, a
@@ -63,7 +66,7 @@ Both:
 - Grouped heads: ``k`` and ``v`` may hold one head a group of query
   heads.  They stay that size in HBM; the kernels' ``index_map`` reads
   head ``j // group``, and dK/dV come out a query head and are summed
-  over the group by one reduction after the backward kernel.
+  over the group by one pass after the backward kernel.
 
 ``TransformerLM(attn_impl="blockwise")`` selects it, and ``"auto"``
 does wherever the kernels would run (:func:`engage_kernels`, the one
@@ -111,6 +114,8 @@ _MAX_TILE = 1024
 _TILE_VMEM_BYTES = 32 << 20
 #: what the backward kernel may ask of a core's 128 MiB of VMEM
 _VMEM_BYTES = 100 << 20
+#: heads of whole lanes that share a block of the model's rows, at most
+_WIDE_HEADS = 4
 
 
 def _to_blocks(a, n, block):
@@ -196,11 +201,20 @@ def _fwd_schedule(q_blocks, k_blocks, v_blocks, causal, scale, block, kv_len):
 
 # -- the Pallas flash kernels --------------------------------------------------
 #
-# Same tiles, same arithmetic as the schedule above, in the layout
-# (B, H, L, D): one head's (block, D) tile is then a contiguous row range
-# and a legal Mosaic block at any head width (192 and 64 are no lane
-# multiples, so no block of the model's (B, L, H*D) view can hold one
-# head).  Every kernel runs a grid (batch, head, held block, streamed
+# Same tiles, same arithmetic as the schedule above.  A kernel works on
+# one head's (block, D) tile of each array at a time, and where that
+# tile comes from follows the head's width (`_in_place`, `_chunk_heads`).
+# Whole lanes wide (128): a lane-aligned slice of a block of the model's
+# own folded rows (B, L, H*D), `_WIDE_HEADS` heads a block, picked out of
+# the last axis by the block index.  Half or a quarter of the lanes wide
+# (64, 32): two or four heads share a 128-lane block of those rows and a
+# head's lanes are picked by a mask (the MXU contracts over all 128
+# lanes for a narrow head anyway).  Either way the kernel runs a block's
+# heads in turn, one grid step for all of them.  Any other width (192 is
+# no lane multiple, so no block of the rows can hold one head) is read
+# from a heads-first (B, H, L, D) copy, where a head's tile is a
+# contiguous row range and a legal Mosaic block at any width.  Every
+# kernel runs a grid (batch, block of heads, held block, streamed
 # block), the last axis sequential: the held side's blocks stay in VMEM
 # while the other side streams past, and the float32 state (output
 # accumulator, row sum, row max; dQ; dK and dV) lives in VMEM scratch and
@@ -312,10 +326,73 @@ def _step(refs, width):
     return refs, held, order[held * width + at], kinds[held * width + at]
 
 
-def _fwd_kernel(*refs, width, **tile):
-    """`_block_update` over the K/V blocks streaming past one Q block."""
+def _chunk(heads, group, narrow):
+    """The heads of one block of lanes, for the kernels' static loop:
+    ``(load, part, only, update)``.  A block holds one head (``heads``
+    1: everything below is the identity), ``heads`` heads narrower than
+    the lanes side by side in 128 of them (``narrow``), or ``heads``
+    heads of whole lanes each.
+
+    ``load(ref, kv=False)`` then ``part(x, i)`` give head i its operand:
+    a lane-aligned slice of the ref where heads are whole lanes wide;
+    the whole 128-lane block where they are narrow, which ``only(x, i)``
+    then zeroes outside head i's lanes (a product over all 128 lanes
+    with one such operand is head i's own: the MXU contracts over whole
+    lanes for a narrow head anyway).  ``kv``: a K/V block.  Where
+    ``group`` query heads share a K/V head (in whole blocks), a block of
+    wide heads brings that one head alone, and a narrow one finds it
+    among the block's heads by the grid's head index and repeats it in
+    every head's lanes.  ``update(ref, i, fn, rows)`` replaces head i's
+    lanes of ``ref[rows]`` by ``fn`` of them."""
+    everything = slice(None)
+
+    def update_all(ref, i, fn, rows=everything):
+        ref[rows, :] = fn(ref[rows, :])
+
+    same = lambda x, i, kv=False: x  # noqa: E731
+    if heads == 1:
+        return (lambda ref, kv=False: ref[...]), same, same, update_all
+    if not narrow:
+        def lanes(ref, i):
+            d = ref.shape[-1] // heads
+            return slice(i * d, (i + 1) * d)
+
+        def part(ref, i, kv=False):
+            # a K/V head a group: the block is that head
+            return ref[...] if kv and group > 1 else ref[:, lanes(ref, i)]
+
+        def update(ref, i, fn, rows=everything):
+            ref[rows, lanes(ref, i)] = fn(ref[rows, lanes(ref, i)])
+
+        return (lambda ref, kv=False: ref), part, same, update
+    d = _LANES // heads
+    of = lax.broadcasted_iota(jnp.int32, (1, _LANES), 1) // d
+    mine = [of == i for i in range(heads)]
+    at = (pl.program_id(1) // max(group // heads, 1)) % heads
+
+    def load(ref, kv=False):
+        x = ref[...]
+        if not kv or group == 1:
+            return x
+        # Mosaic rotates 32-bit lanes only
+        one = jnp.where(of == at, x, jnp.zeros_like(x)).astype(jnp.float32)
+        return functools.reduce(
+            jnp.add, [one] + [pltpu.roll(one, r * d, 1) for r in range(1, heads)]
+        ).astype(x.dtype)
+
+    def update(ref, i, fn, rows=everything):
+        old = ref[rows, :]
+        ref[rows, :] = jnp.where(mine[i], fn(old), old)
+
+    return load, same, lambda x, i: jnp.where(mine[i], x, jnp.zeros_like(x)), update
+
+
+def _fwd_kernel(*refs, width, heads, group, narrow, **tile):
+    """`_block_update` over the K/V blocks streaming past one Q block,
+    a head of the block at a time (`_chunk`)."""
     (q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, l_ref, m_ref), q_idx, k_idx, kind = _step(
         refs, width)
+    load, part, only, update = _chunk(heads, group, narrow)
 
     @pl.when(pl.program_id(3) == 0)
     def _():
@@ -323,34 +400,40 @@ def _fwd_kernel(*refs, width, **tile):
         l_ref[...] = jnp.zeros_like(l_ref)
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
 
-    def update(masked):
-        v = v_ref[...]
-        s = _nt_dot(q_ref[...], k_ref[...]) * tile["scale"]  # (queries, keys) f32
-        if masked:
-            s = jnp.where(_valid(q_idx, k_idx, **tile), s, -jnp.inf)
-        m = m_ref[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        m_exp = m_new
-        if masked and kind is not None:  # a row that has seen no key yet
-            m_exp = jnp.where(m_new == -jnp.inf, 0.0, m_new)
-        p = jnp.exp(s - m_exp)
-        correction = jnp.exp(m - m_exp)
-        l_ref[...] = l_ref[...] * correction + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * correction + _dot(p.astype(v.dtype), v)
-        m_ref[...] = m_new
+    def step(masked):
+        qs, ks, vs = load(q_ref), load(k_ref, kv=True), load(v_ref, kv=True)
+        for i in range(heads):
+            v = part(vs, i, kv=True)
+            # (queries, keys) f32
+            s = _nt_dot(only(part(qs, i), i), part(ks, i, kv=True)) * tile["scale"]
+            if masked:
+                s = jnp.where(_valid(q_idx, k_idx, **tile), s, -jnp.inf)
+            m = m_ref[i]
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            m_exp = m_new
+            if masked and kind is not None:  # a row that has seen no key yet
+                m_exp = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+            p = jnp.exp(s - m_exp)
+            correction = jnp.exp(m - m_exp)
+            l_ref[i] = l_ref[i] * correction + jnp.sum(p, axis=1, keepdims=True)
+            update(acc_ref, i, lambda acc: acc * correction + _dot(p.astype(v.dtype), v))
+            m_ref[i] = m_new
 
-    _visit(q_idx, k_idx, update, kind, **tile)
+    _visit(q_idx, k_idx, step, kind, **tile)
 
     @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
     def _():
-        lsum = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = (acc_ref[...] / lsum).astype(o_ref.dtype)
-        lse_ref[...] = _col_to_row(m_ref[...] + jnp.log(lsum))
+        accs = load(acc_ref)
+        for i in range(heads):
+            lsum = jnp.maximum(l_ref[i], 1e-30)
+            update(o_ref, i, lambda _: (part(accs, i) / lsum).astype(o_ref.dtype))
+            lse_ref[i] = _col_to_row(m_ref[i] + jnp.log(lsum))
 
 
-def _bwd_kernel(*refs, width, **tile):
+def _bwd_kernel(*refs, width, heads, group, narrow, **tile):
     """`_tile_grads` and the three products over the Q blocks streaming
-    past one K/V block: the schedule's two passes in one.  The tile is
+    past one K/V block: the schedule's two passes in one, a head of the
+    block at a time (`_chunk`).  The tile is
     held keys-first (scores transposed): the row statistics then
     broadcast along the rows as they arrive, and the dK/dV products
     contract over the tile's last axis, nothing transposed.  Each tile's
@@ -362,6 +445,7 @@ def _bwd_kernel(*refs, width, **tile):
      dk_acc, dv_acc, dq_acc), k_idx, q_idx, kind = _step(refs, width)
     first, last = pl.program_id(3) == 0, pl.program_id(3) == pl.num_programs(3) - 1
     side = tile["side"]
+    load, part, only, update = _chunk(heads, group, narrow)
 
     @pl.when((k_idx == 0) & first)
     def _():
@@ -372,24 +456,27 @@ def _bwd_kernel(*refs, width, **tile):
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def update(masked):
-        q, do, k = q_ref[...], do_ref[...], k_ref[...]
-        s = _nt_dot(k, q) * tile["scale"]  # (keys, queries) f32
-        if masked:
-            s = jnp.where(
-                _valid(q_idx, k_idx, keys_first=True, **tile),
-                s, -jnp.inf)
-        p = jnp.exp(s - lse_ref[...])
-        dv_acc[...] += _dot(p.astype(do.dtype), do)
-        dp = _nt_dot(v_ref[...], do)
-        ds = (p * (dp - delta_ref[...]) * tile["scale"]).astype(q.dtype)
-        dk_acc[...] += _dot(ds, q)
+    def step(masked):
+        qs, dos = load(q_ref), load(do_ref)
+        ks, vs = load(k_ref, kv=True), load(v_ref, kv=True)
         rows = pl.ds(pl.multiple_of(q_idx * side, side), side)
-        dq_acc[rows, :] += lax.dot_general(  # ds.T @ k
-            ds, k, (((0,), (0,)), ((), ())), precision=_precision(ds),
-            preferred_element_type=jnp.float32)
+        for i in range(heads):
+            q, do, k = part(qs, i), part(dos, i), part(ks, i, kv=True)
+            s = _nt_dot(only(k, i), q) * tile["scale"]  # (keys, queries) f32
+            if masked:
+                s = jnp.where(
+                    _valid(q_idx, k_idx, keys_first=True, **tile),
+                    s, -jnp.inf)
+            p = jnp.exp(s - lse_ref[i])
+            update(dv_acc, i, lambda dv: dv + _dot(p.astype(do.dtype), do))
+            dp = _nt_dot(only(part(vs, i, kv=True), i), do)
+            ds = (p * (dp - delta_ref[i]) * tile["scale"]).astype(q.dtype)
+            update(dk_acc, i, lambda dk: dk + _dot(ds, q))
+            update(dq_acc, i, lambda dq: dq + lax.dot_general(  # ds.T @ k
+                ds, k, (((0,), (0,)), ((), ())), precision=_precision(ds),
+                preferred_element_type=jnp.float32), rows)
 
-    _visit(q_idx, k_idx, update, kind, **tile)
+    _visit(q_idx, k_idx, step, kind, **tile)
 
     @pl.when(last)
     def _():
@@ -401,26 +488,81 @@ def _bwd_kernel(*refs, width, **tile):
         dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
 
+def _in_place(width):
+    """The chunk rule for one array, from its head width alone: a head
+    that is whole lanes wide is a legal Mosaic block of the model's
+    folded rows (B, L, H*D), picked out of the last axis by the block's
+    index; any other width (192) is read from a heads-first copy."""
+    return width % _LANES == 0
+
+
+def _chunk_heads(h, kv_heads, d, dv, l_pad):
+    """How many heads one block of lanes holds for a call's kernels, every
+    array then in place.  Heads narrower than the lanes by a whole factor
+    (64: two) run side by side in 128 lanes where q, k and v are one
+    width; heads of whole lanes (128) run `_WIDE_HEADS` a block where a
+    block's dQ over ``l_pad`` positions still fits VMEM: fewer, longer
+    grid steps and longer runs in HBM.  Either way the heads and the K/V
+    heads come in whole blocks (a group of query heads over one K/V head
+    likewise); else 1, and each array goes by its own width
+    (`_in_place`)."""
+    group = h // kv_heads
+    wide = _in_place(d) and _in_place(dv)
+    if wide:
+        n = _WIDE_HEADS  # reckoned for float32 rows, the widest the kernels take
+        while n > 1 and _bwd_vmem_bytes(l_pad, n * d, jnp.float32) > _VMEM_BYTES:
+            n //= 2
+    else:
+        n = _LANES // d if d == dv and _LANES % d == 0 else 1
+
+    def whole(n):
+        return h % n == 0 and (
+            group == 1 or group % n == 0 and (wide or kv_heads % n == 0))
+
+    while wide and not whole(n):
+        n //= 2
+    return n if whole(n) else 1  # narrow heads fill the 128 lanes or go by copies
+
+
+def layout_counts(h, kv_heads, d, dv):
+    """(in place, copied): how many of the eleven arrays a layer's two
+    kernels read and write (q, k, v and the output; q, k, v, dO, dQ, dK,
+    dV) stay in the model's rows, by the chunk rule.  Row statistics
+    are not counted."""
+    if _chunk_heads(h, kv_heads, d, dv, 0) > 1:
+        return 11, 0
+    in_place = 6 * _in_place(d) + 5 * _in_place(dv)
+    return in_place, 11 - in_place
+
+
 def _flash_call(kernel, name, operands, outs, scratch, *, streams, side,
-                causal, scale, kv_len, interpret, held_axis="parallel",
-                vmem_bytes=None):
+                causal, scale, kv_len, interpret, chunk=1,
+                held_axis="parallel", vmem_bytes=None):
     """One kernel of the family over grid (batch, head, held, streamed),
     in square tiles of ``side`` positions.
 
-    ``operands`` and ``outs`` are (array or ShapeDtypeStruct, role) pairs:
+    ``operands`` and ``outs`` are (array or ShapeDtypeStruct, role) pairs
+    in the model's layout (B, L, H, D), and the results come back in it:
     role ``"q"`` / ``"k"`` says which side's block index the array
-    follows (``"all"``: a head's whole sequence, resident); a
-    (B, H, 1, L) array is a row statistic.  ``scratch`` lists the shapes
-    of the float32 VMEM scratch.  ``streams`` names the side that moves
-    along the last grid axis; under a causal mask its index is clamped
-    to the diagonal, from above for keys (tiles past it) and from below
+    follows (``"all"``: a head's whole sequence, resident); ``"stat"`` is
+    a (B, H, 1, L) row statistic of the queries.  Each array is handed
+    to the kernel by its own head width (`_in_place`): the folded rows
+    (B, L, H*D) as they are, a block a head's lanes, or a heads-first
+    (B, H, L, D) copy; the kernel sees a (positions, D) block either
+    way, and a call may mix both.  ``chunk`` > 1 (`_chunk_heads`): the
+    grid's head axis counts blocks of that many heads, every array in
+    its rows, and the kernel runs a block's heads in turn (`_chunk`).
+    ``scratch`` lists the shapes of the float32 VMEM scratch.
+    ``streams`` names the side that moves along the last grid axis;
+    under a causal mask its index is clamped to the diagonal, from above for keys (tiles past it) and from below
     for queries (tiles before it), so a step that computes nothing
     fetches nothing; under a rule on positions the last grid axis counts
     a held block's live tiles and the index comes from `_tile_plan`.  An
-    array with fewer heads than the first operand is read a group of
-    heads at a time (head ``j // group``).  ``vmem_bytes`` replaces
-    Mosaic's 16 MiB of scoped VMEM."""
-    b, h, l_pad, _ = operands[0][0].shape
+    operand with fewer heads than the first is read a group of heads at
+    a time (head ``j // group``); a result that asks for fewer is written
+    a query head and summed over the group after the kernel.
+    ``vmem_bytes`` replaces Mosaic's 16 MiB of scoped VMEM."""
+    b, l_pad, h, _ = operands[0][0].shape
     if l_pad % side:  # a floored grid would leave rows unvisited
         raise ValueError(f"tiles of {side} do not divide {l_pad} padded positions")
     clamp = {"k": jnp.minimum, "q": jnp.maximum}[streams]
@@ -429,46 +571,98 @@ def _flash_call(kernel, name, operands, outs, scratch, *, streams, side,
     if isinstance(causal, BlockDiffusionMask):
         *plan, width = _tile_plan(causal, l_pad, side, kv_len, streams)
 
-    def spec(a, role):
-        group = h // a.shape[1]
+    def block_index(role, held, streamed, plan):
+        if role == "all":
+            return 0
+        if ("q" if role == "stat" else role) != streams:
+            return held
+        if plan:
+            return plan[0][held * width + streamed]
+        return clamp(streamed, held) if causal else streamed
+
+    narrow = chunk > 1 and not _in_place(operands[0][0].shape[3])
+
+    def rows_of(a):
+        return chunk > 1 or _in_place(a.shape[3])
+
+    def spec(a, role, heads):
+        if role == "stat":
+            return pl.BlockSpec(
+                (None, chunk, 1, side),
+                lambda b_, h_, held, streamed, *plan: (
+                    b_, h_, 0, block_index(role, held, streamed, plan)))
+        group, rows = h // heads, rows_of(a)
+        # the heads a block of this array holds: the grid step's, but of
+        # wide heads a group shares the one K/V head
+        per = chunk if narrow or group == 1 else 1
+        d = a.shape[3] * per
 
         def at(b_, h_, held, streamed, *plan):
-            idx = held
-            if role == streams:
-                if plan:
-                    idx = plan[0][held * width + streamed]
-                else:
-                    idx = clamp(streamed, held) if causal else streamed
-            h_ = h_ if group == 1 else h_ // group
-            return (b_, h_, 0, idx) if a.shape[2] == 1 else (b_, h_, idx, 0)
+            idx = block_index(role, held, streamed, plan)
+            head = h_ * chunk // group // per
+            return (b_, idx, head) if rows else (b_, head, idx, 0)
 
-        if role == "all":  # the whole sequence of one head, resident
-            return pl.BlockSpec((None, None, *a.shape[2:]),
-                                lambda b_, h_, held, streamed, *plan: (b_, h_, 0, 0))
-        if a.shape[2] == 1:
-            return pl.BlockSpec((None, None, 1, side), at)
-        return pl.BlockSpec((None, None, side, a.shape[3]), at)
+        length = l_pad if role == "all" else side
+        return pl.BlockSpec(
+            (None, length, d) if rows else (None, None, length, d), at)
+
+    def placed(a, role):
+        """An operand as the kernel takes it: its rows, or the copy."""
+        if role == "stat":
+            return a
+        return a.reshape(b, l_pad, -1) if rows_of(a) else _heads_first(a)
+
+    def written(a, role):
+        """What the kernel writes for result ``a``: a block a query head."""
+        if role == "stat":
+            return a
+        d = a.shape[3]
+        return jax.ShapeDtypeStruct(
+            (b, l_pad, h * d) if rows_of(a) else (b, h, l_pad, d), a.dtype)
+
+    def returned(result, a, role):
+        """A result in the model's layout, a group of heads summed where
+        ``a`` asks for fewer than the kernel wrote."""
+        if role == "stat":
+            return result
+        heads, d = a.shape[2:]
+        if not rows_of(a):
+            if heads != h:
+                result = jnp.sum(result.reshape(b, heads, -1, l_pad, d), axis=2,
+                                 dtype=jnp.float32).astype(a.dtype)
+            return _heads_first(result)
+        if heads != h:
+            # a head's lanes added as they lie: a reduction over a group
+            # axis would lay the rows out heads-apart first
+            group = h // heads
+            lanes = lambda j: result[..., j * d:(j + 1) * d].astype(jnp.float32)  # noqa: E731
+            result = jnp.concatenate(
+                [sum(lanes(j * group + i) for i in range(group)) for j in range(heads)],
+                axis=-1).astype(a.dtype)
+        return result.reshape(a.shape)
 
     grid = dict(
-        grid=(b, h, n, width or n),
-        in_specs=[spec(a, role) for a, role in operands],
-        out_specs=tuple(spec(a, role) for a, role in outs),
+        grid=(b, h // chunk, n, width or n),
+        in_specs=[spec(a, role, a.shape[2]) for a, role in operands],
+        out_specs=tuple(spec(a, role, h) for a, role in outs),
         scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in scratch],
     )
     if plan:
         grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(plan), **grid))
-    return pl.pallas_call(
+    results = pl.pallas_call(
         functools.partial(kernel, causal=causal, scale=scale, side=side,
-                          kv_len=kv_len, l_pad=l_pad, width=width),
+                          kv_len=kv_len, l_pad=l_pad, width=width, heads=chunk,
+                          group=h // operands[1][0].shape[2], narrow=narrow),
         **grid,
-        out_shape=tuple(a for a, _ in outs),
+        out_shape=tuple(written(a, role) for a, role in outs),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", held_axis, "arbitrary"),
             vmem_limit_bytes=vmem_bytes),
         interpret=interpret,
         name=name,
-    )(*(jnp.asarray(p) for p in plan), *(a for a, _ in operands))
+    )(*(jnp.asarray(p) for p in plan), *(placed(a, role) for a, role in operands))
+    return tuple(returned(r, a, role) for r, (a, role) in zip(results, outs))
 
 
 @functools.lru_cache(maxsize=64)
@@ -521,47 +715,81 @@ def _bwd_vmem_bytes(l_pad, d, dtype):
         4 + 2 * jnp.dtype(dtype).itemsize)
 
 
+# Both kernels are jitted for themselves: a model's layers then share one
+# trace and one lowering of each (a pallas_call is traced and lowered to
+# Mosaic anew at every call site otherwise, and a kernel body that runs
+# several heads is that many times the text: 48 of them are seconds of a
+# warm first step).
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
 def _flash_fwd(q, k, v, causal, scale, side, kv_len, interpret):
-    """(B, H, L, D) q/k, (B, H, L, Dv) v -> out (B, H, L, Dv) in the
+    """(B, L, H, D) q/k, (B, L, H, Dv) v -> out (B, L, H, Dv) in the
     storage dtype and the rows' logsumexp (B, H, 1, L) float32."""
-    b, h, l_pad, _ = q.shape
-    dv = v.shape[-1]
+    (b, l_pad, h, d), dv = q.shape, v.shape[-1]
+    n = _chunk_heads(h, k.shape[2], d, dv, l_pad)
     return _flash_call(
         _fwd_kernel, "tpuframe_flash_fwd",
         [(q, "q"), (k, "k"), (v, "k")],
-        [(jax.ShapeDtypeStruct((b, h, l_pad, dv), q.dtype), "q"),
-         (jax.ShapeDtypeStruct((b, h, 1, l_pad), jnp.float32), "q")],
-        [(side, dv), (side, 1), (side, 1)],
-        streams="k", side=side,
+        [(jax.ShapeDtypeStruct((b, l_pad, h, dv), q.dtype), "q"),
+         (jax.ShapeDtypeStruct((b, h, 1, l_pad), jnp.float32), "stat")],
+        [(side, n * dv), (n, side, 1), (n, side, 1)],
+        streams="k", side=side, chunk=n,
+        # a block of several heads outgrows Mosaic's 16 MiB: the tiles, and
+        # the blocks' buffers and statistics a head
+        vmem_bytes=None if n == 1 else 2 * _TILE_VMEM_BYTES,
         causal=causal, scale=scale, kv_len=kv_len, interpret=interpret,
     )
 
 
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10))
 def _flash_bwd(q, k, v, do, lse, delta, causal, scale, side, kv_len, interpret):
-    """dQ, dK, dV in the layout and dtypes of q, k, v.  ``lse`` and
-    ``delta`` (rowsum(dO . O)) are (B, H, 1, L) rows."""
-    (b, h, l_pad, d), dv = q.shape, v.shape[-1]
-    # dK and dV a query head: a group's are summed after the kernel
-    like = lambda a: jax.ShapeDtypeStruct((b, h, *a.shape[2:]), a.dtype)  # noqa: E731
+    """dQ, dK, dV in the layout (B, L, H, D) and the dtypes of q, k, v.
+    ``lse`` and ``delta`` (rowsum(dO . O)) are (B, H, 1, L) rows."""
+    (b, l_pad, h, d), dv = q.shape, v.shape[-1]
+    n = _chunk_heads(h, k.shape[2], d, dv, l_pad)
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
     dk, dv_, dq = _flash_call(
         _bwd_kernel, "tpuframe_flash_bwd",
-        [(q, "q"), (k, "k"), (v, "k"), (do, "q"), (lse, "q"), (delta, "q")],
+        [(q, "q"), (k, "k"), (v, "k"), (do, "q"), (lse, "stat"), (delta, "stat")],
         [(like(k), "k"), (like(v), "k"), (like(q), "all")],
-        [(side, d), (side, dv), (l_pad, d)], streams="q", side=side,
-        held_axis="arbitrary", vmem_bytes=_bwd_vmem_bytes(l_pad, d, q.dtype),
+        [(side, n * d), (side, n * dv), (l_pad, n * d)], streams="q", side=side,
+        chunk=n, held_axis="arbitrary", vmem_bytes=_bwd_vmem_bytes(l_pad, n * d, q.dtype),
         causal=causal, scale=scale, kv_len=kv_len, interpret=interpret,
     )
-    if k.shape[1] != h:
-        dk, dv_ = (
-            jnp.sum(a.reshape(b, k.shape[1], -1, *a.shape[2:]), axis=2,
-                    dtype=jnp.float32).astype(a.dtype)
-            for a in (dk, dv_))
     return dq, dk, dv_
 
 
 def _heads_first(a):
-    """(B, L, H, D) <-> (B, H, L, D): the model's layout and the kernels'."""
+    """(B, L, H, D) <-> (B, H, L, D): the model's layout and the one the
+    kernels read a head from where its width is no whole lanes."""
     return a.transpose(0, 2, 1, 3)
+
+
+def _row_delta(out, g, heads):
+    """delta_i = rowsum(dO . O), the softmax-normalization term of dS, as
+    the (B, H, 1, L) float32 rows the backward kernel reads, from ``out``
+    and ``g`` as they lie: the folded rows (B, L, H*D) in their own
+    dtype.  The float32 products are summed a head by a product with a
+    0/1 matrix (H*D, H) on the MXU, which XLA fuses with the casts and
+    writes heads-first: a reduction over D would lay a float32
+    (B, L, H, D) copy out heads-apart first, three passes over four
+    bytes an element.  The MXU takes bfloat16, so the float32 product
+    goes in as bfloat16 terms, each the rounding of what the terms
+    before left: two hold the 16 significant bits of a product of
+    bfloat16 values exactly, three a float32 product's 24; the
+    accumulation is float32."""
+    products = out.astype(jnp.float32) * g.astype(jnp.float32)
+    width = products.shape[-1]
+    of_head = (jnp.arange(width)[:, None] // (width // heads)
+               == jnp.arange(heads)[None, :]).astype(jnp.float32)
+    delta = 0.0
+    for _ in range(2 if out.dtype == jnp.bfloat16 else 3):
+        # float32 operands that bfloat16 holds exactly, in one MXU pass
+        # whatever the ambient precision (XLA:CPU has no bfloat16 dot)
+        term = products.astype(jnp.bfloat16).astype(jnp.float32)
+        delta = delta + jnp.einsum("blk,kh->bhl", term, of_head,
+                                   precision=lax.Precision.DEFAULT)
+        products = products - term
+    return delta[:, :, None, :]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -577,15 +805,12 @@ def _blockwise_padded_fwd(q, k, v, causal, block, kv_len, scale, interpret):
     Either way the residuals are q, k, v, the output and the rows'
     logsumexp, each held once."""
     if interpret is not None:
-        out, lse = _flash_fwd(
-            _heads_first(q), _heads_first(k), _heads_first(v),
-            causal, scale, block[0], kv_len, interpret,
-        )
-        out = _heads_first(out)
-        # kept with the heads folded into the rows, (B, L, H*D): whole
-        # lanes at any head width.  The kernels' layout pads a 192-wide
-        # row to 256 lanes in HBM (a 64-wide one to 128), and XLA lays a
-        # (B, L, H, D) array kept for the backward out the same way.
+        out, lse = _flash_fwd(q, k, v, causal, scale, block[0], kv_len, interpret)
+        # kept with the heads folded into the rows, (B, L, H*D), as the
+        # projections made them: whole lanes at any head width, and what
+        # the kernels read in place where a head is whole lanes wide (XLA
+        # lays a (B, L, H, D) array kept for the backward out padded: a
+        # 192-wide row to 256 lanes, a 64-wide one to 128).
         return out, (*(a.reshape(*a.shape[:2], -1) for a in (q, k, v, out)), lse)
     b, l_pad, h, d = q.shape
     n = l_pad // block
@@ -600,24 +825,23 @@ def _blockwise_padded_fwd(q, k, v, causal, block, kv_len, scale, interpret):
 def _blockwise_padded_bwd(causal, block, kv_len, scale, interpret, res, g):
     q, k, v, out, lses = res
     if interpret is not None:
-        # behind a barrier, or XLA shares the forward's transposes with
-        # these and the padded copies live from one pass to the other
-        q, k, v, out = lax.optimization_barrier(res[:4])
-        kv_heads = k.shape[-1] * g.shape[2] // q.shape[-1]
-        q, k, v, out = (
-            a.reshape(*g.shape[:2], heads, -1)
-            for a, heads in ((q, g.shape[2]), (k, kv_heads), (v, kv_heads),
-                             (out, g.shape[2])))
-        # delta_i = rowsum(dO . O) — the softmax-normalization term of dS
-        delta = jnp.einsum(
-            "blhd,blhd->bhl", out.astype(jnp.float32), g.astype(jnp.float32)
-        )[:, :, None, :]
-        grads = _flash_bwd(
-            _heads_first(q), _heads_first(k), _heads_first(v),
-            _heads_first(g).astype(q.dtype), lses, delta,
+        heads = g.shape[2]
+        delta = _row_delta(out, g.reshape(out.shape), heads)
+        kv_heads = k.shape[-1] * heads // q.shape[-1]
+        q, k, v = (a.reshape(*g.shape[:2], n, -1)
+                   for a, n in ((q, heads), (k, kv_heads), (v, kv_heads)))
+        # what the kernels read from heads-first copies goes behind a
+        # barrier, or XLA shares the forward's copies with the backward's
+        # and the padded copies live from one pass to the other
+        chunk = _chunk_heads(heads, kv_heads, q.shape[-1], v.shape[-1], g.shape[1])
+        if chunk == 1 and not _in_place(q.shape[-1]):
+            q, k = lax.optimization_barrier((q, k))
+        if chunk == 1 and not _in_place(v.shape[-1]):
+            v = lax.optimization_barrier(v)
+        return _flash_bwd(
+            q, k, v, g.astype(q.dtype), lses, delta,
             causal, scale, block[1], kv_len, interpret,
         )
-        return tuple(_heads_first(a) for a in grads)
     b, l_pad, h, d = q.shape
     n = l_pad // block
     do = g.astype(q.dtype)
@@ -760,21 +984,31 @@ def blockwise_attention_reference(
 
 def engage_kernels(q, *, block_size: int | None = None,
                    interpret: bool | None = None,
-                   shardable: bool = False) -> bool | None:
+                   shardable: bool = False,
+                   v=None) -> bool | None:
     """Whether :func:`blockwise_attention` runs its flash kernels for
     queries shaped like ``q`` (B, L, H, D): the interpret flag they run
     with, or None for the scan schedule.  The op's shape rule (a head's
     dQ fits VMEM) and then `resolve_interpret`; the one predicate the
     op itself and ``attn_impl="auto"`` ask.  ``shardable``: the caller
-    runs the op per shard under ``shard_map``."""
+    runs the op per shard under ``shard_map``.  ``v`` is the values
+    (B, L, Hkv, Dv), which the op's own call knows: its verdict event
+    says how many of the kernels' arrays stay in the model's rows
+    (`layout_counts`); a caller that asks with ``q`` alone leaves the
+    announcement of kernels that engage to the call that follows."""
     l, d = q.shape[1], q.shape[-1]
     l_pad = pad_to(l, _tiles(l, block_size)[0])
     if interpret is None and _bwd_vmem_bytes(l_pad, d, q.dtype) > _VMEM_BYTES:
         return None
-    return resolve_interpret(
-        interpret, shardable=shardable, op="blockwise_attention",
-        shape_class=shape_class(l=l, d=d),
-    )
+    decision = resolve_interpret(interpret, shardable=shardable)
+    if decision is None or v is not None:
+        layout = None if v is None else dict(zip(
+            ("operands_in_place", "operands_copied"),
+            layout_counts(q.shape[2], v.shape[2], d, v.shape[3])))
+        resolve_interpret(
+            interpret, shardable=shardable, op="blockwise_attention",
+            shape_class=shape_class(l=l, d=d), engaged_attrs=layout)
+    return decision
 
 
 def tile_counts(mask: BlockDiffusionMask, length: int, *,
@@ -828,7 +1062,7 @@ def blockwise_attention(
     mode on any backend.
     """
     _check_shapes(q, k, v, mask)
-    interpret = engage_kernels(q, block_size=block_size, interpret=interpret)
+    interpret = engage_kernels(q, block_size=block_size, interpret=interpret, v=v)
     if interpret is None:
         return blockwise_attention_reference(
             q, k, v, causal=causal, block_size=block_size, scale=scale, mask=mask)

@@ -61,10 +61,13 @@ _VERDICT_EMITTED: set[tuple] = set()
 
 
 def _emit_verdict(op: str, shape_cls: str | None, *, enable: bool,
-                  source: str) -> None:
+                  source: str, **attrs) -> None:
     """One ``ops/kernel_verdict`` event per distinct (op, shape class,
     decision).  ``source`` is ``"forced"`` (an explicit ``interpret=``
-    or ``TPUFRAME_DISABLE_PALLAS``) or ``"default"`` (the engage rule)."""
+    or ``TPUFRAME_DISABLE_PALLAS``) or ``"default"`` (the engage rule);
+    ``attrs`` is what the op says of the form its kernels take for that
+    shape class (`blockwise_attention`: ``operands_in_place`` /
+    ``operands_copied``)."""
     key = (op, shape_cls, enable, source)
     if key in _VERDICT_EMITTED:
         return
@@ -74,7 +77,7 @@ def _emit_verdict(op: str, shape_cls: str | None, *, enable: bool,
 
         get_telemetry().event(
             "ops/kernel_verdict", op=op, shape_class=shape_cls,
-            enable=bool(enable), source=source, mode=pallas_mode(),
+            enable=bool(enable), source=source, mode=pallas_mode(), **attrs,
         )
     except Exception:
         pass  # telemetry must never take dispatch down
@@ -129,7 +132,8 @@ def _engage(interpret: bool | None, shardable: bool) -> bool | None:
 
 def resolve_interpret(interpret: bool | None, shardable: bool, *,
                       op: str | None = None,
-                      shape_class: str | None = None) -> bool | None:
+                      shape_class: str | None = None,
+                      engaged_attrs: dict | None = None) -> bool | None:
     """Shared op-level engage decision.
 
     Returns the interpret flag to use, or None meaning "run the jnp
@@ -141,13 +145,15 @@ def resolve_interpret(interpret: bool | None, shardable: bool, *,
     jit is the one placement that would force operand replication.
 
     Ops that pass their ``op`` (and optionally a ``shape_class``) leave
-    one ``ops/kernel_verdict`` event per distinct decision.
+    one ``ops/kernel_verdict`` event per distinct decision, with
+    ``engaged_attrs`` on it where the kernels engage.
     """
     decision = _engage(interpret, shardable)
     if op is not None:
         forced = interpret is not None or _env_truthy("TPUFRAME_DISABLE_PALLAS")
         _emit_verdict(op, shape_class, enable=decision is not None,
-                      source="forced" if forced else "default")
+                      source="forced" if forced else "default",
+                      **((engaged_attrs or {}) if decision is not None else {}))
     return decision
 
 
