@@ -24,8 +24,8 @@ their oracles' XLA fusions).
 
 Usage: python benchmarks/check_kernels_tpu.py [--only a,b,...]
 (exits 1 on any failure).  ``--only`` runs a named subset — sections:
-layer_norm, cross_entropy, quant_wire, blockwise, flash_layout, ring, ulysses,
-moe_windows, short_conv, head_norm_rope.
+layer_norm, cross_entropy, quant_wire, blockwise, flash_layout, window, ring,
+ulysses, moe_windows, short_conv, head_norm_rope.
 """
 
 from __future__ import annotations
@@ -55,6 +55,7 @@ def main() -> None:
         "quant_wire": _check_quant_wire,
         "blockwise": _check_blockwise,
         "flash_layout": _check_flash_layout,
+        "window": _check_window,
         "ring": _check_ring,
         "ulysses": _check_ulysses,
         "moe_windows": _check_moe_windows,
@@ -387,6 +388,43 @@ def _check_flash_layout(jax, jnp, np, rng) -> None:
         ("flash_layout_rows_padded", (2, 1000, 8, 2, 128, 128), {}),
     ):
         _schedule_parity(jax, jnp, name, qkv(*shape), ftol=2 ** -9, gtol=2 ** -6, **how)
+
+
+def _check_window(jax, jnp, np, rng) -> None:
+    """The two rules of a model whose layers are sliding-window and full
+    attention, alone at mellum2-12b-a2.5b-instruct's call shape (8192
+    positions, 32 heads over 4 of 128): the band of 1024 keys and plain
+    causal, kernels against the scan schedule on the same bf16 inputs; the
+    band against the float32 oracle at one key/value group of that shape
+    (four heads a block of lanes, as the cell runs them; all 32 heads'
+    scores would not fit); a row that pads up to a tile under a band no
+    multiple of it."""
+    from tpuframe.ops import SlidingWindowMask, attention_reference, blockwise_attention
+
+    def qkv(b, l, h, kv_heads, d):
+        return tuple(
+            jnp.asarray(rng.standard_normal((b, l, heads, d)) * 0.5, jnp.bfloat16)
+            for heads in (h, kv_heads, kv_heads))
+
+    cell = (1, 8192, 32, 4, 128)
+    band = {"causal": False, "mask": SlidingWindowMask(1024)}
+    _schedule_parity(jax, jnp, "window_band_cell", qkv(*cell), ftol=2 ** -9, gtol=2 ** -6, **band)
+    _schedule_parity(jax, jnp, "window_causal_cell", qkv(*cell), ftol=2 ** -9, gtol=2 ** -6)
+    _schedule_parity(jax, jnp, "window_band_padded", qkv(2, 1000, 8, 2, 128),
+                     ftol=2 ** -9, gtol=2 ** -6, causal=False, mask=SlidingWindowMask(300))
+    group = qkv(1, 8192, 4, 1, 128)
+    f32 = tuple(a.astype(jnp.float32) for a in group)
+    loss = lambda fn, args: jnp.sum(fn(*args, **band).astype(jnp.float32) ** 2)  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda *a: attention_reference(*a, **band))(*f32)
+        want_g = jax.jit(jax.grad(lambda *a: loss(attention_reference, a), (0, 1, 2)))(*f32)
+    got = jax.jit(lambda *a: blockwise_attention(*a, **band))(*group)
+    got_g = jax.jit(jax.grad(lambda *a: loss(blockwise_attention, a), (0, 1, 2)))(*group)
+    gap = lambda a, b: float(jnp.max(  # noqa: E731
+        jnp.abs(a.astype(jnp.float32) - b) / jnp.maximum(jnp.abs(b), 1.0)))
+    record("window_band_group_fwd_vs_oracle", gap(got, want), 2 ** -7)
+    record("window_band_group_grads_vs_oracle",
+           max(gap(a, b) for a, b in zip(got_g, want_g)), 2 ** -5)
 
 
 def _check_moe_windows(jax, jnp, np, rng) -> None:

@@ -581,6 +581,44 @@ class TestTrainerTelemetry:
             assert covered[i] <= it.end_ns - it.start_ns
         # the health sentinel's fetch has a span of its own, with its step
         assert [r.step for r in by["train/health_fetch"]] == [12]
+        # the step's add into the window is dispatched before the fetch
+        # waits for the step, not in the device's idle time after it
+        add = [r for r in by["train/metrics_window"] if r.step == 12]
+        assert len(add) == 1
+        assert add[0].end_ns <= by["train/health_fetch"][0].start_ns
+
+    def test_a_drain_and_a_health_check_are_one_transfer_each(
+            self, cpu_runtime, monkeypatch):
+        """Between the end of a window's last step and the next dispatch the
+        device is idle: the loop fetches the drained window in one
+        ``device_get`` and the health check's flags and state in one,
+        whatever the number of leaves."""
+        import traceback
+
+        import jax
+
+        from tpuframe.models import MnistNet
+        from tpuframe.train import Trainer
+
+        callers = []
+        real = jax.device_get
+
+        def counting(x):
+            callers.append(traceback.extract_stack()[-2].name)
+            return real(x)
+
+        monkeypatch.setattr(jax, "device_get", counting)
+        T.configure()
+        result = Trainer(
+            MnistNet(num_classes=4),
+            train_dataloader=_tiny_loader(n=16 * 12),
+            max_duration="12ba",
+            num_classes=4,
+            log_interval=4,
+            eval_interval=0,
+        ).fit()
+        assert callers == ["drain", "drain", "drain", "_health_check"]
+        assert result.metrics["host_block_s"] > 0
 
     def test_empty_queue_dispatch_is_seen_and_counted(self, cpu_runtime):
         """A step dispatched after the device ran dry says so."""

@@ -106,32 +106,35 @@ def test_flash_kernels_compile_for_v5e(one_chip, shape, dtype, precision, block)
         assert compiled.memory_analysis().temp_size_in_bytes < scores / 2
 
 
-@pytest.mark.parametrize("shape, half, block, tile", [
-    ((1, 8192, 32, 4, 128), 4096, 4, None),   # sdar-30b-a3b-chat's row: 3/4 of the tile grid dead
-    ((2, 600, 8, 2, 128), 300, 4, None),      # a tile straddles the two copies; padded keys
-    ((1, 768, 8, 8, 64), 384, 3, 256),        # a block that is no power of two (a vector division)
-], ids=["sdar", "straddle_padded", "block_of_3"])
-def test_masked_grouped_kernels_compile_for_v5e(one_chip, shape, half, block, tile):
-    """The flash kernels under a block-diffusion mask rule, their plan in
-    scalar memory and a grid of the live tiles alone, K and V at one head
-    a group: Mosaic takes the scalar-prefetch index maps, the rule's
-    element-wise mask (column and row codes, a shift or a division) and
-    the grouped K/V blocks."""
-    from tpuframe.ops import BlockDiffusionMask
+@pytest.mark.parametrize("shape, rule, tile", [
+    ((1, 8192, 32, 4, 128), ("block", 4096, 4), None),   # sdar-30b-a3b-chat's row: 3/4 of the tile grid dead
+    ((2, 600, 8, 2, 128), ("block", 300, 4), None),      # a tile straddles the two copies; padded keys
+    ((1, 768, 8, 8, 64), ("block", 384, 3), 256),        # a block that is no power of two (a vector division)
+    ((1, 8192, 32, 4, 128), ("window", 1024), None),     # mellum2-12b-a2.5b-instruct's window layers
+    ((2, 1000, 8, 2, 128), ("window", 300), None),       # a band no tile multiple over a padded row
+], ids=["sdar", "straddle_padded", "block_of_3", "mellum2_window", "window_padded"])
+def test_masked_grouped_kernels_compile_for_v5e(one_chip, shape, rule, tile):
+    """The flash kernels under a mask rule (the block-diffusion rule, the
+    sliding-window band), their plan in scalar memory and a grid of the
+    live tiles alone, K and V at one head a group: Mosaic takes the
+    scalar-prefetch index maps, the rule's element-wise mask (column and
+    row codes, a shift or a division; two compares and a clamp) and the
+    grouped K/V blocks."""
+    from tpuframe.ops import BlockDiffusionMask, SlidingWindowMask
 
     b, l, h, kv_heads, d = shape
     q = jax.ShapeDtypeStruct((b, l, h, d), jnp.bfloat16, sharding=one_chip)
     k = jax.ShapeDtypeStruct((b, l, kv_heads, d), jnp.bfloat16, sharding=one_chip)
+    rule = {"block": BlockDiffusionMask, "window": SlidingWindowMask}[rule[0]](*rule[1:])
 
     def loss(q, k, v):
-        out = blockwise_attention(q, k, v, mask=BlockDiffusionMask(half, block),
-                                  block_size=tile, interpret=False)
+        out = blockwise_attention(q, k, v, mask=rule, block_size=tile, interpret=False)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
     compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, k, k).compile()
     text = compiled.as_text()
-    assert len(_kernel_calls(text, "tpuframe_flash_fwd")) == 1
-    assert len(_kernel_calls(text, "tpuframe_flash_bwd")) == 1
+    assert len(_kernel_calls(text, "tpuframe_flash_fwd" + rule.suffix)) == 1
+    assert len(_kernel_calls(text, "tpuframe_flash_bwd" + rule.suffix)) == 1
     assert " while(" not in text
     if l >= 1024:  # nothing the size of one head's (L, L) scores, let alone all heads'
         assert compiled.memory_analysis().temp_size_in_bytes < b * h * l * l * 2 / 8

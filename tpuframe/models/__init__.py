@@ -7,8 +7,10 @@ ResNet18/34/50 with ImageNet stems, the MNIST `Net` CNN
 and frozen-backbone transfer-learning wrappers
 (`/root/reference/01_torch_distributor/02_cifar_torch_distributor_resnet.py:141-159`).
 Beside them the decoder LM (``TransformerLM``: GPT-2's layer, latent or
-grouped-query attention, LFM2's gated short-convolution mixers by
-``layer_types``, dense or routed MLPs) and ``BlockDiffusionLM`` on its rows.
+grouped-query attention, LFM2's gated short-convolution mixers and
+Mellum2's sliding-window layers beside full-attention layers by
+``layer_types``, rotary tables by kind of layer, dense or routed MLPs) and
+``BlockDiffusionLM`` on its rows.
 """
 
 from tpuframe.models.cnn import MnistNet

@@ -45,7 +45,12 @@ from tpuframe.core.runtime import (
 )
 from tpuframe.ops.dispatch import batch_sharding_info, effective_mesh
 from tpuframe.ops.head_norm_rope import head_norm_rope, head_norm_rope_reference
-from tpuframe.ops.ring_attention import attention_reference, ring_attention_local
+from tpuframe.ops.ring_attention import (
+    SlidingWindowMask,
+    attention_reference,
+    mask_or_causal,
+    ring_attention_local,
+)
 from tpuframe.ops.layer_norm import FusedLayerNorm
 from tpuframe.ops.short_conv import short_conv, short_conv_reference
 from tpuframe.ops.ulysses import ulysses_attention_local
@@ -163,6 +168,8 @@ def rope_tables(length: int, dim: int, theta: float,
     if scaling:
         m = (yarn_mscale(scaling["factor"], scaling.get("mscale", 1.0))
              / yarn_mscale(scaling["factor"], scaling.get("mscale_all_dim", 0.0)))
+        # a config that states the factor itself is taken at its word
+        m = scaling.get("attention_factor") or m
     return (jnp.asarray(np.cos(ang) * m, jnp.float32),
             jnp.asarray(np.sin(ang) * m, jnp.float32))
 
@@ -261,13 +268,16 @@ def _attend(q, k, v, *, impl: str, causal: bool, num_heads: int,
     (B, L, H, D) q/k and (B, L, H, Dv) v -> (B, L, H, Dv) by ``impl``.
     ``scale`` (None: ``1/sqrt(D)``), a value width of its own, a
     ``mask`` that is a rule on positions in ``causal``'s place
-    (`ops.ring_attention.BlockDiffusionMask`) and ``k``/``v`` of one head
+    (`ops.ring_attention`'s protocol; one that is the causal mask on
+    this row runs as ``causal``) and ``k``/``v`` of one head
     a group of query heads are taken by ``full`` and ``blockwise``; the
     sequence-sharded forms keep one head width, the default scale,
     ``causal`` and as many key/value heads as query heads.  ``on_tiles``
     is called with `blockwise_attention.tile_counts` of the call where
     the blockwise form runs it under a mask rule, kernels or schedule as
     decided here."""
+    if mask is not None and mask_or_causal(causal, mask, q.shape[1]) is True:
+        causal, mask = True, None
     mesh = _mesh_or_none()
     # heads split over the model axis only in whole groups
     per_shard = _per_shard_spec(mesh, q.shape[0], k.shape[2])
@@ -349,8 +359,8 @@ class SelfAttention(nn.Module):
     #: RMSNorm with a learned scale on every query head and key head
     qk_norm: bool = False
     norm_eps: float = 1e-6
-    #: a rule on positions in ``causal``'s place
-    #: (`ops.ring_attention.BlockDiffusionMask`)
+    #: a rule on positions in ``causal``'s place (`ops.ring_attention`'s
+    #: protocol: `BlockDiffusionMask`, `SlidingWindowMask`)
     mask: Any = None
 
     @nn.compact
@@ -390,7 +400,10 @@ class SelfAttention(nn.Module):
                 registry.counter(f"attention/tiles_{name}").inc(
                     value * b * self.num_heads)
 
-        with jax.named_scope("tpuframe/attn"):
+        # a rule that names its kernels names its layers' scope too
+        # (``tpuframe/attn/window``)
+        scope = "tpuframe/attn" + getattr(self.mask, "suffix", "").replace("_", "/")
+        with jax.named_scope(scope):
             out = _attend(
                 q, k, v, impl=self.attn_impl, causal=self.causal,
                 num_heads=self.num_heads, initializing=self.is_initializing(),
@@ -507,8 +520,14 @@ class Block(nn.Module):
     positions given as ``rope``) and ``mlp_gated`` (SiLU-gated MLP, no
     bias) are the other kinds of layer; the defaults are GPT-2's.
     ``mixer="conv"`` puts the short-convolution operator
-    (:class:`ShortConv`) in attention's place.
+    (:class:`ShortConv`) in attention's place; ``"sliding_attention"``
+    is multi-head attention under a causal band of ``sliding_window``
+    keys (`ops.ring_attention.SlidingWindowMask`), its parameters a
+    ``"full_attention"`` layer's leaf for leaf.
     """
+
+    #: the mixers a layer can have
+    MIXERS = ("full_attention", "sliding_attention", "conv")
 
     num_heads: int
     head_dim: int
@@ -540,8 +559,9 @@ class Block(nn.Module):
     num_kv_heads: int = 0
     qk_norm: bool = False
     mask: Any = None
-    mixer: str = "full_attention"  # | "conv"
+    mixer: str = "full_attention"  # one of ``MIXERS``
     conv_taps: int = 3
+    sliding_window: int = 0
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False, rope=None) -> jax.Array:
@@ -557,10 +577,12 @@ class Block(nn.Module):
         y = ln("ln1")(x)
         if self.mixer == "conv":
             y = ShortConv(self.conv_taps, dtype=self.dtype, name="conv")(y)
-        elif self.mixer != "full_attention":
+        elif self.mixer not in self.MIXERS:
             raise ValueError(f"unknown mixer {self.mixer!r}; known: "
-                             "full_attention, conv")
+                             + ", ".join(self.MIXERS))
         elif self.kv_lora_rank:
+            if self.mixer != "full_attention":
+                raise ValueError("latent attention takes no window")
             y = LatentAttention(
                 self.num_heads, self.head_dim, self.rope_dim, self.v_head_dim,
                 self.kv_lora_rank, scale=self.attn_scale,
@@ -568,11 +590,14 @@ class Block(nn.Module):
                 attn_impl=self.attn_impl, dtype=self.dtype, name="attn",
             )(y, rope, train=train)
         else:
+            mask = self.mask
+            if self.mixer == "sliding_attention":
+                mask = SlidingWindowMask(self.sliding_window)
             y = SelfAttention(
                 self.num_heads, self.head_dim, causal=self.causal,
                 attn_impl=self.attn_impl, dtype=self.dtype,
                 num_kv_heads=self.num_kv_heads, qk_norm=self.qk_norm,
-                norm_eps=self.norm_eps, mask=self.mask, name="attn",
+                norm_eps=self.norm_eps, mask=mask, name="attn",
             )(y, train=train, rope=rope)
         if self.dropout:
             y = nn.Dropout(self.dropout, deterministic=not train)(y)
@@ -623,8 +648,15 @@ class TransformerLM(nn.Module):
     ``moe_experts > 0`` the expert layer in every block from
     ``moe_first_dense`` on, with the dense MLP before it;
     ``layer_types`` a mixer for each layer, as a config publishes them:
-    ``"full_attention"`` (the attention the other sizes describe) or
-    ``"conv"`` (the short-convolution operator of ``conv_taps`` taps).
+    ``"full_attention"`` (the attention the other sizes describe),
+    ``"sliding_attention"`` (the same under a causal band of
+    ``sliding_window`` keys) or ``"conv"`` (the short-convolution
+    operator of ``conv_taps`` taps); ``rope_parameters`` rotary
+    parameters by kind of attention layer, as a config publishes them
+    (``{"full_attention": {"rope_type": "yarn", "rope_theta": ..,
+    "factor": .., ...}, "sliding_attention": {"rope_type": "default",
+    "rope_theta": ..}}``): each kind that occurs gets tables of its own,
+    and a kind without an entry ``rope_theta`` / ``rope_scaling``.
 
     ``remat=True`` rematerializes each block in the backward pass
     (``jax.checkpoint`` via ``nn.remat``): activation memory drops from
@@ -666,10 +698,15 @@ class TransformerLM(nn.Module):
     #: 0: ``num_heads`` (multi-head attention)
     num_kv_heads: int = 0
     qk_norm: bool = False
-    #: one mixer a layer, ``"full_attention"`` | ``"conv"`` (empty: attention
-    #: in every layer); a list, kept as a tuple
+    #: one mixer a layer, of ``Block.MIXERS`` (empty: attention in every
+    #: layer); a list, kept as a tuple
     layer_types: Any = ()
     conv_taps: int = 3
+    #: keys a ``"sliding_attention"`` layer's query sees, its own among them
+    sliding_window: int = 0
+    #: rotary parameters by kind of attention layer: a dict of dicts, kept
+    #: as sorted items
+    rope_parameters: Any = None
 
     def __post_init__(self):
         # module attributes are hashed with the train state's treedef:
@@ -679,7 +716,7 @@ class TransformerLM(nn.Module):
                 return tuple(sorted((k, frozen(x)) for k, x in v.items()))
             return tuple(frozen(x) for x in v) if isinstance(v, list) else v
 
-        for name in ("rope_scaling", "moe_kwargs", "layer_types"):
+        for name in ("rope_scaling", "moe_kwargs", "layer_types", "rope_parameters"):
             object.__setattr__(self, name, frozen(getattr(self, name)))
         super().__post_init__()
 
@@ -692,6 +729,16 @@ class TransformerLM(nn.Module):
         m = yarn_mscale(scaling.get("factor", 1.0), scaling.get("mscale_all_dim", 0.0))
         return (self.head_dim + self.rope_dim) ** -0.5 * m * m
 
+    def _rope_of(self, kind: str) -> tuple[float, dict | None]:
+        """(theta, YaRN scaling or None) of the rotary tables of a kind of
+        attention layer: its entry in ``rope_parameters``, or the model's
+        ``rope_theta`` / ``rope_scaling`` where it has none."""
+        entry = dict(dict(self.rope_parameters or ()).get(kind, ()))
+        if not entry:
+            return self.rope_theta, dict(self.rope_scaling or ()) or None
+        return (entry.get("rope_theta", self.rope_theta),
+                entry if entry.get("rope_type") == "yarn" else None)
+
     @nn.compact
     def __call__(self, tokens: jax.Array, train: bool = False) -> jax.Array:
         return self._decode(tokens, train)
@@ -700,27 +747,33 @@ class TransformerLM(nn.Module):
         """Embedding, blocks, final norm and head over (B, L) tokens.  A
         subclass that builds its own rows hands over what they need:
         ``positions`` (static position ids where they are not ``0 .. L - 1``),
-        a ``mask`` rule for every block's attention in place of causal, and
+        a ``mask`` rule for every block's attention in place of causal
+        (a model whose layers bring rules of their own takes none), and
         ``head_len`` (logits for the first so many positions only)."""
         d_model = self.d_model or self.num_heads * self.head_dim
         x = nn.Embed(self.vocab_size, d_model, dtype=self.dtype, name="embed")(tokens)
-        rope = None
+        mixers = self.layer_types or ("full_attention",) * self.num_layers
+        if len(mixers) != self.num_layers:
+            raise ValueError(f"layer_types names {len(mixers)} layers of "
+                             f"{self.num_layers}")
+        if mask is not None and "sliding_attention" in mixers:
+            raise ValueError("a mask rule for every block and window layers, which "
+                             "bring their own, do not go together")
+        ropes = {}
         if self.rope_dim:
             if not self.kv_lora_rank and self.rope_dim != self.head_dim:
                 raise ValueError("multi-head attention turns its whole heads: "
                                  f"rope_dim {self.rope_dim} is not head_dim {self.head_dim}")
-            rope = rope_tables(tokens.shape[1], self.rope_dim, self.rope_theta,
-                               dict(self.rope_scaling or ()) or None, positions)
+            # one pair of tables a kind of attention layer that occurs
+            ropes = {kind: rope_tables(tokens.shape[1], self.rope_dim,
+                                       *self._rope_of(kind), positions)
+                     for kind in dict.fromkeys(mixers) if kind != "conv"}
         else:
             pos = nn.Embed(self.max_len, d_model, dtype=self.dtype, name="pos_embed")(
                 jnp.arange(tokens.shape[1])[None, :]
             )
             x = x + pos
         block_cls = RematBlock if self.remat else Block
-        mixers = self.layer_types or ("full_attention",) * self.num_layers
-        if len(mixers) != self.num_layers:
-            raise ValueError(f"layer_types names {len(mixers)} layers of "
-                             f"{self.num_layers}")
         for i in range(self.num_layers):
             sparse = self.moe_experts and i >= self.moe_first_dense
             x = block_cls(
@@ -733,8 +786,9 @@ class TransformerLM(nn.Module):
                 attn_scale=self.attn_scale(), mlp_dim=self.mlp_dim,
                 mlp_gated=self.mlp_gated, moe_kwargs=self.moe_kwargs,
                 num_kv_heads=self.num_kv_heads, qk_norm=self.qk_norm, mask=mask,
-                mixer=mixers[i], conv_taps=self.conv_taps, name=f"block{i}",
-            )(x, train, rope)
+                mixer=mixers[i], conv_taps=self.conv_taps,
+                sliding_window=self.sliding_window, name=f"block{i}",
+            )(x, train, ropes.get(mixers[i]))
         if head_len is not None:
             x = x[:, :head_len]
         if self.norm == "rms":

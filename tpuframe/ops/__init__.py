@@ -59,6 +59,7 @@ _LAZY = {
     "ulysses_attention_local": "tpuframe.ops.ulysses",
     "attention_reference": "tpuframe.ops.ring_attention",
     "BlockDiffusionMask": "tpuframe.ops.ring_attention",
+    "SlidingWindowMask": "tpuframe.ops.ring_attention",
     "ring_attention": "tpuframe.ops.ring_attention",
     "ring_attention_local": "tpuframe.ops.ring_attention",
     "short_conv": "tpuframe.ops.short_conv",

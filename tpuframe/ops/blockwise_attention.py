@@ -57,12 +57,16 @@ Both:
   tile).
 
 - A mask that is neither causal nor full is a rule on positions
-  (``mask=``: `ring_attention.BlockDiffusionMask`, two static integers):
+  (``mask=``: the protocol `ring_attention` states beside its two rules,
+  `BlockDiffusionMask` and `SlidingWindowMask`, a few static integers):
   `_tile_classes` sorts the tiles into dead, whole and masked once, at
   trace time; the scan schedule skips by that table, and the kernels run
   a grid that holds the live tiles alone (`_tile_plan`: per held block
   the streamed blocks to visit, read by the ``index_map`` from scalar
-  memory), so a dead tile costs neither a fetch nor a grid step.
+  memory), so a dead tile costs neither a fetch nor a grid step.  The
+  kernels' names carry the rule's ``suffix`` in a trace
+  (``tpuframe_flash_fwd_window``); a rule that is the causal mask on
+  the row at hand runs as ``causal`` (`ring_attention.mask_or_causal`).
 - Grouped heads: ``k`` and ``v`` may hold one head a group of query
   heads.  They stay that size in HBM; the kernels' ``index_map`` reads
   head ``j // group``, and dK/dV come out a query head and are summed
@@ -93,11 +97,11 @@ from jax.experimental.pallas import tpu as pltpu
 from tpuframe.ops.dispatch import pad_to, resolve_interpret
 from tpuframe.ops.registry import shape_class
 from tpuframe.ops.ring_attention import (
-    BlockDiffusionMask,
     _block_update,
     _causal_skip,
     _repeat_kv,
     _tile_grads,
+    mask_or_causal,
 )
 
 __all__ = ["blockwise_attention", "blockwise_attention_reference",
@@ -256,12 +260,18 @@ def _col_to_row(col):
     return jnp.sum(jnp.where(_eye(col.shape[0]), col, 0.0), axis=0, keepdims=True)
 
 
+def _is_rule(causal) -> bool:
+    """``causal`` as the schedules carry it: a bool, or a rule on
+    positions in its place (`ring_attention`'s protocol)."""
+    return not isinstance(causal, bool)
+
+
 def _valid(q_idx, k_idx, *, side, causal, kv_len, keys_first=False, **_):
     """Which scores of tile (q_idx, k_idx) count: keys before ``kv_len``
     and, if causal, not after their query (a rule on positions: as it
     says).  (side, side) bool, queries along the rows (``keys_first``:
     keys along the rows)."""
-    if isinstance(causal, BlockDiffusionMask):
+    if _is_rule(causal):
         # each side coded along its own axis, a column and a row
         along = lambda axis: lax.broadcasted_iota(  # noqa: E731
             jnp.int32, (side, 1) if axis == 0 else (1, side), axis)
@@ -568,7 +578,7 @@ def _flash_call(kernel, name, operands, outs, scratch, *, streams, side,
     clamp = {"k": jnp.minimum, "q": jnp.maximum}[streams]
     n = l_pad // side
     plan, width = (), None
-    if isinstance(causal, BlockDiffusionMask):
+    if _is_rule(causal):
         *plan, width = _tile_plan(causal, l_pad, side, kv_len, streams)
 
     def block_index(role, held, streamed, plan):
@@ -719,7 +729,9 @@ def _bwd_vmem_bytes(l_pad, d, dtype):
 # trace and one lowering of each (a pallas_call is traced and lowered to
 # Mosaic anew at every call site otherwise, and a kernel body that runs
 # several heads is that many times the text: 48 of them are seconds of a
-# warm first step).
+# warm first step).  ``causal`` is static, so a model whose layers run
+# under different rules holds one trace a rule, not one a layer; under a
+# rule the kernel's name carries the rule's ``suffix``.
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
 def _flash_fwd(q, k, v, causal, scale, side, kv_len, interpret):
     """(B, L, H, D) q/k, (B, L, H, Dv) v -> out (B, L, H, Dv) in the
@@ -727,7 +739,7 @@ def _flash_fwd(q, k, v, causal, scale, side, kv_len, interpret):
     (b, l_pad, h, d), dv = q.shape, v.shape[-1]
     n = _chunk_heads(h, k.shape[2], d, dv, l_pad)
     return _flash_call(
-        _fwd_kernel, "tpuframe_flash_fwd",
+        _fwd_kernel, "tpuframe_flash_fwd" + getattr(causal, "suffix", ""),
         [(q, "q"), (k, "k"), (v, "k")],
         [(jax.ShapeDtypeStruct((b, l_pad, h, dv), q.dtype), "q"),
          (jax.ShapeDtypeStruct((b, h, 1, l_pad), jnp.float32), "stat")],
@@ -748,7 +760,7 @@ def _flash_bwd(q, k, v, do, lse, delta, causal, scale, side, kv_len, interpret):
     n = _chunk_heads(h, k.shape[2], d, dv, l_pad)
     like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
     dk, dv_, dq = _flash_call(
-        _bwd_kernel, "tpuframe_flash_bwd",
+        _bwd_kernel, "tpuframe_flash_bwd" + getattr(causal, "suffix", ""),
         [(q, "q"), (k, "k"), (v, "k"), (do, "q"), (lse, "stat"), (delta, "stat")],
         [(like(k), "k"), (like(v), "k"), (like(q), "all")],
         [(side, n * d), (side, n * dv), (l_pad, n * d)], streams="q", side=side,
@@ -937,17 +949,15 @@ def _blockwise_padded_bwd(causal, block, kv_len, scale, interpret, res, g):
 _blockwise_padded.defvjp(_blockwise_padded_fwd, _blockwise_padded_bwd)
 
 
-def _check_shapes(q, k, v, mask=None):
+def _check_shapes(q, k, v):
     """q (B, L, H, D), k (B, L, Hkv, D), v (B, L, Hkv, Dv), ``Hkv``
-    dividing ``H``; a rule on positions spans the row."""
+    dividing ``H``."""
     same = (0, 1, 3)
     if (any(k.shape[i] != q.shape[i] for i in same) or v.shape[:3] != k.shape[:3]
             or q.shape[2] % k.shape[2]):
         raise ValueError(
             f"q/k/v shapes must match, got {q.shape}/{k.shape}/{v.shape}"
         )
-    if mask is not None and 2 * mask.half != q.shape[1]:
-        raise ValueError(f"{mask} is no rule for a row of {q.shape[1]} positions")
 
 
 def _padded_call(q, k, v, causal, block, scale, interpret):
@@ -970,15 +980,15 @@ def blockwise_attention_reference(
     causal: bool = False,
     block_size: int | None = None,
     scale: float | None = None,
-    mask: BlockDiffusionMask | None = None,
+    mask=None,
 ) -> jax.Array:
     """The scan schedule: what :func:`blockwise_attention` runs wherever
     its kernels do not, and what they are held to.  Grouped heads run as
     multi-head attention on copies of ``k`` and ``v``."""
-    _check_shapes(q, k, v, mask)
+    _check_shapes(q, k, v)
     k, v = _repeat_kv(q, k, v)
     block = min(_SCAN_BLOCK if block_size is None else block_size, q.shape[1])
-    return _padded_call(q, k, v, bool(causal) if mask is None else mask,
+    return _padded_call(q, k, v, mask_or_causal(causal, mask, q.shape[1]),
                         block, scale, None)
 
 
@@ -1011,10 +1021,11 @@ def engage_kernels(q, *, block_size: int | None = None,
     return decision
 
 
-def tile_counts(mask: BlockDiffusionMask, length: int, *,
+def tile_counts(mask, length: int, *,
                 block_size: int | None = None, kernels: bool = True
                 ) -> tuple[float, float]:
-    """(visited, needed) for one head of one row under ``mask``, forward
+    """(visited, needed) for one head of one row of ``length`` positions
+    under the rule ``mask`` (one that is no plain causal there), forward
     and backward together, both in tiles of the backward's side: the
     tiles the sweeps visit, and the area of the scores that count.
     ``kernels``: the flash kernels' two sweeps in their own `_tiles`;
@@ -1029,7 +1040,7 @@ def tile_counts(mask: BlockDiffusionMask, length: int, *,
     visited = sum(
         times * int((_tile_classes(mask, l_pad, side, length) > 0).sum()) * side * side
         for side, times in sweeps)
-    return visited / unit, sum(t for _, t in sweeps) * mask.area() / unit
+    return visited / unit, sum(t for _, t in sweeps) * mask.area(length) / unit
 
 
 def blockwise_attention(
@@ -1041,15 +1052,15 @@ def blockwise_attention(
     block_size: int | None = None,
     scale: float | None = None,
     interpret: bool | None = None,
-    mask: BlockDiffusionMask | None = None,
+    mask=None,
 ) -> jax.Array:
     """Exact attention over (B, L, H, D) without materializing (.., L, L).
 
     ``scale`` replaces the default ``1/sqrt(D)``; ``v`` may have a width
     of its own (latent attention: 192-wide queries and keys, 128-wide
-    values), which the output takes.  ``mask``, a rule on positions,
-    stands in ``causal``'s place; ``k`` and ``v`` may hold one head a
-    group of query heads.
+    values), which the output takes.  ``mask``, a rule on positions
+    (`ring_attention`'s protocol), stands in ``causal``'s place; ``k``
+    and ``v`` may hold one head a group of query heads.
 
     ``block_size`` None: the scan schedule takes ``_SCAN_BLOCK`` (512),
     the kernels tiles that follow L alone (`_tiles`).  An explicit value
@@ -1061,11 +1072,11 @@ def blockwise_attention(
     scan schedule elsewhere); True runs the kernels in Pallas interpret
     mode on any backend.
     """
-    _check_shapes(q, k, v, mask)
+    _check_shapes(q, k, v)
     interpret = engage_kernels(q, block_size=block_size, interpret=interpret, v=v)
     if interpret is None:
         return blockwise_attention_reference(
             q, k, v, causal=causal, block_size=block_size, scale=scale, mask=mask)
     return _padded_call(
-        q, k, v, bool(causal) if mask is None else mask,
+        q, k, v, mask_or_causal(causal, mask, q.shape[1]),
         _tiles(q.shape[1], block_size), scale, interpret)

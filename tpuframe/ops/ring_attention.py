@@ -35,6 +35,47 @@ from jax.sharding import PartitionSpec as P
 from tpuframe.core.runtime import DATA_AXIS, FSDP_AXIS, SEQUENCE_AXIS
 
 
+# -- masks that are rules on positions ------------------------------------------
+#
+# A mask that is neither causal nor full is a *rule*: a hashable value of
+# a few static integers, never an array in HBM.  The oracle, the scan
+# schedule and the flash kernels of `blockwise_attention` ask it, and
+# name no class.  What a rule answers:
+#
+# - ``allowed(q_pos, k_pos, kv_len=None)``: which scores count, for int32
+#   positions that broadcast against each other (a few compares, each
+#   side coded along its own axis first: it runs on every masked tile of
+#   a kernel).  With ``kv_len`` keys at or past it never count, and rows
+#   at or past it (padding up to a tile) still see a key, so that every
+#   logsumexp a kernel keeps is finite.
+# - ``tiles(q_lo, q_hi, k_lo, k_hi)`` -> ``(live, whole)`` for tiles of
+#   queries and keys (numpy ints, inclusive, broadcast): ``live`` holds a
+#   score that counts, ``whole`` holds no other.  A sweep visits the live
+#   tiles alone and masks element-wise those that are not whole.
+# - ``area(length)``: the scores that count in one row of ``length``
+#   positions, what a roofline and the ``attention/tiles_*`` counters
+#   weigh the tiles visited against.
+# - ``fits(length)``: whether it is a rule for a row of ``length``
+#   positions; a rule says itself which rows it fits.
+# - ``plain(length)``: True where the rule, on a row of ``length``
+#   positions, is the causal mask itself; the op then runs as ``causal``
+#   runs (no plan, no rule: `mask_or_causal`, the one place that asks).
+# - ``suffix``: what the flash kernels' names carry under this rule in a
+#   trace (``tpuframe_flash_fwd`` + suffix), so that a model's layers
+#   under different rules can be told apart; empty keeps the plain names.
+
+
+def mask_or_causal(causal: bool, mask, length: int):
+    """What stands in ``causal``'s place for a row of ``length``
+    positions: the rule ``mask``, ``True`` where the rule is the causal
+    mask there, or ``causal`` itself without a rule."""
+    if mask is None:
+        return bool(causal)
+    if not mask.fits(length):
+        raise ValueError(f"{mask} is no rule for a row of {length} positions")
+    return True if mask.plain(length) else mask
+
+
 class BlockDiffusionMask(NamedTuple):
     """The mask of a block-diffusion training row, as a rule on positions.
 
@@ -108,9 +149,59 @@ class BlockDiffusionMask(NamedTuple):
                  & (~nc | (d1 < a0)) & (~cc | (d1 <= e0)))
         return live, live & whole
 
-    def area(self) -> int:
-        """Scores that count in one row: ``half^2 + half * block``."""
+    def area(self, length: int | None = None) -> int:
+        """Scores that count in one row: ``half^2 + half * block``
+        (``length`` is the row's own, ``2 * half``)."""
         return self.half * (self.half + self.block)
+
+    def fits(self, length: int) -> bool:
+        return length == 2 * self.half
+
+    def plain(self, length: int) -> bool:
+        return False
+
+    suffix = ""
+
+
+class SlidingWindowMask(NamedTuple):
+    """A causal band: query ``i`` sees key ``j`` iff ``i - window < j <= i``,
+    the ``window`` keys up to and with its own (the Hugging Face sliding
+    window).  A rule for a row of any length; on a row no longer than
+    the window it is the causal mask."""
+
+    window: int
+
+    def allowed(self, q_pos, k_pos, kv_len=None):
+        """Two compares over the broadcast shape; a row at or past
+        ``kv_len`` sees what the last row before it sees."""
+        q_pos = jnp.asarray(q_pos, jnp.int32)
+        k_pos = jnp.asarray(k_pos, jnp.int32)
+        if kv_len is not None:
+            q_pos = jnp.minimum(q_pos, kv_len - 1)
+        return (k_pos <= q_pos) & (k_pos > q_pos - self.window)
+
+    def tiles(self, q_lo, q_hi, k_lo, k_hi):
+        """A tile meets the band iff its first key is not after its last
+        query and its last key is inside the first query's window; it
+        lies inside the band iff its last key is not after its first
+        query and its first key is inside the last query's window."""
+        live = (k_lo <= q_hi) & (k_hi > q_lo - self.window)
+        whole = (k_hi <= q_lo) & (k_lo > q_hi - self.window)
+        return live, live & whole
+
+    def area(self, length: int) -> int:
+        """``length * window - window (window - 1) / 2`` from ``window``
+        positions on; the causal triangle below."""
+        w = min(self.window, length)
+        return length * w - w * (w - 1) // 2
+
+    def fits(self, length: int) -> bool:
+        return self.window >= 1
+
+    def plain(self, length: int) -> bool:
+        return self.window >= length
+
+    suffix = "_window"
 
 
 def _repeat_kv(q, k, v):
@@ -127,23 +218,25 @@ def _repeat_kv(q, k, v):
 
 def attention_reference(
     q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = False,
-    scale: float | None = None, mask: BlockDiffusionMask | None = None,
+    scale: float | None = None, mask=None,
 ) -> jax.Array:
     """Full (unsharded) attention oracle, (B, L, H, D) layout.
 
     ``scale`` replaces the default ``1/sqrt(D)`` (latent attention folds
     its rotary scaling into it); ``v`` may be narrower or wider than
     ``q``/``k``: the output takes ``v``'s width.  ``mask`` is a rule on
-    positions in place of ``causal`` (the dense mask is built from it
-    here); ``k`` and ``v`` may hold fewer heads than ``q``, one a group."""
+    positions (the protocol above) in place of ``causal`` (the dense
+    mask is built from it here); ``k`` and ``v`` may hold fewer heads
+    than ``q``, one a group."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     k, v = _repeat_kv(q, k, v)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-    if mask is not None or causal:
+    causal = mask_or_causal(causal, mask, q.shape[1])
+    if causal:
         qi = jnp.arange(q.shape[1])[:, None]
         ki = jnp.arange(k.shape[1])[None, :]
-        seen = ki <= qi if mask is None else mask.allowed(qi, ki)
+        seen = ki <= qi if causal is True else causal.allowed(qi, ki)
         scores = jnp.where(seen, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)

@@ -724,11 +724,8 @@ class Trainer:
         # the loop's one host sync besides the window drain
         with tele.span("train/health_fetch", emit=False,
                        step=self.batches_seen):
-            stats = jax.device_get(self._health_flags)
-            hs = {
-                k: float(v)
-                for k, v in jax.device_get(self.state.health).items()
-            }
+            stats, hs = jax.device_get((self._health_flags, self.state.health))
+            hs = {k: float(v) for k, v in hs.items()}
         n_bad = int(round(sum(float(s[0]) for s in stats)))
         window_steps = len(stats)
         self._health_flags = []
@@ -1399,6 +1396,11 @@ class Trainer:
             # the drained window: first_step .. step
             with tele.span("train/host_block", emit=False,
                            step=self.batches_seen, first_step=first_step) as sp:
+                # every leaf in one fetch: the device idles from the end of
+                # the window's last step until the next dispatch lands, so a
+                # transfer a leaf (and an eager slice a health field) would
+                # be the device's time, not only the host's
+                window = jax.device_get(window)
                 out = {
                     k: float(v) for k, v in window.items()
                     if k not in ("health_stats", "model_stats")
@@ -1518,6 +1520,19 @@ class Trainer:
                 # boundary-to-boundary step time: charges whatever actually
                 # slowed this rank (wait, dispatch, snapshot, callback)
                 self._straggler.observe()
+                # Accumulate on device (async) — floating every step would
+                # block the host on each step's completion and serialize the
+                # pipeline.  Before the health sentinel, whose check waits
+                # for this step: the adds then queue behind the step, and
+                # the device runs them while the host is still waiting
+                if window is None:
+                    window, window_first = metrics, self.batches_seen
+                else:
+                    # one eager add a leaf: each a dispatch, and the place
+                    # the loop waits where the device's queue is full
+                    with tele.span("train/metrics_window", emit=False,
+                                   leaves=len(jax.tree.leaves(metrics))):
+                        window = jax.tree.map(jnp.add, window, metrics)
                 # health sentinel: accumulate the step's bad-flag on device
                 # (async, like the metrics window) and check once per window
                 # — may raise Divergence, BEFORE this step's interval
@@ -1562,17 +1577,6 @@ class Trainer:
                 # atomic unit of progress, so a SIGTERM/maintenance notice is
                 # acted on here — last-chance checkpoint, then Preempted out
                 self._maybe_preempt_exit()
-                # Accumulate on device (async) — floating every step would
-                # block the host on each step's completion and serialize the
-                # pipeline.
-                if window is None:
-                    window, window_first = metrics, self.batches_seen
-                else:
-                    # one eager add a leaf: each a dispatch, and the place
-                    # the loop waits where the device's queue is full
-                    with tele.span("train/metrics_window", emit=False,
-                                   leaves=len(jax.tree.leaves(metrics))):
-                        window = jax.tree.map(jnp.add, window, metrics)
                 self._emit("on_step_end")
                 if self.log_interval and self.batches_seen % self.log_interval == 0:
                     w = drain(window, window_first)
