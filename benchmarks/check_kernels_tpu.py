@@ -20,12 +20,15 @@ the no-drop expert layer's bounded slot buffers (one window, and a router that
 overflows the bound into several: XLA's ragged-dot kernel leaves the tiles it
 does not visit unwritten on the chip, which no CPU run shows), the short
 convolution, and the head norms with rotary positions (both timed beside
-their oracles' XLA fusions).
+their oracles' XLA fusions), and the grouped products of the expert layer
+(the three kernels against the oracle, and each timed beside
+``jax.lax.ragged_dot`` at the four expert cells' shapes).
 
 Usage: python benchmarks/check_kernels_tpu.py [--only a,b,...]
 (exits 1 on any failure).  ``--only`` runs a named subset — sections:
 layer_norm, cross_entropy, quant_wire, blockwise, flash_layout, window, ring,
-ulysses, moe_windows, short_conv, head_norm_rope.
+ulysses, moe_windows, short_conv, head_norm_rope, grouped
+(``--grouped-tiles 128,256,512`` prices other row tiles beside the default).
 """
 
 from __future__ import annotations
@@ -61,11 +64,15 @@ def main() -> None:
         "moe_windows": _check_moe_windows,
         "short_conv": _check_short_conv,
         "head_norm_rope": _check_head_norm_rope,
+        "grouped": _check_grouped,
     }
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help=f"comma list of sections to run ({','.join(sections)})")
+    ap.add_argument("--grouped-tiles", default="",
+                    help="comma list of row tiles the grouped section prices beside the default")
     cli = ap.parse_args()
+    _GROUPED_TILES[:] = [int(t) for t in cli.grouped_tiles.split(",") if t]
     chosen = set(cli.only.split(",")) if cli.only else set(sections)
     unknown = chosen - set(sections)
     if unknown:
@@ -579,6 +586,113 @@ def _check_head_norm_rope(jax, jnp, np, rng) -> None:
                           "shape": [b, l, h, d], **times,
                           "kernels_pct_of_819_gb_s": 100 * moved / (times["kernels"] * 1e-3 * 819e9)}),
               flush=True)
+
+
+#: (M, K, N, G) of the expert products in dsv2lite_seq4096, sdar_blockdiff_seq4096,
+#: lfm2moe_seq4096 and mellum2_seq8192
+_GROUPED_SHAPES = ((12288, 2048, 1408, 8), (16384, 2048, 768, 16),
+                   (16384, 2048, 1792, 8), (16384, 2304, 896, 8))
+#: row tiles ``--grouped-tiles`` asks the section to price beside the default
+_GROUPED_TILES: list = []
+
+
+def _check_grouped(jax, jnp, np, rng) -> None:
+    """The grouped products' three kernels (rows x weights, cotangent x
+    weights transposed, rows transposed x cotangent) against the float32
+    oracle: at a cut shape in float32 with a tile two groups share, empty
+    groups and a tail of NaN rows that must come out as exact zeros, then in
+    bfloat16 at the four expert cells' shapes (M, K, N, G) and their
+    transposes, half the buffer routed (as `slot_bound` leaves it), the
+    groups as a balanced router makes them and with the fullest 1.4 times
+    the mean.  Each product is timed beside ``jax.lax.ragged_dot`` with
+    its selects on the same operands (a line of its own a shape: ms a call,
+    the median of 5 laps of 10, and the share of the MXU's peak over the
+    routed rows; the time passes or fails nothing)."""
+    import importlib
+    import time
+
+    gm = importlib.import_module("tpuframe.ops.grouped_matmul")
+    peak = 197e12
+
+    def parts(op):
+        return {
+            "fwd": jax.jit(lambda r, w, s, g: op(r, w, s)),
+            "drows": jax.jit(lambda r, w, s, g: jax.vjp(lambda r: op(r, w, s), r)[1](g)[0]),
+            "dweights": jax.jit(lambda r, w, s, g: jax.vjp(lambda w: op(r, w, s), w)[1](g)[0]),
+        }
+
+    def oracle(r, w, s):
+        with jax.default_matmul_precision("highest"):
+            return gm.grouped_matmul_reference(r.astype(jnp.float32), w.astype(jnp.float32), s)
+
+    rel = lambda a, b: float(jnp.linalg.norm(a.astype(jnp.float32) - b)  # noqa: E731
+                             / jnp.maximum(jnp.linalg.norm(b), 1e-30))
+    kernels = lambda tile: parts(  # noqa: E731
+        lambda r, w, s: gm.grouped_matmul(r, w, s, interpret=False, tile_rows=tile))
+
+    # a cut shape, float32: groups that share tiles of 128, empty first, middle and last
+    m, k, n = 1024, 384, 896
+    sizes = jnp.asarray([0, 300, 0, 129, 260, 0], jnp.int32)
+    total = int(sizes.sum())
+    r = jnp.asarray(rng.standard_normal((m, k)), jnp.float32).at[total:].set(jnp.nan)
+    w = jnp.asarray(rng.standard_normal((6, k, n)) / 16, jnp.float32).at[0].set(jnp.nan)
+    g = jnp.asarray(rng.standard_normal((m, n)), jnp.float32).at[total:].set(jnp.nan)
+    clean = lambda a: jnp.nan_to_num(a, nan=0.0)  # noqa: E731
+    want = parts(oracle)
+    for name, fn in kernels(128).items():
+        got = fn(r, w, sizes, g)
+        record(f"grouped_f32_cut_{name}", rel(got, want[name](clean(r), clean(w), sizes, clean(g))), 2e-2)
+        outside = got[jnp.asarray([0, 2, 5])] if name == "dweights" else got[total:]
+        record(f"grouped_f32_cut_{name}_zeros_past_the_groups",
+               float(jnp.max(jnp.abs(jnp.nan_to_num(outside, nan=1.0)))), 1e-30)
+
+    def laps(fn, *args):
+        jax.block_until_ready(fn(*args))
+        took = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            for _ in range(10):
+                out = fn(*args)
+            jax.block_until_ready(out)
+            took.append((time.perf_counter() - t0) / 10)
+        return 1e3 * sorted(took)[2]
+
+    tiles = [gm._TILE_ROWS] + [t for t in _GROUPED_TILES if t != gm._TILE_ROWS]
+    for m, k, n, groups in _GROUPED_SHAPES:
+        for k, n in ((k, n), (n, k)):
+            r = jnp.asarray(rng.standard_normal((m, k)), jnp.bfloat16)
+            w = jnp.asarray(rng.standard_normal((groups, k, n)) / 32, jnp.bfloat16)
+            g = jnp.asarray(rng.standard_normal((m, n)), jnp.bfloat16)
+            for load in ("balanced", "fullest_1.4"):
+                share = np.full(groups, 1.0)
+                if load != "balanced":
+                    share[1] = 1.4
+                    share[2:] = (groups - 1.4 - 1.0) / (groups - 2)
+                sizes = rng.multinomial(m // 2, share / share.sum())
+                routed = int(sizes.sum())
+                sizes = jnp.asarray(sizes, jnp.int32)
+                flops = 2 * routed * k * n
+                line = {"check": "grouped_ms_a_call", "shape": [m, k, n, groups], "load": load,
+                        "fullest_over_mean": float(sizes.max() * groups / routed)}
+                ragged = parts(gm._ragged)
+                for name, fn in ragged.items():
+                    line[f"ragged_dot_{name}"] = laps(fn, r, w, sizes, g)
+                f32 = [a.astype(jnp.float32) for a in (r, w, g)]
+                want = {name: fn(f32[0], f32[1], sizes, f32[2])
+                        for name, fn in parts(oracle).items()} if load == "balanced" else None
+                for tile in tiles:
+                    tag = f"tile{tile}"
+                    for name, fn in kernels(tile).items():
+                        if want is not None:
+                            record(f"grouped_{m}x{k}x{n}x{groups}_{tag}_{name}",
+                                   rel(fn(r, w, sizes, g), want[name]), 1e-2)
+                        ms = laps(fn, r, w, sizes, g)
+                        line[f"{tag}_{name}"] = ms
+                        line[f"{tag}_{name}_pct_of_peak"] = 100 * flops / (ms * 1e-3 * peak)
+                    edge = gm._edge_rows(tile)
+                    line[f"{tag}_padded_rows_pct"] = 100 * (1 - routed / float(
+                        gm.tiles_visited(sizes, edge) * edge))
+                print(json.dumps(line), flush=True)
 
 
 def _check_ring(jax, jnp, np, rng) -> None:

@@ -3,6 +3,8 @@ shared experts, and ``ops.grouped_matmul``: the program against the plain
 reference of ``chipbench/reference/deepseek-v2-lite.py`` and against
 hand-computed values, at small sizes on the CPU."""
 
+import functools
+import importlib
 import json
 import math
 import os
@@ -17,9 +19,13 @@ from tpuframe.models import TransformerLM
 from tpuframe.models import transformer as tr
 from tpuframe.models.moe import MoEMLP, slot_bound
 from tpuframe.ops.blockwise_attention import blockwise_attention
+from tpuframe.ops import dispatch
 from tpuframe.ops.grouped_matmul import (
+    RAGGED_TILE_ROWS,
     grouped_matmul,
+    grouped_matmul_grads,
     grouped_matmul_reference,
+    row_tile,
     tiles_visited,
 )
 from tpuframe.ops.ring_attention import attention_reference
@@ -348,6 +354,246 @@ class TestGroupedMatmul:
     def test_shapes_are_checked(self):
         with pytest.raises(ValueError):
             grouped_matmul(jnp.ones((4, 3)), jnp.ones((2, 4, 5)), jnp.ones((2,), jnp.int32))
+
+
+#: the kernels under interpret at cut shapes: 320 rows in tiles of 64, five groups
+_M, _TILE = 320, 64
+_GROUPS = {
+    "boundary_inside_a_tile": (100, 60, 30, 40, 20),
+    "a_group_of_several_tiles": (200, 10, 10, 10, 10),
+    "empty_first": (0, 100, 50, 20, 30),
+    "empty_middle": (64, 0, 0, 128, 5),
+    "empty_last": (70, 80, 90, 10, 0),
+    "sum_equal_to_m_on_tile_edges": (64, 64, 64, 64, 64),
+    "sum_equal_to_m_off_tile_edges": (100, 100, 60, 30, 30),
+    "sum_well_under_m": (10, 0, 7, 0, 3),
+    "every_group_empty": (0, 0, 0, 0, 0),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel_products(k, n):
+    """(operands, kernels, oracle) at (320, k) x (5, k, n) in float32: each
+    a jitted ``(rows, weights, sizes, cotangent) -> (result, d rows, d
+    weights)``, the group sizes an argument so one trace serves every case."""
+    keys = jax.random.split(jax.random.PRNGKey(11), 3)
+    operands = (jax.random.normal(keys[0], (_M, k), jnp.float32),
+                jax.random.normal(keys[1], (5, k, n), jnp.float32) / 8,
+                jax.random.normal(keys[2], (_M, n), jnp.float32))
+
+    def both(op):
+        def run(rows, w, sizes, g):
+            y, vjp = jax.vjp(lambda r, m: op(r, m, sizes), rows, w)
+            return (y,) + vjp(g)
+        return jax.jit(run)
+
+    kernels = both(functools.partial(grouped_matmul, interpret=True, tile_rows=_TILE))
+    return operands, kernels, both(grouped_matmul_reference)
+
+
+class TestGroupedKernels:
+    """``ops.grouped_matmul``'s three Pallas kernels in interpret mode."""
+
+    @pytest.mark.parametrize("widths", [(896, 1408), (1408, 896)], ids=["7x128_by_11x128", "11x128_by_7x128"])
+    @pytest.mark.parametrize("case", list(_GROUPS))
+    def test_value_and_both_gradients_against_the_oracle(self, case, widths):
+        (rows, w, g), kernels, oracle = _kernel_products(*widths)
+        sizes = jnp.asarray(_GROUPS[case], jnp.int32)
+        for name, got, want in zip(("result", "d_rows", "d_weights"),
+                                   kernels(rows, w, sizes, g), oracle(rows, w, sizes, g)):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4,
+                                       err_msg=name)
+
+    @pytest.mark.parametrize("case", ["boundary_inside_a_tile", "empty_first", "empty_middle",
+                                      "empty_last", "sum_well_under_m", "every_group_empty"])
+    def test_exact_zeros_past_the_groups_whatever_the_buffers_held(self, case):
+        """The buffers' tails (rows and cotangent past the groups) and an
+        empty group's weights hold NaN: the result and the row gradient are
+        exact zeros past the groups, an empty group's weight gradient is an
+        exact zero block, and inside the groups nothing is touched by it."""
+        (rows, w, g), kernels, oracle = _kernel_products(896, 1408)
+        sizes = _GROUPS[case]
+        total = sum(sizes)
+        empty = jnp.asarray([s == 0 for s in sizes])
+        dirty = (rows.at[total:].set(jnp.nan), jnp.where(empty[:, None, None], jnp.nan, w),
+                 g.at[total:].set(jnp.nan))
+        sizes = jnp.asarray(sizes, jnp.int32)
+        got = kernels(dirty[0], dirty[1], sizes, dirty[2])
+        want = oracle(rows, jnp.where(empty[:, None, None], 0.0, w), sizes, g.at[total:].set(0.0))
+        for a, b in zip(got, want):
+            assert bool(jnp.all(jnp.isfinite(a)))
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4)
+        assert float(jnp.max(jnp.abs(got[0][total:]), initial=0.0)) == 0.0
+        assert float(jnp.max(jnp.abs(got[1][total:]), initial=0.0)) == 0.0
+        assert float(jnp.max(jnp.abs(jnp.where(empty[:, None, None], got[2], 0.0)))) == 0.0
+
+    @pytest.mark.parametrize("tile", [16, 64, 128, 320])
+    @pytest.mark.parametrize("case", list(_GROUPS))
+    def test_tiles_visited_counts_the_plans_own_visits(self, case, tile):
+        """``tiles_visited`` (the ``moe/rows_computed`` counter) against the
+        steps of the kernels' plan that multiply; every row tile is visited
+        at least once (the tiles past the groups to be zeroed), every group
+        at least once by the weight gradient's walk, and the steps fit the grid."""
+        gm = importlib.import_module("tpuframe.ops.grouped_matmul")
+        sizes = jnp.asarray(_GROUPS[case], jnp.int32)
+        tiles = -(-_M // tile)
+        for every_group in (False, True):
+            n, group, tile_id, lo, hi = (np.asarray(a) for a in gm._plan(
+                sizes, _M, tile, every_group=every_group))
+            n = int(n[0])
+            assert 1 <= n <= tiles + len(_GROUPS[case]) - 1 == len(group)
+            assert int(np.sum((hi > lo)[:n])) == int(tiles_visited(sizes, tile))
+            if tile % 32 == 0:
+                # a step that touches one half of its tile multiplies that half alone
+                live, middle = (hi > lo)[:n], (tile_id * tile + tile // 2)[:n]
+                halves = (lo[:n] < middle).astype(int) + (hi[:n] > middle).astype(int)
+                assert int(np.sum(halves[live])) == int(tiles_visited(sizes, tile // 2))
+            assert (tile_id >= 0).all() and (tile_id < tiles).all()
+            if every_group:
+                assert set(group[:n]) == set(range(len(_GROUPS[case])))
+                assert (np.diff(group[:n]) >= 0).all()
+            else:
+                assert set(tile_id[:n]) == set(range(tiles))
+            # a step's rows lie in its tile, and the steps behind the plan repeat the last
+            live = hi[:n] > lo[:n]
+            assert (lo[:n][live] < (tile_id[:n][live] + 1) * tile).all()
+            assert (hi[:n][live] > tile_id[:n][live] * tile).all()
+            assert (group[n:] == group[n - 1]).all() and (tile_id[n:] == tile_id[n - 1]).all()
+
+    def test_bfloat16_operands_accumulate_in_float32(self):
+        """The configurations' precision: bfloat16 operands, float32 sums over
+        K and over a group's rows, one rounding at the end."""
+        (rows, w, g), _, oracle = _kernel_products(896, 1408)
+        sizes = jnp.asarray(_GROUPS["boundary_inside_a_tile"], jnp.int32)
+        narrow = [a.astype(jnp.bfloat16) for a in (rows, w, g)]
+        y, vjp = jax.vjp(lambda r, m: grouped_matmul(r, m, sizes, interpret=True, tile_rows=_TILE),
+                         narrow[0], narrow[1])
+        got = (y,) + vjp(narrow[2])
+        want = oracle(*(a.astype(jnp.float32) for a in narrow[:2]), sizes, narrow[2].astype(jnp.float32))
+        for a, b in zip(got, want):
+            assert a.dtype == jnp.bfloat16
+            scale = float(jnp.max(jnp.abs(b)))
+            assert float(jnp.max(jnp.abs(a.astype(jnp.float32) - b))) < 2 ** -7 * scale
+
+    @pytest.mark.parametrize("env, kernels", [({}, False), ({"TPUFRAME_PALLAS_INTERPRET": "1"}, True)])
+    def test_dispatch_and_the_verdict_event(self, env, kernels, monkeypatch, tmp_path):
+        """Through ``ops/dispatch.py`` like every kernel: ``jax.lax.ragged_dot``
+        where Pallas is not compiled, the kernels where it runs; one
+        ``ops/kernel_verdict`` event a distinct decision, with the tile; and
+        ``row_tile`` names the tile of the product that runs."""
+        from tpuframe.track import telemetry as T
+
+        for knob in ("TPUFRAME_PALLAS_INTERPRET", "TPUFRAME_DISABLE_PALLAS"):
+            monkeypatch.delenv(knob, raising=False)
+        for knob, value in env.items():
+            monkeypatch.setenv(knob, value)
+        rows, w = jnp.ones((48, 8)), jnp.ones((3, 8, 4))
+        sizes = jnp.asarray([5, 0, 20], jnp.int32)
+        dispatch._VERDICT_EMITTED.clear()
+        tele = T.configure(str(tmp_path / "events.jsonl"))
+        try:
+            for _ in range(3):
+                text = str(jax.make_jaxpr(jax.grad(
+                    lambda r, m: jnp.sum(grouped_matmul(r, m, sizes)), (0, 1)))(rows, w))
+                assert ("ragged_dot" in text) is not kernels
+                assert [name in text for name in (
+                    "tpuframe_grouped_fwd", "tpuframe_grouped_drows",
+                    "tpuframe_grouped_dweights")] == [kernels] * 3
+            (event,) = [e for e in tele.recent_events(50) if e["name"] == "ops/kernel_verdict"]
+            assert (event["op"], event["shape_class"]) == ("grouped_matmul", "g4_k8_m64_n4")
+            assert event["enable"] is kernels and event["source"] == "default"
+            assert event.get("tile_rows") == event.get("edge_rows") == (48 if kernels else None)
+            assert row_tile(48, 8, 4, jnp.float32) == (48 if kernels else RAGGED_TILE_ROWS)
+            assert row_tile(4096, 8, 4, jnp.float32) == (128 if kernels else RAGGED_TILE_ROWS)
+            np.testing.assert_allclose(np.asarray(grouped_matmul(rows, w, sizes)),
+                                       np.asarray(grouped_matmul_reference(rows, w, sizes)))
+        finally:
+            T.reset()
+            dispatch._VERDICT_EMITTED.clear()
+
+    @pytest.mark.parametrize("how", [{"interpret": True, "tile_rows": _TILE}, {"kernels": False}, {}],
+                             ids=["kernels", "ragged_dot_kept", "auto_on_a_cpu"])
+    def test_the_gradients_alone_are_what_the_vjp_computes(self, how):
+        """``grouped_matmul_grads``: both gradients from the forward pass's
+        arrays, by the same dispatch, with no forward product traced."""
+        (rows, w, g), _, oracle = _kernel_products(896, 1408)
+        sizes = jnp.asarray(_GROUPS["empty_middle"], jnp.int32)
+        text = str(jax.make_jaxpr(lambda *a: grouped_matmul_grads(*a, **how))(rows, w, sizes, g))
+        assert "tpuframe_grouped_fwd" not in text
+        assert ("tpuframe_grouped_drows" in text) is ("interpret" in how)
+        for got, want in zip(grouped_matmul_grads(rows, w, sizes, g, **how),
+                             oracle(rows, w, sizes, g)[1:]):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
+
+    def test_the_further_windows_keep_ragged_dot(self, monkeypatch):
+        """A layer whose router overflows its buffers: the first window's
+        products are the kernels, the loops' bodies hold ``ragged_dot``
+        alone, and the layer matches itself on ``ragged_dot`` throughout."""
+        layer = MoEMLP(num_experts=8, top_k=2, expert_dim=16, held=(0, 2), gated=True,
+                       capacity_factor=None)
+        x = jax.random.normal(jax.random.PRNGKey(0), (1, 2048, 8)).at[..., 0].set(1.0)
+        p = layer.init({"params": jax.random.PRNGKey(1)}, x)["params"]
+        p = {**p, "router": {"kernel": p["router"]["kernel"].at[0, :2].add(8.0)}}  # most pairs here
+
+        def loss(p, x):
+            out, upd = layer.apply({"params": p}, x, mutable=["counters", "gauges", "aux_loss"])
+            return jnp.sum(out ** 2), upd
+
+        (want, _), want_grads = jax.value_and_grad(loss, has_aux=True)(p, x)
+        monkeypatch.setenv("TPUFRAME_PALLAS_INTERPRET", "1")
+        jax.clear_caches()  # the layer's jitted bodies were traced on `ragged_dot` just now
+        jaxpr = jax.make_jaxpr(jax.grad(lambda p, x: loss(p, x)[0]))(p, x)
+        (value, upd), grads = jax.value_and_grad(loss, has_aux=True)(p, x)
+        assert float(upd["counters"]["moe/overflow_calls"]) == 1.0
+
+        def names(jaxpr, inside_a_loop=False):
+            for e in jaxpr.eqns:
+                if e.primitive.name == "pallas_call":
+                    yield e.params["name"], inside_a_loop
+                if e.primitive.name == "ragged_dot_general":
+                    yield "ragged_dot", inside_a_loop
+                for sub in jax.core.jaxprs_in_params(e.params):
+                    yield from names(sub, inside_a_loop or e.primitive.name == "while")
+
+        found = set(names(jaxpr.jaxpr))
+        assert {n for n, looped in found if not looped} == {
+            "tpuframe_grouped_fwd", "tpuframe_grouped_drows", "tpuframe_grouped_dweights"}
+        assert {n for n, looped in found if looped} == {"ragged_dot"}
+        np.testing.assert_allclose(float(value), float(want), rtol=1e-5)
+        for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4)
+
+    def test_a_manual_region_keeps_the_reference_lowering(self, monkeypatch):
+        """The kernels were written for one device's whole operands: under a
+        ``shard_map`` the auto dispatch takes ``ragged_dot``."""
+        from jax.sharding import PartitionSpec as P
+
+        from tpuframe.core import MeshSpec
+
+        monkeypatch.setenv("TPUFRAME_PALLAS_INTERPRET", "1")
+        mesh = MeshSpec(data=2).build(jax.devices()[:2])
+        rows, w = jnp.ones((2, 32, 8)), jnp.ones((3, 8, 4))
+        sizes = jnp.asarray([5, 0, 20], jnp.int32)
+        per_shard = jax.shard_map(lambda r: grouped_matmul(r[0], w, sizes)[None], mesh=mesh,
+                                  in_specs=P("data"), out_specs=P("data"), check_vma=False)
+        text = str(jax.make_jaxpr(per_shard)(rows))
+        assert "ragged_dot" in text and "tpuframe_grouped" not in text
+
+    def test_the_layers_counter_counts_the_tile_that_ran(self, monkeypatch):
+        """``moe/rows_computed`` in row tiles of the product that ran: XLA's
+        512 where ``ragged_dot`` runs, the kernels' own where they do."""
+        layer = MoEMLP(num_experts=4, top_k=2, expert_dim=16, capacity_factor=None)
+        x = jax.random.normal(jax.random.PRNGKey(0), (1, 384, 8))
+        p = layer.init({"params": jax.random.PRNGKey(1)}, x)["params"]
+        read = {}
+        for mode, env in (("ragged", "0"), ("kernels", "1")):
+            monkeypatch.setenv("TPUFRAME_PALLAS_INTERPRET", env)
+            _, upd = layer.apply({"params": p}, x, mutable=["counters", "gauges", "aux_loss"])
+            read[mode] = (float(upd["counters"]["moe/rows_computed"]),
+                          float(upd["counters"]["moe/assignments_here"]))
+        assert read["ragged"][1] == read["kernels"][1] == 768
+        assert read["ragged"][0] % 512 == 0 and read["kernels"][0] % 128 == 0
+        assert 768 <= read["kernels"][0] <= read["ragged"][0]
 
 
 class TestYarn:

@@ -1,5 +1,6 @@
 """The flash-attention kernels, the placements ``attn_impl="auto"``
-gives them, and the short-convolution and head-norm-and-rotary pairs, compiled for a described v5e: no chip, the
+gives them, the short-convolution and head-norm-and-rotary pairs, and the
+expert layer's grouped products, compiled for a described v5e: no chip, the
 TPU's own compiler (Mosaic refuses here what it would refuse there: a
 tile that does not fit VMEM, a block it cannot lay out, a precision it
 does not take).  Nothing runs, so this says nothing of results or times.
@@ -249,6 +250,78 @@ def test_head_norm_rope_kernels_compile_for_v5e(one_chip, shape, dtype):
     assert len(_kernel_calls(text, "tpuframe_head_norm_rope_bwd")) == 1
     # no float32 array of the input's size: less than the input in its own dtype
     assert compiled.memory_analysis().temp_size_in_bytes < b * l * h * d * jnp.dtype(dtype).itemsize
+
+
+@pytest.mark.parametrize("width, tokens, layer", [
+    (2048, (2, 4096), dict(num_experts=64, top_k=6, expert_dim=1408, held=(0, 8), renormalize=False)),
+    (2048, (1, 8192), dict(num_experts=128, top_k=8, expert_dim=768, held=(0, 16))),
+    (2048, (2, 4096), dict(num_experts=32, top_k=4, expert_dim=1792, held=(0, 8),
+                           scoring="sigmoid", select_bias=True, aux_loss_weight=0.0)),
+    (2304, (1, 8192), dict(num_experts=64, top_k=8, expert_dim=896, held=(0, 8))),
+], ids=["dsv2lite_12288x2048x1408x8", "sdar_16384x2048x768x16",
+        "lfm2_16384x2048x1792x8", "mellum2_16384x2304x896x8"])
+def test_expert_layer_holds_the_grouped_kernels(v5e_runtime, one_chip, width, tokens, layer):
+    """One no-drop expert layer as each expert cell runs it (bfloat16
+    products over float32 master weights, the slot buffers `slot_bound`
+    gives), its value, gradient and a plain update in one program: the
+    nine grouped products of the pass that runs are this repo's kernels
+    and none is XLA's ragged-dot kernel (which the further windows' loops,
+    for traffic that overflows the buffers, keep); Mosaic takes their blocks (a whole (K, N) weight a
+    group, a float32 (K, N) accumulator) within the VMEM they ask for; a
+    (G, K, N) bfloat16 array is written once a leaf by the cast and once
+    by the weight gradient's kernel, the kernels read the casts as they lie
+    (the row gradient contracts over the weights' last axis in place; the
+    one layout copy left, of w_in and w_gate, feeds the further windows'
+    loop) and none passes through a select of its own (the kernels write
+    the zeros)."""
+    from tpuframe.models.moe import MoEMLP
+
+    v5e_runtime(1)
+    moe = MoEMLP(capacity_factor=None, gated=True, dtype=jnp.bfloat16, **layer)
+    x = jax.ShapeDtypeStruct((*tokens, width), jnp.bfloat16, sharding=one_chip)
+    params = jax.eval_shape(lambda: moe.init(
+        {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 16, width), jnp.bfloat16)))["params"]
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), params)
+
+    def loss(p, x):
+        out, _ = moe.apply({"params": p}, x, mutable=["counters", "gauges", "aux_loss"])
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    def step(p, x):
+        value, (grads, d_tokens) = jax.value_and_grad(loss, (0, 1))(p, x)
+        return value, jax.tree.map(lambda a, g: a - 0.1 * g, p, grads), d_tokens
+
+    lowered = jax.jit(step, donate_argnums=0).lower(params, x)
+    # the jitted kernels are lowered once a shape (w_in and w_gate share
+    # theirs), in the first window's pass alone: the further windows' loop
+    # bodies, lowered for themselves, keep `ragged_dot`
+    for kernel in ("fwd", "drows", "dweights"):
+        assert lowered.as_text().count(f'kernel_name = "tpuframe_grouped_{kernel}"') == 2
+    text = lowered.compile().as_text()
+    entry = text[text.index("\nENTRY "):]
+    for kernel in ("fwd", "drows", "dweights"):
+        assert len(_kernel_calls(text, f"tpuframe_grouped_{kernel}")) == 3
+        assert len(_kernel_calls(entry, f"tpuframe_grouped_{kernel}")) == 3
+    assert "ragged" not in entry
+    g, k, n = params["w_in"].shape
+    # every array of a weight leaf's shape the program writes (as the entry's
+    # text has them: `name = dtype[G,K,N]{layout} opcode(`)
+    leaves = [m.groups() for m in re.finditer(
+        rf"%?([\w.\-]+) = (\w+)\[(?:{g},{k},{n}|{g},{n},{k})\]\S* ([\w\-]+)\(", entry)]
+    narrow = [(name, op) for name, dtype, op in leaves
+              if dtype == "bf16" and op not in ("get-tuple-element", "copy-start", "copy-done")]
+    written = [name for name, op in narrow if op != "custom-call"]
+    casts = [name for name in written if "convert" in name]
+    assert len(casts) == 3, narrow
+    # what else is written of a leaf's shape is the layout `ragged_dot`'s row
+    # gradient wants in the further windows' loop (w_in, w_gate): no kernel reads it
+    copies = [name for name in written if name not in casts]
+    assert len(copies) <= 2 and all(name.startswith("copy") for name in copies), narrow
+    calls = " ".join(_kernel_calls(entry, "tpuframe_grouped"))
+    assert not [name for name in copies if f"%{name}," in calls or f"%{name})" in calls]
+    moved = [(name, op) for name, _, op in leaves if re.search("transpose|select", name + " " + op)]
+    assert not moved, moved
 
 
 def test_gpt2_heads_per_shard_compile_for_v5e_2x2(v5e_runtime):

@@ -31,7 +31,12 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from tpuframe.core.runtime import EXPERT_AXIS
-from tpuframe.ops.grouped_matmul import TILE_ROWS, grouped_matmul, tiles_visited
+from tpuframe.ops.grouped_matmul import (
+    grouped_matmul,
+    grouped_matmul_grads,
+    row_tile,
+    tiles_visited,
+)
 from tpuframe.ops.moe_gating import moe_dispatch_combine
 
 
@@ -98,27 +103,36 @@ def _sum_choices_bwd(n, res, g):
 _sum_choices.defvjp(_sum_choices_fwd, _sum_choices_bwd)
 
 
+#: the slot buffers' rows come in whole multiples of this (a constant of
+#: the buffers: the grouped product's row tile is its kernels' own)
+_SLOT_ROWS = 512
+
+
 def slot_bound(pairs: int, count: int, experts: int) -> int:
     """Rows of the no-drop layer's slot buffers: twice the share of the
     ``pairs`` (token, choice) pairs that a balanced router sends to
-    ``count`` held experts of ``experts``, in whole row tiles of the
-    grouped product, and never more than a slot a pair."""
+    ``count`` held experts of ``experts``, rounded up to ``_SLOT_ROWS``,
+    and never more than a slot a pair."""
     balanced = -(-pairs * count // experts)
-    return min(pairs, -(-2 * balanced // TILE_ROWS) * TILE_ROWS)
+    return min(pairs, -(-2 * balanced // _SLOT_ROWS) * _SLOT_ROWS)
 
 
 def _scale_rows(y, weight):
     return y * weight[:, None].astype(y.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(9, 10))
-def _expert_parts(tokens, w_gate, w_in, w_out, weight, tok, inv, sizes, lo, cap, act):
+@functools.partial(jax.jit, static_argnums=(9, 10, 11))
+def _expert_parts(tokens, w_gate, w_in, w_out, weight, tok, inv, sizes, lo, cap, act,
+                  kernels=True):
     """``sum p_e E_e(x)`` over the sorted slots ``[lo, lo + cap)``, and
     the arrays its backward pass reads.  ``tok`` and ``weight`` are whole
     windows long.  Jitted, like :func:`_window_bwd`: the layers of a
-    model and a layer's first and further windows are then one traced
-    and lowered function the step calls, not a copy each (seconds of a
-    job's first step)."""
+    model are then one traced and lowered function the step calls, not a
+    copy each (seconds of a job's first step).  ``kernels=False`` (the
+    further windows) keeps the grouped products on ``ragged_dot``: a
+    loop's body is lowered for itself, and every kernel in it is one
+    more for each layer's executable to compile and load for traffic
+    that overflows the buffers."""
     n = tokens.shape[0]
     if cap < tok.shape[0]:
         ends = jnp.cumsum(sizes)
@@ -127,13 +141,14 @@ def _expert_parts(tokens, w_gate, w_in, w_out, weight, tok, inv, sizes, lo, cap,
         weight = jax.lax.dynamic_slice_in_dim(weight, lo, cap)
         inv = inv - lo
     rows = _take_tokens(tokens, tok, inv, n)
-    pre = grouped_matmul(rows, w_in, sizes)
+    product = functools.partial(grouped_matmul, group_sizes=sizes, kernels=kernels)
+    pre = product(rows, w_in)
     if w_gate is not None:
-        gate = grouped_matmul(rows, w_gate, sizes)
+        gate = product(rows, w_gate)
         hid = act(gate) * pre
     else:
         gate, hid = None, act(pre)
-    y = grouped_matmul(hid, w_out, sizes)
+    y = product(hid, w_out)
     out = _sum_choices(_scale_rows(y, weight), tok, inv, n)
     return out, (rows, gate, pre, hid, y, weight, tok, inv, sizes)
 
@@ -165,25 +180,25 @@ def _experts_windowed_fwd(tokens, w_gate, w_in, w_out, weight, tok, inv, sizes, 
     out, parts = _expert_parts(*args, jnp.int32(0), cap, act)
     out = jax.lax.fori_loop(
         1, _windows(sizes, cap),
-        lambda i, acc: acc + _expert_parts(*args, i * cap, cap, act)[0], out)
+        lambda i, acc: acc + _expert_parts(*args, i * cap, cap, act, False)[0], out)
     return out, (parts, args)
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5))
-def _window_bwd(parts, w_gate, w_in, w_out, n, act, g):
+@functools.partial(jax.jit, static_argnums=(4, 5, 7))
+def _window_bwd(parts, w_gate, w_in, w_out, n, act, g, kernels=True):
     """The transposes of :func:`_expert_parts`' lines, last to first, each
     from the arrays the forward pass made: cotangents of the tokens, the
     three weights and the window's gate weights."""
     rows, gate, pre, hid, y, weight, tok, inv, sizes = parts
-    product = lambda r, w: grouped_matmul(r, w, sizes)  # noqa: E731
+    grads = functools.partial(grouped_matmul_grads, group_sizes=sizes, kernels=kernels)
     d_y, d_weight = jax.vjp(_scale_rows, y, weight)[1](_take_tokens(g, tok, inv, n))
-    d_hid, d_out = jax.vjp(product, hid, w_out)[1](d_y)
+    d_hid, d_out = grads(hid, w_out, g=d_y)
     if w_gate is not None:
         d_gate, d_pre = jax.vjp(lambda a, b: act(a) * b, gate, pre)[1](d_hid)
-        d_rows, d_wg = jax.vjp(product, rows, w_gate)[1](d_gate)
+        d_rows, d_wg = grads(rows, w_gate, g=d_gate)
     else:
         (d_pre,), d_rows, d_wg = jax.vjp(act, pre)[1](d_hid), 0, None
-    d_more, d_in = jax.vjp(product, rows, w_in)[1](d_pre)
+    d_more, d_in = grads(rows, w_in, g=d_pre)
     return _sum_choices(d_rows + d_more, tok, inv, n), d_wg, d_in, d_out, d_weight
 
 
@@ -192,14 +207,14 @@ def _experts_windowed_bwd(cap, act, res, g):
     tokens, w_gate, w_in, w_out, padded, _, inv, sizes = args
     n, pairs = tokens.shape[0], inv.shape[0]
 
-    def window(parts, lo):
-        *d, d_weight = _window_bwd(parts, w_gate, w_in, w_out, n, act, g)
+    def window(parts, lo, kernels=True):
+        *d, d_weight = _window_bwd(parts, w_gate, w_in, w_out, n, act, g, kernels)
         return (*d, jax.lax.dynamic_update_slice_in_dim(
             jnp.zeros_like(padded), d_weight, lo, 0))
 
     def further(i, acc):
-        again = _expert_parts(*args, i * cap, cap, act)[1]
-        return jax.tree.map(jnp.add, acc, window(again, i * cap))
+        again = _expert_parts(*args, i * cap, cap, act, False)[1]
+        return jax.tree.map(jnp.add, acc, window(again, i * cap, False))
 
     *d, d_weight = jax.lax.fori_loop(1, _windows(sizes, cap), further, window(parts, 0))
     return (*d, d_weight[:pairs], None, None, None)
@@ -438,7 +453,9 @@ class MoEMLP(nn.Module):
                      reduce_fn=lambda a, b: b, init_fn=lambda: f32(0))
 
         sow("counters", "moe/assignments_here", jnp.sum(load))
-        sow("counters", "moe/rows_computed", tiles_visited(sizes) * TILE_ROWS)
+        # in row tiles of the product that ran: the kernels' own, or ragged_dot's
+        tile = row_tile(cap, tokens.shape[1], w_in.shape[2], self.dtype)
+        sow("counters", "moe/rows_computed", tiles_visited(sizes, tile) * tile)
         sow("counters", "moe/slot_rows", windows * cap)
         sow("counters", "moe/overflow_calls", windows > 1)
         sow("gauges", "moe/expert_load_max_over_mean",

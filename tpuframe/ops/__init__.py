@@ -15,8 +15,9 @@ fallback and the correctness oracle for tests.
   that recomputes the softmax in the backward kernel instead of
   materializing it in HBM.
 - :func:`grouped_matmul` — rows sorted by group times one weight a
-  group, the expert product of the no-drop mixture-of-experts layer
-  (``jax.lax.ragged_dot``: XLA's own tiled kernel on the TPU).
+  group, the expert product of the no-drop mixture-of-experts layer:
+  three kernels driven by the group sizes (the product and both
+  gradients' products), ``jax.lax.ragged_dot`` where they do not run.
 - :func:`short_conv` — LFM2's double-gated short convolution between
   its two projections (gate, a few causal depthwise taps along the
   sequence, gate) in one pass each way.

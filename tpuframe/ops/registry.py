@@ -128,7 +128,7 @@ OP_NAME_TOKENS = (
     ("attention", ("attention", "flash", "fmha", "scaled_dot_product")),
     ("short_conv", ("short_conv",)),
     ("head_norm_rope", ("head_norm_rope",)),
-    ("grouped_matmul", ("ragged-dot", "ragged_dot", "grouped_matmul")),
+    ("grouped_matmul", ("tpuframe_grouped", "ragged-dot", "ragged_dot", "grouped_matmul")),
     ("moe_gating", ("top_k_gating", "moe", "expert_dispatch")),
 )
 
