@@ -27,7 +27,7 @@ their oracles' XLA fusions), and the grouped products of the expert layer
 Usage: python benchmarks/check_kernels_tpu.py [--only a,b,...]
 (exits 1 on any failure).  ``--only`` runs a named subset — sections:
 layer_norm, cross_entropy, quant_wire, blockwise, flash_layout, window, ring,
-ulysses, moe_windows, short_conv, head_norm_rope, grouped
+ulysses, moe_windows, short_conv, head_norm_rope, grouped, gated_delta
 (``--grouped-tiles 128,256,512`` prices other row tiles beside the default).
 """
 
@@ -65,6 +65,7 @@ def main() -> None:
         "short_conv": _check_short_conv,
         "head_norm_rope": _check_head_norm_rope,
         "grouped": _check_grouped,
+        "gated_delta": _check_gated_delta,
     }
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
@@ -693,6 +694,110 @@ def _check_grouped(jax, jnp, np, rng) -> None:
                     line[f"{tag}_padded_rows_pct"] = 100 * (1 - routed / float(
                         gm.tiles_visited(sizes, edge) * edge))
                 print(json.dumps(line), flush=True)
+
+
+def _check_gated_delta(jax, jnp, np, rng) -> None:
+    """The gated delta rule's kernels against the recurrence position by
+    position (float32, a ragged length, decays as strong as the published
+    initialisation's) and against the scan schedule at
+    ``qwen3-next-80b-a3b-instruct``'s shape in bfloat16 (one row of 8192
+    positions, 16 key heads and 32 value heads of 128), output and all five
+    gradients; there the kernels, the scan schedule and the chunk-local part
+    alone are also timed, forward + backward a call (lines of their own; the
+    times pass or fail nothing).  Then the two neighbours the configuration
+    runs at a width no other has: the head-norm-and-rotary pair with tables
+    of 64 of a head's 256 dimensions, and the flash kernels at 256-wide heads
+    (16 over 2), each against its oracle."""
+    import importlib
+    import time
+
+    from tpuframe.models.transformer import rope_tables
+    from tpuframe.ops.head_norm_rope import head_norm_rope, head_norm_rope_reference
+
+    gd = importlib.import_module("tpuframe.ops.gated_delta")
+    rel = lambda a, b: float(jnp.linalg.norm((a - b).astype(jnp.float32))  # noqa: E731
+                             / jnp.linalg.norm(b.astype(jnp.float32)))
+
+    def inputs(b, l, hk, h, dk, dv, dtype):
+        unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
+        q = unit(jnp.asarray(rng.standard_normal((b, l, hk, dk)), jnp.float32)) * dk ** -0.5
+        k = unit(jnp.asarray(rng.standard_normal((b, l, hk, dk)), jnp.float32))
+        v = jnp.asarray(rng.standard_normal((b, l, h, dv)), jnp.float32)
+        rates = jnp.asarray(16.0 * (np.arange(h) + 0.5) / h, jnp.float32)
+        g = -rates * jax.nn.softplus(jnp.asarray(rng.standard_normal((b, l, h)), jnp.float32) + 1)
+        beta = jax.nn.sigmoid(jnp.asarray(rng.standard_normal((b, l, h)), jnp.float32))
+        ct = jnp.asarray(rng.standard_normal((b, l, h, dv)), dtype)
+        return (q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta), ct
+
+    def both(op):
+        def run(args, ct):
+            y, vjp = jax.vjp(op, *args)
+            return (y,) + vjp(ct)
+        return jax.jit(run)
+
+    forms = {"kernels": both(functools.partial(gd.gated_delta, interpret=False)),
+             "schedule": both(gd.gated_delta_chunked),
+             "recurrence": both(gd.gated_delta_reference)}
+    parts = ("out", "dq", "dk", "dv", "dg", "dbeta")
+    args, ct = inputs(2, 300, 2, 4, 128, 128, jnp.float32)
+    want = forms["recurrence"](args, ct)
+    for form in ("kernels", "schedule"):
+        for part, a, c in zip(parts, forms[form](args, ct), want):
+            record(f"gated_delta_f32_ragged_{form}_{part}", rel(a, c), 2e-3)
+    args, ct = inputs(1, 8192, 16, 32, 128, 128, jnp.bfloat16)
+    got, want = forms["kernels"](args, ct), forms["schedule"](args, ct)
+    for part, a, c in zip(parts, got, want):
+        record(f"gated_delta_qwen3next_kernels_vs_schedule_{part}", rel(a, c), 2e-2)
+    # against float32: what bfloat16 operands cost, the same for both forms
+    exact = forms["schedule"](tuple(a.astype(jnp.float32) for a in args), ct.astype(jnp.float32))
+    for part, a, c in zip(parts, got, exact):
+        record(f"gated_delta_qwen3next_kernels_vs_float32_{part}", rel(a, c), 5e-2)
+
+    def laps(fn, *a):
+        jax.block_until_ready(fn(*a))
+        out = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            for _ in range(5):
+                r = fn(*a)
+            jax.block_until_ready(r)
+            out.append((time.perf_counter() - t0) / 5)
+        return 1e3 * sorted(out)[len(out) // 2]
+
+    forward = jax.jit(functools.partial(gd.gated_delta, interpret=False))
+    prepare = both(lambda *a: gd._prepare(*a)[0])
+    d_parts = jax.tree.map(lambda a: jnp.ones(a.shape, a.dtype),
+                           jax.eval_shape(gd._prepare, *args)[0])
+    times = {"kernels_fwd_bwd": laps(forms["kernels"], args, ct),
+             "kernels_fwd": laps(forward, *args),
+             "schedule_fwd_bwd": laps(forms["schedule"], args, ct),
+             "chunk_local_fwd_bwd": laps(prepare, args, d_parts)}
+    steps = gd.chunks_walked(1, 8192, 32)
+    print(json.dumps({"check": "gated_delta_ms_a_call", "shape": [1, 8192, 16, 32, 128, 128],
+                      **times, "chunk_steps_fwd_and_bwd": steps}), flush=True)
+
+    # the head norms with partial rotary tables at 256-wide heads
+    for name, h in (("q", 16), ("k", 2)):
+        x = jnp.asarray(rng.standard_normal((1, 8192, h * 256)), jnp.bfloat16)
+        g = jnp.asarray(rng.standard_normal((1, 8192, h * 256)), jnp.bfloat16)
+        scale = jnp.asarray(1 + 0.3 * rng.standard_normal((256,)), jnp.float32)
+        cos, sin = rope_tables(8192, 64, 1e7)
+
+        def pair(op):
+            def run(x, scale):
+                y, vjp = jax.vjp(lambda x, s: op(x, s, cos, sin, num_heads=h, eps=1e-6), x, scale)
+                return (y,) + vjp(g)
+            return jax.jit(run)
+
+        got = pair(functools.partial(head_norm_rope, interpret=False))(x, scale)
+        want = pair(head_norm_rope_reference)(x, scale)
+        for part, a, c in zip(("out", "dx", "dscale"), got, want):
+            record(f"head_norm_rope_qwen3next_{name}_partial_rotary_{part}", rel(a, c), 2e-2)
+
+    # plain causal flash kernels at 256-wide heads, 16 over 2, 8192 keys
+    qkv = tuple(jnp.asarray(0.5 * rng.standard_normal((1, 8192, heads, 256)), jnp.bfloat16)
+                for heads in (16, 2, 2))
+    _schedule_parity(jax, jnp, "flash_qwen3next_256_wide", qkv, ftol=2e-2, gtol=3e-2)
 
 
 def _check_ring(jax, jnp, np, rng) -> None:

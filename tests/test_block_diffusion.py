@@ -247,10 +247,14 @@ class TestSelfAttentionAgainstTheReference:
         np.testing.assert_array_equal(np.asarray(cos), np.tile(np.asarray(base_cos), (2, 1)))
         np.testing.assert_array_equal(np.asarray(sin), np.tile(np.asarray(base_sin), (2, 1)))
 
-    def test_partial_rotary_in_multi_head_attention_is_refused(self):
-        m = TransformerLM(vocab_size=16, num_layers=1, num_heads=2, head_dim=8, rope_dim=4)
-        with pytest.raises(ValueError, match="whole heads"):
+    def test_a_rotary_width_over_the_head_in_multi_head_attention_is_refused(self):
+        """Since PR 43 a width under the head's turns its first dimensions
+        (`tests/test_qwen3_next.py`); one over it, or an odd one, has no pairs."""
+        m = TransformerLM(vocab_size=16, num_layers=1, num_heads=2, head_dim=8, rope_dim=10)
+        with pytest.raises(ValueError, match="odd or over"):
             m.init(jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))
+        TransformerLM(vocab_size=16, num_layers=1, num_heads=2, head_dim=8, rope_dim=4).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))
 
 
 class TestForwardProcess:

@@ -390,7 +390,7 @@ class TestProgramAgainstReference:
 
     @pytest.mark.parametrize("kw, match", [
         ({"layer_types": ["conv"]}, "names 1 layers of 3"),
-        ({"layer_types": ["conv", "linear_attention", "conv"]}, "unknown mixer"),
+        ({"layer_types": ["conv", "mamba", "conv"]}, "unknown mixer"),
     ])
     def test_layer_types_are_checked(self, small, kw, match):
         model = TransformerLM(**{**CFG["model"]["kwargs"], **kw})

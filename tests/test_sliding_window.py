@@ -375,7 +375,7 @@ class TestProgramAgainstReference:
 
     def test_unknown_kinds_are_named_from_one_tuple(self, small):
         model = TransformerLM(**{**CFG["model"]["kwargs"],
-                                 "layer_types": ["sliding_attention", "linear_attention"]})
+                                 "layer_types": ["sliding_attention", "mamba"]})
         with pytest.raises(ValueError, match="known: " + ", ".join(tr.Block.MIXERS)):
             model.init(jax.random.PRNGKey(0), small["x"])
 

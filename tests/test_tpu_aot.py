@@ -159,7 +159,8 @@ def _materialized(text):
     ((1, 8192, 32, 4, 128), (4096, 4)),   # sdar-30b-a3b-chat's call: four heads a block of lanes
     ((4, 1024, 16, 16, 64), None),        # gpt2-medium's: two heads a block
     ((2, 4096, 32, 8, 64), None),         # lfm2-8b-a1b's: a group of four over half a K/V block
-], ids=["sdar", "gpt2m", "lfm2"])
+    ((1, 8192, 16, 2, 256), None),        # qwen3-next's: 256-wide heads, two lane blocks a head
+], ids=["sdar", "gpt2m", "lfm2", "qwen3next"])
 def test_flash_kernels_take_the_models_rows(one_chip, shape, mask):
     """The call as the model makes it (the projections' (B, L, H*D) rows
     in, the rows of the output out): the kernels read and write those rows,
@@ -227,7 +228,11 @@ def test_short_conv_kernels_compile_for_v5e(one_chip, shape, dtype):
     ((2, 4096, 32, 64), jnp.bfloat16),    # lfm2-8b-a1b's: two heads side by side in 128 lanes
     ((2, 4096, 8, 64), jnp.bfloat16),
     ((2, 600, 4, 128), jnp.float32),      # check_kernels_tpu's: a length that is no tile multiple
-], ids=["sdar_q", "sdar_k", "lfm2_q", "lfm2_k", "f32_ragged"])
+    ((1, 8192, 16, 256, 64), jnp.bfloat16),  # qwen3-next's query: 256-wide heads, 64 of them turned
+    ((1, 8192, 2, 256, 64), jnp.bfloat16),   # ... and its key projection
+    ((2, 600, 4, 128, 32), jnp.float32),
+], ids=["sdar_q", "sdar_k", "lfm2_q", "lfm2_k", "f32_ragged", "qwen3next_q_partial",
+        "qwen3next_k_partial", "f32_ragged_partial"])
 def test_head_norm_rope_kernels_compile_for_v5e(one_chip, shape, dtype):
     """The head-norm-and-rotary pair: whole-row blocks of 256 positions, a
     head (or two) a chunk of whole lanes, lane rolls and lane sums within a
@@ -235,10 +240,12 @@ def test_head_norm_rope_kernels_compile_for_v5e(one_chip, shape, dtype):
     for the backward pass but the input in its own dtype."""
     from tpuframe.ops.head_norm_rope import head_norm_rope
 
-    b, l, h, d = shape
+    # a fifth number: the rotary tables' width where it is under the head's
+    b, l, h, d = shape[:4]
     x = jax.ShapeDtypeStruct((b, l, h * d), dtype, sharding=one_chip)
     scale = jax.ShapeDtypeStruct((d,), jnp.float32, sharding=one_chip)
-    table = jax.ShapeDtypeStruct((l, d), jnp.float32, sharding=one_chip)
+    table = jax.ShapeDtypeStruct((l, shape[4] if len(shape) > 4 else d), jnp.float32,
+                                 sharding=one_chip)
 
     def loss(x, scale, cos, sin):
         out = head_norm_rope(x, scale, cos, sin, num_heads=h, eps=1e-6, interpret=False)
@@ -250,6 +257,82 @@ def test_head_norm_rope_kernels_compile_for_v5e(one_chip, shape, dtype):
     assert len(_kernel_calls(text, "tpuframe_head_norm_rope_bwd")) == 1
     # no float32 array of the input's size: less than the input in its own dtype
     assert compiled.memory_analysis().temp_size_in_bytes < b * l * h * d * jnp.dtype(dtype).itemsize
+
+
+@pytest.mark.parametrize("shape, dtype", [
+    ((1, 8192, 16, 32, 128, 128), jnp.bfloat16),   # qwen3-next's linear-attention layers
+    ((2, 300, 2, 4, 128, 128), jnp.float32),       # check_kernels_tpu's: a ragged length, float32
+    ((1, 1024, 4, 4, 256, 128), jnp.bfloat16),     # keys twice as wide as values
+], ids=["qwen3next", "f32_ragged", "wide_keys"])
+def test_gated_delta_kernels_compile_for_v5e(one_chip, shape, dtype):
+    """The gated delta rule's pass over the chunks: a head's rows of 256
+    positions a grid step, the float32 state and its gradient resident in
+    VMEM along the chunks' axis, the products with a transposed left operand
+    (``Kd^T V'``, ``M^T dO``), float32 operands whole; one forward and one
+    backward kernel, and nothing kept for the backward pass the size of a
+    state a position."""
+    from tpuframe.ops.gated_delta import gated_delta
+
+    b, l, hk, h, dk, dv = shape
+    arr = lambda *s, t=dtype: jax.ShapeDtypeStruct(s, t, sharding=one_chip)  # noqa: E731
+    args = (arr(b, l, hk, dk), arr(b, l, hk, dk), arr(b, l, h, dv),
+            arr(b, l, h, t=jnp.float32), arr(b, l, h, t=jnp.float32))
+
+    def loss(*a):
+        return jnp.sum(gated_delta(*a, interpret=False).astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(*args).compile()
+    text = compiled.as_text()
+    assert len(_kernel_calls(text, "tpuframe_gated_delta_fwd")) == 1
+    assert len(_kernel_calls(text, "tpuframe_gated_delta_bwd")) == 1
+    # a state a chunk and the chunk-local arrays, never a state a position
+    assert compiled.memory_analysis().temp_size_in_bytes < b * l * h * dk * dv * 4 / 8
+
+
+def test_qwen3_next_period_holds_its_kernels_once_a_kind(v5e_runtime):
+    """A linear-attention layer twice and a gated full-attention layer of
+    ``TransformerLM`` at qwen3-next's widths, the gradient of a loss over
+    its logits: the rule's two kernels lowered once and called a layer, the
+    flash and the head-norm-and-rotary pairs at 256-wide heads with 64 of
+    them turned, and what the backward pass of a linear-attention layer
+    keeps stays under a float32 copy of its fused projection."""
+    from tpuframe.models import TransformerLM
+
+    mesh = v5e_runtime(1)
+    model = TransformerLM(
+        vocab_size=1024, num_layers=3, num_heads=16, head_dim=256, d_model=2048, max_len=8192,
+        attn_impl="auto", dtype=jnp.bfloat16, norm="rms", norm_unit_offset=True, rope_dim=64,
+        rope_theta=1e7, num_kv_heads=2, qk_norm=True, attn_gated=True, mlp_gated=True,
+        mlp_dim=512, layer_types=["linear_attention", "linear_attention", "full_attention"],
+        linear_attention={"num_key_heads": 16, "num_value_heads": 32, "key_dim": 128,
+                          "value_dim": 128, "conv_taps": 4})
+    params = jax.eval_shape(
+        lambda: model.init({"params": jax.random.PRNGKey(0)},
+                           jnp.zeros((1, 16), jnp.int32), train=False))["params"]
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=NamedSharding(mesh, P())),
+        params)
+    toks = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=NamedSharding(mesh, P()))
+
+    def loss(params, toks):
+        logits = model.apply({"params": params}, toks, train=True)
+        return jnp.mean(jax.nn.logsumexp(logits, axis=-1))
+
+    lowered = jax.jit(jax.grad(loss)).lower(params, toks)
+    for kernel in ("tpuframe_gated_delta_fwd", "tpuframe_gated_delta_bwd", "tpuframe_flash_fwd",
+                   "tpuframe_flash_bwd"):
+        assert lowered.as_text().count(f'kernel_name = "{kernel}"') == 1
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    for kernel, calls in (("tpuframe_gated_delta_fwd", 2), ("tpuframe_gated_delta_bwd", 2),
+                          ("tpuframe_flash_fwd", 1), ("tpuframe_flash_bwd", 1),
+                          ("tpuframe_head_norm_rope_fwd", 2), ("tpuframe_head_norm_rope_bwd", 2)):
+        # the instruction's own line: a kernel that reads another's output names it too
+        assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == calls, kernel
+    # two linear-attention layers' residuals and one's transients: with every
+    # chunk-local array of every layer kept (no barrier before the backward
+    # pass computes them again) this read 7.5 GiB for two layers
+    assert compiled.memory_analysis().temp_size_in_bytes < 5 << 30
 
 
 @pytest.mark.parametrize("width, tokens, layer", [

@@ -253,6 +253,9 @@ class MoEMLP(nn.Module):
         ``(silu(x W_gate) * (x W_in)) W_out``; else GELU, no gate.
       shared_dim: > 0 adds a shared MLP of this width every token passes
         through (shared experts, built as one MLP).
+      shared_token_gate: the shared MLP's output is multiplied, token by token,
+        by ``sigmoid(x w_sg)``, ``w_sg`` (d, 1) the leaf
+        ``shared_expert_gate/kernel`` (Qwen's gated shared expert).
       renormalize: chosen gates are rescaled to sum to 1 (GShard);
         False keeps the router's probabilities as they are.
       scoring: ``"softmax"`` over the router's outputs, or ``"sigmoid"``
@@ -289,6 +292,7 @@ class MoEMLP(nn.Module):
     held: tuple | None = None
     gated: bool = False
     shared_dim: int = 0
+    shared_token_gate: bool = False
     renormalize: bool = True
     seq_aux: bool = False
     scoring: str = "softmax"
@@ -370,7 +374,11 @@ class MoEMLP(nn.Module):
                 hid = dense(self.shared_dim, "shared_in")(t)
                 hid = (act(dense(self.shared_dim, "shared_gate")(t)) * hid
                        if self.gated else act(hid))
-                out = out + dense(d, "shared_out")(hid).astype(out.dtype)
+                shared = dense(d, "shared_out")(hid)
+                if self.shared_token_gate:
+                    shared = shared * jax.nn.sigmoid(nn.Dense(
+                        1, use_bias=False, dtype=self.dtype, name="shared_expert_gate")(t))
+                out = out + shared.astype(out.dtype)
 
         # --- load-balance aux loss ---------------------------------------
         if probs is None:

@@ -44,6 +44,7 @@ from tpuframe.core.runtime import (
     current_runtime,
 )
 from tpuframe.ops.dispatch import batch_sharding_info, effective_mesh
+from tpuframe.ops.gated_delta import chunks_walked, gated_delta, gated_delta_reference
 from tpuframe.ops.head_norm_rope import head_norm_rope, head_norm_rope_reference
 from tpuframe.ops.ring_attention import (
     SlidingWindowMask,
@@ -89,6 +90,11 @@ def transformer_tp_rules():
         # three, so the input projection stays whole and the output
         # projection splits its output columns
         (r"conv/out_proj/kernel", P(None, MODEL_AXIS)),
+        # the gated delta rule has no split over the model axis yet (a head's
+        # q, k, v, z, b and a lie in two fused projections, and the op takes
+        # whole heads): the linear-attention layer's input projections stay
+        # whole too, and its output projection splits its output columns
+        (r"deltanet/out_proj/kernel", P(None, MODEL_AXIS)),
         (r"embed/embedding", P(None, MODEL_AXIS)),
         (r"lm_head/kernel", P(None, MODEL_AXIS)),
     )
@@ -102,7 +108,9 @@ def _mesh_or_none():
 
 
 class RMSNorm(nn.Module):
-    """``x * rsqrt(mean(x^2) + eps) * scale``, statistics in float32.
+    """``x * rsqrt(mean(x^2) + eps) * scale``, statistics in float32;
+    ``unit_offset``: ``* (1 + scale)``, the scale seeded 0 (Qwen3-Next's
+    and Gemma's form of the same norm).
 
     Plain jnp on purpose: on a block's (B, L, D) rows XLA fuses it into
     its neighbours, and a kernel of its own would cost layout copies
@@ -111,15 +119,23 @@ class RMSNorm(nn.Module):
 
     eps: float = 1e-6
     dtype: Any = jnp.float32
+    unit_offset: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        scale = _norm_scale(self, x.shape[-1], self.unit_offset)
         x32 = x.astype(jnp.float32)
         y = x32 * jax.lax.rsqrt(
             jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps
         )
         return (y * scale.astype(jnp.float32)).astype(self.dtype)
+
+
+def _norm_scale(module, width: int, unit_offset: bool):
+    """A norm's learned ``scale`` leaf as the factor it stands for."""
+    if not unit_offset:
+        return module.param("scale", nn.initializers.ones, (width,))
+    return 1.0 + module.param("scale", nn.initializers.zeros, (width,))
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -175,7 +191,12 @@ def rope_tables(length: int, dim: int, theta: float,
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """Rotate (B, L, H, dim) by position: ``x cos + rotate_half(x) sin``."""
+    """Rotate (B, L, H, dim) by position: ``x cos + rotate_half(x) sin``;
+    tables narrower than ``dim`` turn the first dimensions only."""
+    width = cos.shape[-1]
+    if width < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :width], cos, sin), x[..., width:]], axis=-1)
     half = x.shape[-1] // 2
     x32 = x.astype(jnp.float32)
     rot = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
@@ -187,15 +208,17 @@ class HeadNormRope(nn.Module):
     then the rotary turn, in one pass each way
     (`tpuframe.ops.head_norm_rope`: XLA makes of the two, on a
     (B, L, H, D) view, a handful of float32 passes and re-tiled
-    residuals).  The scale is where `RMSNorm` keeps it in the tree."""
+    residuals).  The scale is where `RMSNorm` keeps it in the tree, in
+    either of its forms; tables narrower than a head turn its first
+    dimensions and leave the others as the norm made them."""
 
     num_heads: int
     eps: float = 1e-6
+    unit_offset: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array, rope) -> jax.Array:
-        scale = self.param("scale", nn.initializers.ones,
-                           (x.shape[-1] // self.num_heads,))
+        scale = _norm_scale(self, x.shape[-1] // self.num_heads, self.unit_offset)
         if self.is_initializing():
             # init's sample batch need not divide the mesh
             return head_norm_rope_reference(
@@ -362,11 +385,18 @@ class SelfAttention(nn.Module):
     #: a rule on positions in ``causal``'s place (`ops.ring_attention`'s
     #: protocol: `BlockDiffusionMask`, `SlidingWindowMask`)
     mask: Any = None
+    #: an output gate: the query projection twice as wide, ``[q | gate]``
+    #: (all heads' queries, then all heads' gates), and ``sigmoid(gate)`` on
+    #: the attention output before ``attn_out``
+    gated: bool = False
+    #: the head norms in the ``(1 + scale)`` form
+    norm_unit_offset: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False, rope=None) -> jax.Array:
         """``rope``: (cos, sin) tables at the rows' position ids, turned
-        into every query and key head over its whole width."""
+        into every query and key head over the tables' width (a head's
+        first dimensions where they are narrower than the head)."""
         features = self.num_heads * self.head_dim
         kv_heads = self.num_kv_heads or self.num_heads
         dense = lambda name, heads: nn.Dense(  # noqa: E731
@@ -375,19 +405,28 @@ class SelfAttention(nn.Module):
         b, l, _ = x.shape
         # head norms before rotary positions: one op on the projection's rows
         fused = self.qk_norm and rope is not None
+        gate = None
 
         def project(name, heads, norm=None):
-            y = dense(name, heads)(x)
+            nonlocal gate
+            if self.gated and name == "query":
+                y = dense(name, 2 * heads)(x)
+                y, gate = y[..., :features], y[..., features:]
+            else:
+                y = dense(name, heads)(x)
             if fused and norm:
-                y = HeadNormRope(heads, self.norm_eps, name=norm)(y, rope)
+                y = HeadNormRope(heads, self.norm_eps, self.norm_unit_offset,
+                                 name=norm)(y, rope)
             return y.reshape(b, l, heads, self.head_dim)
 
         q = project("query", self.num_heads, "q_norm")
         k = project("key", kv_heads, "k_norm")
         v = project("value", kv_heads)
         if self.qk_norm and not fused:
-            q = RMSNorm(eps=self.norm_eps, dtype=self.dtype, name="q_norm")(q)
-            k = RMSNorm(eps=self.norm_eps, dtype=self.dtype, name="k_norm")(k)
+            q = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
+                        unit_offset=self.norm_unit_offset, name="q_norm")(q)
+            k = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
+                        unit_offset=self.norm_unit_offset, name="k_norm")(k)
         elif rope is not None and not fused:
             q, k = apply_rope(q, *rope), apply_rope(k, *rope)
 
@@ -410,6 +449,8 @@ class SelfAttention(nn.Module):
                 mask=self.mask, on_tiles=count_tiles,
             )
         out = out.reshape(b, l, features)
+        if gate is not None:
+            out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
         return nn.Dense(
             x.shape[-1], use_bias=False, dtype=self.dtype, name="attn_out"
         )(out)
@@ -454,6 +495,109 @@ class ShortConv(nn.Module):
             else:
                 y = short_conv(bch, w, mesh=_mesh_or_none())
             return dense(d, "out_proj")(y)
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """Gated DeltaNet's decay rates: ``log(uniform(0, 16))``."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, minval=1e-3, maxval=16.0))
+
+
+def causal_taps(u: jax.Array, w: jax.Array) -> jax.Array:
+    """A depthwise causal convolution along the sequence: ``c_t = sum_j w_j
+    u_{t-(K-1)+j}`` of ``u`` (B, L, D) under the taps ``w`` (K, D), zeros
+    before the row, as the sum of ``K`` shifted products in float32.  XLA
+    makes one fusion of it and the activation that follows."""
+    taps, length = w.shape[0], u.shape[1]
+    # padded as stored: the one array the fusion reads, not a float32 copy
+    u = jnp.pad(u, ((0, 0), (taps - 1, 0), (0, 0)))
+    w32 = w.astype(jnp.float32)
+    return sum(w32[j] * u[:, j:j + length].astype(jnp.float32) for j in range(taps))
+
+
+def _deltanet_inputs(qkv, ba, w, a_log, dt_bias, hk, hv, dk, dtype):
+    """What the rule reads, from the fused projections' outputs: the
+    convolution and SiLU on ``[q | k | v]``, unit queries and keys, ``beta``
+    and ``g``.  Elementwise but for the taps, so the backward pass computes
+    it again from the projections' outputs (`jax.checkpoint`) and keeps none
+    of its float32 intermediates: 8192 channels of 8192 positions each."""
+    b, l, _ = qkv.shape
+    keys = hk * dk
+    # the taps' float32 sum rounded to the storage dtype before the SiLU, as
+    # the source's convolution leaves it: what the backward pass keeps of
+    # this function's 8192 channels is then half as wide
+    qkv = nn.silu(causal_taps(qkv, w).astype(dtype).astype(jnp.float32))
+    q, k = (qkv[..., i * keys:(i + 1) * keys].reshape(b, l, hk, dk) for i in (0, 1))
+    unit = lambda a: a * jax.lax.rsqrt(  # noqa: E731
+        jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+    v = qkv[..., 2 * keys:].reshape(b, l, hv, -1)
+    beta = jax.nn.sigmoid(ba[..., :hv])
+    g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
+        ba[..., hv:] + dt_bias.astype(jnp.float32))
+    return (unit(q) * dk ** -0.5).astype(dtype), unit(k).astype(dtype), v.astype(dtype), g, beta
+
+
+def _deltanet_gate(o, z, scale, eps, dtype):
+    """The gated norm on the rule's output: ``o / rms(o) * scale * silu(z)``
+    a head, float32 inside; computed again in the backward pass too."""
+    o32 = o.astype(jnp.float32)
+    o32 = o32 * jax.lax.rsqrt(jnp.mean(o32 * o32, axis=-1, keepdims=True) + eps)
+    return (o32 * scale.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))).astype(dtype)
+
+
+class GatedDeltaNet(nn.Module):
+    """Qwen3-Next's linear-attention mixer: the gated delta rule
+    (`tpuframe.ops.gated_delta`) between fused projections.  ``[q | k | v |
+    z] = x W_qkvz`` (the columns in that order, the heads of each side by
+    side) and ``[b | a] = x W_ba``; ``[q | k | v]`` goes through a
+    ``conv_taps``-tap causal depthwise convolution and SiLU; queries and
+    keys are L2-normalised over their width, the queries scaled by
+    ``key_dim^-1/2``; ``beta = sigmoid(b)`` and ``g = -exp(A_log) *
+    softplus(a + dt_bias)`` a value head, in float32; a key head serves
+    ``num_value_heads / num_key_heads`` value heads in a row; the rule's
+    output is RMS-normalised a head with a learned scale, gated by
+    ``silu(z)``, and projected by ``W_out``.  No bias anywhere."""
+
+    num_key_heads: int
+    num_value_heads: int
+    key_dim: int
+    value_dim: int
+    conv_taps: int = 4
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        b, l, d = x.shape
+        hk, hv, dk, dv = self.num_key_heads, self.num_value_heads, self.key_dim, self.value_dim
+        keys, values = hk * dk, hv * dv
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, use_bias=False, dtype=self.dtype, name=name
+        )
+        with jax.named_scope("tpuframe/deltanet"):
+            qkvz = dense(2 * keys + 2 * values, "in_proj_qkvz")(x)
+            ba = dense(2 * hv, "in_proj_ba")(x).astype(jnp.float32)
+            w = self.param("conv", nn.initializers.lecun_normal(),
+                           (self.conv_taps, 2 * keys + values))
+            a_log = self.param("A_log", _a_log_init, (hv,))
+            dt_bias = self.param("dt_bias", nn.initializers.ones, (hv,))
+            scale = self.param("norm", nn.initializers.ones, (dv,))
+            q, k, v, g, beta = jax.checkpoint(_deltanet_inputs, static_argnums=(5, 6, 7, 8))(
+                qkvz[..., :2 * keys + values], ba, w, a_log, dt_bias, hk, hv, dk, self.dtype)
+            with jax.named_scope("tpuframe/deltanet/rule"):
+                if self.is_initializing():
+                    # init's sample batch need not divide the mesh
+                    o = gated_delta_reference(q, k, v, g, beta)
+                else:
+                    o = gated_delta(q, k, v, g, beta, mesh=_mesh_or_none())
+                    # static numbers a call, counted here on the host, once a
+                    # trace, where their reader divides them
+                    registry = get_telemetry().registry
+                    registry.counter("deltanet/chunks").inc(chunks_walked(b, l, hv))
+                    registry.counter("deltanet/calls").inc(1)
+            z = qkvz[..., 2 * keys + values:].reshape(b, l, hv, dv)
+            y = jax.checkpoint(_deltanet_gate, static_argnums=(3, 4))(
+                o, z, scale, self.norm_eps, self.dtype)
+            return dense(d, "out_proj")(y.reshape(b, l, values))
 
 
 class LatentAttention(nn.Module):
@@ -523,11 +667,13 @@ class Block(nn.Module):
     (:class:`ShortConv`) in attention's place; ``"sliding_attention"``
     is multi-head attention under a causal band of ``sliding_window``
     keys (`ops.ring_attention.SlidingWindowMask`), its parameters a
-    ``"full_attention"`` layer's leaf for leaf.
+    ``"full_attention"`` layer's leaf for leaf; ``"linear_attention"`` is
+    the gated delta rule's mixer (:class:`GatedDeltaNet`), its sizes in
+    ``linear_attention``.
     """
 
     #: the mixers a layer can have
-    MIXERS = ("full_attention", "sliding_attention", "conv")
+    MIXERS = ("full_attention", "sliding_attention", "conv", "linear_attention")
 
     num_heads: int
     head_dim: int
@@ -562,13 +708,21 @@ class Block(nn.Module):
     mixer: str = "full_attention"  # one of ``MIXERS``
     conv_taps: int = 3
     sliding_window: int = 0
+    #: multi-head attention's output gate (`SelfAttention.gated`)
+    attn_gated: bool = False
+    #: every RMSNorm of the block, the head norms too, in the ``(1 + scale)`` form
+    norm_unit_offset: bool = False
+    #: the sizes of a ``"linear_attention"`` layer (`GatedDeltaNet`'s
+    #: arguments), as a tuple of (name, value)
+    linear_attention: tuple = ()
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False, rope=None) -> jax.Array:
         d = x.shape[-1]
         if self.norm == "rms":
             ln = lambda name: RMSNorm(  # noqa: E731
-                eps=self.norm_eps, dtype=self.dtype, name=name
+                eps=self.norm_eps, dtype=self.dtype,
+                unit_offset=self.norm_unit_offset, name=name
             )
         else:
             ln = lambda name: FusedLayerNorm(  # noqa: E731
@@ -577,6 +731,13 @@ class Block(nn.Module):
         y = ln("ln1")(x)
         if self.mixer == "conv":
             y = ShortConv(self.conv_taps, dtype=self.dtype, name="conv")(y)
+        elif self.mixer == "linear_attention":
+            if not self.linear_attention:
+                raise ValueError("a linear_attention layer takes its sizes from "
+                                 "linear_attention (num_key_heads, num_value_heads, "
+                                 "key_dim, value_dim)")
+            y = GatedDeltaNet(norm_eps=self.norm_eps, dtype=self.dtype, name="deltanet",
+                              **dict(self.linear_attention))(y)
         elif self.mixer not in self.MIXERS:
             raise ValueError(f"unknown mixer {self.mixer!r}; known: "
                              + ", ".join(self.MIXERS))
@@ -597,7 +758,8 @@ class Block(nn.Module):
                 self.num_heads, self.head_dim, causal=self.causal,
                 attn_impl=self.attn_impl, dtype=self.dtype,
                 num_kv_heads=self.num_kv_heads, qk_norm=self.qk_norm,
-                norm_eps=self.norm_eps, mask=mask, name="attn",
+                norm_eps=self.norm_eps, mask=mask, gated=self.attn_gated,
+                norm_unit_offset=self.norm_unit_offset, name="attn",
             )(y, train=train, rope=rope)
         if self.dropout:
             y = nn.Dropout(self.dropout, deterministic=not train)(y)
@@ -650,8 +812,14 @@ class TransformerLM(nn.Module):
     ``layer_types`` a mixer for each layer, as a config publishes them:
     ``"full_attention"`` (the attention the other sizes describe),
     ``"sliding_attention"`` (the same under a causal band of
-    ``sliding_window`` keys) or ``"conv"`` (the short-convolution
-    operator of ``conv_taps`` taps); ``rope_parameters`` rotary
+    ``sliding_window`` keys), ``"conv"`` (the short-convolution
+    operator of ``conv_taps`` taps) or ``"linear_attention"`` (the gated
+    delta rule, its sizes in ``linear_attention``: ``num_key_heads``,
+    ``num_value_heads``, ``key_dim``, ``value_dim``, ``conv_taps``);
+    ``attn_gated`` an output gate on multi-head attention; ``rope_dim``
+    under ``head_dim`` turns the first ``rope_dim`` dimensions of every
+    head (a config's ``partial_rotary_factor``); ``norm_unit_offset``
+    every RMSNorm in the ``(1 + scale)`` form; ``rope_parameters`` rotary
     parameters by kind of attention layer, as a config publishes them
     (``{"full_attention": {"rope_type": "yarn", "rope_theta": ..,
     "factor": .., ...}, "sliding_attention": {"rope_type": "default",
@@ -707,6 +875,10 @@ class TransformerLM(nn.Module):
     #: rotary parameters by kind of attention layer: a dict of dicts, kept
     #: as sorted items
     rope_parameters: Any = None
+    attn_gated: bool = False
+    norm_unit_offset: bool = False
+    #: a ``"linear_attention"`` layer's sizes: a dict, kept as sorted items
+    linear_attention: Any = ()
 
     def __post_init__(self):
         # module attributes are hashed with the train state's treedef:
@@ -716,7 +888,8 @@ class TransformerLM(nn.Module):
                 return tuple(sorted((k, frozen(x)) for k, x in v.items()))
             return tuple(frozen(x) for x in v) if isinstance(v, list) else v
 
-        for name in ("rope_scaling", "moe_kwargs", "layer_types", "rope_parameters"):
+        for name in ("rope_scaling", "moe_kwargs", "layer_types", "rope_parameters",
+                     "linear_attention"):
             object.__setattr__(self, name, frozen(getattr(self, name)))
         super().__post_init__()
 
@@ -761,13 +934,15 @@ class TransformerLM(nn.Module):
                              "bring their own, do not go together")
         ropes = {}
         if self.rope_dim:
-            if not self.kv_lora_rank and self.rope_dim != self.head_dim:
-                raise ValueError("multi-head attention turns its whole heads: "
-                                 f"rope_dim {self.rope_dim} is not head_dim {self.head_dim}")
+            if not self.kv_lora_rank and (self.rope_dim > self.head_dim or self.rope_dim % 2):
+                raise ValueError("multi-head attention turns a head's first dimensions "
+                                 f"in pairs: rope_dim {self.rope_dim} is odd or over "
+                                 f"head_dim {self.head_dim}")
             # one pair of tables a kind of attention layer that occurs
             ropes = {kind: rope_tables(tokens.shape[1], self.rope_dim,
                                        *self._rope_of(kind), positions)
-                     for kind in dict.fromkeys(mixers) if kind != "conv"}
+                     for kind in dict.fromkeys(mixers)
+                     if kind not in ("conv", "linear_attention")}
         else:
             pos = nn.Embed(self.max_len, d_model, dtype=self.dtype, name="pos_embed")(
                 jnp.arange(tokens.shape[1])[None, :]
@@ -787,12 +962,15 @@ class TransformerLM(nn.Module):
                 mlp_gated=self.mlp_gated, moe_kwargs=self.moe_kwargs,
                 num_kv_heads=self.num_kv_heads, qk_norm=self.qk_norm, mask=mask,
                 mixer=mixers[i], conv_taps=self.conv_taps,
-                sliding_window=self.sliding_window, name=f"block{i}",
+                sliding_window=self.sliding_window, attn_gated=self.attn_gated,
+                norm_unit_offset=self.norm_unit_offset,
+                linear_attention=self.linear_attention, name=f"block{i}",
             )(x, train, ropes.get(mixers[i]))
         if head_len is not None:
             x = x[:, :head_len]
         if self.norm == "rms":
-            x = RMSNorm(eps=self.norm_eps, dtype=self.dtype, name="ln_f")(x)
+            x = RMSNorm(eps=self.norm_eps, dtype=self.dtype,
+                        unit_offset=self.norm_unit_offset, name="ln_f")(x)
         else:
             x = FusedLayerNorm(dtype=self.dtype, name="ln_f")(x)
         logits = nn.Dense(

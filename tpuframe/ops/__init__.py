@@ -25,6 +25,10 @@ fallback and the correctness oracle for tests.
   projection's rows and the rotary turn behind it, in one pass each way
   (XLA makes of the two a handful of float32 passes over a
   (B, L, H, D) view).
+- :func:`gated_delta` — the gated delta rule (Gated DeltaNet's linear
+  attention): a recurrence with a (d_k, d_v) state a head, run in chunks;
+  the pass that carries the state over the chunks is a kernel pair, the
+  state resident in VMEM.
 - :func:`quant_encode` / :func:`quant_decode` — the compressed gradient
   wire's amax/scale/round/pack stages in one VMEM pass each
   (``parallel.compression`` calls them for the bucketed transport).
@@ -67,6 +71,9 @@ _LAZY = {
     "short_conv_reference": "tpuframe.ops.short_conv",
     "head_norm_rope": "tpuframe.ops.head_norm_rope",
     "head_norm_rope_reference": "tpuframe.ops.head_norm_rope",
+    "gated_delta": "tpuframe.ops.gated_delta",
+    "gated_delta_chunked": "tpuframe.ops.gated_delta",
+    "gated_delta_reference": "tpuframe.ops.gated_delta",
     "bucket_abs_max": "tpuframe.ops.quant_wire",
     "bucket_abs_max_reference": "tpuframe.ops.quant_wire",
     "quant_encode": "tpuframe.ops.quant_wire",
@@ -95,9 +102,9 @@ def __dir__():
 
 
 class _OpsModule(_types.ModuleType):
-    """Five exports share their kernel module's name
-    (``blockwise_attention``, ``grouped_matmul``, ``head_norm_rope``,
-    ``ring_attention``, ``short_conv``), and
+    """Six exports share their kernel module's name
+    (``blockwise_attention``, ``gated_delta``, ``grouped_matmul``,
+    ``head_norm_rope``, ``ring_attention``, ``short_conv``), and
     importing such a submodule makes the import machinery rebind the
     module object over the package attribute of the same name — which
     would shadow the function for every later
@@ -115,8 +122,8 @@ def _shadow_proof(name):
     )
 
 
-for _name in ("blockwise_attention", "grouped_matmul", "head_norm_rope", "ring_attention",
-              "short_conv"):
+for _name in ("blockwise_attention", "gated_delta", "grouped_matmul", "head_norm_rope",
+              "ring_attention", "short_conv"):
     setattr(_OpsModule, _name, _shadow_proof(_name))
 
 _sys.modules[__name__].__class__ = _OpsModule

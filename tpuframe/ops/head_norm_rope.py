@@ -8,6 +8,11 @@ own (B, L, H * D) rows, a head ``x`` (D wide) at position ``t``::
     y   = n * scale
     out = y * cos_t + rotate_half(y) * sin_t      (rotate_half: [-y2 | y1])
 
+Tables narrower than the head (a partial rotary factor: ``R`` of ``D``
+dimensions) turn the head's first ``R`` dimensions, ``i`` with ``i + R /
+2``, and leave the others as ``y``: to the kernels that is a cosine of 1
+and a sine of 0 there, and a roll by ``R / 2`` in place of ``D / 2``.
+
 As plain XLA ops on a (B, L, H, D) view this is a handful of float32
 passes each way, the statistic over a 128- or 64-wide minor dimension a
 fusion of its own and the saved float32 intermediates re-tiled for the
@@ -59,13 +64,20 @@ _VMEM_BYTES = 64 * 2**20
 def head_norm_rope_reference(x: jax.Array, scale: jax.Array, cos: jax.Array,
                              sin: jax.Array, *, num_heads: int, eps: float) -> jax.Array:
     """jnp oracle: ``x`` (B, L, H * D), ``scale`` (D,), ``cos`` / ``sin``
-    (L, D) -> (B, L, H * D): every head normed, scaled and turned in
-    float32, rounded once."""
+    (L, R), ``R <= D`` -> (B, L, H * D): every head normed, scaled and its
+    first ``R`` dimensions turned in float32, rounded once."""
     b, l, width = x.shape
-    d = width // num_heads
+    d, r = width // num_heads, cos.shape[1]
     x32 = x.astype(jnp.float32).reshape(b, l, num_heads, d)
     y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
     y = y * scale.astype(jnp.float32)
+    if r < d:
+        # a branch of its own: the lines below stay what the older models lower to
+        rot = jnp.concatenate([-y[..., r // 2:r], y[..., :r // 2]], axis=-1)
+        out = jnp.concatenate(
+            [y[..., :r] * cos[None, :, None, :] + rot * sin[None, :, None, :], y[..., r:]],
+            axis=-1)
+        return out.astype(x.dtype).reshape(b, l, width)
     rot = jnp.concatenate([-y[..., d // 2:], y[..., :d // 2]], axis=-1)
     out = y * cos[None, :, None, :] + rot * sin[None, :, None, :]
     return out.astype(x.dtype).reshape(b, l, width)
@@ -94,21 +106,23 @@ def _head_sum(v, d):
     return out
 
 
-def _half_turn(v, d):
-    """``v`` (rows, chunk) with the two halves of every head exchanged:
-    lane ``j`` of a head holds the head's lane ``(j + d / 2) % d``."""
+def _half_turn(v, d, r):
+    """``v`` (rows, chunk) with the two halves of the first ``r`` lanes of
+    every head exchanged: lane ``j < r`` of a head holds the head's lane
+    ``(j + r / 2) % r`` (a lane past ``r`` holds one that its sine of 0
+    drops)."""
     chunk = v.shape[1]
-    if chunk == d:
+    if chunk == d == r:
         return pltpu.roll(v, d // 2, 1)
-    return jnp.where(_lane(v) % d < d // 2, pltpu.roll(v, chunk - d // 2, 1),
-                     pltpu.roll(v, d // 2, 1))
+    return jnp.where(_lane(v) % d < r // 2, pltpu.roll(v, chunk - r // 2, 1),
+                     pltpu.roll(v, r // 2, 1))
 
 
 def _rsqrt_mean_square(x, d, eps):
     return jax.lax.rsqrt(_head_sum(x * x, d) * (1.0 / d) + eps)
 
 
-def _fwd_kernel(x_ref, cos_ref, sin_ref, scale_ref, out_ref, *, d, eps):
+def _fwd_kernel(x_ref, cos_ref, sin_ref, scale_ref, out_ref, *, d, r, eps):
     chunk = cos_ref.shape[1]
     # y cos + half_turn(y) sin with y = n scale: the scale goes into the tables
     a = cos_ref[...] * scale_ref[pl.ds(0, 1), :]
@@ -116,12 +130,13 @@ def _fwd_kernel(x_ref, cos_ref, sin_ref, scale_ref, out_ref, *, d, eps):
     for c0 in range(0, x_ref.shape[2], chunk):
         cols = pl.ds(c0, chunk)
         x = x_ref[0, :, cols].astype(jnp.float32)
-        r = _rsqrt_mean_square(x, d, eps)
-        out_ref[0, :, cols] = (r * (x * a + _half_turn(x, d) * b)).astype(out_ref.dtype)
+        out_ref[0, :, cols] = (
+            _rsqrt_mean_square(x, d, eps) * (x * a + _half_turn(x, d, r) * b)
+        ).astype(out_ref.dtype)
 
 
 def _bwd_kernel(x_ref, g_ref, cos_ref, sin_ref, scale_ref, dx_ref, dscale_ref,
-                *, d, eps, length):
+                *, d, r, eps, length):
     i = pl.program_id(0)
     tile, chunk = cos_ref.shape
 
@@ -141,14 +156,14 @@ def _bwd_kernel(x_ref, g_ref, cos_ref, sin_ref, scale_ref, dx_ref, dscale_ref,
         cols = pl.ds(c0, chunk)
         x = x_ref[0, :, cols].astype(jnp.float32)
         g = g_ref[0, :, cols].astype(jnp.float32)
-        r = _rsqrt_mean_square(x, d, eps)
-        n = x * r
-        dy = g * cos + _half_turn(g, d) * sin
+        rms = _rsqrt_mean_square(x, d, eps)
+        n = x * rms
+        dy = g * cos + _half_turn(g, d, r) * sin
         part = dy * n if here is None else jnp.where(here, dy * n, 0.0)
         # eight partial sums a column, added up outside
         dscale = dscale + jnp.sum(part.reshape(tile // 8, 8, chunk), axis=0)
         dn = dy * scale
-        dx = r * (dn - n * (_head_sum(dn * n, d) * (1.0 / d)))
+        dx = rms * (dn - n * (_head_sum(dn * n, d) * (1.0 / d)))
         dx_ref[0, :, cols] = dx.astype(dx_ref.dtype)
     dscale_ref[...] += dscale
 
@@ -170,12 +185,20 @@ def _tables(scale, cos, sin, d, *, transpose=False):
     """What a kernel multiplies by, side by side over a chunk's lanes:
     ``cos``; ``sin`` with rotate-half's sign (its halves exchanged for the
     transpose: ``d y = g cos + half_turn(g sin_signed)``); the scale at a
-    lane and at the lane it is exchanged with."""
-    sin = sin * jnp.where(jnp.arange(d) < d // 2, -1.0, 1.0)
+    lane and at the lane it is exchanged with.  Tables of ``r < d`` columns
+    are widened to the head: cosine 1 and sine 0 past ``r``, the exchange
+    inside it."""
+    r = cos.shape[1]
+    sin = sin * jnp.where(jnp.arange(r) < r // 2, -1.0, 1.0)
     if transpose:
-        sin = jnp.roll(sin, d // 2, axis=1)
+        sin = jnp.roll(sin, r // 2, axis=1)
     scale = scale.astype(jnp.float32)
-    scales = jnp.stack([scale, jnp.roll(scale, d // 2)])
+    if r < d:
+        cos = jnp.pad(cos, ((0, 0), (0, d - r)), constant_values=1.0)
+        sin = jnp.pad(sin, ((0, 0), (0, d - r)))
+        scales = jnp.stack([scale, jnp.concatenate([jnp.roll(scale[:r], r // 2), scale[r:]])])
+    else:
+        scales = jnp.stack([scale, jnp.roll(scale, d // 2)])
     return tuple(jnp.tile(t, (1, _chunk(d) // d)) for t in (cos, sin, scales))
 
 
@@ -185,7 +208,7 @@ def _fwd_pallas(x, scale, cos, sin, num_heads, eps, interpret):
     tile = min(_TILE_ROWS, length)
     rows, table, scales = _specs(tile, width, _chunk(d))
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, d=d, eps=eps),
+        functools.partial(_fwd_kernel, d=d, r=cos.shape[1], eps=eps),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         grid=(pl.cdiv(length, tile), batch),
         in_specs=[rows, table, table, scales],
@@ -203,7 +226,7 @@ def _bwd_pallas(x, scale, cos, sin, g, num_heads, eps, interpret):
     tile = min(_TILE_ROWS, length)
     rows, table, scales = _specs(tile, width, chunk)
     dx, dscale = pl.pallas_call(
-        functools.partial(_bwd_kernel, d=d, eps=eps, length=length),
+        functools.partial(_bwd_kernel, d=d, r=cos.shape[1], eps=eps, length=length),
         out_shape=(jax.ShapeDtypeStruct(x.shape, x.dtype),
                    jax.ShapeDtypeStruct((8, chunk), jnp.float32)),
         grid=(pl.cdiv(length, tile), batch),
@@ -251,8 +274,9 @@ def head_norm_rope(x: jax.Array, scale: jax.Array, cos: jax.Array, sin: jax.Arra
                    mesh=None, batch_axes: tuple | None = None) -> jax.Array:
     """``rope(rms_norm_per_head(x) * scale)`` of a projection's rows ``x``
     (B, L, H * D) under the head norm's ``scale`` (D,) and the rotary
-    tables ``cos`` / ``sin`` (L, D) at the rows' position ids (rotate-half
-    convention, `models.transformer.rope_tables`) -> (B, L, H * D).
+    tables ``cos`` / ``sin`` (L, R) at the rows' position ids (rotate-half
+    convention, `models.transformer.rope_tables`; ``R <= D`` turns a head's
+    first ``R`` dimensions) -> (B, L, H * D).
     Differentiable in ``x`` and ``scale``; the tables get no gradient.
 
     ``interpret``: None = auto (the kernels on a TPU, the jnp oracle
@@ -265,9 +289,11 @@ def head_norm_rope(x: jax.Array, scale: jax.Array, cos: jax.Array, sin: jax.Arra
     if x.ndim != 3 or x.shape[-1] % num_heads:
         raise ValueError(f"x {x.shape} is not (B, L, {num_heads} * D)")
     d = x.shape[-1] // num_heads
-    if scale.shape != (d,) or cos.shape != (x.shape[1], d) or sin.shape != cos.shape:
+    r = cos.shape[-1]
+    if (scale.shape != (d,) or cos.shape != (x.shape[1], r) or sin.shape != cos.shape
+            or r > d or r % 2):
         raise ValueError(f"scale {scale.shape} and tables {cos.shape}, {sin.shape} are not "
-                         f"({d},) and ({x.shape[1]}, {d}) for x {x.shape}")
+                         f"({d},) and ({x.shape[1]}, R) with an even R <= {d} for x {x.shape}")
     oracle = functools.partial(head_norm_rope_reference, num_heads=num_heads, eps=eps)
     if interpret is None and (x.shape[-1] % _LANES or d < _MIN_HEAD
                               or (d % _LANES and _LANES % d)):

@@ -103,6 +103,12 @@ OPS_REGISTRY = {
         "reference": "head_norm_rope_reference",
         "parity_test": "tests/test_head_norm_rope.py::TestKernels::test_kernels_match_the_oracle",
     },
+    "gated_delta": {
+        "module": "tpuframe.ops.gated_delta",
+        "symbol": "gated_delta",
+        "reference": "gated_delta_reference",
+        "parity_test": "tests/test_gated_delta.py::TestAgainstTheRecurrence::test_outputs_and_all_five_gradients",
+    },
     "moe_gating": {
         "module": "tpuframe.ops.moe_gating",
         "symbol": "moe_dispatch_combine",
@@ -128,6 +134,7 @@ OP_NAME_TOKENS = (
     ("attention", ("attention", "flash", "fmha", "scaled_dot_product")),
     ("short_conv", ("short_conv",)),
     ("head_norm_rope", ("head_norm_rope",)),
+    ("gated_delta", ("gated_delta",)),
     ("grouped_matmul", ("tpuframe_grouped", "ragged-dot", "ragged_dot", "grouped_matmul")),
     ("moe_gating", ("top_k_gating", "moe", "expert_dispatch")),
 )
