@@ -715,8 +715,9 @@ def _check_gated_delta(jax, jnp, np, rng) -> None:
     from tpuframe.ops.head_norm_rope import head_norm_rope, head_norm_rope_reference
 
     gd = importlib.import_module("tpuframe.ops.gated_delta")
+    # (at the strongest decays every gamma is 0: no quotient of two zeros)
     rel = lambda a, b: float(jnp.linalg.norm((a - b).astype(jnp.float32))  # noqa: E731
-                             / jnp.linalg.norm(b.astype(jnp.float32)))
+                             / jnp.maximum(jnp.linalg.norm(b.astype(jnp.float32)), 1e-30))
 
     def inputs(b, l, hk, h, dk, dv, dtype):
         unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
@@ -775,6 +776,7 @@ def _check_gated_delta(jax, jnp, np, rng) -> None:
     steps = gd.chunks_walked(1, 8192, 32)
     print(json.dumps({"check": "gated_delta_ms_a_call", "shape": [1, 8192, 16, 32, 128, 128],
                       **times, "chunk_steps_fwd_and_bwd": steps}), flush=True)
+    _check_delta_chunk(jax, jnp, gd, inputs, rel, laps, args)
 
     # the head norms with partial rotary tables at 256-wide heads
     for name, h in (("q", 16), ("k", 2)):
@@ -798,6 +800,95 @@ def _check_gated_delta(jax, jnp, np, rng) -> None:
     qkv = tuple(jnp.asarray(0.5 * rng.standard_normal((1, 8192, heads, 256)), jnp.bfloat16)
                 for heads in (16, 2, 2))
     _schedule_parity(jax, jnp, "flash_qwen3next_256_wide", qkv, ftol=2e-2, gtol=3e-2)
+
+
+def _solve_with(jnp, gd, form, top=0):
+    """The kernels' solve in other forms, for their price: ``masked``, every
+    level two products of the whole (C, C) array with the level's quarters
+    of ``a`` masked in (`_inv_unit_lower` as it stands); ``block``, that with
+    the ``top`` upper levels as ``T21 = -T22 A21 T11`` a block, products of
+    the level's own block side."""
+    hi, side = gd._dot, gd._CHUNK
+    levels = side.bit_length() - 1
+
+    def solve(a, row, col):
+        t = (row == col).astype(jnp.float32)
+        for level in range(levels):
+            s = 1 << level
+            if form == "masked" or level < levels - top:
+                quarter = ((((row ^ col) >> (level + 1)) == 0)
+                           & (((row >> level) & 1) == 1) & (((col >> level) & 1) == 0))
+                t = t - hi(hi(t, jnp.where(quarter, a, 0.0)), t)
+                continue
+            bands = []
+            for r in range(0, side, 2 * s):
+                t11, t22 = t[r:r + s, r:r + s], t[r + s:r + 2 * s, r + s:r + 2 * s]
+                band = [-hi(hi(t22, a[r + s:r + 2 * s, r:r + s]), t11), t22]
+                if r:
+                    band.insert(0, jnp.zeros((s, r), jnp.float32))
+                if side - r - 2 * s:
+                    band.append(jnp.zeros((s, side - r - 2 * s), jnp.float32))
+                bands += [t[r:r + s], jnp.concatenate(band, axis=1)]
+            t = jnp.concatenate(bands, axis=0)
+        return t
+
+    return solve
+
+
+def _check_delta_chunk(jax, jnp, gd, inputs, rel, laps, args) -> None:
+    """The chunk-local kernels (``tpuframe_delta_chunk_fwd`` / ``_again`` /
+    ``_bwd``) against XLA's ``_prepare`` and its transpose: every part, the
+    solve's ``T`` and the five cotangents, float32 at a cut shape and
+    bfloat16 at the cell's (``args``); then each kernel's time a
+    layer beside XLA's, and the price of the solve's levels in other forms
+    and of other chunks a grid step (lines of their own; the times pass or
+    fail nothing)."""
+    names = ("u", "w", "qe", "kd", "m", "gamma", "T")
+    grads = ("dq", "dk", "dv", "dg", "dbeta")
+    xla = jax.jit(gd._prepare)
+    xla_t = jax.jit(lambda a, t, d: jax.vjp(lambda *a: gd._prepare(*a, t=t)[0], *a)[1](d))
+    chunk_fwd = lambda a: gd._pallas_chunk_fwd(*a, False)  # noqa: E731
+    chunk_again = lambda a, t: gd._pallas_chunk_again(*a, t, False)  # noqa: E731
+    chunk_bwd = lambda a, t, d: gd._pallas_chunk_bwd(*a, t, d, False)  # noqa: E731
+    small, _ = inputs(2, 512, 2, 4, 128, 128, jnp.float32)
+    waves = lambda a: jax.tree.map(  # noqa: E731
+        lambda x: jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape).astype(x.dtype),
+        jax.eval_shape(gd._prepare, *a)[0])
+    d_parts = waves(args)
+    for tag, a, d, tol, t_tol in (("f32", small, waves(small), 2e-3, 1e-4),
+                                  ("qwen3next", args, d_parts, 2e-2, 1e-4)):
+        want, t = xla(*a)
+        got, t_got = chunk_fwd(a)
+        for name, x, y in zip(names, (*got, t_got), (*want, t)):
+            record(f"delta_chunk_{tag}_fwd_{name}", rel(x, y), t_tol if name == "T" else tol)
+        for name, x, y in zip(names, chunk_again(a, t), want):
+            record(f"delta_chunk_{tag}_again_{name}", rel(x, y), tol)
+        for name, x, y in zip(grads, chunk_bwd(a, t, d), xla_t(a, t, d)):
+            record(f"delta_chunk_{tag}_bwd_{name}", rel(x, y), tol)
+    times = {"xla_prepare": laps(xla, *args), "xla_transpose": laps(xla_t, args, t, d_parts),
+             "chunk_fwd": laps(chunk_fwd, args), "chunk_again": laps(chunk_again, args, t),
+             "chunk_bwd": laps(chunk_bwd, args, t, d_parts)}
+    print(json.dumps({"check": "delta_chunk_ms_a_layer", "shape": [1, 8192, 16, 32, 128, 128],
+                      **times}), flush=True)
+
+    # the solve's levels in other forms, and other chunks a grid step
+    kept = gd._solve, gd._LOCAL_CHUNKS
+    prices = {}
+    for tag, solve, chunks in (
+            ("kernel", gd._solve, kept[1]), ("masked", _solve_with(jnp, gd, "masked"), kept[1]),
+            ("block_top1", _solve_with(jnp, gd, "block", 1), kept[1]),
+            ("block_top2", _solve_with(jnp, gd, "block", 2), kept[1]),
+            ("kernel_chunks2", gd._solve, 2), ("kernel_chunks8", gd._solve, 8)):
+        gd._solve, gd._LOCAL_CHUNKS = solve, chunks
+        try:
+            fn = jax.jit(lambda *a: gd._chunk_parts(*a, None, False))
+            got_t = fn(*args)[1]
+            prices[tag] = {"ms": laps(fn, *args), "T_rel": rel(got_t, t)}
+        except Exception as e:  # Mosaic refuses a form: say so and price the next
+            prices[tag] = {"refused": f"{type(e).__name__}: {e}"[:300]}
+        finally:
+            gd._solve, gd._LOCAL_CHUNKS = kept
+    print(json.dumps({"check": "delta_chunk_solve_levels_ms_a_layer", **prices}), flush=True)
 
 
 def _check_ring(jax, jnp, np, rng) -> None:

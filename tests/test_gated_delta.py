@@ -2,7 +2,8 @@
 the kernels in interpret mode against the recurrence position by position,
 outputs and all five gradients, at lengths that are and are not whole chunks
 and grid steps, with decays as strong as the published initialisation makes
-them; the solve inside a chunk; the dispatch.  Small sizes, on the CPU."""
+them; the solve inside a chunk; the chunk-local kernels against `_prepare`
+and its transpose; the dispatch.  Small sizes, on the CPU."""
 
 import functools
 import importlib
@@ -181,7 +182,138 @@ class TestTheSolveInsideAChunk:
         assert float(jnp.abs(m).max()) <= float(jnp.abs(q).max()) * 128 ** 0.5 + 1e-6
 
 
+#: the chunk-local kernels' cases: (rows, length, key heads, value heads, dk, dv, rate)
+CHUNK_CASES = {
+    "one_chunk": (1, 128, 1, 1, 128, 128, 2.0),
+    "three_grid_steps": (1, 1536, 1, 1, 128, 128, 1.0),
+    "two_value_heads_a_key_head": (2, 256, 2, 4, 128, 128, 4.0),
+    "keys_all_alike": (1, 256, 1, 2, 128, 128, 1.0),
+    "strongest_decays": (1, 256, 1, 2, 128, 128, 16.0),
+}
+PARTS = ("u", "w", "qe", "kd", "m", "gamma", "T")
+
+
+def _chunk_inputs(case, dtype=jnp.float32):
+    (q, k, v, g, beta), _ = _inputs(21, *CHUNK_CASES[case], dtype=dtype)
+    if case == "keys_all_alike":
+        # every key of a chunk the same, beta 1, no decay: A is all ones under
+        # the diagonal (what made the product-of-factors solve cancel)
+        k, beta, g = jnp.broadcast_to(k[:, :1], k.shape), jnp.ones_like(beta), jnp.zeros_like(g)
+    elif case == "strongest_decays":
+        g = g - 5.0  # -20 and under a position for the fastest head
+    return q, k, v, g, beta
+
+
+def _cotangents(parts, seed=22):
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(parts))
+    return tuple(jax.random.normal(key, p.shape).astype(p.dtype) for key, p in zip(keys, parts))
+
+
+def _xla_chunk_local(args, d_parts=None):
+    """`_prepare`'s parts and ``T``, and its transpose with ``T`` handed back."""
+    parts, t = gd._prepare(*args)
+    d_parts = _cotangents(parts) if d_parts is None else d_parts
+    grads = jax.vjp(lambda *a: gd._prepare(*a, t=t)[0], *args)[1](d_parts)
+    return parts, t, d_parts, grads
+
+
+@functools.lru_cache(maxsize=None)
+def _chunk_local(case):
+    args = _chunk_inputs(case)
+    parts, t, d_parts, grads = _xla_chunk_local(args)
+    got, got_t = gd._pallas_chunk_fwd(*args, True)
+    return {"fwd": ((*got, got_t), (*parts, t)),
+            "again": (gd._pallas_chunk_again(*args, t, True), parts),
+            "bwd": (gd._pallas_chunk_bwd(*args, t, d_parts, True), grads)}
+
+
+class TestTheChunkLocalKernels:
+    """``tpuframe_delta_chunk_fwd`` / ``_again`` / ``_bwd`` in interpret mode
+    against `_prepare` and ``jax.vjp(_prepare)``."""
+
+    @staticmethod
+    def _close(got, want, rtol, atol, floor=1e-6):
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, want, rtol=rtol,
+                                   atol=atol * max(float(np.abs(want).max()), floor))
+
+    @pytest.mark.parametrize("part", PARTS)
+    @pytest.mark.parametrize("case", list(CHUNK_CASES))
+    def test_every_part_and_the_solve(self, case, part):
+        got, want = (x[PARTS.index(part)] for x in _chunk_local(case)["fwd"])
+        assert got.shape == want.shape and got.dtype == want.dtype
+        self._close(got, want, 1e-5, 1e-6)
+
+    @pytest.mark.parametrize("part", INPUTS)
+    @pytest.mark.parametrize("case", list(CHUNK_CASES))
+    def test_all_five_cotangents(self, case, part):
+        got, want = (x[INPUTS.index(part)] for x in _chunk_local(case)["bwd"])
+        assert got.shape == want.shape and got.dtype == want.dtype
+        # dg is a difference of sums of terms the size of dq's (at the strongest
+        # decays it is 1e-3 of them): its rounding goes with theirs
+        floor = float(jnp.abs(_chunk_local(case)["bwd"][1][0]).max()) if part == "g" else 0.0
+        self._close(got, want, 2e-4, 1e-4, floor)
+
+    @pytest.mark.parametrize("case", list(CHUNK_CASES))
+    def test_the_entry_that_is_handed_t_equals_the_one_that_solves(self, case):
+        results = _chunk_local(case)
+        for again, solved in zip(results["again"][0], results["fwd"][0]):
+            np.testing.assert_array_equal(np.asarray(again), np.asarray(solved))
+
+    def test_the_solve_of_keys_all_alike_is_the_bidiagonal(self):
+        t = np.asarray(_chunk_local("keys_all_alike")["fwd"][0][-1])[0, 0, 0]
+        want = np.eye(gd._CHUNK) - np.eye(gd._CHUNK, k=-1)
+        np.testing.assert_allclose(t, want, atol=1e-4)
+
+    @pytest.mark.parametrize("which", ["fwd", "bwd"])
+    def test_bfloat16_inputs_stay_near_the_float32_form(self, which):
+        wide = _chunk_inputs("two_value_heads_a_key_head")
+        narrow = _chunk_inputs("two_value_heads_a_key_head", jnp.bfloat16)
+        parts, t, d_parts, grads = _xla_chunk_local(wide)
+        if which == "fwd":
+            got, got_t = gd._pallas_chunk_fwd(*narrow, True)
+            assert all(a.dtype == jnp.bfloat16 for a in got[:5]) and got_t.dtype == jnp.float32
+            pairs = zip((*got, got_t), (*parts, t))
+        else:
+            d_narrow = tuple(d.astype(jnp.bfloat16) for d in d_parts[:5]) + d_parts[5:]
+            got = gd._pallas_chunk_bwd(*narrow, t, d_narrow, True)
+            assert [a.dtype for a in got] == [a.dtype for a in narrow]
+            pairs = zip(got, grads)
+        for a, b in pairs:
+            a, b = np.asarray(a, np.float32), np.asarray(b)
+            assert np.linalg.norm(a - b) <= 2e-2 * np.linalg.norm(b)
+
+    def test_the_numbers_run_along_the_lanes(self):
+        """A number a position is (.., C) in HBM, never (.., C, 1): a key
+        head's ``c`` rows, then its ``beta`` rows, a chunk."""
+        _, _, _, g, beta = _chunk_inputs("two_value_heads_a_key_head")
+        numbers, gamma = gd._numbers(g, beta, 2)
+        assert numbers.shape == (2, 2, 2, 4, gd._CHUNK) and gamma.shape == (2, 4, 2)
+        c = np.cumsum(np.asarray(g).reshape(2, 2, gd._CHUNK, 4), axis=2)
+        np.testing.assert_allclose(np.asarray(numbers)[1, 1, 0, 1], c[1, 0, :, 3], rtol=1e-6)
+        np.testing.assert_array_equal(np.asarray(numbers)[0, 1, 1, 2],
+                                      np.asarray(beta)[0, gd._CHUNK:, 2])
+        np.testing.assert_allclose(np.asarray(gamma)[1, 3, 0], np.exp(c[1, 0, -1, 3]), rtol=1e-6)
+
+
 class TestDispatch:
+    def test_the_chunk_local_kernels_are_in_the_lowered_step_once_for_three_layers(
+            self, monkeypatch):
+        monkeypatch.setenv("TPUFRAME_PALLAS_INTERPRET", "1")
+        (q, k, v, g, beta), _ = _inputs(9, 1, 256, 1, 2, 128, 128, 1.0)
+
+        def three(q, k, v, g, beta):
+            for _ in range(3):
+                v = gated_delta(q, k, v, g, beta)
+            return jnp.sum(v)
+
+        text = jax.jit(jax.grad(three, (0, 1, 2, 3, 4))).lower(q, k, v, g, beta).as_text()
+        for caller, most in (("_pallas_chunk_fwd", 2), ("_pallas_chunk_again", 1),
+                             ("_pallas_chunk_bwd", 1)):
+            assert len(re.findall(rf"func.func private @{caller}(_\d+)?\(", text)) <= most
+            assert len(re.findall(rf"call @{caller}(_\d+)?\(", text)) == 3
+
     def test_the_kernels_are_in_the_lowered_step_once_for_three_layers(self, monkeypatch):
         monkeypatch.setenv("TPUFRAME_PALLAS_INTERPRET", "1")
         (q, k, v, g, beta), _ = _inputs(9, 1, 256, 1, 2, 128, 128, 1.0)

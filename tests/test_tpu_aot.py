@@ -270,7 +270,10 @@ def test_gated_delta_kernels_compile_for_v5e(one_chip, shape, dtype):
     VMEM along the chunks' axis, the products with a transposed left operand
     (``Kd^T V'``, ``M^T dO``), float32 operands whole; one forward and one
     backward kernel, and nothing kept for the backward pass the size of a
-    state a position."""
+    state a position.  And the chunk-local part: the kernel that solves (its
+    float32 products whole, through Mosaic), the entry that is handed ``T``
+    and the transpose, once each, and no float32 (.., 128, 128) array of a
+    chunk left to an XLA product or fusion."""
     from tpuframe.ops.gated_delta import gated_delta
 
     b, l, hk, h, dk, dv = shape
@@ -283,8 +286,10 @@ def test_gated_delta_kernels_compile_for_v5e(one_chip, shape, dtype):
 
     compiled = jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(*args).compile()
     text = compiled.as_text()
-    assert len(_kernel_calls(text, "tpuframe_gated_delta_fwd")) == 1
-    assert len(_kernel_calls(text, "tpuframe_gated_delta_bwd")) == 1
+    for kernel in ("tpuframe_gated_delta_fwd", "tpuframe_gated_delta_bwd", "tpuframe_delta_chunk_fwd",
+                   "tpuframe_delta_chunk_again", "tpuframe_delta_chunk_bwd"):
+        assert len(_kernel_calls(text, kernel)) == 1, kernel
+    assert not re.findall(r"= \(?f32\[[\d,]*128,128\]\S* (?:fusion|convolution|dot)\(", text)
     # a state a chunk and the chunk-local arrays, never a state a position
     assert compiled.memory_analysis().temp_size_in_bytes < b * l * h * dk * dv * 4 / 8
 
@@ -292,10 +297,11 @@ def test_gated_delta_kernels_compile_for_v5e(one_chip, shape, dtype):
 def test_qwen3_next_period_holds_its_kernels_once_a_kind(v5e_runtime):
     """A linear-attention layer twice and a gated full-attention layer of
     ``TransformerLM`` at qwen3-next's widths, the gradient of a loss over
-    its logits: the rule's two kernels lowered once and called a layer, the
-    flash and the head-norm-and-rotary pairs at 256-wide heads with 64 of
-    them turned, and what the backward pass of a linear-attention layer
-    keeps stays under a float32 copy of its fused projection."""
+    its logits: the rule's five kernels (the pass's two, the chunk-local
+    part's three) lowered once and called a layer, the flash and the
+    head-norm-and-rotary pairs at 256-wide heads with 64 of them turned, and
+    what the backward pass of a linear-attention layer keeps stays under a
+    float32 copy of its fused projection."""
     from tpuframe.models import TransformerLM
 
     mesh = v5e_runtime(1)
@@ -319,12 +325,13 @@ def test_qwen3_next_period_holds_its_kernels_once_a_kind(v5e_runtime):
         return jnp.mean(jax.nn.logsumexp(logits, axis=-1))
 
     lowered = jax.jit(jax.grad(loss)).lower(params, toks)
-    for kernel in ("tpuframe_gated_delta_fwd", "tpuframe_gated_delta_bwd", "tpuframe_flash_fwd",
-                   "tpuframe_flash_bwd"):
-        assert lowered.as_text().count(f'kernel_name = "{kernel}"') == 1
+    rule = ("tpuframe_gated_delta_fwd", "tpuframe_gated_delta_bwd", "tpuframe_delta_chunk_fwd",
+            "tpuframe_delta_chunk_again", "tpuframe_delta_chunk_bwd")
+    for kernel in (*rule, "tpuframe_flash_fwd", "tpuframe_flash_bwd"):
+        assert lowered.as_text().count(f'kernel_name = "{kernel}"') == 1, kernel
     compiled = lowered.compile()
     text = compiled.as_text()
-    for kernel, calls in (("tpuframe_gated_delta_fwd", 2), ("tpuframe_gated_delta_bwd", 2),
+    for kernel, calls in (*((kernel, 2) for kernel in rule),
                           ("tpuframe_flash_fwd", 1), ("tpuframe_flash_bwd", 1),
                           ("tpuframe_head_norm_rope_fwd", 2), ("tpuframe_head_norm_rope_bwd", 2)):
         # the instruction's own line: a kernel that reads another's output names it too

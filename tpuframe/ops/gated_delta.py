@@ -15,11 +15,23 @@ positions: the oracle, and what ``init`` runs.  Everything else runs the
 position to its i-th, ``D_ij = exp(c_i - c_j)`` on and under the diagonal,
 ``K``, ``Q``, ``V`` the chunk's rows and ``B = diag(beta)``:
 
-* inside a chunk, all chunks at once (:func:`_prepare`, plain XLA batched
-  products, differentiated by autodiff): ``T = (I + tril((B K K^T) * D,
-  -1))^-1`` in float32 by block substitution, ``U = T B V``, ``W = T (B K *
-  exp(c))``, ``Qe = Q * exp(c)``, ``Kd = K * exp(c_C - c)``, ``M = tril((Q
-  K^T) * D)`` and ``gamma = exp(c_C)``;
+* inside a chunk, no chunk waiting for another: ``T = (I + tril((B K K^T)
+  * D, -1))^-1`` in float32 by block substitution, ``U = T B V``, ``W = T (B
+  K * exp(c))``, ``Qe = Q * exp(c)``, ``Kd = K * exp(c_C - c)``, ``M =
+  tril((Q K^T) * D)`` and ``gamma = exp(c_C)``.  :func:`_prepare` is that as
+  plain XLA batched products over every chunk at once, differentiated by
+  autodiff: what the scan schedule runs, and the oracle of the kernels
+  ``tpuframe_delta_chunk_fwd`` / ``_again`` / ``_bwd``, which run it
+  wherever the pass's kernels run.  Their grid step is a key head's block
+  of chunks (every axis parallel) and a loop over them in which a chunk's
+  (C, C) arrays, the decays, ``K K^T`` and ``Q K^T`` (made once for the key
+  head's value heads), ``A``, the seven levels of the solve, never leave
+  VMEM; the model's own rows come in, the parts leave a head's rows
+  together, ``T`` leaves once in float32 (``_again``, the backward pass's
+  recomputation, is handed it and skips the solve), and ``_bwd`` is the
+  transpose written by hand (``d A = -T^T d T T^T``).  A number a position
+  (``c``, ``beta``) reaches them along the lanes, (.., C), and is made
+  down the rows inside;
 * over the chunks, one after another, the state ``S`` in float32::
 
       V' = U - W S;   O = Qe S + M V';   S <- gamma S + Kd^T V'
@@ -39,13 +51,17 @@ Backward is a ``custom_vjp`` that keeps one state a chunk boundary
 (``L / _CHUNK`` of them a head), the solve's ``T`` and the op's five
 inputs; it computes the other chunk-local arrays again, runs the pass in
 reverse (``dS`` resident in VMEM) and hands the pass's cotangents to the
-chunk-local part's own transpose (the solve's is ``-T^T dT T^T``).  Products take operands in the inputs' dtype and accumulate in
-float32; the solve, the decays and the state are float32.
+chunk-local part's own transpose (the solve's is ``-T^T dT T^T``).
+Products take operands in the inputs' dtype and accumulate in float32; the
+solve and its transpose (float32 operands whole), the decays and the
+state are float32.
 """
 
 from __future__ import annotations
 
 import functools
+import math
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -61,10 +77,10 @@ __all__ = ["gated_delta", "gated_delta_chunked", "gated_delta_reference", "chunk
 
 _LANES = 128
 #: positions a chunk.  The source's own is 64; on the v5e 128 reads better
-#: (PERF.md section 6, PR 43): the chunk-local products XLA keeps are then
-#: whole 128 x 128 tiles of the MXU (a 64-wide batched product is padded to
-#: them and costs as much), every product of the pass has 128 rows, and the
-#: pass is half as many steps
+#: (PERF.md section 6, PR 43): the chunk-local products are then whole
+#: 128 x 128 tiles of the MXU (a 64-wide product is padded to them and costs
+#: as much), every product of the pass has 128 rows, and the pass is half as
+#: many steps
 _CHUNK = 128
 #: chunks a grid step of the kernels holds
 _STEP_CHUNKS = 2
@@ -93,7 +109,7 @@ def gated_delta_reference(q, k, v, g, beta):
     return jnp.moveaxis(out, 0, 1).astype(v.dtype)
 
 
-# -- inside a chunk: plain XLA, every chunk at once -----------------------------
+# -- inside a chunk: plain XLA, every chunk at once (the scan schedule's) --------
 def _mm(spec, a, b):
     """A product that accumulates in float32; float32 operands whole."""
     return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32,
@@ -385,6 +401,262 @@ def _pallas_bwd(parts, states, do, interpret):
     return (*d, jnp.sum(d_gamma, axis=(-2, -1)))
 
 
+# -- inside a chunk: the kernels ------------------------------------------------
+#: chunks a grid step of the chunk-local kernels holds at most (a loop in the body)
+_LOCAL_CHUNKS = 4
+
+
+def _solve(a, row, col):
+    """`_inv_unit_lower` of one (C, C) array inside a kernel: the same seven
+    levels with the same float32 products (`_dot` multiplies float32
+    operands whole, at ``HIGHEST``), less the work that is known to
+    be nothing.  At the first level ``T`` is ``I``, so ``T - T a T`` is ``I -
+    a`` without a product; from blocks of 8 rows on (a float32 tile) a level
+    changes the rows of its blocks' lower halves alone, and those rows alone
+    are multiplied (priced on the chip beside whole-array products at every
+    level and beside products of the level's own block side: PERF.md
+    section 6, PR 44).  ``row``, ``col``: the (C, C) iotas."""
+    def quarters(level):
+        # ``a`` in the lower left quarters of the diagonal blocks of side 2 << level
+        inside = ((((row ^ col) >> (level + 1)) == 0)
+                  & (((row >> level) & 1) == 1) & (((col >> level) & 1) == 0))
+        return jnp.where(inside, a, 0.0)
+
+    t = (row == col).astype(jnp.float32) - quarters(0)
+    for level in range(1, _CHUNK.bit_length() - 1):
+        s, a_s = 1 << level, quarters(level)
+        if s < 8:
+            t = t - _dot(_dot(t, a_s), t)
+            continue
+        lower = range(s, _CHUNK, 2 * s)
+        low = jnp.concatenate([t[r:r + s] for r in lower], axis=0)
+        low = low - _dot(_dot(low, a_s), t)
+        t = jnp.concatenate([piece for i, r in enumerate(lower)
+                             for piece in (t[r - s:r], low[i * s:(i + 1) * s])], axis=0)
+    return t
+
+
+def _col(row, eye):
+    """A number a position along the lanes, (1, C), made down the rows, (C, 1)."""
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
+def _row(col, eye):
+    """(C, 1) -> (1, C)."""
+    return jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
+
+
+def _iotas():
+    return (lax.broadcasted_iota(jnp.int32, (_CHUNK, _CHUNK), 0),
+            lax.broadcasted_iota(jnp.int32, (_CHUNK, _CHUNK), 1))
+
+
+def _chunk_numbers(numbers_ref, j, h, group, row, col):
+    """Chunk ``j``'s numbers of the key head's value head ``h``: ``c`` and
+    ``beta`` down the rows (C, 1), made from their copies along the lanes,
+    the decays ``D`` (C, C), ``exp(c)`` and ``exp(c_C - c)`` (C, 1)."""
+    eye = row == col
+    c_row = numbers_ref[0, 0, j, pl.ds(h, 1), :]
+    c = _col(c_row, eye)
+    beta = _col(numbers_ref[0, 0, j, pl.ds(group + h, 1), :], eye)
+    decay = jnp.exp(jnp.where(row >= col, c - c_row, -jnp.inf))
+    last = jnp.sum(jnp.where(col[:1] == _CHUNK - 1, c_row, 0.0), axis=1, keepdims=True)
+    return c, beta, decay, jnp.exp(c), jnp.exp(last - c)
+
+
+def _chunk_fwd_kernel(q_ref, k_ref, v_ref, numbers_ref, *refs, chunks, group, solve):
+    """`_prepare` for ``chunks`` chunks of a key head and its ``group`` value
+    heads, a chunk's (C, C) arrays in VMEM from the decays to ``U`` and
+    ``W``.  ``solve`` False is the entry that is handed ``T``."""
+    if solve:
+        u_ref, w_ref, qe_ref, kd_ref, m_ref, t_ref = refs
+    else:
+        t_ref, u_ref, w_ref, qe_ref, kd_ref, m_ref = refs
+    dtype = v_ref.dtype
+    dv = v_ref.shape[-1] // group
+    row, col = _iotas()
+
+    def chunk(j, carry):
+        rows = pl.ds(pl.multiple_of(j * _CHUNK, _CHUNK), _CHUNK)
+        q, k = q_ref[0, rows, :], k_ref[0, rows, :]
+        kk, qk = _dot(k, k, _NT), _dot(q, k, _NT)  # once a key head
+        qf, kf = q.astype(jnp.float32), k.astype(jnp.float32)
+        for h in range(group):
+            c, beta, decay, ec, el = _chunk_numbers(numbers_ref, j, h, group, row, col)
+            if solve:
+                t = _solve(jnp.where(row > col, beta * kk * decay, 0.0), row, col)
+                t_ref[0, h, j] = t
+            else:
+                t = t_ref[0, h, j]
+            tb = t.astype(dtype)
+            vf = v_ref[0, rows, h * dv:(h + 1) * dv].astype(jnp.float32)
+            u_ref[0, h, rows, :] = _dot(tb, (beta * vf).astype(dtype)).astype(dtype)
+            w_ref[0, h, rows, :] = _dot(tb, (beta * ec * kf).astype(dtype)).astype(dtype)
+            qe_ref[0, h, rows, :] = (qf * ec).astype(dtype)
+            kd_ref[0, h, rows, :] = (kf * el).astype(dtype)
+            m_ref[0, h, rows, :] = (qk * decay).astype(dtype)
+        return carry
+
+    lax.fori_loop(0, chunks, chunk, 0)
+
+
+def _chunk_bwd_kernel(q_ref, k_ref, v_ref, numbers_ref, t_ref, du_ref, dw_ref, dqe_ref, dkd_ref,
+                      dm_ref, dq_ref, dk_ref, dv_ref, dnumbers_ref, *, chunks, group):
+    """The transpose of `_chunk_fwd_kernel`, a chunk at a time: the
+    cotangents of ``q`` and ``k`` summed over the key head's value heads, of
+    ``v``, and of ``c`` and ``beta`` along the lanes as they came."""
+    dtype = v_ref.dtype
+    dv = v_ref.shape[-1] // group
+    row, col = _iotas()
+    eye = row == col
+    rowsum = lambda a: jnp.sum(a, axis=1, keepdims=True)  # noqa: E731
+
+    def chunk(j, carry):
+        rows = pl.ds(pl.multiple_of(j * _CHUNK, _CHUNK), _CHUNK)
+        q, k = q_ref[0, rows, :], k_ref[0, rows, :]
+        kk, qk = _dot(k, k, _NT), _dot(q, k, _NT)
+        qf, kf = q.astype(jnp.float32), k.astype(jnp.float32)
+        d_kk = d_qk = jnp.zeros_like(kk)
+        dq = dk = jnp.zeros_like(kf)
+        for h in range(group):
+            c, beta, decay, ec, el = _chunk_numbers(numbers_ref, j, h, group, row, col)
+            t = t_ref[0, h, j]
+            tb = t.astype(dtype)
+            lanes = slice(h * dv, (h + 1) * dv)
+            vf = v_ref[0, rows, lanes].astype(jnp.float32)
+            bec = beta * ec
+            du, dw = du_ref[0, h, rows, :], dw_ref[0, h, rows, :]
+            # U = T (B V), W = T (B K e^c): into T, and through the inverse into A
+            d_t = (_dot(du, (beta * vf).astype(dtype), _NT)
+                   + _dot(dw, (bec * kf).astype(dtype), _NT))
+            d_a = jnp.where(row > col, -_dot(_dot(t, d_t, _TN), t, _NT), 0.0)
+            # A = beta K K^T D under the diagonal, M = Q K^T D on and under it
+            x, y = d_a * decay, dm_ref[0, h, rows, :].astype(jnp.float32) * decay
+            p = x * kk
+            d_kk, d_qk = d_kk + beta * x, d_qk + y
+            e = beta * p + y * qk  # the cotangent of c_i - c_j
+            d_c = rowsum(e) - _col(jnp.sum(e, axis=0, keepdims=True), eye)
+            d_bv, d_bke = _dot(tb, du, _TN), _dot(tb, dw, _TN)
+            dv_ref[0, rows, lanes] = (beta * d_bv).astype(dtype)
+            r = rowsum(d_bke * kf)
+            d_beta = rowsum(p) + rowsum(d_bv * vf) + r * ec
+            d_qe = dqe_ref[0, h, rows, :].astype(jnp.float32)
+            d_kd = dkd_ref[0, h, rows, :].astype(jnp.float32)
+            dq = dq + d_qe * ec
+            dk = dk + bec * d_bke + d_kd * el
+            z = rowsum(d_kd * kf) * el  # Kd = K exp(c_C - c): c_C is the last row's c
+            d_c = (d_c + (r * beta + rowsum(d_qe * qf)) * ec - z
+                   + jnp.where(row[:, :1] == _CHUNK - 1, jnp.sum(z, axis=0, keepdims=True), 0.0))
+            dnumbers_ref[0, 0, j, pl.ds(h, 1), :] = _row(d_c, eye)
+            dnumbers_ref[0, 0, j, pl.ds(group + h, 1), :] = _row(d_beta, eye)
+        d_kk, d_qk = d_kk.astype(dtype), d_qk.astype(dtype)
+        dq_ref[0, rows, :] = (dq + _dot(d_qk, k)).astype(dtype)
+        dk_ref[0, rows, :] = (dk + _dot(d_qk, q, _TN) + _dot(d_kk, k)
+                              + _dot(d_kk, k, _TN)).astype(dtype)
+        return carry
+
+    lax.fori_loop(0, chunks, chunk, 0)
+
+
+def _numbers(g, beta, hk):
+    """The per-position numbers as the chunk-local kernels read them, a
+    number a position along the lanes: (B, L, H) -> (B, Hk, N, 2 G, C)
+    float32, a chunk's ``c`` (the sum of ``g`` from its first position) a
+    value head of the key head, then its ``beta`` a value head; and
+    ``gamma`` (B, H, N).  XLA's, over (B, L, H) numbers, and differentiated
+    by autodiff (the reverse prefix sum from ``dc`` to ``dg`` is in it)."""
+    b, length, h = g.shape
+    group, n = h // hk, length // _CHUNK
+    lanes = lambda a: jnp.transpose(  # noqa: E731
+        a.astype(jnp.float32).reshape(b, n, _CHUNK, hk, group), (0, 3, 1, 4, 2))
+    c = jnp.cumsum(lanes(g), axis=-1)
+    gamma = jnp.swapaxes(jnp.exp(c[..., -1]), 2, 3).reshape(b, h, n)
+    return jnp.concatenate([c, lanes(beta)], axis=3), gamma
+
+
+def _chunk_call(kernel, name, q, v, interpret):
+    """-> ``pl.pallas_call`` with one chunk-local kernel on the grid (rows,
+    key heads, blocks of chunks), every axis parallel, and its block specs:
+    ``key`` and ``value`` a key head's lanes of the model's rows (B, L,
+    heads * width), ``numbers``, ``head(width)`` the key head's value heads of
+    a (B, H, L, width) array and ``solved`` of ``T`` (B, H, N, C, C)."""
+    b, length, hk, dk = q.shape
+    h, dv = v.shape[2:]
+    group, n = h // hk, length // _CHUNK
+    chunks = math.gcd(n, _LOCAL_CHUNKS)
+    rows = chunks * _CHUNK
+    model = lambda width: pl.BlockSpec((1, rows, width), lambda b, h, i: (b, i, h))  # noqa: E731
+    heads = lambda *block: pl.BlockSpec(  # noqa: E731
+        (1, group) + block, lambda b, h, i: (b, h, i) + (0,) * (len(block) - 1))
+    specs = SimpleNamespace(
+        key=model(dk), value=model(group * dv),
+        numbers=pl.BlockSpec((1, 1, chunks, 2 * group, _CHUNK), lambda b, h, i: (b, h, i, 0, 0)),
+        head=lambda width: heads(rows, width), solved=heads(chunks, _CHUNK, _CHUNK))
+    call = functools.partial(
+        pl.pallas_call, functools.partial(kernel, chunks=chunks, group=group),
+        grid=(b, hk, n // chunks),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3, vmem_limit_bytes=_VMEM_BYTES),
+        interpret=interpret, name=name)
+    return call, specs
+
+
+def _model_rows(*arrays):
+    """(B, L, heads, width) -> (B, L, heads * width): no copy."""
+    return tuple(a.reshape(a.shape[:2] + (-1,)) for a in arrays)
+
+
+def _chunk_parts(q, k, v, g, beta, t, interpret):
+    """`_prepare` as a kernel: with ``t`` None the one that solves (-> the
+    parts and ``T``), else the one that is handed ``T`` (-> the parts)."""
+    b, length, hk, dk = q.shape
+    h, dv = v.shape[2:]
+    call, s = _chunk_call(functools.partial(_chunk_fwd_kernel, solve=t is None),
+                          "tpuframe_delta_chunk_" + ("fwd" if t is None else "again"),
+                          q, v, interpret)
+    numbers, gamma = _numbers(g, beta, hk)
+    row = lambda width: jax.ShapeDtypeStruct((b, h, length, width), v.dtype)  # noqa: E731
+    parts = (row(dv), row(dk), row(dk), row(dk), row(_CHUNK))
+    part_specs = (s.head(dv), s.head(dk), s.head(dk), s.head(dk), s.head(_CHUNK))
+    solved = jax.ShapeDtypeStruct((b, h, length // _CHUNK, _CHUNK, _CHUNK), jnp.float32)
+    own = [s.key, s.key, s.value, s.numbers]
+    if t is None:
+        *parts, t = call(out_shape=parts + (solved,), in_specs=own,
+                         out_specs=part_specs + (s.solved,))(*_model_rows(q, k, v), numbers)
+        return (*parts, gamma), t
+    parts = call(out_shape=parts, in_specs=own + [s.solved],
+                 out_specs=part_specs)(*_model_rows(q, k, v), numbers, t)
+    return (*parts, gamma)
+
+
+# Jitted like the pass's callers: one trace and one lowering for a model's layers.
+@functools.partial(jax.jit, static_argnums=(5,))
+def _pallas_chunk_fwd(q, k, v, g, beta, interpret):
+    return _chunk_parts(q, k, v, g, beta, None, interpret)
+
+
+@functools.partial(jax.jit, static_argnums=(6,))
+def _pallas_chunk_again(q, k, v, g, beta, t, interpret):
+    return _chunk_parts(q, k, v, g, beta, t, interpret)
+
+
+@functools.partial(jax.jit, static_argnums=(7,))
+def _pallas_chunk_bwd(q, k, v, g, beta, t, d_parts, interpret):
+    """The cotangents of the op's five inputs from those of the parts."""
+    *d_rows, d_gamma = d_parts
+    call, s = _chunk_call(_chunk_bwd_kernel, "tpuframe_delta_chunk_bwd", q, v, interpret)
+    (numbers, _), transpose = jax.vjp(lambda g, beta: _numbers(g, beta, q.shape[2]), g, beta)
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
+    rows = _model_rows(q, k, v)
+    dq, dk, dv, d_numbers = call(
+        out_shape=tuple(like(a) for a in (*rows, numbers)),
+        in_specs=[s.key, s.key, s.value, s.numbers, s.solved,
+                  *(s.head(a.shape[-1]) for a in d_rows)],
+        out_specs=(s.key, s.key, s.value, s.numbers))(*rows, numbers, t, *d_rows)
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
+            *transpose((d_numbers, d_gamma)))
+
+
 # -- the op ---------------------------------------------------------------------
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def _rule(q, k, v, g, beta, interpret):
@@ -394,24 +666,32 @@ def _rule(q, k, v, g, beta, interpret):
 
 
 def _rule_fwd(q, k, v, g, beta, interpret):
-    parts, t = _prepare(q, k, v, g, beta)
-    o, states = _scan_fwd(parts) if interpret is None else _pallas_fwd(parts, interpret)
+    if interpret is None:
+        parts, t = _prepare(q, k, v, g, beta)
+        o, states = _scan_fwd(parts)
+    else:
+        parts, t = _pallas_chunk_fwd(q, k, v, g, beta, interpret)
+        o, states = _pallas_fwd(parts, interpret)
     # (B, H, L, dv) -> the model's (B, L, H, dv)
     return jnp.swapaxes(o, 1, 2).astype(v.dtype), (q, k, v, g, beta, t, states)
 
 
 def _rule_bwd(interpret, residuals, do):
     *inputs, t, states = residuals
-    # the chunk-local arrays again (all but the solve, whose result was
-    # kept), and only now: without the barrier XLA sees the forward pass's
-    # own computation of them, merges the two and keeps every intermediate
-    # of every layer alive across the step
-    inputs, t, do = lax.optimization_barrier((inputs, t, do))
-    parts, transpose = jax.vjp(lambda *a: _prepare(*a, t=t)[0], *inputs)
+    if interpret is None:
+        # the chunk-local arrays again (all but the solve, whose result was
+        # kept), and only now: without the barrier XLA sees the forward
+        # pass's own computation of them, merges the two and keeps every
+        # intermediate of every layer alive across the step
+        inputs, t, do = lax.optimization_barrier((inputs, t, do))
+        parts, transpose = jax.vjp(lambda *a: _prepare(*a, t=t)[0], *inputs)
+        return transpose(tuple(_scan_bwd(parts, states, jnp.swapaxes(do, 1, 2))))
+    # a kernel of its own computes them again: nothing for XLA to merge, and
+    # no barrier (the step's temporaries are 0.05 GiB lower without it)
     do = jnp.swapaxes(do, 1, 2)
-    d_parts = (_scan_bwd(parts, states, do) if interpret is None
-               else _pallas_bwd(parts, states, do, interpret))
-    return transpose(tuple(d_parts))
+    parts = _pallas_chunk_again(*inputs, t, interpret)
+    d_parts = _pallas_bwd(parts, states, do, interpret)
+    return _pallas_chunk_bwd(*inputs, t, d_parts, interpret)
 
 
 _rule.defvjp(_rule_fwd, _rule_bwd)
