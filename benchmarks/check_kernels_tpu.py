@@ -27,7 +27,7 @@ their oracles' XLA fusions), and the grouped products of the expert layer
 Usage: python benchmarks/check_kernels_tpu.py [--only a,b,...]
 (exits 1 on any failure).  ``--only`` runs a named subset — sections:
 layer_norm, cross_entropy, quant_wire, blockwise, flash_layout, window, ring,
-ulysses, moe_windows, short_conv, head_norm_rope, grouped, gated_delta
+ulysses, moe_windows, short_conv, conv_silu, head_norm_rope, grouped, gated_delta
 (``--grouped-tiles 128,256,512`` prices other row tiles beside the default).
 """
 
@@ -63,6 +63,7 @@ def main() -> None:
         "ulysses": _check_ulysses,
         "moe_windows": _check_moe_windows,
         "short_conv": _check_short_conv,
+        "conv_silu": _check_conv_silu,
         "head_norm_rope": _check_head_norm_rope,
         "grouped": _check_grouped,
         "gated_delta": _check_gated_delta,
@@ -488,8 +489,6 @@ def _check_short_conv(jax, jnp, np, rng) -> None:
     ``lfm2-8b-a1b``'s shape (2 rows of 4096 positions, 2048 wide, 3 taps),
     where both forms are also timed, forward + backward a call (a line of
     its own; the time passes or fails nothing)."""
-    import time
-
     from tpuframe.ops.short_conv import short_conv, short_conv_reference
 
     def both(op):
@@ -509,19 +508,67 @@ def _check_short_conv(jax, jnp, np, rng) -> None:
         g = jnp.asarray(rng.standard_normal((b, l, d)), dtype)
         for part, got, want in zip(("out", "dx", "dw"), kernel(x, w, g), oracle(x, w, g)):
             record(f"short_conv_{name}_{part}", rel(got, want), tol)
-    times = {}
-    for form, fn in (("kernels", kernel), ("oracle", oracle)):
-        jax.block_until_ready(fn(x, w, g))
-        laps = []
-        for _ in range(5):
-            t0 = time.perf_counter()
-            for _ in range(20):
-                out = fn(x, w, g)
-            jax.block_until_ready(out)
-            laps.append((time.perf_counter() - t0) / 20)
-        times[form] = 1e3 * sorted(laps)[len(laps) // 2]
+    times = {form: _ms_a_call(jax, fn, (x, w, g))
+             for form, fn in (("kernels", kernel), ("oracle", oracle))}
     print(json.dumps({"check": "short_conv_ms_a_call_fwd_bwd", "shape": [b, l, d, k],
                       **times}), flush=True)
+
+
+def _check_conv_silu(jax, jnp, np, rng) -> None:
+    """What the gated delta rule reads (`ops.short_conv.conv_silu`: four
+    taps, SiLU, unit queries and keys) against its oracle, the three outputs
+    and both gradients: float32 at a cut shape whose length is no tile
+    multiple, and bfloat16 at ``qwen3-next-80b-a3b-instruct``'s (one row of
+    8192 positions, the first 8192 of the fused projection's 12288 columns,
+    16 key heads and 32 value heads of 128), where both forms are timed, the
+    forward alone and forward + backward a call (lines of their own; a time
+    passes or fails nothing)."""
+    from tpuframe.ops.short_conv import conv_silu, conv_silu_reference
+
+    def forms(op, hk):
+        fwd = functools.partial(op, key_heads=hk, key_dim=128)
+
+        def both(x, w, gs):
+            out, vjp = jax.vjp(fwd, x, w)
+            return out + vjp(gs)
+        return jax.jit(fwd), jax.jit(both)
+
+    rel = lambda a, b: float(jnp.linalg.norm((a - b).astype(jnp.float32))  # noqa: E731
+                             / jnp.linalg.norm(b.astype(jnp.float32)))
+    for name, (b, l, width, hk, hv), dtype, tol in (
+            ("f32_ragged", (2, 600, 1536, 2, 4), jnp.float32, 1e-5),
+            ("bf16_qwen3next", (1, 8192, 12288, 16, 32), jnp.bfloat16, 2e-2)):
+        keys, channels = hk * 128, (2 * hk + hv) * 128
+        x = jnp.asarray(rng.standard_normal((b, l, width)), dtype)
+        w = jnp.asarray(0.5 * rng.standard_normal((4, channels)), jnp.float32)
+        gs = tuple(jnp.asarray(rng.standard_normal((b, l, n)), dtype)
+                   for n in (keys, keys, channels - 2 * keys))
+        kernel = forms(functools.partial(conv_silu, interpret=False), hk)
+        oracle = forms(conv_silu_reference, hk)
+        for part, got, want in zip(("q", "k", "v", "dx", "dw"),
+                                   kernel[1](x, w, gs), oracle[1](x, w, gs)):
+            record(f"conv_silu_{name}_{part}", rel(got, want), tol)
+    times = {}
+    for form, (fwd, both) in (("kernels", kernel), ("oracle", oracle)):
+        times[form + "_fwd"] = _ms_a_call(jax, fwd, (x, w))
+        times[form + "_fwd_bwd"] = _ms_a_call(jax, both, (x, w, gs))
+    print(json.dumps({"check": "conv_silu_ms_a_call", "shape": [b, l, width, hk, hv],
+                      **times}), flush=True)
+
+
+def _ms_a_call(jax, fn, args, calls=20, laps=5) -> float:
+    """The median of ``laps`` laps of ``calls`` calls, in ms a call."""
+    import time
+
+    jax.block_until_ready(fn(*args))
+    took = []
+    for _ in range(laps):
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        took.append((time.perf_counter() - t0) / calls)
+    return 1e3 * sorted(took)[len(took) // 2]
 
 
 def _check_head_norm_rope(jax, jnp, np, rng) -> None:

@@ -13,9 +13,9 @@ import jax.numpy as jnp
 import pytest
 
 # by module path: tpuframe.ops re-exports functions under some of these names
-ce, ln, qw = (
+ce, ln, qw, sc = (
     importlib.import_module(f"tpuframe.ops.{m}")
-    for m in ("cross_entropy", "layer_norm", "quant_wire")
+    for m in ("cross_entropy", "layer_norm", "quant_wire", "short_conv")
 )
 
 _F32 = jnp.float32
@@ -52,6 +52,15 @@ KERNELS = {
     "tpuframe_quant_decode": (
         lambda t, a: qw._pallas_decode(t, a, "int8", 8, False),
         (_x(8, 2048, dtype=jnp.int32), _x(8, 1)),
+    ),
+    # one key head and two value heads of 128, 256 columns behind them
+    "tpuframe_conv_silu_fwd": (
+        lambda x, w: sc._conv_silu_fwd_pallas(x, w, 1, 128, False),
+        (_x(1, 64, 768), _x(4, 512)),
+    ),
+    "tpuframe_conv_silu_bwd": (
+        lambda x, w, *gs: sc._conv_silu_bwd_pallas(x, w, gs, 1, 128, False),
+        (_x(1, 64, 768), _x(4, 512), _x(1, 64, 128), _x(1, 64, 128), _x(1, 64, 256)),
     ),
 }
 

@@ -21,6 +21,7 @@ from tpuframe.models import TransformerLM, moe_rules, transformer_tp_rules
 from tpuframe.models import transformer as tr
 from tpuframe.models.moe import MoEMLP
 from tpuframe.ops.head_norm_rope import head_norm_rope, head_norm_rope_reference
+from tpuframe.ops.short_conv import causal_taps
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "qwen3-next-80b-a3b-instruct"
@@ -156,9 +157,9 @@ class TestLayersAgainstPlainJnp:
     def test_causal_taps_are_the_references_and_causal(self):
         u = jax.random.normal(jax.random.PRNGKey(3), (2, 20, 6))
         w = jax.random.normal(jax.random.PRNGKey(4), (4, 6))
-        np.testing.assert_allclose(np.asarray(tr.causal_taps(u, w)), np.asarray(REF._taps(u, w)),
+        np.testing.assert_allclose(np.asarray(causal_taps(u, w)), np.asarray(REF._taps(u, w)),
                                    rtol=1e-6, atol=1e-6)
-        moved = np.asarray(tr.causal_taps(u.at[0, 7].add(1.0), w) - tr.causal_taps(u, w))
+        moved = np.asarray(causal_taps(u.at[0, 7].add(1.0), w) - causal_taps(u, w))
         assert not moved[0, :7].any() and moved[0, 7:11].all() and not moved[0, 11:].any()
 
     def test_the_expert_layer_with_the_gated_shared_expert(self, seeded):

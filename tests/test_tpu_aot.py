@@ -1,5 +1,6 @@
 """The flash-attention kernels, the placements ``attn_impl="auto"``
-gives them, the short-convolution and head-norm-and-rotary pairs, and the
+gives them, the short-convolution pair and the pair of what the gated delta
+rule reads, the head-norm-and-rotary pair, and the
 expert layer's grouped products, compiled for a described v5e: no chip, the
 TPU's own compiler (Mosaic refuses here what it would refuse there: a
 tile that does not fit VMEM, a block it cannot lay out, a precision it
@@ -223,6 +224,38 @@ def test_short_conv_kernels_compile_for_v5e(one_chip, shape, dtype):
 
 
 @pytest.mark.parametrize("shape, dtype", [
+    ((1, 8192, 12288, 16, 32), jnp.bfloat16),   # qwen3-next's: the first 8192 of the fused 12288 columns
+    ((2, 600, 1536, 2, 4), jnp.float32),        # check_kernels_tpu's: a length that is no tile multiple
+    ((1, 1024, 16384, 32, 64), jnp.float32),    # float32 rows twice as wide: a tile of 128 rows
+], ids=["qwen3next", "f32_ragged", "f32_wide"])
+def test_conv_silu_kernels_compile_for_v5e(one_chip, shape, dtype):
+    """What the gated delta rule reads: a block of the fused array's first
+    columns (what lies behind them never fetched), three outputs of the
+    model's rows, a lane reduction a key head, the 16-row views of a tile's
+    neighbours in both directions and the taps' gradient resident across the
+    grid; one kernel each way, and nothing kept for the backward pass but the
+    input."""
+    from tpuframe.ops.short_conv import conv_silu
+
+    b, l, width, hk, hv = shape
+    channels = (2 * hk + hv) * 128
+    x = jax.ShapeDtypeStruct((b, l, width), dtype, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((4, channels), jnp.float32, sharding=one_chip)
+
+    def loss(x, w):
+        return sum(jnp.sum(o.astype(jnp.float32) ** 2)
+                   for o in conv_silu(x, w, key_heads=hk, key_dim=128, interpret=False))
+
+    compiled = jax.jit(jax.grad(loss, (0, 1))).lower(x, w).compile()
+    text = compiled.as_text()
+    assert len(_kernel_calls(text, "tpuframe_conv_silu_fwd")) == 1
+    assert len(_kernel_calls(text, "tpuframe_conv_silu_bwd")) == 1
+    # the three outputs, their squares' gradients and the 8192 columns' cotangent
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.1 * b * l * channels * jnp.dtype(
+        dtype).itemsize
+
+
+@pytest.mark.parametrize("shape, dtype", [
     ((1, 8192, 32, 128), jnp.bfloat16),   # sdar-30b-a3b-chat's query projection: a head a vreg column
     ((1, 8192, 4, 128), jnp.bfloat16),    # ... and its key projection
     ((2, 4096, 32, 64), jnp.bfloat16),    # lfm2-8b-a1b's: two heads side by side in 128 lanes
@@ -298,7 +331,8 @@ def test_qwen3_next_period_holds_its_kernels_once_a_kind(v5e_runtime):
     """A linear-attention layer twice and a gated full-attention layer of
     ``TransformerLM`` at qwen3-next's widths, the gradient of a loss over
     its logits: the rule's five kernels (the pass's two, the chunk-local
-    part's three) lowered once and called a layer, the flash and the
+    part's three) and the pair that makes what it reads from the fused
+    projection's output lowered once and called a layer, the flash and the
     head-norm-and-rotary pairs at 256-wide heads with 64 of them turned, and
     what the backward pass of a linear-attention layer keeps stays under a
     float32 copy of its fused projection."""
@@ -326,7 +360,8 @@ def test_qwen3_next_period_holds_its_kernels_once_a_kind(v5e_runtime):
 
     lowered = jax.jit(jax.grad(loss)).lower(params, toks)
     rule = ("tpuframe_gated_delta_fwd", "tpuframe_gated_delta_bwd", "tpuframe_delta_chunk_fwd",
-            "tpuframe_delta_chunk_again", "tpuframe_delta_chunk_bwd")
+            "tpuframe_delta_chunk_again", "tpuframe_delta_chunk_bwd",
+            "tpuframe_conv_silu_fwd", "tpuframe_conv_silu_bwd")
     for kernel in (*rule, "tpuframe_flash_fwd", "tpuframe_flash_bwd"):
         assert lowered.as_text().count(f'kernel_name = "{kernel}"') == 1, kernel
     compiled = lowered.compile()
@@ -336,6 +371,11 @@ def test_qwen3_next_period_holds_its_kernels_once_a_kind(v5e_runtime):
                           ("tpuframe_head_norm_rope_fwd", 2), ("tpuframe_head_norm_rope_bwd", 2)):
         # the instruction's own line: a kernel that reads another's output names it too
         assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == calls, kernel
+    # nothing of the convolution's float32 (8192, 8192) arrays, nor of q and k
+    # sliced out of one and brought to heads for the norm, is left to XLA
+    # (the gated attention layer's fused query projection is 8192 wide too)
+    assert not [line for line in text.splitlines() if "/deltanet/" in line
+                and ("f32[1,8192,8192]" in line or "f32[1024,8,16,128]" in line)]
     # two linear-attention layers' residuals and one's transients: with every
     # chunk-local array of every layer kept (no barrier before the backward
     # pass computes them again) this read 7.5 GiB for two layers

@@ -53,7 +53,12 @@ from tpuframe.ops.ring_attention import (
     ring_attention_local,
 )
 from tpuframe.ops.layer_norm import FusedLayerNorm
-from tpuframe.ops.short_conv import short_conv, short_conv_reference
+from tpuframe.ops.short_conv import (
+    conv_silu,
+    conv_silu_reference,
+    short_conv,
+    short_conv_reference,
+)
 from tpuframe.ops.ulysses import ulysses_attention_local
 from tpuframe.track.telemetry import get_telemetry
 
@@ -502,46 +507,33 @@ def _a_log_init(key, shape, dtype=jnp.float32):
     return jnp.log(jax.random.uniform(key, shape, dtype, minval=1e-3, maxval=16.0))
 
 
-def causal_taps(u: jax.Array, w: jax.Array) -> jax.Array:
-    """A depthwise causal convolution along the sequence: ``c_t = sum_j w_j
-    u_{t-(K-1)+j}`` of ``u`` (B, L, D) under the taps ``w`` (K, D), zeros
-    before the row, as the sum of ``K`` shifted products in float32.  XLA
-    makes one fusion of it and the activation that follows."""
-    taps, length = w.shape[0], u.shape[1]
-    # padded as stored: the one array the fusion reads, not a float32 copy
-    u = jnp.pad(u, ((0, 0), (taps - 1, 0), (0, 0)))
-    w32 = w.astype(jnp.float32)
-    return sum(w32[j] * u[:, j:j + length].astype(jnp.float32) for j in range(taps))
-
-
-def _deltanet_inputs(qkv, ba, w, a_log, dt_bias, hk, hv, dk, dtype):
-    """What the rule reads, from the fused projections' outputs: the
-    convolution and SiLU on ``[q | k | v]``, unit queries and keys, ``beta``
-    and ``g``.  Elementwise but for the taps, so the backward pass computes
-    it again from the projections' outputs (`jax.checkpoint`) and keeps none
-    of its float32 intermediates: 8192 channels of 8192 positions each."""
-    b, l, _ = qkv.shape
-    keys = hk * dk
-    # the taps' float32 sum rounded to the storage dtype before the SiLU, as
-    # the source's convolution leaves it: what the backward pass keeps of
-    # this function's 8192 channels is then half as wide
-    qkv = nn.silu(causal_taps(qkv, w).astype(dtype).astype(jnp.float32))
-    q, k = (qkv[..., i * keys:(i + 1) * keys].reshape(b, l, hk, dk) for i in (0, 1))
-    unit = lambda a: a * jax.lax.rsqrt(  # noqa: E731
-        jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
-    v = qkv[..., 2 * keys:].reshape(b, l, hv, -1)
+def _deltanet_decay(ba, a_log, dt_bias, hv):
+    """``g`` and ``beta`` a position and value head from the ``[b | a]``
+    projection's float32 output: a few small arrays, computed again in the
+    backward pass (`jax.checkpoint`)."""
     beta = jax.nn.sigmoid(ba[..., :hv])
     g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
         ba[..., hv:] + dt_bias.astype(jnp.float32))
-    return (unit(q) * dk ** -0.5).astype(dtype), unit(k).astype(dtype), v.astype(dtype), g, beta
+    return g, beta
 
 
-def _deltanet_gate(o, z, scale, eps, dtype):
+def _deltanet_gate(o, qkvz, scale, eps, dtype):
     """The gated norm on the rule's output: ``o / rms(o) * scale * silu(z)``
-    a head, float32 inside; computed again in the backward pass too."""
-    o32 = o.astype(jnp.float32)
+    a head, float32 inside; computed again in the backward pass too.  ``z``
+    is the last columns of the fused projection's output, sliced here and
+    not before: what a layer keeps for its backward pass is then that output
+    itself, once, which `conv_silu` keeps too, and no copy of a part of it.
+    Written on views that split the sequence into a tile's 8 positions: on
+    plain (B, L, heads, width) views XLA converts ``z`` to float32 and copies
+    that to ``o``'s layout, a head's rows together, each way (AOT compiles,
+    PR 45); on these it reads ``z``'s tiles where they lie."""
+    b, l, hv, dv = o.shape
+    tiles = (b, l // 8, 8, hv, dv) if l % 8 == 0 else o.shape
+    z = qkvz[..., qkvz.shape[-1] - hv * dv:].reshape(tiles)
+    o32 = o.reshape(tiles).astype(jnp.float32)
     o32 = o32 * jax.lax.rsqrt(jnp.mean(o32 * o32, axis=-1, keepdims=True) + eps)
-    return (o32 * scale.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))).astype(dtype)
+    y = (o32 * scale.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))).astype(dtype)
+    return y.reshape(o.shape)
 
 
 class GatedDeltaNet(nn.Module):
@@ -581,8 +573,16 @@ class GatedDeltaNet(nn.Module):
             a_log = self.param("A_log", _a_log_init, (hv,))
             dt_bias = self.param("dt_bias", nn.initializers.ones, (hv,))
             scale = self.param("norm", nn.initializers.ones, (dv,))
-            q, k, v, g, beta = jax.checkpoint(_deltanet_inputs, static_argnums=(5, 6, 7, 8))(
-                qkvz[..., :2 * keys + values], ba, w, a_log, dt_bias, hk, hv, dk, self.dtype)
+            # the convolution, SiLU and unit norms of [q | k | v], read in
+            # place from the fused output; init's sample batch need not
+            # divide the mesh
+            inputs = conv_silu_reference if self.is_initializing() else functools.partial(
+                conv_silu, mesh=_mesh_or_none())
+            q, k, v = inputs(qkvz, w, key_heads=hk, key_dim=dk)
+            q, k = q.reshape(b, l, hk, dk), k.reshape(b, l, hk, dk)
+            v = v.reshape(b, l, hv, dv)
+            g, beta = jax.checkpoint(_deltanet_decay, static_argnums=(3,))(
+                ba, a_log, dt_bias, hv)
             with jax.named_scope("tpuframe/deltanet/rule"):
                 if self.is_initializing():
                     # init's sample batch need not divide the mesh
@@ -594,9 +594,8 @@ class GatedDeltaNet(nn.Module):
                     registry = get_telemetry().registry
                     registry.counter("deltanet/chunks").inc(chunks_walked(b, l, hv))
                     registry.counter("deltanet/calls").inc(1)
-            z = qkvz[..., 2 * keys + values:].reshape(b, l, hv, dv)
             y = jax.checkpoint(_deltanet_gate, static_argnums=(3, 4))(
-                o, z, scale, self.norm_eps, self.dtype)
+                o, qkvz, scale, self.norm_eps, self.dtype)
             return dense(d, "out_proj")(y.reshape(b, l, values))
 
 

@@ -21,6 +21,10 @@ fallback and the correctness oracle for tests.
 - :func:`short_conv` — LFM2's double-gated short convolution between
   its two projections (gate, a few causal depthwise taps along the
   sequence, gate) in one pass each way.
+- :func:`conv_silu` — what Qwen3-Next's gated delta rule reads, the
+  second entry of the same module: causal depthwise taps over the
+  ``[q | k | v]`` columns of a fused projection's output, SiLU, and unit
+  queries and keys a head, in one pass each way.
 - :func:`head_norm_rope` — an RMSNorm on every head of a query or key
   projection's rows and the rotary turn behind it, in one pass each way
   (XLA makes of the two a handful of float32 passes over a
@@ -69,6 +73,8 @@ _LAZY = {
     "ring_attention_local": "tpuframe.ops.ring_attention",
     "short_conv": "tpuframe.ops.short_conv",
     "short_conv_reference": "tpuframe.ops.short_conv",
+    "conv_silu": "tpuframe.ops.short_conv",
+    "conv_silu_reference": "tpuframe.ops.short_conv",
     "head_norm_rope": "tpuframe.ops.head_norm_rope",
     "head_norm_rope_reference": "tpuframe.ops.head_norm_rope",
     "gated_delta": "tpuframe.ops.gated_delta",

@@ -97,6 +97,12 @@ OPS_REGISTRY = {
         "reference": "short_conv_reference",
         "parity_test": "tests/test_lfm2.py::TestShortConvOp::test_kernels_match_the_oracle",
     },
+    "conv_silu": {
+        "module": "tpuframe.ops.short_conv",
+        "symbol": "conv_silu",
+        "reference": "conv_silu_reference",
+        "parity_test": "tests/test_conv_silu.py::TestConvSiluOp::test_kernels_match_the_oracle",
+    },
     "head_norm_rope": {
         "module": "tpuframe.ops.head_norm_rope",
         "symbol": "head_norm_rope",
@@ -133,6 +139,7 @@ OP_NAME_TOKENS = (
     ("quant_wire", ("quant", "dequant", "stochastic_round")),
     ("attention", ("attention", "flash", "fmha", "scaled_dot_product")),
     ("short_conv", ("short_conv",)),
+    ("conv_silu", ("conv_silu",)),
     ("head_norm_rope", ("head_norm_rope",)),
     ("gated_delta", ("gated_delta",)),
     ("grouped_matmul", ("tpuframe_grouped", "ragged-dot", "ragged_dot", "grouped_matmul")),
