@@ -52,34 +52,18 @@ def record(check: str, diff: float, tol: float) -> None:
 
 
 def main() -> None:
-    sections = {
-        "layer_norm": _check_layer_norm,
-        "cross_entropy": _check_cross_entropy,
-        "quant_wire": _check_quant_wire,
-        "blockwise": _check_blockwise,
-        "flash_layout": _check_flash_layout,
-        "window": _check_window,
-        "ring": _check_ring,
-        "ulysses": _check_ulysses,
-        "moe_windows": _check_moe_windows,
-        "short_conv": _check_short_conv,
-        "conv_silu": _check_conv_silu,
-        "head_norm_rope": _check_head_norm_rope,
-        "grouped": _check_grouped,
-        "gated_delta": _check_gated_delta,
-    }
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
-                    help=f"comma list of sections to run ({','.join(sections)})")
+                    help=f"comma list of sections to run ({','.join(SECTIONS)})")
     ap.add_argument("--grouped-tiles", default="",
                     help="comma list of row tiles the grouped section prices beside the default")
     cli = ap.parse_args()
     _GROUPED_TILES[:] = [int(t) for t in cli.grouped_tiles.split(",") if t]
-    chosen = set(cli.only.split(",")) if cli.only else set(sections)
-    unknown = chosen - set(sections)
+    chosen = set(cli.only.split(",")) if cli.only else set(SECTIONS)
+    unknown = chosen - set(SECTIONS)
     if unknown:
         raise SystemExit(f"unknown sections {sorted(unknown)}; "
-                         f"known: {list(sections)}")
+                         f"known: {list(SECTIONS)}")
 
     import jax
     import jax.numpy as jnp
@@ -91,15 +75,15 @@ def main() -> None:
             f"{jax.default_backend()!r}"
         )
 
-    import bench as headline_bench
+    from tpuframe.compile import cache as compile_cache
 
-    headline_bench.enable_compile_cache()
+    compile_cache.enable_from_env()
     dev = jax.devices()[0]
     print(json.dumps({"platform": dev.platform, "device_kind": dev.device_kind,
                       "count": len(jax.devices())}), flush=True)
     rng = np.random.default_rng(0)
 
-    for name, run_section in sections.items():
+    for name, (run_section, _) in SECTIONS.items():
         if name not in chosen:
             continue
         try:
@@ -960,6 +944,36 @@ def _check_ulysses(jax, jnp, np, rng) -> None:
                                           batch_axes=("data",)),
         _qkv(jnp, rng), causal=True,
     )
+
+
+#: section -> (its check, the rows of ``tpuframe/ops/registry.py::OPS_REGISTRY``
+#: it holds to their oracles on the chip).  ``tests/test_chip_tools.py`` holds
+#: every registry row to a section here or a reason in ``NO_CHIP_CHECK``.
+SECTIONS = {
+    "layer_norm": (_check_layer_norm, ("layer_norm",)),
+    "cross_entropy": (_check_cross_entropy, ("cross_entropy",)),
+    "quant_wire": (_check_quant_wire, ("quant_wire",)),
+    "blockwise": (_check_blockwise, ("blockwise_attention",)),
+    "flash_layout": (_check_flash_layout, ("blockwise_attention",)),
+    "window": (_check_window, ("blockwise_attention",)),
+    "ring": (_check_ring, ("ring_attention",)),
+    "ulysses": (_check_ulysses, ("ulysses",)),
+    "moe_windows": (_check_moe_windows, ("grouped_matmul",)),
+    "short_conv": (_check_short_conv, ("short_conv",)),
+    "conv_silu": (_check_conv_silu, ("conv_silu",)),
+    "head_norm_rope": (_check_head_norm_rope, ("head_norm_rope",)),
+    "grouped": (_check_grouped, ("grouped_matmul",)),
+    "gated_delta": (_check_gated_delta,
+                    ("gated_delta", "head_norm_rope", "blockwise_attention")),
+}
+
+#: registry rows with no section, each with why none is owed
+NO_CHIP_CHECK = (
+    ("normalize", "plain jnp on every backend, no kernel to refuse; "
+                  "chip_smoke.py holds it to its oracle on the chip (normalize_vs_oracle)"),
+    ("moe_gating", "pure XLA scatter and gather, the same program on every backend; "
+                   "tests/test_moe.py holds it to the dense oracle"),
+)
 
 
 if __name__ == "__main__":

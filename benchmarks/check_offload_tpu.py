@@ -7,9 +7,10 @@ in the reference) maps to JAX memory kinds: optimizer-state leaves live in
 (`tpuframe/parallel/sharding.py::state_shardings`,
 `tpuframe/train/step.py::_wrap_offload`).  The CPU simulation backend
 cannot compile host-placement annotations, so this is the one code path
-tests cannot cover — this script executes it on a real chip and emits a
-JSON record for `benchmarks/results/` (VERDICT r03 weak #4: "dead code
-until proven").
+tests cannot cover — this script executes it on a real chip and prints a
+JSON record (VERDICT r03 weak #4: "dead code until proven").  With no TPU,
+or a backend without ``pinned_host`` memory, it exits non-zero with the
+reason on stderr and prints no record.
 
 Checks, in order:
 1. optimizer state materializes with ``memory_kind == "pinned_host"``
@@ -31,8 +32,6 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from bench import enable_compile_cache  # shared cache + methodology
 
 
 def leaf_memory_kinds(tree) -> set[str]:
@@ -91,25 +90,25 @@ def run_steps(plan, n_steps: int = 8):
 
 
 def main() -> None:
-    enable_compile_cache()
     import jax
 
+    from tpuframe.compile import cache as compile_cache
     from tpuframe.core.runtime import MeshSpec
     from tpuframe.parallel import supports_host_offload, zero_3, zero_3_offload
 
+    if jax.default_backend() != "tpu":
+        raise SystemExit(
+            f"check_offload_tpu needs a TPU: jax.default_backend() is "
+            f"{jax.default_backend()!r}"
+        )
+    if not supports_host_offload():
+        raise SystemExit("check_offload_tpu: the backend exposes no pinned_host memory")
+    compile_cache.enable_from_env()
     rec: dict = {
         "check": "zero3_offload_optimizer_pinned_host",
         "backend": jax.default_backend(),
         "device_kind": jax.devices()[0].device_kind,
     }
-    if jax.default_backend() != "tpu":
-        rec.update(ok=False, reason="needs a real TPU backend (pinned_host)")
-        print(json.dumps(rec))
-        return
-    if not supports_host_offload():
-        rec.update(ok=False, reason="backend exposes no pinned_host memory")
-        print(json.dumps(rec))
-        return
 
     mesh = MeshSpec(fsdp=-1).build()
     off = run_steps(zero_3_offload(mesh))

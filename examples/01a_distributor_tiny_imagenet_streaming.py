@@ -138,8 +138,7 @@ def train_tiny_imagenet(cfg: dict):
     # NOTE for jpg-column volumes (this example's shards store ndarray
     # columns): pass decode_min_hw=(px, px) AND lead the transform with
     # Resize(px) — jpeg then decodes at the covering M/8 DCT scale
-    # (fused decode+resize, GIL-free) and Resize finishes the exact size;
-    # benchmarks/bench_e2e.py pairs the two correctly.
+    # (fused decode+resize, GIL-free) and Resize finishes the exact size.
     train_ds = StreamingDataset(
         cfg["train_remote"],
         local_cache=os.path.join(local_cache, "train"),

@@ -11,8 +11,7 @@ Then stands up the real serving spine over the artifact: a
 :class:`~tpuframe.serve.ServeEngine` (deadline-aware dynamic batching
 into AOT-precompiled bucket shapes, bounded-queue admission control,
 graceful drain — SERVE.md) and fires a small closed-loop load generator
-at it, printing the throughput and latency distribution the production
-bench (``benchmarks/bench_serve.py``) commits at full scale.
+at it, printing its throughput and latency distribution.
 
 Also demonstrates the migration entry: ``--from-torch <state_dict.pt>``
 skips training and exports a torchvision-format checkpoint directly

@@ -7,7 +7,9 @@ CPU devices so every mesh/sharding path is exercised without TPU hardware.
 ``simulate_cpu_devices`` overrides both the env and the live jax config.
 """
 
+import json
 import os
+import re
 import tempfile
 
 import jax
@@ -30,6 +32,30 @@ os.environ.setdefault(
     "TPUFRAME_COMPILE_CACHE",
     os.path.join(tempfile.gettempdir(), "tpuframe_scratch", "compile_cache"),
 )
+
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+#: a script a document or the doctor hands its reader: anything under
+#: ``benchmarks/``, anything called ``bench*.py``, the smoke
+_SCRIPT_NAME = re.compile(
+    r"(?<![\w/.-])(benchmarks/[\w./*-]*|bench\w*\.py|chip_smoke\.py)")
+
+
+@pytest.fixture(scope="session")
+def scripts_not_in_tree():
+    """``f(text_or_section) -> list``: the scripts it names that the tree
+    does not hold (a bare ``bench*.py`` may live under ``benchmarks/``)."""
+
+    def missing(named) -> list[str]:
+        text = named if isinstance(named, str) else json.dumps(named)
+        names = {m.rstrip(".-") for m in _SCRIPT_NAME.findall(text)}
+        return sorted(
+            n for n in names
+            if not any(os.path.exists(os.path.join(_REPO, d, n))
+                       for d in ("", "benchmarks"))
+        )
+
+    return missing
 
 
 @pytest.fixture(scope="session")

@@ -532,7 +532,7 @@ class TestTrainerApply:
 
 
 class TestViews:
-    def test_doctor_section_lists_this_hosts_configs(self, store):
+    def test_doctor_section_lists_this_hosts_configs(self, store, scripts_not_in_tree):
         from tpuframe.autotune.config import default_host
         from tpuframe.doctor import autotune_section
 
@@ -545,7 +545,7 @@ class TestViews:
         sec = autotune_section({"device_count": 8, "process_count": 1})
         assert sec["store"] == autotune_dir()
         assert "python -m tpuframe.autotune" in sec["show"]
-        assert "bench_autotune" in sec["tune"]
+        assert scripts_not_in_tree(sec) == []
         (row,) = sec["configs"]  # the other host's config filtered out
         assert row["matches_probed_topology"] is True
         assert row["convergence_ratio"] == pytest.approx(0.5)

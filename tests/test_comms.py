@@ -564,12 +564,12 @@ class TestTelemetryAndKnobs:
         with pytest.raises(ValueError, match="unknown grad_compression"):
             CommsConfig.from_env("int7")
 
-    def test_doctor_comms_section(self, monkeypatch):
+    def test_doctor_comms_section(self, monkeypatch, scripts_not_in_tree):
         from tpuframe.doctor import comms_section
 
         monkeypatch.delenv("TPUFRAME_COMMS_COMPRESSION", raising=False)
         sec = comms_section()
-        assert sec["enabled"] is False and "bench_collectives" in sec["bench"]
+        assert sec["enabled"] is False and scripts_not_in_tree(sec) == []
         monkeypatch.setenv("TPUFRAME_COMMS_COMPRESSION", "int8")
         sec = comms_section()
         assert sec["enabled"] and sec["config"]["mode"] == "int8"

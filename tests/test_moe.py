@@ -140,7 +140,7 @@ class TestMoEGatingKernel:
             got = moe_dispatch_combine(*inputs, capacity=capacity, fused=True)
             # bit-close, not bit-identical: the scatter accumulates in a
             # different order than the einsum reduction (atol pinned by
-            # the module docstring + bench_kernels_cpu.json)
+            # the module docstring)
             np.testing.assert_allclose(
                 np.asarray(got), np.asarray(want), atol=1e-5
             )

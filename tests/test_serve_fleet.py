@@ -528,7 +528,7 @@ class TestPromotionStories:
 
 
 class TestDoctorFleetSection:
-    def test_section_shape(self, monkeypatch):
+    def test_section_shape(self, monkeypatch, scripts_not_in_tree):
         from tpuframe.doctor import fleet_section
 
         monkeypatch.setenv("TPUFRAME_FLEET_REPLICAS", "5")
@@ -536,7 +536,7 @@ class TestDoctorFleetSection:
         assert sec["knobs"]["replicas"] == 5
         assert sec["env"] == {"TPUFRAME_FLEET_REPLICAS": "5"}
         assert sec["detection_window_ms"] == sec["knobs"]["probe_ms"]
-        assert sec["bench"].endswith("bench_serve.py --fleet")
+        assert scripts_not_in_tree(sec) == []
 
     def test_report_includes_fleet(self):
         from tpuframe.doctor import report
@@ -570,21 +570,6 @@ class TestAnalyzePerReplica:
             set(sv["per_replica"]) == {0, 1}
         for block in sv["per_replica"].values():
             assert block["count"] == 5 and block["p50"] <= block["p99"]
-
-
-class TestFleetBenchRecord:
-    def test_committed_record_feeds_baseline_gate(self):
-        here = os.path.dirname(os.path.abspath(__file__))
-        path = os.path.join(here, os.pardir, "benchmarks", "results",
-                            "bench_serve_fleet_cpu.json")
-        if not os.path.exists(path):
-            pytest.skip("fleet bench record not committed yet")
-        with open(path) as f:
-            rec = json.load(f)
-        assert rec["metric"] == "serve_fleet_throughput_rps"
-        assert rec["serve_latency"]["count"] > 0
-        assert rec["rolling_restart"]["dropped_in_flight"] == 0
-        assert rec["rolling_restart"]["p99_under_slo"] is True
 
 
 # ===========================================================================
@@ -1237,39 +1222,3 @@ class TestAnalyzeServeTrace:
         assert diff["baselines"], "serve_latency baseline must compare"
         assert "ratio_queue_wait_p99" not in diff["baselines"][0]
         assert "ratio_burn_rate" not in diff["baselines"][0]
-
-
-class TestTraceBenchRecord:
-    def test_committed_record_shape(self):
-        here = os.path.dirname(os.path.abspath(__file__))
-        path = os.path.join(here, os.pardir, "benchmarks", "results",
-                            "bench_serve_trace_cpu.json")
-        if not os.path.exists(path):
-            pytest.skip("trace bench record not committed yet")
-        with open(path) as f:
-            rec = json.load(f)
-        assert rec["metric"] == "serve_trace_request_path"
-        tr = rec["serve_trace"]
-        assert tr["traces"] > 0
-        for hop in ("route", "hop", "door", "queue_wait", "assemble",
-                    "infer", "respond"):
-            assert tr["hops"][hop]["count"] > 0, hop
-        assert rec["recompile_events"] == 0
-        ov = rec["trace_overhead"]
-        assert ov["untraced_p99_ms"] > 0 and ov["traced_p99_ms"] > 0
-        sample = rec["trace_sample"]
-        assert sample["trace"] and sample["hops"]
-
-    def test_committed_record_feeds_trace_gates(self, tmp_path):
-        here = os.path.dirname(os.path.abspath(__file__))
-        path = os.path.join(here, os.pardir, "benchmarks", "results",
-                            "bench_serve_trace_cpu.json")
-        if not os.path.exists(path):
-            pytest.skip("trace bench record not committed yet")
-        import tpuframe.track.analyze as A
-
-        TestAnalyzeServeTrace()._traced_run(tmp_path, n=4)
-        report = A.skew_report(A.load_dir(str(tmp_path)))
-        diff = A.baseline_diff(report, path, backend="cpu")
-        assert diff["baselines"], "committed trace record not comparable"
-        assert diff["baselines"][0].get("ratio_queue_wait_p99") is not None

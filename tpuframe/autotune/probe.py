@@ -1,6 +1,6 @@
-"""The measured-probe harness: short timeboxed A/B runs, bench-style.
+"""The measured-probe harness: short timeboxed A/B runs.
 
-Methodology is lifted from the bench harness (``benchmarks/bench_*``):
+Methodology:
 per-step walls with the warmup prefix discarded, medians (robust to the
 one GC pause), and the fleet analyzer's exit-3 regression-gate stance —
 a probe can observe whatever it likes, but it can only *commit* a config

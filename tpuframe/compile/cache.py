@@ -1,7 +1,7 @@
 """Persistent XLA compilation cache: the warm-start half of the compile spine.
 
 Every cold start, eval switch, and supervised restart pays a full XLA
-trace+compile on the hot path — ``bench_fault_cpu.json`` charges the
+trace+compile on the hot path — a supervised recovery pays the
 recompile inside its recovery wall, and the fleet analyzer must
 special-case the first step because compile jitter pollutes skew numbers.
 jax ships a persistent compilation cache that turns a repeat backend

@@ -77,8 +77,8 @@ def _native_jpeg():
     provides the parallelism — a nested pool would oversubscribe.
     ``TPUFRAME_JPEG_THREADS=N`` widens the decoder's own pool for
     low-worker setups (e.g. one loader worker feeding the ring on a
-    many-core host; `bench_decode.py --threads` measures the scaling
-    curve).  Kill switch: ``TPUFRAME_NATIVE_JPEG=0``.
+    many-core host; the scaling curve is not measured: ROADMAP S5's
+    JPEG cell).  Kill switch: ``TPUFRAME_NATIVE_JPEG=0``.
     """
     global _JPEG_DECODER
     if _JPEG_DECODER == "unset":

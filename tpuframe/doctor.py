@@ -261,10 +261,9 @@ def serve_section(export_path: str | None = None) -> dict:
     queue / shed-policy knobs (env overrides applied), the
     ``TPUFRAME_SERVE_*`` env, and — given an export artifact
     (``--export`` / ``TPUFRAME_SERVE_EXPORT``) — its meta plus the
-    padded bucket shapes the engine would AOT-precompile for it, with
-    the paste-ready ``bench_serve`` one-liner.  Stdlib-only reads
-    (:func:`~tpuframe.serve.admission.read_export_meta`) — works
-    against a wedged backend, like the ckpt/health sections."""
+    padded bucket shapes the engine would AOT-precompile for it.
+    Stdlib-only reads (:func:`~tpuframe.serve.admission.read_export_meta`)
+    — works against a wedged backend, like the ckpt/health sections."""
     import dataclasses
 
     from tpuframe.serve.admission import SERVE_ENV_VARS, ServeKnobs
@@ -275,16 +274,11 @@ def serve_section(export_path: str | None = None) -> dict:
         "env": {
             k: os.environ[k] for k in SERVE_ENV_VARS if k in os.environ
         },
-        "bench": "python benchmarks/bench_serve.py",
     }
     export_path = export_path or os.environ.get("TPUFRAME_SERVE_EXPORT")
     if export_path:
         from tpuframe.serve.admission import read_export_meta
 
-        out["bench"] = (
-            f"python benchmarks/bench_serve.py --export "
-            f"{shlex.quote(export_path)}"
-        )
         try:
             meta = read_export_meta(export_path)
         except (OSError, ValueError) as e:
@@ -314,10 +308,10 @@ def serve_section(export_path: str | None = None) -> dict:
 def fleet_section() -> dict:
     """State of the fleet layer (``tpuframe.serve.fleet``): the
     router/replica-set knobs (env overrides applied), the
-    ``TPUFRAME_ROUTER_*``/``TPUFRAME_FLEET_*`` env subset, the bounded
-    detection window those knobs imply, and the paste-ready fleet bench
-    one-liner.  Stdlib-only (:class:`~tpuframe.serve.router.FleetKnobs`
-    never touches jax), like the serve section."""
+    ``TPUFRAME_ROUTER_*``/``TPUFRAME_FLEET_*`` env subset and the bounded
+    detection window those knobs imply.  Stdlib-only
+    (:class:`~tpuframe.serve.router.FleetKnobs` never touches jax), like
+    the serve section."""
     import dataclasses
 
     from tpuframe.serve.admission import SERVE_ENV_VARS
@@ -334,7 +328,6 @@ def fleet_section() -> dict:
         # worst-case probe-driven rotation delay; in-band forwarding
         # failures rotate a replica out immediately, ahead of this
         "detection_window_ms": knobs.probe_ms,
-        "bench": "python benchmarks/bench_serve.py --fleet",
     }
 
 
@@ -375,9 +368,8 @@ def slo_section() -> dict:
 def comms_section() -> dict:
     """State of the wire-compression spine
     (``tpuframe.parallel.compression``): the resolved compression config
-    (env knobs applied — mode/buckets/stochastic/EF), the
-    ``TPUFRAME_COMMS_*`` env that is set, and the paste-ready
-    ``bench_collectives`` one-liner.  Stdlib-only reads
+    (env knobs applied — mode/buckets/stochastic/EF) and the
+    ``TPUFRAME_COMMS_*`` env that is set.  Stdlib-only reads
     (``parallel.comms_env``) — works against a wedged backend, like the
     serve/ckpt sections."""
     import dataclasses
@@ -394,7 +386,6 @@ def comms_section() -> dict:
         "env": {
             k: os.environ[k] for k in COMMS_ENV_VARS if k in os.environ
         },
-        "bench": "python benchmarks/bench_collectives.py",
     }
     # the async-scheduler knob resolves per-platform (restart-only):
     # print exactly the XLA flag set initialize() would merge, so "why
@@ -414,21 +405,17 @@ def comms_section() -> dict:
     if config is not None:
         out["config"] = dataclasses.asdict(config)
     # in-collective wire: fused is resolved off the same config (it is
-    # a no-op without a compressed mode), and the A/B arm is the proof
-    out["fused"] = {
-        "enabled": bool(config is not None and config.fused),
-        "bench": "python benchmarks/bench_collectives.py --fused",
-    }
+    # a no-op without a compressed mode)
+    out["fused"] = {"enabled": bool(config is not None and config.fused)}
     return out
 
 
 def parallel_section() -> dict:
     """State of the composed-parallelism knobs (``parallel.compose``):
-    the resolved pipeline/TP env (``TPUFRAME_PP_*``/``TPUFRAME_TP_SIZE``),
-    the legal schedules, and the paste-ready pipeline A/B one-liner.
-    Stdlib-only reads (``parallel.comms_env``) — works against a wedged
-    backend; what mesh the plan actually composed is a runtime question
-    the ``pp/schedule`` event answers."""
+    the resolved pipeline/TP env (``TPUFRAME_PP_*``/``TPUFRAME_TP_SIZE``)
+    and the legal schedules.  Stdlib-only reads (``parallel.comms_env``)
+    — works against a wedged backend; what mesh the plan actually
+    composed is a runtime question the ``pp/schedule`` event answers."""
     from tpuframe.parallel.comms_env import (
         PP_SCHEDULE_CHOICES,
         pp_microbatches,
@@ -447,7 +434,6 @@ def parallel_section() -> dict:
                       "TPUFRAME_TP_SIZE")
             if k in os.environ
         },
-        "bench": "python benchmarks/bench_collectives.py --pipeline",
     }
 
 
@@ -576,7 +562,7 @@ def autotune_section(devices: dict | None = None) -> dict:
     is armed, where the per-``(host, topology, signature)`` configs
     persist, every config stored for THIS host (the plan signature is
     run-scoped, so the doctor lists all of the host's entries and marks
-    which match the probed topology), and the paste-ready one-liners —
+    which match the probed topology), and the paste-ready one-liner —
     so a "my run is slow" report says up front whether a tuned config
     exists and what it would set.  Stdlib-only reads — works against a
     wedged backend, like the serve/ckpt sections."""
@@ -601,11 +587,8 @@ def autotune_section(devices: dict | None = None) -> dict:
         "env": {
             k: os.environ[k] for k in AUTOTUNE_ENV_VARS if k in os.environ
         },
-        # the paste-ready pair, consistent with the other sections: what
-        # is persisted, and how to (re)tune this host
+        # paste-ready, consistent with the other sections: what is persisted
         "show": "python -m tpuframe.autotune --json",
-        "tune": ("TPUFRAME_AUTOTUNE=1 python benchmarks/bench_autotune.py "
-                 "--json"),
     }
     configs = []
     for cfg in list_tuned():
@@ -770,7 +753,7 @@ def main(argv: list[str] | None = None) -> int:
                          "default: TPUFRAME_CKPT_DIR)")
     ap.add_argument("--export", default=None, dest="export_path",
                     help="serve export artifact to report on (meta + "
-                         "AOT bucket shapes + the bench_serve one-liner; "
+                         "AOT bucket shapes; "
                          "default: TPUFRAME_SERVE_EXPORT)")
     args = ap.parse_args(argv)
     rec = report(args.probe_timeout, args.ckpt_dir, args.export_path)

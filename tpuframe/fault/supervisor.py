@@ -242,8 +242,8 @@ class Supervisor:
         first attempt: attempt 1 then *writes* every program it compiles,
         and an in-process restart (fresh Trainer => fresh traces) or a
         replacement process on the same host *reads* them back instead of
-        recompiling — the dominant share of the measured recovery wall
-        (bench_fault.py splits it out).  Guarded on jax already being
+        recompiling — the dominant share of a recovery's wall (its
+        ``compile_s`` component).  Guarded on jax already being
         imported: the supervisor itself is stdlib-only and must keep
         working while jax is wedged; if the training fn imports jax
         later, ``core.runtime.initialize`` enables the cache then.

@@ -7,7 +7,7 @@ prose, not policy), and loads the schema docs from the repo root.  The
 rule families (``lint.imports`` / ``knobs`` / ``schema`` / ``hazards`` /
 ``sites`` / ``ops_registry``) are pure functions over that model, so
 the whole pass costs one tree walk + six AST passes — cheap enough for
-tier-1 and the doctor (``benchmarks/bench_lint.py`` prices it).
+tier-1 and the doctor.
 """
 
 # tpuframe-lint: stdlib-only

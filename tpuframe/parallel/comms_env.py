@@ -64,8 +64,8 @@ Knob semantics (the one table, mirrored in OBSERVABILITY.md):
 - ``TPUFRAME_PP_SCHEDULE`` — pipeline hop/compute interleave policy:
   ``interleaved`` (default; ``ppermute`` hops slot behind stage
   compute), ``1f1b`` (interleaved + remat-bounded backward stash), or
-  ``barriered`` (hop-then-compute serialized — the A/B baseline arm of
-  ``bench_collectives.py --pipeline``, not a production schedule).  A
+  ``barriered`` (hop-then-compute serialized — the baseline arm of an
+  A/B against ``interleaved``, not a production schedule).  A
   ``ParallelPlan.pp_schedule`` pin wins over the env.
 - ``TPUFRAME_TP_SIZE`` — tensor-parallel (``model`` axis) size
   ``parallel.compose.compose`` builds its mesh with when the caller

@@ -78,7 +78,7 @@ _tick_barrier.defvjp(_tick_barrier_fwd, _tick_barrier_bwd)
 #:   scheduler discipline applied to the pipeline wire).
 #: - ``barriered`` — an ``optimization_barrier`` ties each hop to the
 #:   tick boundary: hop-then-compute, strictly serialized.  Exists as
-#:   the measured A/B baseline arm (``bench_collectives.py --pipeline``),
+#:   the baseline arm of an A/B against ``interleaved``,
 #:   not a production schedule.
 #: - ``1f1b`` — interleaved hops plus per-tick stage rematerialization
 #:   forced ON: the backward stash is bounded to each tick's stage

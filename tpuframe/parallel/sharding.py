@@ -140,10 +140,10 @@ class ParallelPlan:
     #: inside the update.  EXPERIMENTAL: applied only when the backend has
     #: a usable ``pinned_host`` memory space (real TPUs — CPU simulation
     #: downgrades with a warning), and the pinned-host path has not yet
-    #: been executed on real TPU hardware in this repo —
-    #: ``benchmarks/check_offload_tpu.py`` is the acceptance harness and
-    #: its committed JSON in ``benchmarks/results/`` is the proof of
-    #: support on a given backend.
+    #: been executed on real TPU hardware in this repo (never run on a
+    #: chip; no record exists) — ``benchmarks/check_offload_tpu.py`` is
+    #: the acceptance harness, and a passing run of it on a backend is
+    #: the proof of support there.
     offload_optimizer: bool = False
     #: bucket-group count for the scheduled compressed gradient sync
     #: (see ``parallel.compression.sync_gradients``): None defers to

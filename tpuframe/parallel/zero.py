@@ -84,8 +84,8 @@ def zero_3_offload(mesh: Mesh, **kw) -> ParallelPlan:
     """Stage 3 + optimizer state in pinned host memory
     (`deepspeed_config.py:87-105`).  EXPERIMENTAL: downgrades to plain
     stage 3 — with a loud ``UserWarning`` — on backends without a usable
-    host memory space; validate with ``benchmarks/check_offload_tpu.py``
-    (committed JSON in ``benchmarks/results/``) before relying on the
+    host memory space; never run on a chip, no record exists: validate
+    with ``benchmarks/check_offload_tpu.py`` before relying on the
     HBM savings on a given backend."""
     return ZeroConfig(stage=3, offload_optimizer=True).plan(mesh, **kw)
 

@@ -24,8 +24,8 @@ table stakes:
   ``train/step`` span (+ its ``data_wait_s`` attribute) and ``ckpt/*``
   spans.
 - :func:`baseline_diff` compares the run's step-time distribution
-  against committed ``benchmarks/results/*.json`` records (any record
-  carrying a ``step_time`` block, e.g. ``analyze_selftest_cpu.json``).
+  against records an operator keeps (any JSON file carrying a
+  ``step_time`` block; the tree ships none).
 - :class:`StragglerMonitor` is the *live* counterpart, wired into the
   Trainer: each rank keeps a rolling step-time EWMA in the registry
   (``train/step_ewma_s``), and every ``sync_steps`` steps the fleet
@@ -885,7 +885,7 @@ def skew_report(ranks: Sequence[RankLog], *,
     # bytes_per_step is static per signature; the run total multiplies
     # by the steps each rank dispatched.  allreduce_s quantiles appear
     # when the run timed standalone compressed collectives
-    # (make_compressed_pmean / bench_collectives emit comms/allreduce
+    # (make_compressed_pmean emits comms/allreduce
     # spans) — fused train steps carry the collective inside the step
     # program, so no per-collective wall exists to report there.
     comms_info = None
@@ -1074,23 +1074,23 @@ def skew_report(ranks: Sequence[RankLog], *,
 
 def baseline_diff(report: dict, baseline: str, *,
                   threshold: float = 1.25, backend: str | None = None) -> dict:
-    """Compare this run's step-time distribution against committed bench
-    records — any ``benchmarks/results/*.json`` file whose top-level
-    object carries a ``step_time`` block with ``p50`` (the
-    ``bench_analyze.py`` self-test commits one per backend).
+    """Compare this run's step-time distribution against the records an
+    operator keeps (``baseline``: one JSON file or a directory of them;
+    the tree ships none) — any file whose top-level object carries a
+    ``step_time`` block with ``p50``.
 
     ``ratio_p50 > threshold`` lands the pair in ``regressions``.
-    Records carrying a ``time_to_first_step`` block (``bench_compile.py``
-    commits one) diff the same way against the report's measured
+    Records carrying a ``time_to_first_step`` block
+    diff the same way against the report's measured
     time-to-first-step — a compile-time regression gates exactly like a
     step-time regression (exit 3).  Records carrying a ``serve_latency``
-    block with ``p99`` (``bench_serve.py`` commits one) diff against the
+    block with ``p99`` diff against the
     report's serve-path latency distribution: a p99 latency regression
     on the request path gates the same way.  Records carrying a
-    ``serve_trace`` block (``bench_serve.py --fleet`` commits one) diff
+    ``serve_trace`` block diff
     the per-hop queue-wait p99 (``ratio_queue_wait_p99``) and the SLO
     burn rate (``ratio_burn_rate``) under the same discipline.  Records
-    carrying a ``memory`` block (``bench_memory.py`` commits one) diff
+    carrying a ``memory`` block diff
     the peak HBM watermark — live when the backend reports device
     stats, else the compiled ``peak_executable_mb`` — as
     ``ratio_peak_hbm``: a plan whose footprint grew past threshold
@@ -1861,8 +1861,8 @@ def main(argv: Sequence[str] | None = None) -> int:
     ap.add_argument("--report", action="store_true",
                     help="print the human-readable skew report")
     ap.add_argument("--baseline", metavar="DIR_OR_FILE",
-                    help="diff step times vs committed bench records "
-                         "(e.g. benchmarks/results/)")
+                    help="diff step times vs the records an operator "
+                         "keeps (a JSON file or a directory of them)")
     ap.add_argument("--baseline-backend", metavar="BACKEND",
                     help="only diff against baselines recorded on this "
                          "backend (cpu/tpu) — a CPU run vs a TPU record "

@@ -863,7 +863,7 @@ class MetricsExportCallback:
 
     Duck-typed against ``tpuframe.train.callbacks.Callback`` rather than
     subclassing it — importing the train package would pull jax into every
-    telemetry consumer (bench.py's parent must stay jax-free).
+    telemetry consumer (a launch parent must stay jax-free).
     """
 
     def __init__(self, prefix: str = "telemetry/"):
