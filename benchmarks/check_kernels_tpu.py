@@ -22,11 +22,13 @@ does not visit unwritten on the chip, which no CPU run shows), the short
 convolution, and the head norms with rotary positions (both timed beside
 their oracles' XLA fusions), and the grouped products of the expert layer
 (the three kernels against the oracle, and each timed beside
-``jax.lax.ragged_dot`` at the four expert cells' shapes).
+``jax.lax.ragged_dot`` at the four expert cells' shapes), and the index of
+sparse attention (exact top-k a query) with the flash kernels under the rule
+that reads its choice.
 
 Usage: python benchmarks/check_kernels_tpu.py [--only a,b,...]
 (exits 1 on any failure).  ``--only`` runs a named subset — sections:
-layer_norm, cross_entropy, quant_wire, blockwise, flash_layout, window, ring,
+layer_norm, cross_entropy, quant_wire, blockwise, flash_layout, window, sparse_index, ring,
 ulysses, moe_windows, short_conv, conv_silu, head_norm_rope, grouped, gated_delta
 (``--grouped-tiles 128,256,512`` prices other row tiles beside the default).
 """
@@ -418,6 +420,117 @@ def _check_window(jax, jnp, np, rng) -> None:
     record("window_band_group_fwd_vs_oracle", gap(got, want), 2 ** -7)
     record("window_band_group_grads_vs_oracle",
            max(gap(a, b) for a, b in zip(got_g, want_g)), 2 ** -5)
+
+
+def _check_sparse_index(jax, jnp, np, rng) -> None:
+    """The index of sparse attention and the flash kernels under the rule that
+    reads its choice, at keye-vl-2.0-30b-a3b's call shapes (8192 positions, 16
+    index heads of 64 over one index key head, 2048 keys a query; 32 heads
+    over 4 of 128).  The index kernel against its oracle bit for bit on
+    inputs whose products and sums are exact (values on a coarse grid: plenty
+    of ties, so the cut at the earlier key is held too), at the cell's shape
+    and at a padded one; on normal bfloat16 inputs, where the kernel's float32
+    sums and XLA's differ in their order, every row's count exactly, and every
+    pair on which the two choices differ within 2^-16 of the row's threshold
+    score.  The flash kernels under the rule against the scan schedule on the
+    same choice, forward and gradients, at the cell's shape and a padded one,
+    and against the float32 oracle on one key/value group.  Times: the index
+    kernel a call beside its oracle (XLA's sort of every row's scores), and the
+    flash kernels forward + backward under the rule and under plain causal."""
+    from tpuframe.ops import SelectedKeysMask, attention_reference, blockwise_attention
+    from tpuframe.ops.sparse_index import (
+        index_scores_reference,
+        select_keys,
+        select_keys_reference,
+    )
+
+    def grid(b, l, h, d, dtype):
+        draw = lambda *shape: rng.integers(-2, 3, shape) / 4  # noqa: E731
+        return (jnp.asarray(draw(b, l, h, d), dtype), jnp.asarray(draw(b, l, d), dtype),
+                jnp.asarray(draw(b, l, h) * 2, jnp.float32))
+
+    for name, shape, topk, dtype in (("cell", (1, 8192, 16, 64), 2048, jnp.bfloat16),
+                                     ("padded", (2, 1000, 4, 64), 300, jnp.float32),
+                                     ("wide_heads", (1, 1536, 2, 128), 512, jnp.bfloat16)):
+        args = grid(*shape, dtype)
+        got, counts = jax.jit(lambda *a, k=topk: select_keys(*a, k))(*args)
+        want, _ = jax.jit(lambda *a, k=topk: select_keys_reference(*a, k))(*args)
+        record(f"sparse_index_{name}_pairs_that_differ",
+               float(jnp.sum(got != want, dtype=jnp.float32)), 0.5)
+        exact = jnp.minimum(jnp.arange(shape[1]) + 1, topk).astype(jnp.float32)
+        record(f"sparse_index_{name}_count_gap", float(jnp.max(jnp.abs(counts - exact))), 0.5)
+
+    b, l, hi, di, topk = 1, 8192, 16, 64, 2048
+    qi = jnp.asarray(rng.standard_normal((b, l, hi, di)), jnp.bfloat16)
+    ki = jnp.asarray(rng.standard_normal((b, l, di)), jnp.bfloat16)
+    w = jnp.asarray(rng.standard_normal((b, l, hi)), jnp.float32)
+    index = jax.jit(lambda *a: select_keys(*a, topk))
+    chosen, counts = index(qi, ki, w)
+    exact = jnp.minimum(jnp.arange(l) + 1, topk).astype(jnp.float32)
+    record("sparse_index_normal_count_gap", float(jnp.max(jnp.abs(counts - exact))), 0.5)
+    record("sparse_index_normal_row_sums_gap",
+           float(jnp.max(jnp.abs(jnp.sum(chosen, -1, dtype=jnp.float32) - exact))), 0.5)
+
+    @jax.jit
+    def flips(rows, chosen_rows, first):
+        """(pairs that differ from the oracle's choice, their largest distance
+        from the row's threshold score as a share of it) for a block of rows."""
+        seen = jnp.arange(l)[None, None, :] <= (first + jnp.arange(rows[0].shape[1]))[None, :, None]
+        scores = jnp.where(seen, index_scores_reference(rows[0], ki, rows[1]), -jnp.inf)
+        top, _ = jax.lax.top_k(scores, topk)
+        edge = top[..., -1:]
+        want = seen & (scores >= edge)          # ties aside, the oracle's choice
+        differ = (want != (chosen_rows != 0)) & jnp.isfinite(edge)
+        far = jnp.where(differ, jnp.abs(scores - edge) / jnp.maximum(jnp.abs(edge), 1.0), 0.0)
+        return jnp.sum(differ, dtype=jnp.float32), jnp.max(far)
+
+    n_flips = far = 0.0
+    for first in range(0, l, 512):
+        rows = slice(first, first + 512)
+        n, f = flips((qi[:, rows], w[:, rows]), chosen[:, rows], first)
+        n_flips, far = n_flips + float(n), max(far, float(f))
+    print(json.dumps({"info": "sparse_index_normal", "pairs_that_differ": n_flips,
+                      "of": float(jnp.sum(exact))}), flush=True)
+    record("sparse_index_normal_flips_from_threshold", far, 2 ** -16)
+    record("sparse_index_normal_flip_share", n_flips / float(jnp.sum(exact)), 1e-4)
+
+    def qkv(b, l, h, kv_heads, d):
+        return tuple(
+            jnp.asarray(rng.standard_normal((b, l, heads, d)) * 0.5, jnp.bfloat16)
+            for heads in (h, kv_heads, kv_heads))
+
+    rule = {"causal": False, "mask": SelectedKeysMask(topk), "mask_operands": (chosen,)}
+    cell = qkv(1, 8192, 32, 4, 128)
+    _schedule_parity(jax, jnp, "select_cell", cell, ftol=2 ** -9, gtol=2 ** -6, **rule)
+    small = grid(2, 1000, 4, 64, jnp.float32)
+    small_chosen, _ = select_keys(*small, 300)
+    _schedule_parity(jax, jnp, "select_padded", qkv(2, 1000, 8, 2, 128), ftol=2 ** -9,
+                     gtol=2 ** -6, causal=False, mask=SelectedKeysMask(300),
+                     mask_operands=(small_chosen,))
+    group = qkv(1, 8192, 4, 1, 128)
+    f32 = tuple(a.astype(jnp.float32) for a in group)
+    loss = lambda fn, args: jnp.sum(fn(*args, **rule).astype(jnp.float32) ** 2)  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda *a: attention_reference(*a, **rule))(*f32)
+        want_g = jax.jit(jax.grad(lambda *a: loss(attention_reference, a), (0, 1, 2)))(*f32)
+    got = jax.jit(lambda *a: blockwise_attention(*a, **rule))(*group)
+    got_g = jax.jit(jax.grad(lambda *a: loss(blockwise_attention, a), (0, 1, 2)))(*group)
+    gap = lambda a, b: float(jnp.max(  # noqa: E731
+        jnp.abs(a.astype(jnp.float32) - b) / jnp.maximum(jnp.abs(b), 1.0)))
+    record("select_group_fwd_vs_oracle", gap(got, want), 2 ** -7)
+    record("select_group_grads_vs_oracle",
+           max(gap(a, b) for a, b in zip(got_g, want_g)), 2 ** -5)
+
+    both = lambda how: jax.jit(jax.grad(  # noqa: E731
+        lambda *a: jnp.sum(blockwise_attention(*a, **how).astype(jnp.float32) ** 2), (0, 1, 2)))
+    print(json.dumps({
+        "info": "sparse_index_ms_a_call",
+        "index_topk": _ms_a_call(jax, index, (qi, ki, w), calls=5),
+        "index_oracle_lax_top_k": _ms_a_call(
+            jax, jax.jit(lambda *a: select_keys_reference(*a, topk)), (qi, ki, w), calls=3),
+        "flash_fwd_bwd_select": _ms_a_call(jax, both(rule), cell, calls=5),
+        "flash_fwd_bwd_causal": _ms_a_call(jax, both({"causal": True}), cell, calls=5),
+    }), flush=True)
 
 
 def _check_moe_windows(jax, jnp, np, rng) -> None:
@@ -956,6 +1069,7 @@ SECTIONS = {
     "blockwise": (_check_blockwise, ("blockwise_attention",)),
     "flash_layout": (_check_flash_layout, ("blockwise_attention",)),
     "window": (_check_window, ("blockwise_attention",)),
+    "sparse_index": (_check_sparse_index, ("sparse_index", "blockwise_attention")),
     "ring": (_check_ring, ("ring_attention",)),
     "ulysses": (_check_ulysses, ("ulysses",)),
     "moe_windows": (_check_moe_windows, ("grouped_matmul",)),

@@ -47,12 +47,14 @@ from tpuframe.ops.dispatch import batch_sharding_info, effective_mesh
 from tpuframe.ops.gated_delta import chunks_walked, gated_delta, gated_delta_reference
 from tpuframe.ops.head_norm_rope import head_norm_rope, head_norm_rope_reference
 from tpuframe.ops.ring_attention import (
+    SelectedKeysMask,
     SlidingWindowMask,
     attention_reference,
     mask_or_causal,
     ring_attention_local,
 )
 from tpuframe.ops.layer_norm import FusedLayerNorm
+from tpuframe.ops.sparse_index import select_keys, select_keys_reference
 from tpuframe.ops.short_conv import (
     conv_silu,
     conv_silu_reference,
@@ -100,6 +102,10 @@ def transformer_tp_rules():
         # whole heads): the linear-attention layer's input projections stay
         # whole too, and its output projection splits its output columns
         (r"deltanet/out_proj/kernel", P(None, MODEL_AXIS)),
+        # the index of sparse attention chooses one set of keys for all heads
+        # of a row: every device of the model axis needs all of it, so its
+        # three projections (and the key norm, which no rule names) stay whole
+        (r"attn/index_[qkw]/kernel", P()),
         (r"embed/embedding", P(None, MODEL_AXIS)),
         (r"lm_head/kernel", P(None, MODEL_AXIS)),
     )
@@ -251,17 +257,19 @@ def _per_shard_spec(mesh, batch: int, num_heads: int):
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "spec", "causal", "scale", "mask"))
-def _blockwise_per_shard(q, k, v, *, mesh, spec, causal, scale, mask=None):
-    """``blockwise_attention`` on each device's shard of q, k, v.  A jit
+def _blockwise_per_shard(q, k, v, *operands, mesh, spec, causal, scale, mask=None):
+    """``blockwise_attention`` on each device's shard of q, k, v (and of the
+    ``operands`` a mask rule reads: a device's rows, whole).  A jit
     of its own, so the layers of a model, which call it alike, trace and
     lower one region and not one each: 48 separate regions took the
     four-chip GPT-2-medium step 26 s to lower (PR 30)."""
     return shard_map(
-        lambda q, k, v: _blockwise.blockwise_attention(
-            q, k, v, causal=causal, scale=scale, mask=mask),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_vma=False,
-    )(q, k, v)
+        lambda q, k, v, *operands: _blockwise.blockwise_attention(
+            q, k, v, causal=causal, scale=scale, mask=mask,
+            **({"mask_operands": operands} if operands else {})),
+        mesh=mesh, in_specs=(spec, spec, spec) + (P(spec[0], None, None),) * len(operands),
+        out_specs=spec, check_vma=False,
+    )(q, k, v, *operands)
 
 
 def _resolve_impl(impl: str, q, mesh, initializing: bool,
@@ -291,13 +299,14 @@ def _resolve_impl(impl: str, q, mesh, initializing: bool,
 
 def _attend(q, k, v, *, impl: str, causal: bool, num_heads: int,
             initializing: bool, scale: float | None = None,
-            mask=None, on_tiles=None) -> jax.Array:
+            mask=None, on_tiles=None, mask_operands=()) -> jax.Array:
     """The attention core every attention module dispatches to:
     (B, L, H, D) q/k and (B, L, H, Dv) v -> (B, L, H, Dv) by ``impl``.
     ``scale`` (None: ``1/sqrt(D)``), a value width of its own, a
     ``mask`` that is a rule on positions in ``causal``'s place
     (`ops.ring_attention`'s protocol; one that is the causal mask on
-    this row runs as ``causal``) and ``k``/``v`` of one head
+    this row runs as ``causal``; ``mask_operands`` are the arrays it reads
+    where it reads any) and ``k``/``v`` of one head
     a group of query heads are taken by ``full`` and ``blockwise``; the
     sequence-sharded forms keep one head width, the default scale,
     ``causal`` and as many key/value heads as query heads.  ``on_tiles``
@@ -305,7 +314,7 @@ def _attend(q, k, v, *, impl: str, causal: bool, num_heads: int,
     the blockwise form runs it under a mask rule, kernels or schedule as
     decided here."""
     if mask is not None and mask_or_causal(causal, mask, q.shape[1]) is True:
-        causal, mask = True, None
+        causal, mask, mask_operands = True, None, ()
     mesh = _mesh_or_none()
     # heads split over the model axis only in whole groups
     per_shard = _per_shard_spec(mesh, q.shape[0], k.shape[2])
@@ -313,6 +322,8 @@ def _attend(q, k, v, *, impl: str, causal: bool, num_heads: int,
     widened = {} if scale is None else {"scale": scale}
     if mask is not None:
         widened["mask"] = mask
+    if mask_operands:
+        widened["mask_operands"] = tuple(mask_operands)
     if impl in ("ring", "ulysses"):
         if mesh is None:
             raise ValueError(
@@ -353,8 +364,8 @@ def _attend(q, k, v, *, impl: str, causal: bool, num_heads: int,
             on_tiles(*_blockwise.tile_counts(mask, q.shape[1], kernels=kernels))
         if kernels and per_shard is not None:
             return _blockwise_per_shard(
-                q, k, v, mesh=mesh, spec=per_shard, causal=causal, scale=scale,
-                mask=mask)
+                q, k, v, *mask_operands, mesh=mesh, spec=per_shard, causal=causal,
+                scale=scale, mask=mask)
         return _blockwise.blockwise_attention(q, k, v, causal=causal, **widened)
     if impl == "full":
         return attention_reference(q, k, v, causal=causal, **widened)
@@ -396,6 +407,57 @@ class SelfAttention(nn.Module):
     gated: bool = False
     #: the head norms in the ``(1 + scale)`` form
     norm_unit_offset: bool = False
+    #: a learned index that chooses the keys a query sees (DeepSeek sparse
+    #: attention; `_chosen_keys`), as (name, value) pairs: ``num_heads`` and
+    #: ``head_dim`` of the index, ``topk`` keys a query
+    sparse_index: tuple = ()
+
+    def _chosen_keys(self, x):
+        """(rule, operands) of attention over the keys the index chooses, or
+        (None, ()) where the row is no longer than ``topk`` and the layer is
+        plain causal attention (the index then does not run).
+
+        Index queries ``qI = x W_q`` (``num_heads`` of ``head_dim``), one index
+        key head ``kI = LayerNorm(x W_k)`` (scale and bias), head weights ``w =
+        x W_w`` in float32; ``I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])``
+        and the ``topk`` largest a query among the keys not after it
+        (`ops.sparse_index`), one choice for all heads.  No rotary turn inside
+        the index.  The choice is discrete: no gradient of the objective
+        reaches the index, which runs forward only, under ``stop_gradient``;
+        its four leaves are frozen leaves of the step."""
+        index = dict(self.sparse_index)
+        heads, width = index["num_heads"], index["head_dim"]
+        rule = SelectedKeysMask(index["topk"])
+        b, l, _ = x.shape
+        causal_pairs = b * l * (l + 1) // 2
+
+        def count(selected):
+            for name, value in (("selected", selected), ("causal", causal_pairs)):
+                self.sow("counters", f"attention/pairs_{name}", jnp.float32(value),
+                         reduce_fn=lambda a, b: b, init_fn=lambda: jnp.float32(0))
+
+        plain = rule.plain(l)
+        if plain and not self.is_initializing():
+            count(causal_pairs)
+            return None, ()
+        with jax.named_scope("tpuframe/attn/index"):
+            x = jax.lax.stop_gradient(x)
+            dense = lambda name, n, dtype: nn.Dense(  # noqa: E731
+                n, use_bias=False, dtype=dtype, name=name)
+            qi = dense("index_q", heads * width, self.dtype)(x).reshape(b, l, heads, width)
+            ki = nn.LayerNorm(epsilon=self.norm_eps, dtype=self.dtype, name="index_k_norm")(
+                dense("index_k", width, self.dtype)(x))
+            w = dense("index_w", heads, jnp.float32)(x)
+            if plain:
+                return None, ()
+            qi, ki, w = jax.lax.stop_gradient((qi, ki, w))
+            if self.is_initializing():
+                # init's sample batch need not divide the mesh
+                chosen, counts = select_keys_reference(qi, ki, w, rule.topk)
+            else:
+                chosen, counts = select_keys(qi, ki, w, rule.topk, mesh=_mesh_or_none())
+                count(jnp.sum(counts))
+        return rule, (chosen,)
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False, rope=None) -> jax.Array:
@@ -444,14 +506,21 @@ class SelfAttention(nn.Module):
                 registry.counter(f"attention/tiles_{name}").inc(
                     value * b * self.num_heads)
 
+        mask, operands = self.mask, {}
+        if self.sparse_index:
+            if mask is not None or not self.causal:
+                raise ValueError("an index chooses among the keys not after a query: "
+                                 "sparse_index takes causal attention and no other mask rule")
+            mask, chosen = self._chosen_keys(x)
+            operands = {"mask_operands": chosen} if chosen else {}
         # a rule that names its kernels names its layers' scope too
         # (``tpuframe/attn/window``)
-        scope = "tpuframe/attn" + getattr(self.mask, "suffix", "").replace("_", "/")
+        scope = "tpuframe/attn" + getattr(mask, "suffix", "").replace("_", "/")
         with jax.named_scope(scope):
             out = _attend(
                 q, k, v, impl=self.attn_impl, causal=self.causal,
                 num_heads=self.num_heads, initializing=self.is_initializing(),
-                mask=self.mask, on_tiles=count_tiles,
+                mask=mask, on_tiles=count_tiles, **operands,
             )
         out = out.reshape(b, l, features)
         if gate is not None:
@@ -714,6 +783,8 @@ class Block(nn.Module):
     #: the sizes of a ``"linear_attention"`` layer (`GatedDeltaNet`'s
     #: arguments), as a tuple of (name, value)
     linear_attention: tuple = ()
+    #: multi-head attention's index of chosen keys (`SelfAttention.sparse_index`)
+    sparse_index: tuple = ()
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False, rope=None) -> jax.Array:
@@ -759,6 +830,7 @@ class Block(nn.Module):
                 num_kv_heads=self.num_kv_heads, qk_norm=self.qk_norm,
                 norm_eps=self.norm_eps, mask=mask, gated=self.attn_gated,
                 norm_unit_offset=self.norm_unit_offset, name="attn",
+                **({"sparse_index": self.sparse_index} if self.sparse_index else {}),
             )(y, train=train, rope=rope)
         if self.dropout:
             y = nn.Dropout(self.dropout, deterministic=not train)(y)
@@ -817,7 +889,10 @@ class TransformerLM(nn.Module):
     ``num_value_heads``, ``key_dim``, ``value_dim``, ``conv_taps``);
     ``attn_gated`` an output gate on multi-head attention; ``rope_dim``
     under ``head_dim`` turns the first ``rope_dim`` dimensions of every
-    head (a config's ``partial_rotary_factor``); ``norm_unit_offset``
+    head (a config's ``partial_rotary_factor``); ``sparse_index`` a learned
+    index that chooses the ``topk`` keys a query of a ``"full_attention"``
+    layer sees (``num_heads``, ``head_dim``, ``topk``; `SelfAttention`);
+    ``norm_unit_offset``
     every RMSNorm in the ``(1 + scale)`` form; ``rope_parameters`` rotary
     parameters by kind of attention layer, as a config publishes them
     (``{"full_attention": {"rope_type": "yarn", "rope_theta": ..,
@@ -878,6 +953,10 @@ class TransformerLM(nn.Module):
     norm_unit_offset: bool = False
     #: a ``"linear_attention"`` layer's sizes: a dict, kept as sorted items
     linear_attention: Any = ()
+    #: a learned index that chooses ``topk`` keys a query in every
+    #: ``"full_attention"`` layer (``num_heads``, ``head_dim``, ``topk``: a
+    #: config's ``sa_config``): a dict, kept as sorted items
+    sparse_index: Any = ()
 
     def __post_init__(self):
         # module attributes are hashed with the train state's treedef:
@@ -888,7 +967,7 @@ class TransformerLM(nn.Module):
             return tuple(frozen(x) for x in v) if isinstance(v, list) else v
 
         for name in ("rope_scaling", "moe_kwargs", "layer_types", "rope_parameters",
-                     "linear_attention"):
+                     "linear_attention", "sparse_index"):
             object.__setattr__(self, name, frozen(getattr(self, name)))
         super().__post_init__()
 
@@ -964,6 +1043,8 @@ class TransformerLM(nn.Module):
                 sliding_window=self.sliding_window, attn_gated=self.attn_gated,
                 norm_unit_offset=self.norm_unit_offset,
                 linear_attention=self.linear_attention, name=f"block{i}",
+                **({"sparse_index": self.sparse_index}
+                   if self.sparse_index and mixers[i] == "full_attention" else {}),
             )(x, train, ropes.get(mixers[i]))
         if head_len is not None:
             x = x[:, :head_len]

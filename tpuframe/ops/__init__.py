@@ -33,6 +33,10 @@ fallback and the correctness oracle for tests.
   attention): a recurrence with a (d_k, d_v) state a head, run in chunks;
   the pass that carries the state over the chunks is a kernel pair, the
   state resident in VMEM.
+- :func:`select_keys` — the learned index of sparse attention: index
+  scores of a tile of queries held in VMEM and the exact choice of
+  ``topk`` keys a query by bisection, one byte a (query, key) pair out
+  (what `SelectedKeysMask` reads in the attention schedules).
 - :func:`quant_encode` / :func:`quant_decode` — the compressed gradient
   wire's amax/scale/round/pack stages in one VMEM pass each
   (``parallel.compression`` calls them for the bucketed transport).
@@ -69,6 +73,9 @@ _LAZY = {
     "attention_reference": "tpuframe.ops.ring_attention",
     "BlockDiffusionMask": "tpuframe.ops.ring_attention",
     "SlidingWindowMask": "tpuframe.ops.ring_attention",
+    "SelectedKeysMask": "tpuframe.ops.ring_attention",
+    "select_keys": "tpuframe.ops.sparse_index",
+    "select_keys_reference": "tpuframe.ops.sparse_index",
     "ring_attention": "tpuframe.ops.ring_attention",
     "ring_attention_local": "tpuframe.ops.ring_attention",
     "short_conv": "tpuframe.ops.short_conv",

@@ -67,6 +67,12 @@ Both:
   kernels' names carry the rule's ``suffix`` in a trace
   (``tpuframe_flash_fwd_window``); a rule that is the causal mask on
   the row at hand runs as ``causal`` (`ring_attention.mask_or_causal`).
+  A rule may also read operands (``mask_operands=``: arrays the model
+  computes, one byte a (query, key) pair at most and shared by the
+  heads; `SelectedKeysMask` reads the keys a learned index chose): the
+  scan schedule scans a block of each with the K/V blocks, the kernels
+  fetch a (side, side) tile of each a grid step, and the tile plan is
+  what the rule can say before the operand exists.
 - Grouped heads: ``k`` and ``v`` may hold one head a group of query
   heads.  They stay that size in HBM; the kernels' ``index_map`` reads
   head ``j // group``, and dK/dV come out a query head and are summed
@@ -102,6 +108,8 @@ from tpuframe.ops.ring_attention import (
     _repeat_kv,
     _tile_grads,
     mask_or_causal,
+    pad_operands,
+    rule_operands,
 )
 
 __all__ = ["blockwise_attention", "blockwise_attention_reference",
@@ -155,14 +163,25 @@ def _tile_live(causal, n, block, kv_len):
     return lambda q_idx, k_idx: table[q_idx, k_idx]
 
 
-def _fwd_schedule(q_blocks, k_blocks, v_blocks, causal, scale, block, kv_len):
+def _query_rows(a, n, block):
+    """A rule's operand (B, L, L) as the rows of each Q block: (n, B, block, L)."""
+    return a.reshape(a.shape[0], n, block, -1).transpose(1, 0, 2, 3)
+
+
+def _key_columns(a, n, block):
+    """... as the columns of each K/V block: (n, B, L, block)."""
+    return a.reshape(a.shape[0], -1, n, block).transpose(2, 0, 1, 3)
+
+
+def _fwd_schedule(q_blocks, k_blocks, v_blocks, causal, scale, block, kv_len,
+                  operands=()):
     """Online-softmax forward over blocks -> (out_blocks, lse_blocks)."""
     n, b, _, h, _ = q_blocks.shape
     dv = v_blocks.shape[-1]  # the output takes the values' width
     block_pos = jnp.arange(block)
     live = _tile_live(causal, n, block, kv_len)
 
-    def q_body(q_blk, q_idx):
+    def q_body(q_blk, q_idx, rows):
         q_pos = q_idx * block + block_pos
         init = (
             jnp.zeros((b, block, h, dv), jnp.float32),
@@ -171,13 +190,13 @@ def _fwd_schedule(q_blocks, k_blocks, v_blocks, causal, scale, block, kv_len):
         )
 
         def kv_body(carry, xs):
-            k_blk, v_blk, k_idx = xs
+            k_blk, v_blk, k_idx, blocks = xs
 
             def update(c):
                 return _block_update(
                     q_blk, k_blk, v_blk, *c,
                     q_pos, k_idx * block + block_pos,
-                    causal, scale, kv_len=kv_len,
+                    causal, scale, kv_len=kv_len, blocks=blocks,
                 )
 
             # tiles entirely above the diagonal are SKIPPED at runtime,
@@ -186,7 +205,8 @@ def _fwd_schedule(q_blocks, k_blocks, v_blocks, causal, scale, block, kv_len):
             return carry, None
 
         (o, lsum, m), _ = lax.scan(
-            kv_body, init, (k_blocks, v_blocks, jnp.arange(n))
+            kv_body, init, (k_blocks, v_blocks, jnp.arange(n),
+                            tuple(_key_columns(a, n, block) for a in rows))
         )
         lsum = jnp.maximum(lsum, 1e-30)  # fully-masked (padded/causal) rows
         # logsumexp per row: -inf rows stay -inf (m = -inf dominates)
@@ -198,7 +218,8 @@ def _fwd_schedule(q_blocks, k_blocks, v_blocks, causal, scale, block, kv_len):
         return out, lse
 
     _, (outs, lses) = lax.scan(
-        lambda _, xs: (None, q_body(*xs)), None, (q_blocks, jnp.arange(n))
+        lambda _, xs: (None, q_body(*xs)), None,
+        (q_blocks, jnp.arange(n), tuple(_query_rows(a, n, block) for a in operands))
     )
     return outs, lses  # (n, B, blk, H, D) storage dtype, (n, B, H, blk) f32
 
@@ -283,6 +304,15 @@ def _valid(q_idx, k_idx, *, side, causal, kv_len, keys_first=False, **_):
         jnp.int32, (side, side), int(not keys_first))
     valid = k_pos < kv_len
     return valid & (k_pos <= q_pos) if causal else valid
+
+
+def _valid_by_operands(blocks, *, causal, kv_len, keys_first=False, **_):
+    """`_valid` under a rule that reads operands, from the tile of each
+    ((queries, keys) refs): the same for every head of a block, so a grid
+    step asks once.  Turned here where the keys come first, as the 32-bit
+    values Mosaic transposes."""
+    blocks = [ref[...].astype(jnp.int32) for ref in blocks]
+    return causal.allowed(None, None, kv_len, *(a.T if keys_first else a for a in blocks))
 
 
 def _visit(q_idx, k_idx, update, kind=None, *, causal, side, kv_len, l_pad, **_):
@@ -397,11 +427,12 @@ def _chunk(heads, group, narrow):
     return load, same, lambda x, i: jnp.where(mine[i], x, jnp.zeros_like(x)), update
 
 
-def _fwd_kernel(*refs, width, heads, group, narrow, **tile):
+def _fwd_kernel(*refs, width, heads, group, narrow, reads, **tile):
     """`_block_update` over the K/V blocks streaming past one Q block,
-    a head of the block at a time (`_chunk`)."""
-    (q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, l_ref, m_ref), q_idx, k_idx, kind = _step(
-        refs, width)
+    a head of the block at a time (`_chunk`); ``reads`` operand tiles of
+    the rule follow q, k and v."""
+    (q_ref, k_ref, v_ref, *rest), q_idx, k_idx, kind = _step(refs, width)
+    blocks, (o_ref, lse_ref, acc_ref, l_ref, m_ref) = rest[:reads], rest[reads:]
     load, part, only, update = _chunk(heads, group, narrow)
 
     @pl.when(pl.program_id(3) == 0)
@@ -412,12 +443,14 @@ def _fwd_kernel(*refs, width, heads, group, narrow, **tile):
 
     def step(masked):
         qs, ks, vs = load(q_ref), load(k_ref, kv=True), load(v_ref, kv=True)
+        chosen = _valid_by_operands(blocks, **tile) if masked and blocks else None
         for i in range(heads):
             v = part(vs, i, kv=True)
             # (queries, keys) f32
             s = _nt_dot(only(part(qs, i), i), part(ks, i, kv=True)) * tile["scale"]
             if masked:
-                s = jnp.where(_valid(q_idx, k_idx, **tile), s, -jnp.inf)
+                s = jnp.where(_valid(q_idx, k_idx, **tile) if chosen is None else chosen,
+                              s, -jnp.inf)
             m = m_ref[i]
             m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
             m_exp = m_new
@@ -440,7 +473,7 @@ def _fwd_kernel(*refs, width, heads, group, narrow, **tile):
             lse_ref[i] = _col_to_row(m_ref[i] + jnp.log(lsum))
 
 
-def _bwd_kernel(*refs, width, heads, group, narrow, **tile):
+def _bwd_kernel(*refs, width, heads, group, narrow, reads, **tile):
     """`_tile_grads` and the three products over the Q blocks streaming
     past one K/V block: the schedule's two passes in one, a head of the
     block at a time (`_chunk`).  The tile is
@@ -451,8 +484,9 @@ def _bwd_kernel(*refs, width, heads, group, narrow, **tile):
     rows of a dQ accumulator that spans the sequence and is written once
     a head: five tile products and one ``exp`` where two passes take
     seven and two."""
-    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dq_ref,
-     dk_acc, dv_acc, dq_acc), k_idx, q_idx, kind = _step(refs, width)
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest), k_idx, q_idx, kind = _step(
+        refs, width)
+    blocks, (dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc) = rest[:reads], rest[reads:]
     first, last = pl.program_id(3) == 0, pl.program_id(3) == pl.num_programs(3) - 1
     side = tile["side"]
     load, part, only, update = _chunk(heads, group, narrow)
@@ -470,12 +504,14 @@ def _bwd_kernel(*refs, width, heads, group, narrow, **tile):
         qs, dos = load(q_ref), load(do_ref)
         ks, vs = load(k_ref, kv=True), load(v_ref, kv=True)
         rows = pl.ds(pl.multiple_of(q_idx * side, side), side)
+        chosen = (_valid_by_operands(blocks, keys_first=True, **tile)
+                  if masked and blocks else None)
         for i in range(heads):
             q, do, k = part(qs, i), part(dos, i), part(ks, i, kv=True)
             s = _nt_dot(only(k, i), q) * tile["scale"]  # (keys, queries) f32
             if masked:
                 s = jnp.where(
-                    _valid(q_idx, k_idx, keys_first=True, **tile),
+                    _valid(q_idx, k_idx, keys_first=True, **tile) if chosen is None else chosen,
                     s, -jnp.inf)
             p = jnp.exp(s - lse_ref[i])
             update(dv_acc, i, lambda dv: dv + _dot(p.astype(do.dtype), do))
@@ -555,7 +591,9 @@ def _flash_call(kernel, name, operands, outs, scratch, *, streams, side,
     in the model's layout (B, L, H, D), and the results come back in it:
     role ``"q"`` / ``"k"`` says which side's block index the array
     follows (``"all"``: a head's whole sequence, resident); ``"stat"`` is
-    a (B, H, 1, L) row statistic of the queries.  Each array is handed
+    a (B, H, 1, L) row statistic of the queries; ``"mask"`` an operand of
+    the rule, (B, L, L) with [row, query, key], read a (side, side) tile
+    a grid step whichever side streams.  Each array is handed
     to the kernel by its own head width (`_in_place`): the folded rows
     (B, L, H*D) as they are, a block a head's lanes, or a heads-first
     (B, H, L, D) copy; the kernel sees a (positions, D) block either
@@ -591,11 +629,18 @@ def _flash_call(kernel, name, operands, outs, scratch, *, streams, side,
         return clamp(streamed, held) if causal else streamed
 
     narrow = chunk > 1 and not _in_place(operands[0][0].shape[3])
+    reads = sum(role == "mask" for _, role in operands)
 
     def rows_of(a):
         return chunk > 1 or _in_place(a.shape[3])
 
     def spec(a, role, heads):
+        if role == "mask":
+            return pl.BlockSpec(
+                (None, side, side),
+                lambda b_, h_, held, streamed, *plan: (
+                    b_, block_index("q", held, streamed, plan),
+                    block_index("k", held, streamed, plan)))
         if role == "stat":
             return pl.BlockSpec(
                 (None, chunk, 1, side),
@@ -618,7 +663,7 @@ def _flash_call(kernel, name, operands, outs, scratch, *, streams, side,
 
     def placed(a, role):
         """An operand as the kernel takes it: its rows, or the copy."""
-        if role == "stat":
+        if role in ("stat", "mask"):
             return a
         return a.reshape(b, l_pad, -1) if rows_of(a) else _heads_first(a)
 
@@ -653,7 +698,7 @@ def _flash_call(kernel, name, operands, outs, scratch, *, streams, side,
 
     grid = dict(
         grid=(b, h // chunk, n, width or n),
-        in_specs=[spec(a, role, a.shape[2]) for a, role in operands],
+        in_specs=[spec(a, role, a.shape[2] if a.ndim == 4 else h) for a, role in operands],
         out_specs=tuple(spec(a, role, h) for a, role in outs),
         scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in scratch],
     )
@@ -663,7 +708,7 @@ def _flash_call(kernel, name, operands, outs, scratch, *, streams, side,
     results = pl.pallas_call(
         functools.partial(kernel, causal=causal, scale=scale, side=side,
                           kv_len=kv_len, l_pad=l_pad, width=width, heads=chunk,
-                          group=h // operands[1][0].shape[2], narrow=narrow),
+                          group=h // operands[1][0].shape[2], narrow=narrow, reads=reads),
         **grid,
         out_shape=tuple(written(a, role) for a, role in outs),
         compiler_params=pltpu.CompilerParams(
@@ -733,14 +778,14 @@ def _bwd_vmem_bytes(l_pad, d, dtype):
 # under different rules holds one trace a rule, not one a layer; under a
 # rule the kernel's name carries the rule's ``suffix``.
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
-def _flash_fwd(q, k, v, causal, scale, side, kv_len, interpret):
+def _flash_fwd(q, k, v, causal, scale, side, kv_len, interpret, operands=()):
     """(B, L, H, D) q/k, (B, L, H, Dv) v -> out (B, L, H, Dv) in the
     storage dtype and the rows' logsumexp (B, H, 1, L) float32."""
     (b, l_pad, h, d), dv = q.shape, v.shape[-1]
     n = _chunk_heads(h, k.shape[2], d, dv, l_pad)
     return _flash_call(
         _fwd_kernel, "tpuframe_flash_fwd" + getattr(causal, "suffix", ""),
-        [(q, "q"), (k, "k"), (v, "k")],
+        [(q, "q"), (k, "k"), (v, "k")] + [(a, "mask") for a in operands],
         [(jax.ShapeDtypeStruct((b, l_pad, h, dv), q.dtype), "q"),
          (jax.ShapeDtypeStruct((b, h, 1, l_pad), jnp.float32), "stat")],
         [(side, n * dv), (n, side, 1), (n, side, 1)],
@@ -753,7 +798,8 @@ def _flash_fwd(q, k, v, causal, scale, side, kv_len, interpret):
 
 
 @functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10))
-def _flash_bwd(q, k, v, do, lse, delta, causal, scale, side, kv_len, interpret):
+def _flash_bwd(q, k, v, do, lse, delta, causal, scale, side, kv_len, interpret,
+               operands=()):
     """dQ, dK, dV in the layout (B, L, H, D) and the dtypes of q, k, v.
     ``lse`` and ``delta`` (rowsum(dO . O)) are (B, H, 1, L) rows."""
     (b, l_pad, h, d), dv = q.shape, v.shape[-1]
@@ -761,7 +807,8 @@ def _flash_bwd(q, k, v, do, lse, delta, causal, scale, side, kv_len, interpret):
     like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
     dk, dv_, dq = _flash_call(
         _bwd_kernel, "tpuframe_flash_bwd" + getattr(causal, "suffix", ""),
-        [(q, "q"), (k, "k"), (v, "k"), (do, "q"), (lse, "stat"), (delta, "stat")],
+        [(q, "q"), (k, "k"), (v, "k"), (do, "q"), (lse, "stat"), (delta, "stat")]
+        + [(a, "mask") for a in operands],
         [(like(k), "k"), (like(v), "k"), (like(q), "all")],
         [(side, n * d), (side, n * dv), (l_pad, n * d)], streams="q", side=side,
         chunk=n, held_axis="arbitrary", vmem_bytes=_bwd_vmem_bytes(l_pad, n * d, q.dtype),
@@ -804,38 +851,40 @@ def _row_delta(out, g, heads):
     return delta[:, :, None, :]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _blockwise_padded(q, k, v, causal, block, kv_len, scale, interpret):
-    out, _ = _blockwise_padded_fwd(q, k, v, causal, block, kv_len, scale, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _blockwise_padded(q, k, v, operands, causal, block, kv_len, scale, interpret):
+    out, _ = _blockwise_padded_fwd(q, k, v, operands, causal, block, kv_len, scale, interpret)
     return out
 
 
-def _blockwise_padded_fwd(q, k, v, causal, block, kv_len, scale, interpret):
+def _blockwise_padded_fwd(q, k, v, operands, causal, block, kv_len, scale, interpret):
     """``interpret`` None: the scan schedule over blocks of ``block``;
     else the kernels (True: in Pallas interpret mode), ``block`` their
     (forward, backward) `_tiles`, both dividing the padded length.
     Either way the residuals are q, k, v, the output and the rows'
-    logsumexp, each held once."""
+    logsumexp, each held once, and the rule's ``operands`` (padded, no
+    gradient) where it reads any."""
     if interpret is not None:
-        out, lse = _flash_fwd(q, k, v, causal, scale, block[0], kv_len, interpret)
+        out, lse = _flash_fwd(q, k, v, causal, scale, block[0], kv_len, interpret, operands)
         # kept with the heads folded into the rows, (B, L, H*D), as the
         # projections made them: whole lanes at any head width, and what
         # the kernels read in place where a head is whole lanes wide (XLA
         # lays a (B, L, H, D) array kept for the backward out padded: a
         # 192-wide row to 256 lanes, a 64-wide one to 128).
-        return out, (*(a.reshape(*a.shape[:2], -1) for a in (q, k, v, out)), lse)
+        return out, (*(a.reshape(*a.shape[:2], -1) for a in (q, k, v, out)), lse, operands)
     b, l_pad, h, d = q.shape
     n = l_pad // block
     outs, lses = _fwd_schedule(
         _to_blocks(q, n, block), _to_blocks(k, n, block),
-        _to_blocks(v, n, block), causal, scale, block, kv_len,
+        _to_blocks(v, n, block), causal, scale, block, kv_len, operands,
     )
     out = _from_blocks(outs).astype(q.dtype)
-    return out, (q, k, v, out, lses)
+    return out, (q, k, v, out, lses, operands)
 
 
 def _blockwise_padded_bwd(causal, block, kv_len, scale, interpret, res, g):
-    q, k, v, out, lses = res
+    q, k, v, out, lses, operands = res
+    no_grad = tuple(None for _ in operands)
     if interpret is not None:
         heads = g.shape[2]
         delta = _row_delta(out, g.reshape(out.shape), heads)
@@ -850,10 +899,10 @@ def _blockwise_padded_bwd(causal, block, kv_len, scale, interpret, res, g):
             q, k = lax.optimization_barrier((q, k))
         if chunk == 1 and not _in_place(v.shape[-1]):
             v = lax.optimization_barrier(v)
-        return _flash_bwd(
+        return (*_flash_bwd(
             q, k, v, g.astype(q.dtype), lses, delta,
-            causal, scale, block[1], kv_len, interpret,
-        )
+            causal, scale, block[1], kv_len, interpret, operands,
+        ), no_grad)
     b, l_pad, h, d = q.shape
     n = l_pad // block
     do = g.astype(q.dtype)
@@ -874,16 +923,16 @@ def _blockwise_padded_bwd(causal, block, kv_len, scale, interpret, res, g):
 
     # Pass 1: dQ.  Outer scan over Q blocks (ys only), inner scan over
     # K/V blocks with a (B, blk, H, D) f32 accumulator.
-    def dq_body(q_blk, do_blk, lse_blk, delta_blk, q_idx):
+    def dq_body(q_blk, do_blk, lse_blk, delta_blk, q_idx, rows):
         q_pos = q_idx * block + block_pos
 
         def inner(dq, xs):
-            k_blk, v_blk, k_idx = xs
+            k_blk, v_blk, k_idx, blocks = xs
 
             def update(dq):
                 _, ds = _tile_grads(
                     q_blk, k_blk, v_blk, do_blk, lse_blk, delta_blk,
-                    q_pos, k_idx * block + block_pos, causal, scale, kv_len,
+                    q_pos, k_idx * block + block_pos, causal, scale, kv_len, blocks,
                 )
                 return dq + jnp.einsum(
                     "bhqk,bkhd->bqhd", ds.astype(k_blk.dtype), k_blk,
@@ -894,26 +943,28 @@ def _blockwise_padded_bwd(causal, block, kv_len, scale, interpret, res, g):
             return dq, None
 
         dq0 = jnp.zeros((b, block, h, d), jnp.float32)
-        dq, _ = lax.scan(inner, dq0, (k_blocks, v_blocks, idx))
+        dq, _ = lax.scan(inner, dq0, (k_blocks, v_blocks, idx,
+                                      tuple(_key_columns(a, n, block) for a in rows)))
         return dq
 
     _, dq_blocks = lax.scan(
         lambda _, xs: (None, dq_body(*xs)), None,
-        (q_blocks, do_blocks, lses, delta_blocks, idx),
+        (q_blocks, do_blocks, lses, delta_blocks, idx,
+         tuple(_query_rows(a, n, block) for a in operands)),
     )
 
     # Pass 2: dK/dV.  Outer scan over K/V blocks, inner over Q blocks.
-    def dkv_body(k_blk, v_blk, k_idx):
+    def dkv_body(k_blk, v_blk, k_idx, columns):
         k_pos = k_idx * block + block_pos
 
         def inner(carry, xs):
-            q_blk, do_blk, lse_blk, delta_blk, q_idx = xs
+            q_blk, do_blk, lse_blk, delta_blk, q_idx, blocks = xs
 
             def update(c):
                 dk, dv = c
                 p, ds = _tile_grads(
                     q_blk, k_blk, v_blk, do_blk, lse_blk, delta_blk,
-                    q_idx * block + block_pos, k_pos, causal, scale, kv_len,
+                    q_idx * block + block_pos, k_pos, causal, scale, kv_len, blocks,
                 )
                 dv = dv + jnp.einsum(
                     "bhqk,bqhd->bkhd", p.astype(do_blk.dtype), do_blk,
@@ -932,18 +983,20 @@ def _blockwise_padded_bwd(causal, block, kv_len, scale, interpret, res, g):
         zero_v = jnp.zeros((b, block, h, v.shape[-1]), jnp.float32)
         (dk, dv), _ = lax.scan(
             inner, (zero_k, zero_v),
-            (q_blocks, do_blocks, lses, delta_blocks, idx),
+            (q_blocks, do_blocks, lses, delta_blocks, idx,
+             tuple(_query_rows(a, n, block) for a in columns)),
         )
         return dk, dv
 
     _, (dk_blocks, dv_blocks) = lax.scan(
-        lambda _, xs: (None, dkv_body(*xs)), None, (k_blocks, v_blocks, idx)
+        lambda _, xs: (None, dkv_body(*xs)), None,
+        (k_blocks, v_blocks, idx, tuple(_key_columns(a, n, block) for a in operands))
     )
 
     dq = _from_blocks(dq_blocks).astype(q.dtype)
     dk = _from_blocks(dk_blocks).astype(k.dtype)
     dv = _from_blocks(dv_blocks).astype(v.dtype)
-    return dq, dk, dv
+    return dq, dk, dv, no_grad
 
 
 _blockwise_padded.defvjp(_blockwise_padded_fwd, _blockwise_padded_bwd)
@@ -960,7 +1013,7 @@ def _check_shapes(q, k, v):
         )
 
 
-def _padded_call(q, k, v, causal, block, scale, interpret):
+def _padded_call(q, k, v, causal, block, scale, interpret, operands=()):
     l, d = q.shape[1], q.shape[-1]
     l_pad = pad_to(l, block if interpret is None else block[0])
     if l_pad != l:
@@ -968,7 +1021,10 @@ def _padded_call(q, k, v, causal, block, scale, interpret):
         q, k, v = (jnp.pad(a, pad) for a in (q, k, v))
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    out = _blockwise_padded(q, k, v, causal, block, l, float(scale), interpret)
+    # a rule that is causal on this row runs without its operands
+    operands = (pad_operands(rule_operands(causal, operands), l, l_pad)
+                if _is_rule(causal) else ())
+    out = _blockwise_padded(q, k, v, operands, causal, block, l, float(scale), interpret)
     return out[:, :l]
 
 
@@ -981,6 +1037,7 @@ def blockwise_attention_reference(
     block_size: int | None = None,
     scale: float | None = None,
     mask=None,
+    mask_operands=(),
 ) -> jax.Array:
     """The scan schedule: what :func:`blockwise_attention` runs wherever
     its kernels do not, and what they are held to.  Grouped heads run as
@@ -989,7 +1046,7 @@ def blockwise_attention_reference(
     k, v = _repeat_kv(q, k, v)
     block = min(_SCAN_BLOCK if block_size is None else block_size, q.shape[1])
     return _padded_call(q, k, v, mask_or_causal(causal, mask, q.shape[1]),
-                        block, scale, None)
+                        block, scale, None, mask_operands)
 
 
 def engage_kernels(q, *, block_size: int | None = None,
@@ -1053,14 +1110,17 @@ def blockwise_attention(
     scale: float | None = None,
     interpret: bool | None = None,
     mask=None,
+    mask_operands=(),
 ) -> jax.Array:
     """Exact attention over (B, L, H, D) without materializing (.., L, L).
 
     ``scale`` replaces the default ``1/sqrt(D)``; ``v`` may have a width
     of its own (latent attention: 192-wide queries and keys, 128-wide
     values), which the output takes.  ``mask``, a rule on positions
-    (`ring_attention`'s protocol), stands in ``causal``'s place; ``k``
-    and ``v`` may hold one head a group of query heads.
+    (`ring_attention`'s protocol), stands in ``causal``'s place, with
+    ``mask_operands`` the arrays it reads where it reads any (one byte a
+    (query, key) pair, never a head's own); ``k`` and ``v`` may hold one
+    head a group of query heads.
 
     ``block_size`` None: the scan schedule takes ``_SCAN_BLOCK`` (512),
     the kernels tiles that follow L alone (`_tiles`).  An explicit value
@@ -1076,7 +1136,8 @@ def blockwise_attention(
     interpret = engage_kernels(q, block_size=block_size, interpret=interpret, v=v)
     if interpret is None:
         return blockwise_attention_reference(
-            q, k, v, causal=causal, block_size=block_size, scale=scale, mask=mask)
+            q, k, v, causal=causal, block_size=block_size, scale=scale, mask=mask,
+            mask_operands=mask_operands)
     return _padded_call(
         q, k, v, mask_or_causal(causal, mask, q.shape[1]),
-        _tiles(q.shape[1], block_size), scale, interpret)
+        _tiles(q.shape[1], block_size), scale, interpret, mask_operands)

@@ -115,6 +115,12 @@ OPS_REGISTRY = {
         "reference": "gated_delta_reference",
         "parity_test": "tests/test_gated_delta.py::TestAgainstTheRecurrence::test_outputs_and_all_five_gradients",
     },
+    "sparse_index": {
+        "module": "tpuframe.ops.sparse_index",
+        "symbol": "select_keys",
+        "reference": "select_keys_reference",
+        "parity_test": "tests/test_keye_vl2.py::TestIndexOp::test_the_kernel_matches_the_oracle",
+    },
     "moe_gating": {
         "module": "tpuframe.ops.moe_gating",
         "symbol": "moe_dispatch_combine",
@@ -142,6 +148,7 @@ OP_NAME_TOKENS = (
     ("conv_silu", ("conv_silu",)),
     ("head_norm_rope", ("head_norm_rope",)),
     ("gated_delta", ("gated_delta",)),
+    ("sparse_index", ("tpuframe_index",)),
     ("grouped_matmul", ("tpuframe_grouped", "ragged-dot", "ragged_dot", "grouped_matmul")),
     ("moe_gating", ("top_k_gating", "moe", "expert_dispatch")),
 )

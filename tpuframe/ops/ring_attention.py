@@ -63,6 +63,20 @@ from tpuframe.core.runtime import DATA_AXIS, FSDP_AXIS, SEQUENCE_AXIS
 # - ``suffix``: what the flash kernels' names carry under this rule in a
 #   trace (``tpuframe_flash_fwd`` + suffix), so that a model's layers
 #   under different rules can be told apart; empty keeps the plain names.
+#
+# A rule may also *read operands*: which scores count is then something
+# the model computes, and no rule on positions can say it.  The rule
+# itself stays the hashable value (what it answers statically it answers
+# as above, judging a tile by what every operand could leave in it); the
+# arrays travel beside it, ``mask_operands=``, through every schedule:
+#
+# - ``reads``: how many operands, each (B, L, L) with [row, query, key]
+#   (0: a rule on positions alone).  A byte a (query, key) pair at most,
+#   shared by all heads; padding up to a tile is the schedules' (`pad_operands`).
+# - ``allowed(q_pos, k_pos, kv_len, *blocks)``: ``blocks`` are the
+#   operands' values at those positions, [query, key] as the positions
+#   broadcast (a leading batch axis outside a kernel, where a tile is one
+#   row's).
 
 
 def mask_or_causal(causal: bool, mask, length: int):
@@ -204,6 +218,66 @@ class SlidingWindowMask(NamedTuple):
     suffix = "_window"
 
 
+class SelectedKeysMask(NamedTuple):
+    """Attention over keys the model chose: query ``i`` sees key ``j`` iff
+    the operand ``chosen`` (B, L, L) int8 holds a 1 at [i, j]
+    (`ops.sparse_index.select_keys` makes it: the ``topk`` keys not after
+    the query that its index ranks highest, every key not after it where
+    there are no more than ``topk``).  What is static: a row no longer
+    than ``topk`` is causal; a tile whose last query lies under ``topk``
+    is judged as causal judges it; every other tile not above the diagonal
+    may hold a chosen key and is masked element-wise by the operand."""
+
+    topk: int
+    #: a NamedTuple compares as its tuple: without this a band of as many
+    #: keys would be the same static argument to every cache that keys on a rule
+    kind: str = "selected"
+
+    reads = 1
+
+    def allowed(self, q_pos, k_pos, kv_len=None, chosen=None):
+        """The operand's block at these positions says it all: causality,
+        keys past ``kv_len`` and padded rows are `pad_operands`'s."""
+        return chosen != 0
+
+    def tiles(self, q_lo, q_hi, k_lo, k_hi):
+        live = k_lo <= q_hi
+        return live, live & (k_hi <= q_lo) & (q_hi < self.topk)
+
+    def area(self, length: int) -> int:
+        """``sum over t of min(t + 1, topk)``."""
+        k = min(self.topk, length)
+        return k * (k + 1) // 2 + (length - k) * k
+
+    def fits(self, length: int) -> bool:
+        return self.topk >= 1
+
+    def plain(self, length: int) -> bool:
+        return length <= self.topk
+
+    suffix = "_select"
+
+
+def rule_operands(mask, operands) -> tuple:
+    """``operands`` as the tuple the rule ``mask`` reads (none for a rule on
+    positions, for causal and for no mask)."""
+    operands = tuple(operands or ())
+    reads = getattr(mask, "reads", 0)
+    if len(operands) != reads:
+        raise ValueError(f"{mask} reads {reads} operand(s), got {len(operands)}")
+    return operands
+
+
+def pad_operands(operands, length: int, l_pad: int) -> tuple:
+    """A rule's operands (B, length, length) for a row padded to ``l_pad``
+    positions: no key past the row counts, and a row past it sees key 0, so
+    that every logsumexp a schedule keeps is finite."""
+    if l_pad == length:
+        return tuple(operands)
+    grow = [(0, 0), (0, l_pad - length), (0, l_pad - length)]
+    return tuple(jnp.pad(a, grow).at[:, length:, 0].set(1) for a in operands)
+
+
 def _repeat_kv(q, k, v):
     """Grouped heads as multi-head attention: each key/value head copied
     to the query heads of its group (query head ``j`` uses ``j // group``)."""
@@ -218,7 +292,7 @@ def _repeat_kv(q, k, v):
 
 def attention_reference(
     q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = False,
-    scale: float | None = None, mask=None,
+    scale: float | None = None, mask=None, mask_operands=(),
 ) -> jax.Array:
     """Full (unsharded) attention oracle, (B, L, H, D) layout.
 
@@ -226,8 +300,9 @@ def attention_reference(
     its rotary scaling into it); ``v`` may be narrower or wider than
     ``q``/``k``: the output takes ``v``'s width.  ``mask`` is a rule on
     positions (the protocol above) in place of ``causal`` (the dense
-    mask is built from it here); ``k`` and ``v`` may hold fewer heads
-    than ``q``, one a group."""
+    mask is built from it here, with ``mask_operands`` where the rule
+    reads any); ``k`` and ``v`` may hold fewer heads than ``q``, one a
+    group."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     k, v = _repeat_kv(q, k, v)
@@ -236,25 +311,39 @@ def attention_reference(
     if causal:
         qi = jnp.arange(q.shape[1])[:, None]
         ki = jnp.arange(k.shape[1])[None, :]
-        seen = ki <= qi if causal is True else causal.allowed(qi, ki)
+        blocks = () if causal is True else rule_operands(mask, mask_operands)
+        if blocks:
+            seen = causal.allowed(qi, ki, None, *blocks)[:, None]
+        else:
+            seen = ki <= qi if causal is True else causal.allowed(qi, ki)
         scores = jnp.where(seen, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def _seen(causal, q_pos, k_pos):
-    """(Lq, Lk) bool: ``causal`` True, or a rule on positions in its place."""
+def _seen(causal, q_pos, k_pos, blocks=()):
+    """(Lq, Lk) bool: ``causal`` True, or a rule on positions in its place;
+    (B, Lq, Lk) under a rule that reads operands, ``blocks`` their values
+    at these positions."""
     if causal is True:
         return k_pos[None, :] <= q_pos[:, None]
+    if blocks:
+        return causal.allowed(q_pos[:, None], k_pos[None, :], None, *blocks)
     return causal.allowed(q_pos[:, None], k_pos[None, :])
 
 
+def _over_heads(seen):
+    """A mask (Lq, Lk) or (B, Lq, Lk) against scores (B, H, Lq, Lk)."""
+    return seen[None, None] if seen.ndim == 2 else seen[:, None]
+
+
 def _block_update(q, k, v, o, l, m, q_pos, k_pos, causal, scale,
-                  kv_len: int | None = None):
+                  kv_len: int | None = None, blocks=()):
     """Online-softmax accumulation of one K/V block into (o, l, m).
 
     ``kv_len`` masks padded key positions (``k_pos >= kv_len``) — used by
     the blockwise schedule, which pads the sequence to a block multiple.
+    ``blocks``: what a rule that reads operands reads at this tile.
 
     q/k/v keep their storage dtype: the MXU multiplies bf16 natively and
     accumulates f32 (``preferred_element_type``), so upcasting the
@@ -267,8 +356,8 @@ def _block_update(q, k, v, o, l, m, q_pos, k_pos, causal, scale,
         * scale
     )  # (B, H, Lq, Lk) f32
     if causal:
-        mask = _seen(causal, q_pos, k_pos)  # (Lq, Lk)
-        s = jnp.where(mask[None, None], s, -jnp.inf)
+        mask = _seen(causal, q_pos, k_pos, blocks)  # (Lq, Lk)
+        s = jnp.where(_over_heads(mask), s, -jnp.inf)
     if kv_len is not None:
         s = jnp.where((k_pos < kv_len)[None, None, None, :], s, -jnp.inf)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1))  # (B, H, Lq)
@@ -292,7 +381,7 @@ def _block_update(q, k, v, o, l, m, q_pos, k_pos, causal, scale,
 
 
 def _tile_grads(q_blk, k_blk, v_blk, do_blk, lse_blk, delta_blk,
-                q_pos, k_pos, causal, scale, kv_len=None):
+                q_pos, k_pos, causal, scale, kv_len=None, blocks=()):
     """(p, ds) for one (Q block, K/V block) tile of the flash backward.
 
     Probabilities are recomputed from the saved logsumexp —
@@ -310,10 +399,10 @@ def _tile_grads(q_blk, k_blk, v_blk, do_blk, lse_blk, delta_blk,
     if kv_len is not None:
         valid = (k_pos < kv_len)[None, :]
     if causal:
-        cmask = _seen(causal, q_pos, k_pos)
+        cmask = _seen(causal, q_pos, k_pos, blocks)
         valid = cmask if valid is None else (valid & cmask)
     if valid is not None:
-        s = jnp.where(valid[None, None], s, -jnp.inf)
+        s = jnp.where(_over_heads(valid), s, -jnp.inf)
     lse_safe = jnp.where(jnp.isneginf(lse_blk), 0.0, lse_blk)
     p = jnp.exp(s - lse_safe[..., None])  # (B, H, bq, bk) f32, exact rows
     dp = jnp.einsum("bqhd,bkhd->bhqk", do_blk, v_blk,
