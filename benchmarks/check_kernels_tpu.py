@@ -24,12 +24,13 @@ their oracles' XLA fusions), and the grouped products of the expert layer
 (the three kernels against the oracle, and each timed beside
 ``jax.lax.ragged_dot`` at the four expert cells' shapes), and the index of
 sparse attention (exact top-k a query) with the flash kernels under the rule
-that reads its choice.
+that reads its choice, and the expert layer's un-sort (the kernel against XLA's
+gather a pair, timed beside it at the six expert cells' shapes).
 
 Usage: python benchmarks/check_kernels_tpu.py [--only a,b,...]
 (exits 1 on any failure).  ``--only`` runs a named subset — sections:
 layer_norm, cross_entropy, quant_wire, blockwise, flash_layout, window, sparse_index, ring,
-ulysses, moe_windows, short_conv, conv_silu, head_norm_rope, grouped, gated_delta
+ulysses, moe_windows, short_conv, conv_silu, head_norm_rope, grouped, unsort, gated_delta
 (``--grouped-tiles 128,256,512`` prices other row tiles beside the default).
 """
 
@@ -840,6 +841,77 @@ def _check_grouped(jax, jnp, np, rng) -> None:
                 print(json.dumps(line), flush=True)
 
 
+#: (choices a token, held experts, experts) of the expert layers in dsv2lite_seq4096,
+#: sdar_blockdiff_seq4096, lfm2moe_seq4096, mellum2_seq8192, qwen3next_seq8192 and
+#: keyevl2_seq8192 (8,192 tokens a step each), and the rows' width
+_UNSORT_SHAPES = ((6, 8, 64, 2048), (8, 16, 128, 2048), (4, 8, 32, 2048), (8, 8, 64, 2304),
+                  (10, 16, 512, 2048), (8, 8, 128, 2048))
+
+
+def _check_unsort(jax, jnp, np, rng) -> None:
+    """The un-sort kernel (``tpuframe_unsort``) against XLA's form
+    (``models.moe._sum_choices_impl``) at the six expert cells' shapes, 8,192
+    tokens routed as a seeded router routes them (fair, and with the held
+    experts' scores lifted unevenly, so that the fullest holds more than the mean,
+    and with one tile's 256 tokens all choosing one expert, a run of several
+    rounds of windows),
+    on two arrays of rows a shape (the forward's and a cotangent's: the
+    same call): equal where a token has at most two rows here, within one
+    rounding of bfloat16 elsewhere; NaN in the slots past the groups must
+    not reach a sum.  Both forms are timed (a line a shape: ms a call, the
+    median of 5 laps of 20, and the kernel's share of the least time HBM
+    takes to read the routed rows and write every token's; the time passes
+    or fails nothing)."""
+    import importlib
+
+    from tpuframe.models.moe import _sum_choices_impl, slot_bound
+
+    unsort = importlib.import_module("tpuframe.ops.unsort")
+    n = 8192
+    for k, held, experts, d in _UNSORT_SHAPES:
+        cap = slot_bound(n * k, held, experts)
+        for load, lift in (("fair", 0.0), ("lifted", 0.35), ("bunched", 0.0)):
+            scores = rng.standard_normal((n, experts))
+            scores[:, :held] += lift * rng.random(held)
+            if load == "bunched":  # a tile of tokens all choose one expert: further rounds
+                scores[256:512, 0] += 10.0
+            key = np.argsort(-scores, axis=1)[:, :k].reshape(-1)
+            key = np.where(key < held, key, held)
+            order = np.argsort(key, kind="stable")
+            sizes = np.bincount(key, minlength=held + 1)[:held]
+            routed = int(sizes.sum())
+            if routed > cap:  # the layer would run a second window: not this section's
+                print(json.dumps({"check": "unsort_skipped", "routed": routed, "cap": cap}), flush=True)
+                continue
+            tok = jnp.asarray((order // k)[:cap], jnp.int32)
+            inv = jnp.asarray(np.argsort(order), jnp.int32)
+            sizes = jnp.asarray(sizes, jnp.int32)
+            live = (jnp.arange(cap) < routed)[:, None]
+            here = np.bincount(np.asarray(tok[:routed]), minlength=n)
+            xla = jax.jit(functools.partial(_sum_choices_impl, n=n))
+            kernel = jax.jit(functools.partial(unsort.unsort, n=n, interpret=False))
+            tag = f"unsort_{k}of{experts}_held{held}_{cap}x{d}_{load}"
+            line = {"check": "unsort_ms_a_call", "shape": [k, held, experts, cap, d], "load": load,
+                    "routed": routed, "fullest_over_mean": float(sizes.max() * held / routed),
+                    "window": unsort.unsort_window(cap, d, held, n, jnp.bfloat16)}
+            for rows_of in ("fwd", "cotangent"):
+                rows = jnp.asarray(rng.standard_normal((cap, d)), jnp.bfloat16)
+                want = xla(jnp.where(live, rows, 0), inv).astype(jnp.float32)
+                rows = jnp.where(live, rows, jnp.nan)
+                got = kernel(rows, tok, sizes).astype(jnp.float32)
+                gap = jnp.abs(got - want)
+                record(f"{tag}_{rows_of}_equal_at_two_rows_or_fewer",
+                       float(jnp.max(jnp.where((here <= 2)[:, None], gap, 0.0))), 1e-30)
+                record(f"{tag}_{rows_of}_within_one_rounding",
+                       float(jnp.max(gap / jnp.maximum(jnp.abs(want), 1e-3))), 2.0 ** -7)
+                if rows_of == "fwd":
+                    line["kernel_ms"] = ms = _ms_a_call(jax, kernel, (rows, tok, sizes))
+                    line["kernel_pct_of_hbm_roofline"] = 100 * (
+                        (routed + n) * d * 2 / 819e9) / (ms * 1e-3)
+                    line["xla_ms"] = _ms_a_call(jax, xla, (jnp.where(live, rows, 0), inv))
+            print(json.dumps(line), flush=True)
+
+
 def _check_gated_delta(jax, jnp, np, rng) -> None:
     """The gated delta rule's kernels against the recurrence position by
     position (float32, a ragged length, decays as strong as the published
@@ -1077,6 +1149,7 @@ SECTIONS = {
     "conv_silu": (_check_conv_silu, ("conv_silu",)),
     "head_norm_rope": (_check_head_norm_rope, ("head_norm_rope",)),
     "grouped": (_check_grouped, ("grouped_matmul",)),
+    "unsort": (_check_unsort, ("unsort",)),
     "gated_delta": (_check_gated_delta,
                     ("gated_delta", "head_norm_rope", "blockwise_attention")),
 }

@@ -13,9 +13,9 @@ import jax.numpy as jnp
 import pytest
 
 # by module path: tpuframe.ops re-exports functions under some of these names
-ce, ln, qw, sc = (
+ce, ln, qw, sc, us = (
     importlib.import_module(f"tpuframe.ops.{m}")
-    for m in ("cross_entropy", "layer_norm", "quant_wire", "short_conv")
+    for m in ("cross_entropy", "layer_norm", "quant_wire", "short_conv", "unsort")
 )
 
 _F32 = jnp.float32
@@ -61,6 +61,11 @@ KERNELS = {
     "tpuframe_conv_silu_bwd": (
         lambda x, w, *gs: sc._conv_silu_bwd_pallas(x, w, gs, 1, 128, False),
         (_x(1, 64, 768), _x(4, 512), _x(1, 64, 128), _x(1, 64, 128), _x(1, 64, 256)),
+    ),
+    # 256 slots of 8 experts' rows for 256 tokens
+    "tpuframe_unsort": (
+        lambda r, t, s: us._unsort(r, t, s, n=256, window=32, interpret=False),
+        (_x(256, 128), _x(256, dtype=jnp.int32), _x(8, dtype=jnp.int32)),
     ),
 }
 

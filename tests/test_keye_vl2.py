@@ -604,7 +604,12 @@ def test_the_older_configurations_lower_to_the_parents_program(name, digest, ker
     positions, under causal or under no mask.  The first five digests are
     `tests/test_qwen3_next.py`'s, unchanged; the sixth was taken with this very
     function under jax 0.9.0 on PR 46's commit, as were the six with the
-    kernels in interpret mode (the form the chip runs)."""
+    kernels in interpret mode (the form the chip runs).  PR 48 (the un-sort as
+    a kernel, `ops/unsort.py`) left all twelve as they stand: at these sizes a
+    layer's buffers hold a slot a pair (`slot_bound`), so the un-sort is the
+    gather by ``inv`` in both modes, and the expert layer carries the groups'
+    sizes to the kernel's path only where the buffers are bounded
+    (`tests/test_unsort.py` holds the layer with the kernel in)."""
     interpret = {
         "deepseek-v2-lite": "38cb80fea5179eb002f883e4a617b816ab37f92bba5c502a5573f7cc8148bfd0",
         "sdar-30b-a3b-chat": "07ec3cd55dd5ea804aa0e0051242348238a588a129d82648c18a20eec2ab0527",

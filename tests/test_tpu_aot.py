@@ -388,8 +388,11 @@ def test_qwen3_next_period_holds_its_kernels_once_a_kind(v5e_runtime):
     (2048, (2, 4096), dict(num_experts=32, top_k=4, expert_dim=1792, held=(0, 8),
                            scoring="sigmoid", select_bias=True, aux_loss_weight=0.0)),
     (2304, (1, 8192), dict(num_experts=64, top_k=8, expert_dim=896, held=(0, 8))),
+    (2048, (1, 8192), dict(num_experts=512, top_k=10, expert_dim=512, held=(0, 16))),
+    (2048, (1, 8192), dict(num_experts=128, top_k=8, expert_dim=768, held=(0, 8))),
 ], ids=["dsv2lite_12288x2048x1408x8", "sdar_16384x2048x768x16",
-        "lfm2_16384x2048x1792x8", "mellum2_16384x2304x896x8"])
+        "lfm2_16384x2048x1792x8", "mellum2_16384x2304x896x8",
+        "qwen3next_5120x2048x512x16", "keyevl2_8192x2048x768x8"])
 def test_expert_layer_holds_the_grouped_kernels(v5e_runtime, one_chip, width, tokens, layer):
     """One no-drop expert layer as each expert cell runs it (bfloat16
     products over float32 master weights, the slot buffers `slot_bound`
@@ -403,7 +406,10 @@ def test_expert_layer_holds_the_grouped_kernels(v5e_runtime, one_chip, width, to
     (the row gradient contracts over the weights' last axis in place; the
     one layout copy left, of w_in and w_gate, feeds the further windows'
     loop) and none passes through a select of its own (the kernels write
-    the zeros)."""
+    the zeros).  The un-sort is this repo's kernel too (PR 48), once each
+    way: Mosaic takes its windows' DMAs at the dtype's sublane tile and its
+    product contracted over the slots, and outside the further windows'
+    loops no array has a row a (token, choice) pair."""
     from tpuframe.models.moe import MoEMLP
 
     v5e_runtime(1)
@@ -428,12 +434,16 @@ def test_expert_layer_holds_the_grouped_kernels(v5e_runtime, one_chip, width, to
     # bodies, lowered for themselves, keep `ragged_dot`
     for kernel in ("fwd", "drows", "dweights"):
         assert lowered.as_text().count(f'kernel_name = "tpuframe_grouped_{kernel}"') == 2
+    # the un-sort once for each jitted body that calls it (the forward's, the backward's)
+    assert lowered.as_text().count('kernel_name = "tpuframe_unsort"') == 2
     text = lowered.compile().as_text()
     entry = text[text.index("\nENTRY "):]
     for kernel in ("fwd", "drows", "dweights"):
         assert len(_kernel_calls(text, f"tpuframe_grouped_{kernel}")) == 3
         assert len(_kernel_calls(entry, f"tpuframe_grouped_{kernel}")) == 3
     assert "ragged" not in entry
+    assert len(_kernel_calls(text, "tpuframe_unsort")) == len(_kernel_calls(entry, "tpuframe_unsort")) == 2
+    assert f"[{layer['top_k']},8192,{width}]" not in entry
     g, k, n = params["w_in"].shape
     # every array of a weight leaf's shape the program writes (as the entry's
     # text has them: `name = dtype[G,K,N]{layout} opcode(`)

@@ -38,6 +38,7 @@ from tpuframe.ops.grouped_matmul import (
     tiles_visited,
 )
 from tpuframe.ops.moe_gating import moe_dispatch_combine
+from tpuframe.ops.unsort import unsort
 
 
 def moe_rules():
@@ -61,8 +62,8 @@ def _sum_choices_impl(rows, inv, n):
     return picked.sum(axis=0, dtype=jnp.float32).astype(rows.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _take_tokens(tokens, tok, inv, n):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _take_tokens(tokens, tok, inv, sizes, n, kernels):
     """``rows[a] = tokens[tok[a]]`` for the sorted assignments ``tok``
     names (all of them, or a window).  Its transpose is written as a
     gather too (un-sort by ``inv``, then sum each token's choices): the
@@ -70,34 +71,44 @@ def _take_tokens(tokens, tok, inv, n):
     return tokens[tok]
 
 
-def _take_tokens_fwd(tokens, tok, inv, n):
-    return tokens[tok], (tok, inv)
+def _take_tokens_fwd(tokens, tok, inv, sizes, n, kernels):
+    return tokens[tok], (tok, inv, sizes)
 
 
-def _take_tokens_bwd(n, res, g):
-    tok, inv = res
-    return _sum_choices(g, tok, inv, n), None, None
+def _take_tokens_bwd(n, kernels, res, g):
+    return _sum_choices(g, *res, n, kernels), None, None, None
 
 
 _take_tokens.defvjp(_take_tokens_fwd, _take_tokens_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _sum_choices(rows, tok, inv, n):
+def _sum_choices_fn(rows, tok, inv, sizes, n, kernels):
+    xla = functools.partial(_sum_choices_impl, rows, inv, n)
+    if sizes is None or not kernels:
+        return xla()
+    return unsort(rows, tok, sizes, n, otherwise=xla)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _sum_choices(rows, tok, inv, sizes, n, kernels):
     """``out[t] = sum of the rows assigned from token t``: the transpose
-    of :func:`_take_tokens`, again a gather.  ``rows`` may be a window of
-    the slots (those ``tok`` names, ``inv`` counted from its first); a
-    slot outside it adds zero."""
-    return _sum_choices_impl(rows, inv, n)
+    of :func:`_take_tokens`.  ``rows`` may be a window of the slots (those
+    ``tok`` names, ``inv`` counted from its first, ``sizes`` the groups'
+    share of it, None where ``rows`` are all the slots); a slot outside it
+    adds zero.  A window is ``ops.unsort``'s: its kernel reads the routed
+    rows alone and, where it does not run (``kernels=False``, a CPU,
+    several devices, a shape it refuses), :func:`_sum_choices_impl` is the
+    program as it was: a gather a (token, choice) pair by ``inv``, which
+    the kernel never reads."""
+    return _sum_choices_fn(rows, tok, inv, sizes, n, kernels)
 
 
-def _sum_choices_fwd(rows, tok, inv, n):
-    return _sum_choices_impl(rows, inv, n), (tok, inv)
+def _sum_choices_fwd(rows, tok, inv, sizes, n, kernels):
+    return _sum_choices_fn(rows, tok, inv, sizes, n, kernels), (tok, inv, sizes)
 
 
-def _sum_choices_bwd(n, res, g):
-    tok, inv = res
-    return _take_tokens(g, tok, inv, n), None, None
+def _sum_choices_bwd(n, kernels, res, g):
+    return _take_tokens(g, *res, n, kernels), None, None, None
 
 
 _sum_choices.defvjp(_sum_choices_fwd, _sum_choices_bwd)
@@ -134,13 +145,14 @@ def _expert_parts(tokens, w_gate, w_in, w_out, weight, tok, inv, sizes, lo, cap,
     more for each layer's executable to compile and load for traffic
     that overflows the buffers."""
     n = tokens.shape[0]
+    window = None  # the groups' share of a window of the slots, for the un-sort
     if cap < tok.shape[0]:
         ends = jnp.cumsum(sizes)
-        sizes = jnp.clip(ends, lo, lo + cap) - jnp.clip(ends - sizes, lo, lo + cap)
+        window = sizes = jnp.clip(ends, lo, lo + cap) - jnp.clip(ends - sizes, lo, lo + cap)
         tok = jax.lax.dynamic_slice_in_dim(tok, lo, cap)
         weight = jax.lax.dynamic_slice_in_dim(weight, lo, cap)
         inv = inv - lo
-    rows = _take_tokens(tokens, tok, inv, n)
+    rows = _take_tokens(tokens, tok, inv, window, n, kernels)
     product = functools.partial(grouped_matmul, group_sizes=sizes, kernels=kernels)
     pre = product(rows, w_in)
     if w_gate is not None:
@@ -149,7 +161,7 @@ def _expert_parts(tokens, w_gate, w_in, w_out, weight, tok, inv, sizes, lo, cap,
     else:
         gate, hid = None, act(pre)
     y = product(hid, w_out)
-    out = _sum_choices(_scale_rows(y, weight), tok, inv, n)
+    out = _sum_choices(_scale_rows(y, weight), tok, inv, window, n, kernels)
     return out, (rows, gate, pre, hid, y, weight, tok, inv, sizes)
 
 
@@ -191,7 +203,8 @@ def _window_bwd(parts, w_gate, w_in, w_out, n, act, g, kernels=True):
     three weights and the window's gate weights."""
     rows, gate, pre, hid, y, weight, tok, inv, sizes = parts
     grads = functools.partial(grouped_matmul_grads, group_sizes=sizes, kernels=kernels)
-    d_y, d_weight = jax.vjp(_scale_rows, y, weight)[1](_take_tokens(g, tok, inv, n))
+    d_y, d_weight = jax.vjp(_scale_rows, y, weight)[1](
+        _take_tokens(g, tok, inv, sizes, n, kernels))
     d_hid, d_out = grads(hid, w_out, g=d_y)
     if w_gate is not None:
         d_gate, d_pre = jax.vjp(lambda a, b: act(a) * b, gate, pre)[1](d_hid)
@@ -199,7 +212,8 @@ def _window_bwd(parts, w_gate, w_in, w_out, n, act, g, kernels=True):
     else:
         (d_pre,), d_rows, d_wg = jax.vjp(act, pre)[1](d_hid), 0, None
     d_more, d_in = grads(rows, w_in, g=d_pre)
-    return _sum_choices(d_rows + d_more, tok, inv, n), d_wg, d_in, d_out, d_weight
+    d_tokens = _sum_choices(d_rows + d_more, tok, inv, sizes, n, kernels)
+    return d_tokens, d_wg, d_in, d_out, d_weight
 
 
 def _experts_windowed_bwd(cap, act, res, g):

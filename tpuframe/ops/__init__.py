@@ -18,6 +18,10 @@ fallback and the correctness oracle for tests.
   group, the expert product of the no-drop mixture-of-experts layer:
   three kernels driven by the group sizes (the product and both
   gradients' products), ``jax.lax.ragged_dot`` where they do not run.
+- :func:`unsort` — the same layer's un-sort: every token's routed rows
+  summed out of the sorted slots, the routed rows read once and each
+  token's row written once (XLA's form gathers a row a (token, choice)
+  pair and reduces them).
 - :func:`short_conv` — LFM2's double-gated short convolution between
   its two projections (gate, a few causal depthwise taps along the
   sequence, gate) in one pass each way.
@@ -78,6 +82,8 @@ _LAZY = {
     "select_keys_reference": "tpuframe.ops.sparse_index",
     "ring_attention": "tpuframe.ops.ring_attention",
     "ring_attention_local": "tpuframe.ops.ring_attention",
+    "unsort": "tpuframe.ops.unsort",
+    "unsort_reference": "tpuframe.ops.unsort",
     "short_conv": "tpuframe.ops.short_conv",
     "short_conv_reference": "tpuframe.ops.short_conv",
     "conv_silu": "tpuframe.ops.short_conv",
@@ -115,9 +121,9 @@ def __dir__():
 
 
 class _OpsModule(_types.ModuleType):
-    """Six exports share their kernel module's name
+    """Seven exports share their kernel module's name
     (``blockwise_attention``, ``gated_delta``, ``grouped_matmul``,
-    ``head_norm_rope``, ``ring_attention``, ``short_conv``), and
+    ``head_norm_rope``, ``ring_attention``, ``short_conv``, ``unsort``), and
     importing such a submodule makes the import machinery rebind the
     module object over the package attribute of the same name — which
     would shadow the function for every later
@@ -136,7 +142,7 @@ def _shadow_proof(name):
 
 
 for _name in ("blockwise_attention", "gated_delta", "grouped_matmul", "head_norm_rope",
-              "ring_attention", "short_conv"):
+              "ring_attention", "short_conv", "unsort"):
     setattr(_OpsModule, _name, _shadow_proof(_name))
 
 _sys.modules[__name__].__class__ = _OpsModule

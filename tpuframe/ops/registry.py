@@ -91,6 +91,12 @@ OPS_REGISTRY = {
         "parity_test":
             "tests/test_latent_moe.py::TestGroupedMatmul::test_forward_and_both_gradients_with_empty_groups",
     },
+    "unsort": {
+        "module": "tpuframe.ops.unsort",
+        "symbol": "unsort",
+        "reference": "unsort_reference",
+        "parity_test": "tests/test_unsort.py::TestKernel::test_the_cells_shapes_against_xlas_form",
+    },
     "short_conv": {
         "module": "tpuframe.ops.short_conv",
         "symbol": "short_conv",
@@ -149,6 +155,7 @@ OP_NAME_TOKENS = (
     ("head_norm_rope", ("head_norm_rope",)),
     ("gated_delta", ("gated_delta",)),
     ("sparse_index", ("tpuframe_index",)),
+    ("unsort", ("tpuframe_unsort",)),
     ("grouped_matmul", ("tpuframe_grouped", "ragged-dot", "ragged_dot", "grouped_matmul")),
     ("moe_gating", ("top_k_gating", "moe", "expert_dispatch")),
 )
