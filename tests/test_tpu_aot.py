@@ -444,6 +444,17 @@ def test_expert_layer_holds_the_grouped_kernels(v5e_runtime, one_chip, width, to
     assert "ragged" not in entry
     assert len(_kernel_calls(text, "tpuframe_unsort")) == len(_kernel_calls(entry, "tpuframe_unsort")) == 2
     assert f"[{layer['top_k']},8192,{width}]" not in entry
+    # the route is made by counting (PR 49): under its scope nothing as long
+    # as the pairs is sorted, gathered from a number at a time or scattered
+    # into, in either pass or in the further windows' loops.  What is left of
+    # that kind is the expert choice's: `top_k`'s sort over a token's experts
+    # and, at 10 choices of 512, its transpose, which XLA makes a sort of the
+    # pairs and a scatter into the (tokens x experts) scores, under no scope
+    pairs = tokens[0] * tokens[1] * layer["top_k"]
+    routes = [line for line in text.splitlines()
+              if "tpuframe/moe/route" in line and re.search(r" (sort|gather|scatter)\(", line)]
+    assert [line for line in routes if "top_k" in line]
+    assert not [line for line in routes if f"[{pairs}]" in line]
     g, k, n = params["w_in"].shape
     # every array of a weight leaf's shape the program writes (as the entry's
     # text has them: `name = dtype[G,K,N]{layout} opcode(`)
@@ -462,6 +473,39 @@ def test_expert_layer_holds_the_grouped_kernels(v5e_runtime, one_chip, width, to
     assert not [name for name in copies if f"%{name}," in calls or f"%{name})" in calls]
     moved = [(name, op) for name, _, op in leaves if re.search("transpose|select", name + " " + op)]
     assert not moved, moved
+
+
+@pytest.mark.parametrize("tokens, k, held, experts", [
+    (8192, 6, 8, 64), (8192, 8, 16, 128), (8192, 4, 8, 32), (8192, 8, 8, 64), (8192, 10, 16, 512),
+    (8192, 8, 8, 128),
+], ids=["dsv2lite", "sdar", "lfm2", "mellum2", "qwen3next", "keyevl2"])
+def test_the_counting_route_compiles_for_v5e(one_chip, tokens, k, held, experts):
+    """The expert layer's plan of a window of slots at each expert cell's
+    shape, with its transpose: fusions, three products and the scans of the
+    `count x nb` edges; no sort, no gather, no scatter, no loop, and no
+    array of `cap x count x nb` compares is stored."""
+    from tpuframe.models import moe
+
+    pairs = tokens * k
+    cap = moe.slot_bound(pairs, held, experts)
+    assert cap < pairs
+
+    def plan(idx, vals, d_weight):
+        key = jnp.where(idx.reshape(-1) < held, idx.reshape(-1), held)
+        sizes, edges = moe._count_blocks(key, held)
+        tok, weight, pair = moe._window_plan(key, edges, vals, jnp.int32(0), cap, k)
+        return sizes, tok, weight, moe._spread_weights(d_weight, pair, pairs)
+
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip) for shape, dtype in (
+        ((tokens, k), jnp.int32), ((pairs,), jnp.float32), ((cap,), jnp.float32))]
+    compiled = jax.jit(plan).lower(*args).compile()
+    text = compiled.as_text()
+    assert not re.search(r" (sort|gather|scatter|while)\(", text)
+    assert len(re.findall(r" convolution\(", text)) == 3
+    nb = pairs // moe._BLOCK
+    # the largest array stored is the rows of the one-hot product, 5 byte planes
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 5 * cap * moe._BLOCK * 2
+    assert f"[{cap},{held * nb}]" not in text[text.index("\nENTRY "):]
 
 
 def test_gpt2_heads_per_shard_compile_for_v5e_2x2(v5e_runtime):

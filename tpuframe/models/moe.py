@@ -12,10 +12,13 @@ Components:
 - :class:`MoEMLP` — drop-in replacement for a transformer block's MLP:
   top-k softmax gating and a balance loss exposed via the ``"aux_loss"``
   mutable collection; either capacity-factor truncation (GShard dense
-  dispatch) or, with ``capacity_factor=None``, no token dropped: sorted
-  assignments through ``ops.grouped_matmul`` in buffers bounded to the
-  rows routed here, the experts ``held`` here out of all the router
-  scores, and a shared MLP beside them.
+  dispatch) or, with ``capacity_factor=None``, no token dropped: the
+  assignments in slots ordered by expert through ``ops.grouped_matmul``
+  in buffers bounded to the rows routed here, the experts ``held`` here
+  out of all the router scores, and a shared MLP beside them.  Where the
+  buffers are shorter than the (token, choice) pairs the slots' plan is
+  made by counting, for the buffers' slots alone; a layer that holds a
+  slot a pair sorts the pairs.
 - :func:`moe_rules` — ParallelPlan rules placing expert weights on the
   ``expert`` axis (compose with the TP/fsdp rules).
 """
@@ -23,11 +26,12 @@ Components:
 from __future__ import annotations
 
 import functools
-from typing import Any
+from typing import Any, NamedTuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from tpuframe.core.runtime import EXPERT_AXIS
@@ -50,7 +54,143 @@ def moe_rules():
     )
 
 
+#: pairs a block of the counting route: a block's keys are one row of lanes,
+#: and a count inside a block is at most what a bfloat16 holds exactly
+_BLOCK = 256
+#: the slot of a pair routed to no held expert: outside every window
+_NOWHERE = 1 << 30
+
+
+class _Counted(NamedTuple):
+    """The counting route's tables (:func:`_count_blocks`) and the window's
+    first slot: what a pair's slot is read from where XLA's form of the
+    un-sort asks for it (:func:`_pair_slots`); the kernels never do."""
+
+    key: jax.Array    # (P,) a pair's key: its held expert, or `count`
+    edges: jax.Array  # (count * nb,) slots up to the end of (expert, block)
+    lo: jax.Array     # the window's first slot
+
+
+def _blocks_of(pairs: int) -> int:
+    return -(-pairs // _BLOCK)
+
+
+def _in_blocks(flat, fill):
+    """(P,) -> (nb, _BLOCK): the pairs in blocks, the last filled up."""
+    short = -flat.shape[0] % _BLOCK
+    if short:
+        flat = jnp.pad(flat, (0, short), constant_values=fill)
+    return flat.reshape(-1, _BLOCK)
+
+
+def _count_blocks(key, count: int):
+    """``key`` (P,), a pair's held expert or ``count`` -> ``(sizes, edges)``:
+    the pairs of each held expert (count,), and for every (expert, block of
+    ``_BLOCK`` pairs), expert-major, the sorted slots up to the end of that
+    block's pairs of that expert (count * nb,): ascending, so a slot's
+    expert and block are one count of compares.  Compares and sums of 0/1
+    numbers over whole arrays; nothing is sorted or scattered."""
+    held = lax.iota(jnp.int32, count)[:, None, None]
+    per_block = jnp.sum((_in_blocks(key, count)[None] == held).astype(jnp.int32), axis=2)
+    return jnp.sum(per_block, axis=1), jnp.cumsum(per_block.reshape(-1))  # per_block: (count, nb)
+
+
+def _byte_planes(x, planes: int):
+    """The low ``planes`` bytes of ``x``'s bits, each as bfloat16 numbers
+    0..255 along a new leading axis: what a one-hot product moves exactly,
+    whatever the bits spell (a NaN, an infinity, a number under the
+    normal range)."""
+    bits = lax.bitcast_convert_type(x, jnp.uint32) if x.dtype != jnp.uint32 else x
+    shifts = (8 * lax.iota(jnp.uint32, planes)).reshape((planes,) + (1,) * x.ndim)
+    return ((bits[None] >> shifts) & 0xFF).astype(jnp.bfloat16)
+
+
+def _from_planes(planes):
+    """uint32 bits from byte planes along the leading axis (exact whole
+    numbers 0..255 in any float dtype)."""
+    shifts = (8 * lax.iota(jnp.uint32, planes.shape[0])).reshape((-1,) + (1,) * (planes.ndim - 1))
+    return jnp.sum(planes.astype(jnp.uint32) << shifts, axis=0)
+
+
+def _window_plan(key, edges, vals, lo, cap: int, k: int):
+    """The plan of the window ``[lo, lo + cap)`` of the slots, by counting:
+    ``tok`` (cap,) the token of the pair in each slot, ``weight`` (cap,) its
+    gate value out of ``vals`` (P,), ``pair`` (cap,) its number ``t k + j``
+    (-1 past the live slots, where ``tok`` and ``weight`` are 0).  Slots go
+    by expert and, inside an expert, by pair number: what a stable sort of
+    the keys gives, element for element.
+
+    A slot's (expert, block) is the count of ``edges`` at or under it; its
+    block's keys and gate values come as rows out of the (nb, _BLOCK) tables
+    by a one-hot product on byte planes (exact: one term a row); the pair is
+    the lane whose running count of the expert's keys reaches the slot's
+    rank in the block (a product with a triangle of ones).  The gate value
+    is selected, never multiplied: a non-finite one stays with its pair."""
+    nb, bf16 = _blocks_of(key.shape[0]), jnp.bfloat16
+    count = edges.shape[0] // nb
+    key_planes = 1 if count < 256 else 2
+    slot = lo + lax.iota(jnp.int32, cap)
+    under = edges[None, :] <= slot[:, None]  # (cap, count * nb), never stored
+    at = jnp.sum(under.astype(jnp.int32), axis=1)
+    rank = slot + 1 - jnp.max(jnp.where(under, edges[None, :], 0), axis=1)
+    expert, block, live = at // nb, at % nb, slot < edges[-1]
+    table = jnp.concatenate([_byte_planes(_in_blocks(key, count).astype(jnp.uint32), key_planes),
+                             _byte_planes(_in_blocks(vals.astype(jnp.float32), 0), 4)])
+    onehot = (block[:, None] == lax.iota(jnp.int32, nb)[None, :]).astype(bf16)
+    picked = jnp.einsum("ab,pbl->pal", onehot, table, preferred_element_type=bf16)
+    mine = (_from_planes(picked[:key_planes]).astype(jnp.int32) == expert[:, None])
+    lanes = lax.iota(jnp.int32, _BLOCK)
+    upto = (lanes[:, None] <= lanes[None, :]).astype(bf16)
+    run = jnp.dot(mine.astype(bf16), upto, preferred_element_type=jnp.float32)
+    hit = mine & (run == rank[:, None].astype(jnp.float32)) & live[:, None]
+    lane = jnp.sum(jnp.where(hit, lanes[None, :], 0), axis=1)
+    chosen = jnp.sum(jnp.where(hit[None], picked[key_planes:].astype(jnp.float32), 0.0), axis=2)
+    weight = lax.bitcast_convert_type(_from_planes(chosen), jnp.float32)
+    pair = jnp.where(live, block * _BLOCK + lane, -1)
+    return jnp.where(live, pair // k, 0), weight, pair
+
+
+def _spread_weights(d_weight, pair, pairs: int):
+    """The transpose of the plan's selection: (pairs,) zeros with
+    ``d_weight[a]`` at pair ``pair[a]``, each pair from at most one slot,
+    so no sum rounds.  A one-hot product on byte planes again, (nb, cap) @
+    (cap, 4 * _BLOCK): nothing is scattered."""
+    nb = _blocks_of(pairs)
+    # tied to the cotangent: what is made of `pair` alone (the one-hot, 2 MiB
+    # a layer) XLA would else make in the forward pass and keep across the
+    # step's peak, as it did `ops/unsort.py`'s `place` (PERF.md, PR 48)
+    d_weight, pair = lax.optimization_barrier((d_weight, pair))
+    block, lane = pair // _BLOCK, pair % _BLOCK  # a dead slot's block is -1: no row
+    onehot = (lax.iota(jnp.int32, nb)[:, None] == block[None, :]).astype(jnp.bfloat16)
+    here = lane[:, None] == lax.iota(jnp.int32, _BLOCK)[None, :]
+    planes = jnp.where(here[None], _byte_planes(d_weight.astype(jnp.float32), 4)[:, :, None], 0)
+    spread = jnp.einsum("ba,pal->pbl", onehot, planes, preferred_element_type=jnp.float32)
+    out = lax.bitcast_convert_type(_from_planes(spread), jnp.float32)
+    return out.reshape(-1)[:pairs]
+
+
+def _pair_slots(route: _Counted):
+    """(P,) every pair's slot counted from the window's first:
+    the slots of its expert's pairs in the blocks before + its expert's
+    pairs before it in its block; ``_NOWHERE`` for a pair routed to no
+    held expert.  What ``argsort(argsort(key))`` gives on the held pairs.
+    Made where XLA's form of the un-sort reads it and nowhere else."""
+    key, edges, lo = route
+    nb = _blocks_of(key.shape[0])
+    count = edges.shape[0] // nb
+    blocks = _in_blocks(key, count)
+    before = jnp.concatenate([jnp.zeros((1,), edges.dtype), edges[:-1]]).reshape(count, nb)
+    held = lax.iota(jnp.int32, count)[:, None, None]
+    start = jnp.sum(jnp.where(blocks[None] == held, before[:, :, None], 0), axis=0)
+    lanes = lax.iota(jnp.int32, _BLOCK)
+    earlier = (blocks[:, :, None] == blocks[:, None, :]) & (lanes[None, :] < lanes[:, None])[None]
+    slots = start + jnp.sum(earlier.astype(jnp.int32), axis=2)
+    return jnp.where(blocks < count, slots - lo, _NOWHERE).reshape(-1)[:key.shape[0]]
+
+
 def _sum_choices_impl(rows, inv, n):
+    if isinstance(inv, _Counted):
+        inv = _pair_slots(inv)
     if rows.shape[0] == inv.shape[0]:
         return rows[inv].reshape(n, -1, rows.shape[-1]).sum(axis=1)
     # a window of the slots: a slot outside it reads as zero.  Gathered
@@ -64,10 +204,12 @@ def _sum_choices_impl(rows, inv, n):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def _take_tokens(tokens, tok, inv, sizes, n, kernels):
-    """``rows[a] = tokens[tok[a]]`` for the sorted assignments ``tok``
-    names (all of them, or a window).  Its transpose is written as a
-    gather too (un-sort by ``inv``, then sum each token's choices): the
-    scatter-add autodiff would emit is the slow form on the TPU."""
+    """``rows[a] = tokens[tok[a]]`` for the slots ``tok`` names (all of
+    them, or a window).  Its transpose is written as a gather too (un-sort
+    by ``inv``, then sum each token's choices): the scatter-add autodiff
+    would emit is the slow form on the TPU.  ``inv`` is every pair's slot,
+    or the counting route's tables (:class:`_Counted`) it is read from
+    where XLA's form of the un-sort asks."""
     return tokens[tok]
 
 
@@ -132,26 +274,11 @@ def _scale_rows(y, weight):
     return y * weight[:, None].astype(y.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(9, 10, 11))
-def _expert_parts(tokens, w_gate, w_in, w_out, weight, tok, inv, sizes, lo, cap, act,
-                  kernels=True):
-    """``sum p_e E_e(x)`` over the sorted slots ``[lo, lo + cap)``, and
-    the arrays its backward pass reads.  ``tok`` and ``weight`` are whole
-    windows long.  Jitted, like :func:`_window_bwd`: the layers of a
-    model are then one traced and lowered function the step calls, not a
-    copy each (seconds of a job's first step).  ``kernels=False`` (the
-    further windows) keeps the grouped products on ``ragged_dot``: a
-    loop's body is lowered for itself, and every kernel in it is one
-    more for each layer's executable to compile and load for traffic
-    that overflows the buffers."""
+def _slots_mlp(tokens, w_gate, w_in, w_out, weight, tok, inv, sizes, window, act, kernels):
+    """``sum p_e E_e(x)`` over the slots ``tok`` and ``weight`` name (all
+    of them, or a window: ``window`` is then the groups' share of it, for
+    the un-sort), and the arrays the backward pass reads."""
     n = tokens.shape[0]
-    window = None  # the groups' share of a window of the slots, for the un-sort
-    if cap < tok.shape[0]:
-        ends = jnp.cumsum(sizes)
-        window = sizes = jnp.clip(ends, lo, lo + cap) - jnp.clip(ends - sizes, lo, lo + cap)
-        tok = jax.lax.dynamic_slice_in_dim(tok, lo, cap)
-        weight = jax.lax.dynamic_slice_in_dim(weight, lo, cap)
-        inv = inv - lo
     rows = _take_tokens(tokens, tok, inv, window, n, kernels)
     product = functools.partial(grouped_matmul, group_sizes=sizes, kernels=kernels)
     pre = product(rows, w_in)
@@ -165,40 +292,73 @@ def _expert_parts(tokens, w_gate, w_in, w_out, weight, tok, inv, sizes, lo, cap,
     return out, (rows, gate, pre, hid, y, weight, tok, inv, sizes)
 
 
+@functools.partial(jax.jit, static_argnums=(9, 10, 11))
+def _expert_parts(tokens, w_gate, w_in, w_out, weight, tok, inv, sizes, lo, cap, act,
+                  kernels=True):
+    """A layer that holds a slot a pair (``cap`` is the pairs): the sorted
+    route's ``weight``, ``tok`` and ``inv`` whole.  ``lo`` and ``cap`` are
+    read by nothing: they stay in the signature so that this layer's program
+    is, byte for byte, the one it was when windows of a sorted route came
+    through here too.  Jitted, like :func:`_counted_parts`: the layers of a
+    model are then one traced and lowered function the step calls, not a
+    copy each (seconds of a job's first step)."""
+    return _slots_mlp(tokens, w_gate, w_in, w_out, weight, tok, inv, sizes, None, act, kernels)
+
+
+@functools.partial(jax.jit, static_argnums=(9, 10, 11, 12))
+def _counted_parts(tokens, w_gate, w_in, w_out, vals, key, sizes, edges, lo, cap, k, act,
+                   kernels=True):
+    """The window ``[lo, lo + cap)`` of the slots, its plan made by
+    counting (:func:`_window_plan`): the experts' sum over it, the arrays
+    its backward pass reads and each slot's pair.  ``kernels=False`` (the
+    further windows) keeps the grouped products on ``ragged_dot`` and the
+    un-sort on XLA's form: a loop's body is lowered for itself, and every
+    kernel in it is one more for each layer's executable to compile and
+    load for traffic that overflows the buffers."""
+    with jax.named_scope("tpuframe/moe/route"):
+        ends = jnp.cumsum(sizes)
+        window = jnp.clip(ends, lo, lo + cap) - jnp.clip(ends - sizes, lo, lo + cap)
+        tok, weight, pair = _window_plan(key, edges, vals, lo, cap, k)
+    out, parts = _slots_mlp(tokens, w_gate, w_in, w_out, weight, tok, _Counted(key, edges, lo),
+                            window, window, act, kernels)
+    return out, parts, pair
+
+
 def _windows(sizes, cap):
     """Windows of ``cap`` slots that hold the pairs routed here."""
     return jnp.maximum(-(-jnp.sum(sizes) // cap), 1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
-def _experts_windowed(tokens, w_gate, w_in, w_out, weight, tok, inv, sizes, cap, act):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+def _experts_windowed(tokens, w_gate, w_in, w_out, vals, key, sizes, edges, cap, k, act):
     """The held experts' MLPs through buffers of ``cap`` slots: the
-    window of the sorted slots that holds every pair routed here unless
-    the router sent more than ``cap``, and then window after window until
-    all are done.  Nothing is dropped, and no buffer has a slot a pair.
+    window of the slots that holds every pair routed here unless the
+    router sent more than ``cap``, and then window after window until all
+    are done, each asking :func:`_counted_parts` for its own plan.  Nothing
+    is dropped, no buffer has a slot a pair, and nothing as long as the
+    pairs is sorted, gathered from or scattered into.
 
     A ``custom_vjp``: what the first window computed is saved for the
     backward pass, ``cap`` rows long; a further window saves nothing and
-    computes its forward pass again there."""
-    return _experts_windowed_fwd(tokens, w_gate, w_in, w_out, weight, tok, inv,
-                                 sizes, cap, act)[0]
+    computes its forward pass again there.  The gate values' cotangent
+    (``vals``, a number a pair) comes from each window's ``cap`` slots by
+    :func:`_spread_weights`."""
+    return _experts_windowed_fwd(tokens, w_gate, w_in, w_out, vals, key, sizes, edges,
+                                 cap, k, act)[0]
 
 
-def _experts_windowed_fwd(tokens, w_gate, w_in, w_out, weight, tok, inv, sizes, cap, act):
-    # the gate weights and the slots' tokens in whole windows
-    pad = (0, -tok.shape[0] % cap)
-    padded = jnp.pad(weight, pad)
-    args = (tokens, w_gate, w_in, w_out, padded, jnp.pad(tok, pad), inv, sizes)
-    out, parts = _expert_parts(*args, jnp.int32(0), cap, act)
+def _experts_windowed_fwd(tokens, w_gate, w_in, w_out, vals, key, sizes, edges, cap, k, act):
+    args = (tokens, w_gate, w_in, w_out, vals, key, sizes, edges)
+    out, *first = _counted_parts(*args, jnp.int32(0), cap, k, act)
     out = jax.lax.fori_loop(
         1, _windows(sizes, cap),
-        lambda i, acc: acc + _expert_parts(*args, i * cap, cap, act, False)[0], out)
-    return out, (parts, args)
+        lambda i, acc: acc + _counted_parts(*args, i * cap, cap, k, act, False)[0], out)
+    return out, (first, args)
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 7))
-def _window_bwd(parts, w_gate, w_in, w_out, n, act, g, kernels=True):
-    """The transposes of :func:`_expert_parts`' lines, last to first, each
+@functools.partial(jax.jit, static_argnames=("n", "act", "kernels"))
+def _window_bwd(parts, pair, *, w_gate, w_in, w_out, n, act, g, kernels=True):
+    """The transposes of :func:`_slots_mlp`'s lines, last to first, each
     from the arrays the forward pass made: cotangents of the tokens, the
     three weights and the window's gate weights."""
     rows, gate, pre, hid, y, weight, tok, inv, sizes = parts
@@ -213,25 +373,23 @@ def _window_bwd(parts, w_gate, w_in, w_out, n, act, g, kernels=True):
         (d_pre,), d_rows, d_wg = jax.vjp(act, pre)[1](d_hid), 0, None
     d_more, d_in = grads(rows, w_in, g=d_pre)
     d_tokens = _sum_choices(d_rows + d_more, tok, inv, sizes, n, kernels)
-    return d_tokens, d_wg, d_in, d_out, d_weight
+    with jax.named_scope("tpuframe/moe/route"):
+        d_vals = _spread_weights(d_weight, pair, inv.key.shape[0])
+    return d_tokens, d_wg, d_in, d_out, d_vals
 
 
-def _experts_windowed_bwd(cap, act, res, g):
-    parts, args = res
-    tokens, w_gate, w_in, w_out, padded, _, inv, sizes = args
-    n, pairs = tokens.shape[0], inv.shape[0]
-
-    def window(parts, lo, kernels=True):
-        *d, d_weight = _window_bwd(parts, w_gate, w_in, w_out, n, act, g, kernels)
-        return (*d, jax.lax.dynamic_update_slice_in_dim(
-            jnp.zeros_like(padded), d_weight, lo, 0))
+def _experts_windowed_bwd(cap, k, act, res, g):
+    first, args = res
+    tokens, w_gate, w_in, w_out, vals, _, sizes, _ = args
+    window = functools.partial(_window_bwd, w_gate=w_gate, w_in=w_in, w_out=w_out,
+                               n=tokens.shape[0], act=act, g=g)
 
     def further(i, acc):
-        again = _expert_parts(*args, i * cap, cap, act, False)[1]
-        return jax.tree.map(jnp.add, acc, window(again, i * cap, False))
+        again = _counted_parts(*args, i * cap, cap, k, act, False)[1:]
+        return jax.tree.map(jnp.add, acc, window(*again, kernels=False))
 
-    *d, d_weight = jax.lax.fori_loop(1, _windows(sizes, cap), further, window(parts, 0))
-    return (*d, d_weight[:pairs], None, None, None)
+    *d, d_vals = jax.lax.fori_loop(1, _windows(sizes, cap), further, window(*first))
+    return (*d, d_vals.astype(vals.dtype), None, None, None)
 
 
 _experts_windowed.defvjp(_experts_windowed_fwd, _experts_windowed_bwd)
@@ -248,16 +406,29 @@ class MoEMLP(nn.Module):
       capacity_factor: per-expert slots = ceil(top_k * N / E * factor);
         overflow tokens are dropped (their combine weight is zero), the
         standard Switch behavior, through ``ops.moe_dispatch_combine``.
-        ``None`` drops nothing: the (token, choice) pairs are sorted by
-        expert, those routed to held experts in front, and the expert
-        matmuls are ``ops.grouped_matmul`` over whatever group sizes the
-        router made.  The buffers round them hold :func:`slot_bound`
+        ``None`` drops nothing: the (token, choice) pairs routed to held
+        experts take slots ordered by expert and, inside an expert, by
+        pair number ``t k + j``, and the expert matmuls are
+        ``ops.grouped_matmul`` over whatever group sizes the router
+        made.  The buffers round them hold :func:`slot_bound`
         rows: twice the share of the pairs a balanced router sends to
         the held experts (a quarter of the pairs where an eighth of the
         experts is held; a slot a pair where all are, or at small
         shapes).  A call whose router sends more runs further windows
         of that many slots, one after another, so it is slower and
         still exact.  The bound follows from the shapes; nothing sets it.
+        **A window's plan** is the groups' ``sizes`` and, for each of its
+        ``cap`` slots, the pair's token and gate value.  Where the
+        buffers are shorter than the pairs (``cap < n k``: the layer
+        holds under half the experts) it is made by counting
+        (:func:`_count_blocks`, :func:`_window_plan`): compares, sums
+        and one-hot products over whole arrays, for the window's slots
+        alone; no array as long as the pairs is sorted, gathered from a
+        number at a time or scattered into, forward or backward.  Where
+        the buffers hold a slot a pair (``cap == n k``) the plan is a
+        stable sort of the pairs, the right algorithm there (counting
+        would cost a table of experts x pairs), and the program is the
+        one it always was.  The choice follows from the shapes too.
       held: ``(first, count)``: the experts this layer holds out of
         ``num_experts`` (expert parallelism's share; ``None`` = all).
         Every token is still routed over all E; the layer computes
@@ -288,9 +459,11 @@ class MoEMLP(nn.Module):
 
     With ``capacity_factor=None`` the layer also sows, for the step's
     metrics window, the counters ``moe/assignments_here``,
-    ``moe/rows_computed``, ``moe/slot_rows`` (rows its buffers carried)
-    and ``moe/overflow_calls`` (calls that ran more than one window) and
-    the gauge ``moe/expert_load_max_over_mean``; with ``select_bias`` the
+    ``moe/rows_computed``, ``moe/slot_rows`` (rows its buffers carried),
+    ``moe/overflow_calls`` (calls that ran more than one window) and, sown
+    only where the plan was made by counting, ``moe/counted_routes`` (such
+    calls), and the gauge ``moe/expert_load_max_over_mean``; with
+    ``select_bias`` the
     counters ``moe/bias_choices`` (the pairs it chose) and
     ``moe/bias_moved_choices`` (those whose expert is not among the
     ``top_k`` by score alone) (OBSERVABILITY.md).
@@ -443,29 +616,45 @@ class MoEMLP(nn.Module):
 
     def _no_drop(self, tokens, gate_vals, gate_idx, first, count,
                  w_gate, w_in, w_out, act):
-        """``sum p_e E_e(x)`` over the held experts, no token dropped."""
+        """``sum p_e E_e(x)`` over the held experts, no token dropped:
+        the slot buffers' bound from the shapes, then the route by the
+        branch the bound names (sorted where a slot a pair, counted where
+        fewer), the experts, and the layer's counters."""
         n, k = gate_idx.shape
+        pairs = n * k
+        # the buffers hold the pairs routed here, not a slot a pair: twice
+        # a balanced router's share, and a call that gets more runs window
+        # after window of them (_experts_windowed)
+        cap = slot_bound(pairs, count, self.num_experts)
         with jax.named_scope("tpuframe/moe/route"):
-            # one slot for every (token, choice) pair; the pairs routed
-            # to held experts sort to the front, grouped by expert
+            # a pair's key: its held expert, or `count` where routed elsewhere
             local = gate_idx.reshape(-1) - first
             here = (local >= 0) & (local < count)
             key = jnp.where(here, local, count)
-            order = jnp.argsort(key, stable=True)
-            inv = jnp.argsort(order)
-            sizes = jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32)
-            tok = order // k
-            weight = (gate_vals.reshape(-1) * here)[order]
+        if cap == pairs:
+            # every expert held (or small shapes): a slot a pair, and the
+            # route is a stable sort of the pairs by expert
+            with jax.named_scope("tpuframe/moe/route"):
+                # one slot for every (token, choice) pair; the pairs routed
+                # to held experts sort to the front, grouped by expert
+                order = jnp.argsort(key, stable=True)
+                inv = jnp.argsort(order)
+                sizes = jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32)
+                tok = order // k
+                weight = (gate_vals.reshape(-1) * here)[order]
+            with jax.named_scope("tpuframe/moe/experts"):
+                out = _expert_parts(tokens.astype(self.dtype), w_gate, w_in, w_out, weight,
+                                    tok, inv, sizes, 0, pairs, act)[0]
+        else:
+            # fewer slots than pairs: a window's plan is made by counting,
+            # for its `cap` slots alone (_window_plan); nothing `pairs` long
+            # is sorted, gathered from or scattered into
+            with jax.named_scope("tpuframe/moe/route"):
+                sizes, edges = _count_blocks(key, count)
+            with jax.named_scope("tpuframe/moe/experts"):
+                out = _experts_windowed(tokens.astype(self.dtype), w_gate, w_in, w_out,
+                                        gate_vals.reshape(-1), key, sizes, edges, cap, k, act)
         with jax.named_scope("tpuframe/moe/experts"):
-            # the buffers hold the pairs routed here, not a slot a pair:
-            # twice a balanced router's share, and a call that gets more
-            # runs window after window of them (_experts_windowed)
-            pairs = n * k
-            cap = slot_bound(pairs, count, self.num_experts)
-            args = (tokens.astype(self.dtype), w_gate, w_in, w_out, weight,
-                    tok, inv, sizes)
-            out = (_expert_parts(*args, 0, pairs, act)[0] if cap == pairs
-                   else _experts_windowed(*args, cap, act))
             windows = _windows(sizes, cap)
         f32 = jnp.float32
         load = sizes.astype(f32)
@@ -480,6 +669,8 @@ class MoEMLP(nn.Module):
         sow("counters", "moe/rows_computed", tiles_visited(sizes, tile) * tile)
         sow("counters", "moe/slot_rows", windows * cap)
         sow("counters", "moe/overflow_calls", windows > 1)
+        if cap < pairs:  # sown where it counts: the sorted route's program stays as it was
+            sow("counters", "moe/counted_routes", jnp.ones(()))
         sow("gauges", "moe/expert_load_max_over_mean",
             jnp.max(load) / jnp.maximum(jnp.mean(load), 1.0))
         return out
