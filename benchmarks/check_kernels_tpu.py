@@ -30,7 +30,7 @@ gather a pair, timed beside it at the six expert cells' shapes).
 Usage: python benchmarks/check_kernels_tpu.py [--only a,b,...]
 (exits 1 on any failure).  ``--only`` runs a named subset — sections:
 layer_norm, cross_entropy, quant_wire, blockwise, flash_layout, window, sparse_index, ring,
-ulysses, moe_windows, short_conv, conv_silu, head_norm_rope, grouped, unsort, gated_delta
+ulysses, moe_windows, short_conv, conv_silu, head_norm_rope, grouped, unsort, gated_delta, kda
 (``--grouped-tiles 128,256,512`` prices other row tiles beside the default).
 """
 
@@ -1051,6 +1051,81 @@ def _solve_with(jnp, gd, form, top=0):
     return solve
 
 
+def _check_kda(jax, jnp, np, rng) -> None:
+    """The vector-decay delta rule's kernels (`ops.kda`) against the
+    recurrence position by position (float32, a ragged length, a channel's
+    decay up to -20 a position and mixed inside a head) and against the scan
+    schedule at ``kimi-linear-48b-a3b-instruct``'s shape in bfloat16 (one row
+    of 4096 positions, 32 heads of 128), output and all five gradients; there
+    the kernels, the scan schedule and the chunk-local part XLA keeps are also
+    timed, forward + backward a call (a line of its own; the times pass or
+    fail nothing)."""
+    import importlib
+    import time
+
+    kd = importlib.import_module("tpuframe.ops.kda")
+    rel = lambda a, b: float(jnp.linalg.norm((a - b).astype(jnp.float32))  # noqa: E731
+                             / jnp.maximum(jnp.linalg.norm(b.astype(jnp.float32)), 1e-30))
+
+    def inputs(b, l, h, dk, dv, dtype):
+        unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
+        q = unit(jnp.asarray(rng.standard_normal((b, l, h, dk)), jnp.float32)) * dk ** -0.5
+        k = unit(jnp.asarray(rng.standard_normal((b, l, h, dk)), jnp.float32))
+        v = jnp.asarray(rng.standard_normal((b, l, h, dv)), jnp.float32)
+        # a head's rate at the quantiles of uniform(1, 16), a channel in four ten times weaker
+        rates = jnp.asarray(1.0 + 15.0 * (np.arange(h) + 0.5) / h, jnp.float32)[:, None]
+        rates = rates * jnp.asarray([1.0, 1.0, 1.0, 0.1])[jnp.arange(dk) % 4]
+        g = -rates * jax.nn.softplus(
+            jnp.asarray(rng.standard_normal((b, l, h, dk)), jnp.float32) * 0.3 + 1)
+        beta = jax.nn.sigmoid(jnp.asarray(rng.standard_normal((b, l, h)), jnp.float32))
+        ct = jnp.asarray(rng.standard_normal((b, l, h, dv)), dtype)
+        return (q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta), ct
+
+    def both(op):
+        def run(args, ct):
+            y, vjp = jax.vjp(op, *args)
+            return (y,) + vjp(ct)
+        return jax.jit(run)
+
+    forms = {"kernels": both(functools.partial(kd.kda, interpret=False)),
+             "schedule": both(kd.kda_chunked), "recurrence": both(kd.kda_reference)}
+    parts = ("out", "dq", "dk", "dv", "dg", "dbeta")
+    args, ct = inputs(2, 300, 4, 128, 128, jnp.float32)
+    want = forms["recurrence"](args, ct)
+    for form in ("kernels", "schedule"):
+        for part, a, c in zip(parts, forms[form](args, ct), want):
+            record(f"kda_f32_ragged_{form}_{part}", rel(a, c), 2e-3)
+    args, ct = inputs(1, 4096, 32, 128, 128, jnp.bfloat16)
+    got, want = forms["kernels"](args, ct), forms["schedule"](args, ct)
+    for part, a, c in zip(parts, got, want):
+        record(f"kda_kimilinear_kernels_vs_schedule_{part}", rel(a, c), 2e-2)
+    # against float32: what bfloat16 operands cost, the same for both forms
+    exact = forms["schedule"](tuple(a.astype(jnp.float32) for a in args), ct.astype(jnp.float32))
+    for part, a, c in zip(parts, got, exact):
+        record(f"kda_kimilinear_kernels_vs_float32_{part}", rel(a, c), 5e-2)
+
+    def laps(fn, *a):
+        jax.block_until_ready(fn(*a))
+        out = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            for _ in range(5):
+                r = fn(*a)
+            jax.block_until_ready(r)
+            out.append((time.perf_counter() - t0) / 5)
+        return 1e3 * sorted(out)[len(out) // 2]
+
+    prepare = both(lambda *a: kd._prepare(*a)[0])
+    d_parts = jax.tree.map(lambda a: jnp.ones(a.shape, a.dtype),
+                           jax.eval_shape(kd._prepare, *args)[0])
+    times = {"kernels_fwd_bwd": laps(forms["kernels"], args, ct),
+             "kernels_fwd": laps(jax.jit(functools.partial(kd.kda, interpret=False)), *args),
+             "schedule_fwd_bwd": laps(forms["schedule"], args, ct),
+             "chunk_local_fwd_bwd": laps(prepare, args, d_parts)}
+    print(json.dumps({"check": "kda_ms_a_call", "shape": [1, 4096, 32, 128, 128], **times,
+                      "chunk_steps_fwd_and_bwd": kd.chunks_walked(1, 4096, 32)}), flush=True)
+
+
 def _check_delta_chunk(jax, jnp, gd, inputs, rel, laps, args) -> None:
     """The chunk-local kernels (``tpuframe_delta_chunk_fwd`` / ``_again`` /
     ``_bwd``) against XLA's ``_prepare`` and its transpose: every part, the
@@ -1152,6 +1227,7 @@ SECTIONS = {
     "unsort": (_check_unsort, ("unsort",)),
     "gated_delta": (_check_gated_delta,
                     ("gated_delta", "head_norm_rope", "blockwise_attention")),
+    "kda": (_check_kda, ("kda",)),
 }
 
 #: registry rows with no section, each with why none is owed

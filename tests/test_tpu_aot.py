@@ -327,6 +327,37 @@ def test_gated_delta_kernels_compile_for_v5e(one_chip, shape, dtype):
     assert compiled.memory_analysis().temp_size_in_bytes < b * l * h * dk * dv * 4 / 8
 
 
+@pytest.mark.parametrize("shape, dtype", [
+    ((1, 4096, 32, 128, 128), jnp.bfloat16),   # kimi-linear's KDA layers
+    ((2, 300, 2, 128, 128), jnp.float32),      # a ragged length, float32
+], ids=["kimilinear", "f32_ragged"])
+def test_kda_kernels_compile_for_v5e(one_chip, shape, dtype):
+    """The vector-decay delta rule's pass over the chunks: `ops.gated_delta`'s
+    with the state transposed in VMEM, ``gamma`` a row of lanes a chunk; one
+    forward and one backward kernel.  The chunk-local part is XLA's here:
+    nothing the size of a (16, 16, dk) array a sub-block, and no
+    (chunks, C, C, dk) array, is kept (a GiB and 8 GiB a layer at the first
+    shape)."""
+    from tpuframe.ops.kda import kda
+
+    b, l, h, dk, dv = shape
+    arr = lambda *s, t=dtype: jax.ShapeDtypeStruct(s, t, sharding=one_chip)  # noqa: E731
+    args = (arr(b, l, h, dk), arr(b, l, h, dk), arr(b, l, h, dv),
+            arr(b, l, h, dk, t=jnp.float32), arr(b, l, h, t=jnp.float32))
+
+    def loss(*a):
+        return jnp.sum(kda(*a, interpret=False).astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(*args).compile()
+    text = compiled.as_text()
+    for kernel in ("tpuframe_kda_fwd", "tpuframe_kda_bwd"):
+        assert len(_kernel_calls(text, kernel)) == 1, kernel
+    # what the schedule needs is a little over one such array's bytes at the
+    # first shape (1.14 GiB: the parts twice, the operands scaled a sub-block and
+    # their cotangents); with one kept it would be over two
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * b * l * h * 16 * dk * 4
+
+
 def test_qwen3_next_period_holds_its_kernels_once_a_kind(v5e_runtime):
     """A linear-attention layer twice and a gated full-attention layer of
     ``TransformerLM`` at qwen3-next's widths, the gradient of a loss over

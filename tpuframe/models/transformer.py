@@ -46,6 +46,7 @@ from tpuframe.core.runtime import (
 from tpuframe.ops.dispatch import batch_sharding_info, effective_mesh
 from tpuframe.ops.gated_delta import chunks_walked, gated_delta, gated_delta_reference
 from tpuframe.ops.head_norm_rope import head_norm_rope, head_norm_rope_reference
+from tpuframe.ops.kda import kda, kda_reference
 from tpuframe.ops.ring_attention import (
     SelectedKeysMask,
     SlidingWindowMask,
@@ -102,6 +103,11 @@ def transformer_tp_rules():
         # whole heads): the linear-attention layer's input projections stay
         # whole too, and its output projection splits its output columns
         (r"deltanet/out_proj/kernel", P(None, MODEL_AXIS)),
+        # Kimi Delta Attention the same: the vector-decay rule takes whole
+        # heads and has no split over the model axis yet, so its input
+        # projection, the low-rank pairs and beta's stay whole (no rule names
+        # them) and the output projection splits its output columns
+        (r"kda/out_proj/kernel", P(None, MODEL_AXIS)),
         # the index of sparse attention chooses one set of keys for all heads
         # of a row: every device of the model axis needs all of it, so its
         # three projections (and the key norm, which no rule names) stay whole
@@ -668,13 +674,104 @@ class GatedDeltaNet(nn.Module):
             return dense(d, "out_proj")(y.reshape(b, l, values))
 
 
+def _kda_decay(fb, b, a_log, dt_bias, heads):
+    """``g`` a position, head and key channel and ``beta`` a position and
+    head, from the decay pair's float32 output and beta's projection:
+    computed again in the backward pass (`jax.checkpoint`)."""
+    lead = fb.shape[:-1]
+    g = -jnp.exp(a_log.astype(jnp.float32))[:, None] * jax.nn.softplus(
+        fb + dt_bias.astype(jnp.float32)).reshape(lead + (heads, -1))
+    return g, jax.nn.sigmoid(b.astype(jnp.float32))
+
+
+def _kda_gate(o, gate, scale, eps, dtype):
+    """The gated norm on the rule's output: ``o / rms(o) * scale *
+    sigmoid(gate)`` a head, float32 inside; computed again in the backward
+    pass.  On views that split the sequence into a tile's 8 positions, as
+    `_deltanet_gate`'s and for its reason."""
+    b, l, h, dv = o.shape
+    tiles = (b, l // 8, 8, h, dv) if l % 8 == 0 else o.shape
+    o32 = o.reshape(tiles).astype(jnp.float32)
+    o32 = o32 * jax.lax.rsqrt(jnp.mean(o32 * o32, axis=-1, keepdims=True) + eps)
+    y = o32 * scale.astype(jnp.float32) * jax.nn.sigmoid(gate.reshape(tiles).astype(jnp.float32))
+    return y.astype(dtype).reshape(o.shape)
+
+
+class KimiDeltaAttention(nn.Module):
+    """Kimi-Linear's linear-attention mixer (KDA): the delta rule whose decay
+    is a vector a head (`tpuframe.ops.kda`).  ``[q | k | v] = x W_qkv``
+    (``num_heads`` heads of ``head_dim`` each, the heads of each side by
+    side) through a ``conv_taps``-tap causal depthwise convolution and SiLU,
+    queries and keys L2-normalised over a head, the queries scaled by
+    ``head_dim^-1/2`` (`ops.short_conv.conv_silu`, on the model's rows);
+    ``g = -exp(A_log) * softplus((x W_fa) W_fb + dt_bias)`` a position, head
+    and key channel (``A_log`` a head, ``dt_bias`` a channel), float32 from
+    the second product's accumulator on; ``beta = sigmoid(x W_b)`` a head;
+    the rule's output is RMS-normalised a head with a learned scale, gated by
+    ``sigmoid((x W_ga) W_gb)`` (a sigmoid, where `GatedDeltaNet` has SiLU),
+    and projected by ``W_out``.  The two low-rank pairs are ``rank`` wide (0:
+    ``head_dim``).  No bias anywhere."""
+
+    num_heads: int
+    head_dim: int
+    conv_taps: int = 4
+    rank: int = 0
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        b, l, d = x.shape
+        h, dk = self.num_heads, self.head_dim
+        width, rank = h * dk, self.rank or self.head_dim
+        dense = lambda n, name, **kw: nn.Dense(  # noqa: E731
+            n, use_bias=False, dtype=self.dtype, name=name, **kw
+        )
+        with jax.named_scope("tpuframe/kda"):
+            qkv = dense(3 * width, "in_proj_qkv")(x)
+            w = self.param("conv", nn.initializers.lecun_normal(), (self.conv_taps, 3 * width))
+            a_log = self.param("A_log", _a_log_init, (h,))
+            dt_bias = self.param("dt_bias", nn.initializers.ones, (width,))
+            scale = self.param("norm", nn.initializers.ones, (dk,))
+            # init's sample batch need not divide the mesh
+            inputs = conv_silu_reference if self.is_initializing() else functools.partial(
+                conv_silu, mesh=_mesh_or_none())
+            q, k, v = (a.reshape(b, l, h, dk) for a in inputs(qkv, w, key_heads=h, key_dim=dk))
+            with jax.named_scope("tpuframe/kda/decay"):
+                # the decay's second product keeps its float32 sums: g is
+                # summed along a chunk before any exponential
+                fb = dense(width, "f_b", dot_general=functools.partial(
+                    jax.lax.dot_general, preferred_element_type=jnp.float32))(
+                        dense(rank, "f_a")(x))
+                g, beta = jax.checkpoint(_kda_decay, static_argnums=(4,))(
+                    fb, dense(h, "b_proj")(x), a_log, dt_bias, h)
+            with jax.named_scope("tpuframe/kda/rule"):
+                if self.is_initializing():
+                    # init's sample batch need not divide the mesh
+                    o = kda_reference(q, k, v, g, beta)
+                else:
+                    o = kda(q, k, v, g, beta, mesh=_mesh_or_none())
+                    # static numbers a call, counted here on the host, once a
+                    # trace, where their reader divides them
+                    registry = get_telemetry().registry
+                    registry.counter("kda/chunks").inc(chunks_walked(b, l, h))
+                    registry.counter("kda/calls").inc(1)
+            with jax.named_scope("tpuframe/kda/gate"):
+                gate = dense(width, "g_b")(dense(rank, "g_a")(x))
+                y = jax.checkpoint(_kda_gate, static_argnums=(3, 4))(
+                    o, gate, scale, self.norm_eps, self.dtype)
+            return dense(d, "out_proj")(y.reshape(b, l, width))
+
+
 class LatentAttention(nn.Module):
     """Multi-head latent attention (MLA) without a query latent.
 
     Keys and values come from one ``kv_lora_rank``-wide latent per token:
     ``x W_kva -> [c | k_rope]``, ``RMSNorm(c) W_kvb ->`` per head
     ``[k_nope | v]``.  Rotary positions turn ``q_rope`` of every head and
-    the one ``k_rope`` all heads share; queries and keys are
+    the one ``k_rope`` all heads share; ``rope=None`` means no positions:
+    nothing is turned, and ``k_rope`` is only the part of a key that all
+    heads share (Kimi-Linear's ``mla_use_nope``).  Queries and keys are
     ``head_dim + rope_dim`` wide, values ``v_head_dim``, and the softmax
     scale is the caller's (YaRN's temperature folded in).  The core is
     :func:`_attend`, ``full`` or ``blockwise``.
@@ -692,10 +789,9 @@ class LatentAttention(nn.Module):
     dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x: jax.Array, rope, train: bool = False) -> jax.Array:
+    def __call__(self, x: jax.Array, rope=None, train: bool = False) -> jax.Array:
         h, dn, dr, dv = self.num_heads, self.head_dim, self.rope_dim, self.v_head_dim
         b, l, _ = x.shape
-        cos, sin = rope
         dense = lambda n, name: nn.Dense(  # noqa: E731
             n, use_bias=False, dtype=self.dtype, name=name
         )
@@ -706,10 +802,10 @@ class LatentAttention(nn.Module):
                 kva[..., :self.kv_lora_rank]
             )
             kvb = dense(h * (dn + dv), "kv_b")(c).reshape(b, l, h, dn + dv)
-            k_rope = apply_rope(kva[..., None, self.kv_lora_rank:], cos, sin)
-            q = jnp.concatenate(
-                [q[..., :dn], apply_rope(q[..., dn:], cos, sin)], axis=-1
-            )
+            k_rope = kva[..., None, self.kv_lora_rank:]
+            if rope is not None:
+                k_rope = apply_rope(k_rope, *rope)
+                q = jnp.concatenate([q[..., :dn], apply_rope(q[..., dn:], *rope)], axis=-1)
             k = jnp.concatenate(
                 [kvb[..., :dn], jnp.broadcast_to(k_rope, (b, l, h, dr))], axis=-1
             )
@@ -728,20 +824,23 @@ class Block(nn.Module):
     (:class:`tpuframe.models.moe.MoEMLP`): expert weights shard over the
     ``expert`` mesh axis via ``moe_rules`` and the router's balance loss
     rides the ``aux_loss`` collection into the train objective.
-    ``norm="rms"``, ``kv_lora_rank > 0`` (latent attention, rotary
-    positions given as ``rope``) and ``mlp_gated`` (SiLU-gated MLP, no
-    bias) are the other kinds of layer; the defaults are GPT-2's.
+    ``norm="rms"``, ``kv_lora_rank > 0`` (latent attention in the
+    ``"full_attention"`` layers, rotary positions given as ``rope``) and
+    ``mlp_gated`` (SiLU-gated MLP, no bias) are the other kinds of layer;
+    the defaults are GPT-2's.  ``rope=None`` means no positions: no
+    attention layer of the block turns anything.
     ``mixer="conv"`` puts the short-convolution operator
     (:class:`ShortConv`) in attention's place; ``"sliding_attention"``
     is multi-head attention under a causal band of ``sliding_window``
     keys (`ops.ring_attention.SlidingWindowMask`), its parameters a
     ``"full_attention"`` layer's leaf for leaf; ``"linear_attention"`` is
     the gated delta rule's mixer (:class:`GatedDeltaNet`), its sizes in
-    ``linear_attention``.
+    ``linear_attention``; ``"kda"`` the delta rule whose decay is a vector a
+    head (:class:`KimiDeltaAttention`), its sizes in ``kda``.
     """
 
     #: the mixers a layer can have
-    MIXERS = ("full_attention", "sliding_attention", "conv", "linear_attention")
+    MIXERS = ("full_attention", "sliding_attention", "conv", "linear_attention", "kda")
 
     num_heads: int
     head_dim: int
@@ -785,6 +884,9 @@ class Block(nn.Module):
     linear_attention: tuple = ()
     #: multi-head attention's index of chosen keys (`SelfAttention.sparse_index`)
     sparse_index: tuple = ()
+    #: the sizes of a ``"kda"`` layer (`KimiDeltaAttention`'s arguments), as
+    #: a tuple of (name, value)
+    kda: tuple = ()
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False, rope=None) -> jax.Array:
@@ -808,6 +910,11 @@ class Block(nn.Module):
                                  "key_dim, value_dim)")
             y = GatedDeltaNet(norm_eps=self.norm_eps, dtype=self.dtype, name="deltanet",
                               **dict(self.linear_attention))(y)
+        elif self.mixer == "kda":
+            if not self.kda:
+                raise ValueError("a kda layer takes its sizes from kda (num_heads, head_dim)")
+            y = KimiDeltaAttention(norm_eps=self.norm_eps, dtype=self.dtype, name="kda",
+                                   **dict(self.kda))(y)
         elif self.mixer not in self.MIXERS:
             raise ValueError(f"unknown mixer {self.mixer!r}; known: "
                              + ", ".join(self.MIXERS))
@@ -886,7 +993,13 @@ class TransformerLM(nn.Module):
     ``sliding_window`` keys), ``"conv"`` (the short-convolution
     operator of ``conv_taps`` taps) or ``"linear_attention"`` (the gated
     delta rule, its sizes in ``linear_attention``: ``num_key_heads``,
-    ``num_value_heads``, ``key_dim``, ``value_dim``, ``conv_taps``);
+    ``num_value_heads``, ``key_dim``, ``value_dim``, ``conv_taps``) or
+    ``"kda"`` (the delta rule with a vector decay, its sizes in ``kda``:
+    ``num_heads``, ``head_dim``, ``conv_taps``; with ``kv_lora_rank`` the
+    ``"full_attention"`` layers of such a pattern are latent attention);
+    ``nope`` no position encoding anywhere (a config's ``mla_use_nope``): no
+    rotary tables and no position table are built, and ``rope_dim`` is only
+    the width of the key part all heads of latent attention share;
     ``attn_gated`` an output gate on multi-head attention; ``rope_dim``
     under ``head_dim`` turns the first ``rope_dim`` dimensions of every
     head (a config's ``partial_rotary_factor``); ``sparse_index`` a learned
@@ -957,6 +1070,10 @@ class TransformerLM(nn.Module):
     #: ``"full_attention"`` layer (``num_heads``, ``head_dim``, ``topk``: a
     #: config's ``sa_config``): a dict, kept as sorted items
     sparse_index: Any = ()
+    #: a ``"kda"`` layer's sizes: a dict, kept as sorted items
+    kda: Any = ()
+    #: no position encoding anywhere: nothing is turned, no table is built
+    nope: bool = False
 
     def __post_init__(self):
         # module attributes are hashed with the train state's treedef:
@@ -967,7 +1084,7 @@ class TransformerLM(nn.Module):
             return tuple(frozen(x) for x in v) if isinstance(v, list) else v
 
         for name in ("rope_scaling", "moe_kwargs", "layer_types", "rope_parameters",
-                     "linear_attention", "sparse_index"):
+                     "linear_attention", "sparse_index", "kda"):
             object.__setattr__(self, name, frozen(getattr(self, name)))
         super().__post_init__()
 
@@ -1011,7 +1128,7 @@ class TransformerLM(nn.Module):
             raise ValueError("a mask rule for every block and window layers, which "
                              "bring their own, do not go together")
         ropes = {}
-        if self.rope_dim:
+        if self.rope_dim and not self.nope:
             if not self.kv_lora_rank and (self.rope_dim > self.head_dim or self.rope_dim % 2):
                 raise ValueError("multi-head attention turns a head's first dimensions "
                                  f"in pairs: rope_dim {self.rope_dim} is odd or over "
@@ -1020,8 +1137,8 @@ class TransformerLM(nn.Module):
             ropes = {kind: rope_tables(tokens.shape[1], self.rope_dim,
                                        *self._rope_of(kind), positions)
                      for kind in dict.fromkeys(mixers)
-                     if kind not in ("conv", "linear_attention")}
-        else:
+                     if kind not in ("conv", "linear_attention", "kda")}
+        elif not self.nope:
             pos = nn.Embed(self.max_len, d_model, dtype=self.dtype, name="pos_embed")(
                 jnp.arange(tokens.shape[1])[None, :]
             )
@@ -1043,6 +1160,7 @@ class TransformerLM(nn.Module):
                 sliding_window=self.sliding_window, attn_gated=self.attn_gated,
                 norm_unit_offset=self.norm_unit_offset,
                 linear_attention=self.linear_attention, name=f"block{i}",
+                **({"kda": self.kda} if mixers[i] == "kda" else {}),
                 **({"sparse_index": self.sparse_index}
                    if self.sparse_index and mixers[i] == "full_attention" else {}),
             )(x, train, ropes.get(mixers[i]))

@@ -37,6 +37,10 @@ fallback and the correctness oracle for tests.
   attention): a recurrence with a (d_k, d_v) state a head, run in chunks;
   the pass that carries the state over the chunks is a kernel pair, the
   state resident in VMEM.
+- :func:`kda` — Kimi Delta Attention's rule: the same recurrence with a
+  decay a key channel (a vector a head, scaling the state's rows); its
+  pass over the chunks is a kernel pair of its own, the chunk-local part
+  XLA's batched products.
 - :func:`select_keys` — the learned index of sparse attention: index
   scores of a tile of queries held in VMEM and the exact choice of
   ``topk`` keys a query by bisection, one byte a (query, key) pair out
@@ -93,6 +97,9 @@ _LAZY = {
     "gated_delta": "tpuframe.ops.gated_delta",
     "gated_delta_chunked": "tpuframe.ops.gated_delta",
     "gated_delta_reference": "tpuframe.ops.gated_delta",
+    "kda": "tpuframe.ops.kda",
+    "kda_chunked": "tpuframe.ops.kda",
+    "kda_reference": "tpuframe.ops.kda",
     "bucket_abs_max": "tpuframe.ops.quant_wire",
     "bucket_abs_max_reference": "tpuframe.ops.quant_wire",
     "quant_encode": "tpuframe.ops.quant_wire",
@@ -121,9 +128,10 @@ def __dir__():
 
 
 class _OpsModule(_types.ModuleType):
-    """Seven exports share their kernel module's name
+    """Eight exports share their kernel module's name
     (``blockwise_attention``, ``gated_delta``, ``grouped_matmul``,
-    ``head_norm_rope``, ``ring_attention``, ``short_conv``, ``unsort``), and
+    ``head_norm_rope``, ``kda``, ``ring_attention``, ``short_conv``,
+    ``unsort``), and
     importing such a submodule makes the import machinery rebind the
     module object over the package attribute of the same name — which
     would shadow the function for every later
@@ -141,7 +149,7 @@ def _shadow_proof(name):
     )
 
 
-for _name in ("blockwise_attention", "gated_delta", "grouped_matmul", "head_norm_rope",
+for _name in ("blockwise_attention", "gated_delta", "grouped_matmul", "head_norm_rope", "kda",
               "ring_attention", "short_conv", "unsort"):
     setattr(_OpsModule, _name, _shadow_proof(_name))
 

@@ -121,6 +121,12 @@ OPS_REGISTRY = {
         "reference": "gated_delta_reference",
         "parity_test": "tests/test_gated_delta.py::TestAgainstTheRecurrence::test_outputs_and_all_five_gradients",
     },
+    "kda": {
+        "module": "tpuframe.ops.kda",
+        "symbol": "kda",
+        "reference": "kda_reference",
+        "parity_test": "tests/test_kimi_linear.py::TestTheOpAgainstTheRecurrence::test_outputs_and_all_five_gradients",
+    },
     "sparse_index": {
         "module": "tpuframe.ops.sparse_index",
         "symbol": "select_keys",
@@ -154,6 +160,7 @@ OP_NAME_TOKENS = (
     ("conv_silu", ("conv_silu",)),
     ("head_norm_rope", ("head_norm_rope",)),
     ("gated_delta", ("gated_delta",)),
+    ("kda", ("tpuframe_kda",)),
     ("sparse_index", ("tpuframe_index",)),
     ("unsort", ("tpuframe_unsort",)),
     ("grouped_matmul", ("tpuframe_grouped", "ragged-dot", "ragged_dot", "grouped_matmul")),
