@@ -1056,10 +1056,12 @@ def _check_kda(jax, jnp, np, rng) -> None:
     recurrence position by position (float32, a ragged length, a channel's
     decay up to -20 a position and mixed inside a head) and against the scan
     schedule at ``kimi-linear-48b-a3b-instruct``'s shape in bfloat16 (one row
-    of 4096 positions, 32 heads of 128), output and all five gradients; there
-    the kernels, the scan schedule and the chunk-local part XLA keeps are also
-    timed, forward + backward a call (a line of its own; the times pass or
-    fail nothing)."""
+    of 4096 positions, 32 heads of 128), output and all five gradients; the
+    chunk-local kernels (``tpuframe_kdachunk_fwd`` / ``_again`` / ``_bwd``)
+    against XLA's `_prepare` and its transpose at both shapes, parts and
+    cotangents; and the op both ways, `_prepare` and the three kernels timed
+    side by side, forward and forward + backward a call (a line of its own;
+    the times pass or fail nothing)."""
     import importlib
     import time
 
@@ -1115,13 +1117,52 @@ def _check_kda(jax, jnp, np, rng) -> None:
             out.append((time.perf_counter() - t0) / 5)
         return 1e3 * sorted(out)[len(out) // 2]
 
-    prepare = both(lambda *a: kd._prepare(*a)[0])
-    d_parts = jax.tree.map(lambda a: jnp.ones(a.shape, a.dtype),
-                           jax.eval_shape(kd._prepare, *args)[0])
+    # the chunk-local part: the kernels against XLA's `_prepare` and its
+    # transpose (every part, the solve's T, the five cotangents), float32 at
+    # the ragged shape padded to whole chunks, bfloat16 at the cell's
+    names = ("u", "w", "qe", "kd", "m", "gamma", "T")
+    xla = jax.jit(kd._prepare)
+    xla_t = jax.jit(lambda a, t, d: jax.vjp(lambda *a: kd._prepare(*a, t=t)[0], *a)[1](d))
+    local_fwd = lambda a: kd._pallas_local_fwd(*a, False)  # noqa: E731
+    local_again = lambda a, t: kd._pallas_local_again(*a, t, False)  # noqa: E731
+    local_bwd = lambda a, t, d: kd._pallas_local_bwd(*a, t, d, False)  # noqa: E731
+    waves = lambda a: jax.tree.map(  # noqa: E731
+        lambda x: jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape).astype(x.dtype),
+        jax.eval_shape(kd._prepare, *a)[0])
+    small = tuple(jnp.pad(a, ((0, 0), (0, 84)) + ((0, 0),) * (a.ndim - 2))
+                  for a in inputs(2, 300, 4, 128, 128, jnp.float32)[0])
+    d_parts = waves(args)
+    for tag, a, d, tol in (("f32_ragged", small, waves(small), 2e-3),
+                           ("kimilinear", args, d_parts, 2e-2)):
+        want, t = xla(*a)
+        got, t_got = local_fwd(a)
+        for name, x, y in zip(names, (*got, t_got), (*want, t)):
+            record(f"kda_chunk_{tag}_fwd_{name}", rel(x, y), 1e-4 if name == "T" else tol)
+        for name, x, y in zip(names, local_again(a, t), want):
+            record(f"kda_chunk_{tag}_again_{name}", rel(x, y), tol)
+        for name, x, y in zip(parts[1:], local_bwd(a, t, d), xla_t(a, t, d)):
+            record(f"kda_chunk_{tag}_bwd_{name}", rel(x, y), tol)
+    # the cell's cotangents against float32 inputs': what bfloat16 operands cost either form
+    wide = tuple(a.astype(jnp.float32) for a in args)
+    exact = xla_t(wide, xla(*wide)[1], jax.tree.map(lambda x: x.astype(jnp.float32), d_parts))
+    for form, fn in (("kernels", local_bwd), ("xla", xla_t)):
+        for name, x, y in zip(parts[1:], fn(args, t, d_parts), exact):
+            record(f"kda_chunk_kimilinear_{form}_vs_float32_{name}", rel(x, y), 5e-2)
+
+    forward = jax.jit(functools.partial(kd.kda, interpret=False))
+    xla_both = both(lambda *a: kd._prepare(*a)[0])
     times = {"kernels_fwd_bwd": laps(forms["kernels"], args, ct),
-             "kernels_fwd": laps(jax.jit(functools.partial(kd.kda, interpret=False)), *args),
+             "kernels_fwd": laps(forward, *args),
              "schedule_fwd_bwd": laps(forms["schedule"], args, ct),
-             "chunk_local_fwd_bwd": laps(prepare, args, d_parts)}
+             "schedule_fwd": laps(jax.jit(kd.kda_chunked), *args),
+             "chunk_local_xla_fwd": laps(xla, *args),
+             "chunk_local_xla_fwd_bwd": laps(xla_both, args, d_parts),
+             "chunk_local_xla_transpose": laps(xla_t, args, t, d_parts),
+             "chunk_local_kernel_fwd": laps(local_fwd, args),
+             "chunk_local_kernel_again": laps(local_again, args, t),
+             "chunk_local_kernel_bwd": laps(local_bwd, args, t, d_parts)}
+    times["chunk_local_kernels_fwd_bwd"] = sum(
+        times[f"chunk_local_kernel_{k}"] for k in ("fwd", "again", "bwd"))
     print(json.dumps({"check": "kda_ms_a_call", "shape": [1, 4096, 32, 128, 128], **times,
                       "chunk_steps_fwd_and_bwd": kd.chunks_walked(1, 4096, 32)}), flush=True)
 

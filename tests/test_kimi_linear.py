@@ -91,6 +91,50 @@ def op_runs(request):
     return {name: _both(fn, args, weight) for name, fn in forms.items()}
 
 
+LOCAL_PARTS = ("u", "w", "qe", "kd", "m", "gamma", "T")
+
+
+@pytest.fixture(scope="module", params=["mild", "to_minus_20", "mixed"])
+def local_runs(request):
+    """The chunk-local part both ways: XLA's `_prepare` and its autodiff
+    transpose, and the kernels (interpret mode) handed the same inputs,
+    ``T`` and cotangents."""
+    args = _inputs(7, request.param)
+    want, t = kda_op._prepare(*args)
+    got, t_got = kda_op._pallas_local_fwd(*args, True)
+    d_parts = tuple(jax.random.normal(jax.random.PRNGKey(20 + i), a.shape)
+                    for i, a in enumerate(want))
+    transpose = jax.vjp(lambda *a: kda_op._prepare(*a, t=t)[0], *args)[1]
+    return {"decays": request.param,
+            "fwd": dict(zip(LOCAL_PARTS, (*got, t_got))),
+            "again": dict(zip(LOCAL_PARTS, kda_op._pallas_local_again(*args, t_got, True))),
+            "xla": dict(zip(LOCAL_PARTS, (*want, t))),
+            "bwd": dict(zip(PARTS[1:], kda_op._pallas_local_bwd(*args, t, d_parts, True))),
+            "xla_bwd": dict(zip(PARTS[1:], transpose(d_parts)))}
+
+
+class TestTheChunkLocalKernelsAgainstPrepare:
+    @pytest.mark.parametrize("part", LOCAL_PARTS)
+    def test_the_six_parts_and_the_solve(self, local_runs, part):
+        got, want = local_runs["fwd"][part], local_runs["xla"][part]
+        assert got.shape == want.shape and got.dtype == want.dtype
+        assert bool(jnp.isfinite(got).all())
+        # (every gamma is 0 at the strongest decays: no quotient of two zeros)
+        assert float(jnp.linalg.norm(got - want)) <= 2e-6 * float(jnp.linalg.norm(want)), part
+
+    @pytest.mark.parametrize("part", LOCAL_PARTS[:-1])
+    def test_handed_the_solve_it_makes_the_same_parts(self, local_runs, part):
+        np.testing.assert_array_equal(np.asarray(local_runs["again"][part]),
+                                      np.asarray(local_runs["fwd"][part]))
+
+    @pytest.mark.parametrize("part", PARTS[1:])
+    def test_the_transpose(self, local_runs, part):
+        got, want = local_runs["bwd"][part], local_runs["xla_bwd"][part]
+        assert got.shape == want.shape and got.dtype == want.dtype
+        assert bool(jnp.isfinite(got).all())
+        assert float(jnp.linalg.norm(got - want)) < 5e-6 * float(jnp.linalg.norm(want)), part
+
+
 class TestTheOpAgainstTheRecurrence:
     @pytest.mark.parametrize("form", ["schedule", "kernels"])
     @pytest.mark.parametrize("part", PARTS)
@@ -106,6 +150,22 @@ class TestTheOpAgainstTheRecurrence:
         assert float(_inputs(7, "to_minus_20")[3].min()) < -19.0
         g = _inputs(7, "mixed")[3]
         assert float(g[..., 0].min()) < -19.0 and float(g[..., 3].max()) > -0.002
+
+    @pytest.mark.parametrize("decays", ["to_minus_20", "mixed"])
+    def test_the_strongest_decays_stay_finite_inside_the_kernels_in_bfloat16(self, decays):
+        """What the chip runs: bfloat16 rows, float32 ``g``; parts, ``T`` and
+        the five cotangents finite and beside XLA's to bfloat16's rounding."""
+        args = _inputs(7, decays, h=1)
+        args = tuple(a.astype(jnp.bfloat16) for a in args[:3]) + args[3:]
+        want, t = kda_op._prepare(*args)
+        got, t_got = kda_op._pallas_local_fwd(*args, True)
+        d_parts = jax.tree.map(jnp.ones_like, want)
+        grads = kda_op._pallas_local_bwd(*args, t, d_parts, True)
+        xla = jax.vjp(lambda *a: kda_op._prepare(*a, t=t)[0], *args)[1](d_parts)
+        f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+        for a, b in zip((*got, t_got, *grads), (*want, t, *xla)):
+            assert a.dtype == b.dtype and bool(jnp.isfinite(f32(a)).all())
+            assert float(jnp.linalg.norm(f32(a) - f32(b))) <= 2e-2 * float(jnp.linalg.norm(f32(b)))
 
     @pytest.mark.parametrize("length", [200, 128, 384, 37])
     def test_a_ragged_row_is_padded_behind(self, length):
@@ -133,13 +193,22 @@ class TestTheOpAgainstTheRecurrence:
         with pytest.raises(ValueError, match="are not"):
             kda_op.kda(*args[:3], args[3][..., 0], args[4])
 
-    @pytest.mark.parametrize("name, which", [("tpuframe_kda_fwd", 0), ("tpuframe_kda_bwd", 1)])
+    @pytest.mark.parametrize("name, which", [
+        ("tpuframe_kda_fwd", 0), ("tpuframe_kda_bwd", 1), ("tpuframe_kdachunk_fwd", 2),
+        ("tpuframe_kdachunk_again", 3), ("tpuframe_kdachunk_bwd", 4)])
     def test_tpu_lowering_carries_the_stable_kernel_name(self, name, which):
         args = _inputs(1, "mild", l=256, h=1)
-        parts, _ = kda_op._prepare(*args)
+        parts, t = kda_op._prepare(*args)
         if which == 0:
             text = jax.jit(lambda p: kda_op._pallas_fwd(p, False)).trace(parts).lower(
                 lowering_platforms=("tpu",)).as_text()
+        elif which >= 2:
+            fn = (lambda *a: kda_op._pallas_local_fwd(*a, False),
+                  lambda *a: kda_op._pallas_local_again(*a, t, False),
+                  lambda *a: kda_op._pallas_local_bwd(*a, t, parts, False))[which - 2]
+            text = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+            # the pass's readers sum the names that start with tpuframe_kda_
+            assert "tpuframe_kda_" not in text
         else:
             states = jnp.zeros((1, 1, 2, 128, 128), jnp.float32)
             text = jax.jit(lambda p, s, d: kda_op._pallas_bwd(p, s, d, False)).trace(
@@ -167,17 +236,25 @@ class TestTheOpAgainstTheRecurrence:
 
 
 class TestOneDecayAHeadIsTheGatedDeltaRule:
+    @pytest.fixture(scope="class")
+    def one_decay(self):
+        q, k, v, g, beta = _inputs(4, "to_minus_20")
+        args = (q, k, v, g[..., 0], beta)
+        weight = jax.random.normal(jax.random.PRNGKey(12), v.shape)
+        forms = {"schedule": kda_op.kda_chunked,
+                 "kernels": lambda *a: kda_op.kda(*a, interpret=True)}
+        got = {form: _both(lambda q, k, v, g1, beta, fn=fn: fn(
+            q, k, v, jnp.broadcast_to(g1[..., None], q.shape), beta), args, weight)
+            for form, fn in forms.items()}
+        return got, _both(gated_delta_chunked, args, weight)
+
+    @pytest.mark.parametrize("form", ["schedule", "kernels"])
     @pytest.mark.parametrize("part", PARTS)
-    def test_equal_to_gated_delta_to_rounding(self, part):
+    def test_equal_to_gated_delta_to_rounding(self, one_decay, part, form):
         """With ``g`` the same in every channel the rule is the one the
         benchmark holds (`ops.gated_delta`): outputs and gradients, the
-        decay's summed over the channels."""
-        q, k, v, g, beta = _inputs(4, "to_minus_20")
-        g1 = g[..., 0]
-        weight = jax.random.normal(jax.random.PRNGKey(12), v.shape)
-        got = _both(lambda q, k, v, g1, beta: kda_op.kda_chunked(
-            q, k, v, jnp.broadcast_to(g1[..., None], q.shape), beta), (q, k, v, g1, beta), weight)
-        want = _both(gated_delta_chunked, (q, k, v, g1, beta), weight)
+        decay's summed over the channels; the scan schedule and the kernels."""
+        got, want = one_decay[0][form], one_decay[1]
         if part == "out":
             assert abs(float(got[part] - want[part])) < 1e-3
             return

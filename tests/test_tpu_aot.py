@@ -334,10 +334,11 @@ def test_gated_delta_kernels_compile_for_v5e(one_chip, shape, dtype):
 def test_kda_kernels_compile_for_v5e(one_chip, shape, dtype):
     """The vector-decay delta rule's pass over the chunks: `ops.gated_delta`'s
     with the state transposed in VMEM, ``gamma`` a row of lanes a chunk; one
-    forward and one backward kernel.  The chunk-local part is XLA's here:
-    nothing the size of a (16, 16, dk) array a sub-block, and no
-    (chunks, C, C, dk) array, is kept (a GiB and 8 GiB a layer at the first
-    shape)."""
+    forward and one backward kernel.  And the chunk-local part: the kernel
+    that solves (its float32 products whole and its rolls down the sublanes,
+    through Mosaic), the entry that is handed ``T`` and the transpose, once
+    each, and no float32 (.., 128, 128) array of a chunk left to an XLA
+    product or fusion."""
     from tpuframe.ops.kda import kda
 
     b, l, h, dk, dv = shape
@@ -350,12 +351,14 @@ def test_kda_kernels_compile_for_v5e(one_chip, shape, dtype):
 
     compiled = jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(*args).compile()
     text = compiled.as_text()
-    for kernel in ("tpuframe_kda_fwd", "tpuframe_kda_bwd"):
+    for kernel in ("tpuframe_kda_fwd", "tpuframe_kda_bwd", "tpuframe_kdachunk_fwd",
+                   "tpuframe_kdachunk_again", "tpuframe_kdachunk_bwd"):
         assert len(_kernel_calls(text, kernel)) == 1, kernel
-    # what the schedule needs is a little over one such array's bytes at the
-    # first shape (1.14 GiB: the parts twice, the operands scaled a sub-block and
-    # their cotangents); with one kept it would be over two
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 * b * l * h * 16 * dk * 4
+    assert not re.findall(r"= \(?f32\[[\d,]*128,128\]\S* (?:fusion|convolution|dot)\(", text)
+    # T, a state a chunk and the parts with their cotangents (0.39 GiB at the
+    # first shape): under a quarter of what XLA's chunk-local part needed
+    # for the operands scaled a sub-block (1.14 GiB)
+    assert compiled.memory_analysis().temp_size_in_bytes < b * l * h * 16 * dk * 4 / 2
 
 
 def test_qwen3_next_period_holds_its_kernels_once_a_kind(v5e_runtime):
