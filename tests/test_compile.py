@@ -236,6 +236,125 @@ class TestPersistentCache:
         assert compiles[0]["dur_s"] > 0
 
 
+# -- one span-log record a phase of every compile request ----------------------
+
+PHASES = ("compile/jax_trace", "compile/jax_lower", "compile/jax_backend")
+
+
+@pytest.fixture()
+def span_tele():
+    """A fresh process-wide telemetry (the listeners write to whichever
+    instance is current) with the compile listeners installed."""
+    from tpuframe.track import telemetry as tmod
+
+    cc.install_listeners()
+    tele = tmod.configure()
+    yield tele
+    tmod.reset()
+
+
+def _phase_records(tele, name, fun):
+    return [r for r in tele.span_log([name]) if fun in r.attrs["fun"]]
+
+
+class TestCompileRecords:
+    @pytest.mark.parametrize("phase", PHASES)
+    def test_a_jitted_call_leaves_one_record_a_phase_under_the_open_span(
+            self, span_tele, phase):
+        def record_me(x):
+            return x * 3 + 1
+
+        fn = jax.jit(record_me)
+        x = np.ones((8, 8), np.float32)
+        with span_tele.span("x", step=4) as outer:
+            fn(x).block_until_ready()
+        (rec,) = _phase_records(span_tele, phase, "record_me")
+        assert rec.parent_id == outer.id and rec.step == 4
+        assert rec.thread == outer.thread
+        assert outer.start_ns <= rec.start_ns <= rec.end_ns <= outer.end_ns
+        assert 0 < rec.attrs["self_s"] <= rec.elapsed + 1e-6
+        if phase == "compile/jax_backend":
+            assert rec.attrs["cache"] in ("hit", "miss", "uncached")
+            assert "label" in rec.attrs
+        # the same numbers apart, where compile/lower_s mixed two phases
+        hist = span_tele.registry.histogram(f"span/{phase}")
+        assert hist.count >= 1 and hist.total >= rec.elapsed
+        # a second call of the same shapes compiles nothing: no record
+        fn(x).block_until_ready()
+        assert len(_phase_records(span_tele, phase, "record_me")) == 1
+
+    def test_the_two_mixed_histograms_are_gone(self, span_tele):
+        jax.jit(lambda x: x - 2)(np.ones((4,), np.float32)).block_until_ready()
+        snap = span_tele.registry.snapshot()
+        assert not [k for k in snap if k.startswith(
+            ("compile/lower_s", "compile/backend_compile_s"))]
+        assert snap["span/compile/jax_backend_count"] >= 1
+
+    def test_a_nested_trace_is_its_own_record_only_from_a_millisecond_up(
+            self, span_tele):
+        """A step's trace holds the trace of every jitted function it
+        calls; ``self_s`` is a record's time less the records nested in
+        it, so the sum over records counts every moment once."""
+        @jax.jit
+        def slow_inner(x):
+            for _ in range(60):  # a trace of a few milliseconds
+                x = x * 1.5 + 0.5
+            return x
+
+        @jax.jit
+        def quick_inner(x):
+            return x + 1
+
+        def holds_both(x):
+            return slow_inner(x) + quick_inner(x)
+
+        jax.jit(holds_both)(np.ones((4,), np.float32)).block_until_ready()
+        (outer,) = _phase_records(span_tele, "compile/jax_trace", "holds_both")
+        (inner,) = _phase_records(span_tele, "compile/jax_trace", "slow_inner")
+        assert outer.start_ns <= inner.start_ns and inner.end_ns <= outer.end_ns
+        assert inner.elapsed >= cc._NESTED_TRACE_MIN_S
+        assert outer.attrs["self_s"] == pytest.approx(
+            outer.elapsed - inner.elapsed, abs=2e-4)
+        quick = _phase_records(span_tele, "compile/jax_trace", "quick_inner")
+        assert all(r.elapsed >= cc._NESTED_TRACE_MIN_S for r in quick)
+        # nothing is lowered or loaded under a name of its own inside a trace
+        assert len(_phase_records(span_tele, "compile/jax_lower", "holds_both")) == 1
+        assert not _phase_records(span_tele, "compile/jax_lower", "slow_inner")
+
+    def test_a_cache_hit_says_so_and_carries_its_retrieval(
+            self, cache_env, span_tele):
+        """A second process-like compile: the in-memory caches dropped,
+        the same program again, into the same persistent cache."""
+        def twice_compiled(x):
+            return x * 7 - 5
+
+        x = np.ones((8, 8), np.float32)
+        jax.jit(twice_compiled)(x).block_until_ready()
+        jax.clear_caches()
+        jax.jit(twice_compiled)(x).block_until_ready()
+        first, second = _phase_records(
+            span_tele, "compile/jax_backend", "twice_compiled")
+        assert first.attrs["cache"] == "miss" and "retrieval_s" not in first.attrs
+        assert second.attrs["cache"] == "hit"
+        assert 0 < second.attrs["retrieval_s"] <= second.elapsed + 1e-3
+
+    def test_a_record_on_a_bare_thread_has_no_parent_and_a_fun(self, span_tele):
+        import threading
+
+        def on_a_thread(x):
+            return x / 3
+
+        t = threading.Thread(
+            target=lambda: jax.jit(on_a_thread)(np.ones((4,), np.float32)),
+            name="bare-thread")
+        t.start()
+        t.join(timeout=60)
+        assert not t.is_alive()
+        recs = [r for r in span_tele.span_log() if r.thread == "bare-thread"]
+        assert {r.name for r in recs} == set(PHASES)
+        assert all(r.parent_id is None and r.attrs["fun"] for r in recs)
+
+
 # -- signatures + templates ---------------------------------------------------
 
 
@@ -407,6 +526,87 @@ class TestTrainerPrecompile:
                    for s in tr._precompile_report["steps"]}
             assert got == {"train": want, "eval": want}
 
+    @pytest.mark.parametrize("soft_labels", [False, True])
+    def test_the_precompile_span_says_whether_its_executable_was_used(
+            self, span_tele, soft_labels):
+        """The loader template assumes ``(N,)`` integer labels: a dataset
+        with labels of another rank never matches it, its precompile is
+        thrown away and the first step traces again, lazily."""
+        from tpuframe.data import DataLoader, SyntheticImageDataset
+        from tpuframe.models import MnistNet
+        from tpuframe.train import Trainer
+
+        ds = SyntheticImageDataset(n=64, image_size=28, channels=1,
+                                   num_classes=4, seed=0)
+        if soft_labels:
+            class Soft:
+                def __len__(self):
+                    return len(ds)
+
+                def __getitem__(self, i):
+                    img, y = ds[i]
+                    return img, np.eye(4, dtype=np.float32)[int(y)]
+
+            data = Soft()
+        else:
+            data = ds
+        tr = Trainer(
+            MnistNet(num_classes=4),
+            train_dataloader=DataLoader(data, batch_size=16, seed=3),
+            max_duration="3ba", eval_interval=0, log_interval=0,
+        )
+        tr.fit()
+        log = span_tele.span_log()
+        (pre,) = [r for r in log if r.name == "compile/precompile_step"]
+        assert pre.attrs["kind"] == "train" and pre.attrs["signature"]
+        assert pre.attrs["used"] is (not soft_labels)
+        assert pre.thread == "tpuframe-precompile" and pre.parent_id is None
+        # ... parent of the AOT spans that were there, themselves parents
+        # of jax's own phases
+        kids = {r.name: r for r in log if r.parent_id == pre.id}
+        assert set(kids) == {"compile/lower", "compile/backend_compile"}
+        assert [r for r in log if r.name == "compile/jax_lower"
+                and r.parent_id == kids["compile/lower"].id]
+        steps = sorted((r for r in log if r.name == "train/step"),
+                       key=lambda r: r.step)
+        assert steps[0].attrs["aot"] is (not soft_labels)
+        assert all("aot" not in r.attrs for r in steps[1:])
+        lazy = [r for r in log if r.name == "compile/jax_trace"
+                and r.parent_id == steps[0].id]
+        assert bool(lazy) is soft_labels
+        # the set-up's own phases: the initialiser, and fit() entry to the
+        # loop's first iteration
+        (init,) = [r for r in log if r.name == "setup/state_init"]
+        assert [r for r in log if r.name == "compile/jax_backend"
+                and r.parent_id == init.id and "init_fn" in r.attrs["fun"]]
+        (start,) = [r for r in log if r.name == "setup/fit_start"]
+        first_iter = min((r for r in log if r.name == "train/iter"),
+                         key=lambda r: r.start_ns)
+        assert init.end_ns <= start.end_ns <= first_iter.start_ns
+        assert start.start_ns <= init.start_ns  # init_state() ran inside fit()
+
+    def test_past_the_second_window_no_step_adds_a_setup_record(self, span_tele):
+        from tpuframe.data import DataLoader, SyntheticImageDataset
+        from tpuframe.models import MnistNet
+        from tpuframe.train import Trainer
+
+        ds = SyntheticImageDataset(n=16 * 18, image_size=28, channels=1,
+                                   num_classes=4, seed=0)
+        tr = Trainer(
+            MnistNet(num_classes=4),
+            train_dataloader=DataLoader(ds, batch_size=16, seed=3),
+            max_duration="18ba", eval_interval=0, log_interval=4,
+        )
+        tr.fit()
+        log = span_tele.span_log()
+        drains = sorted((r for r in log if r.name == "train/host_block"),
+                        key=lambda r: r.end_ns)
+        assert len(drains) >= 4 and tr.batches_seen == 18
+        late = [r for r in log if r.name.startswith(("compile/", "setup/"))
+                and r.end_ns > drains[1].end_ns]
+        assert late == [], [(r.name, r.attrs.get("fun")) for r in late]
+        assert [r for r in log if r.name.startswith("compile/jax_")]
+
     def test_opt_out_env(self, monkeypatch):
         monkeypatch.setenv("TPUFRAME_PRECOMPILE", "0")
         tr, _ = self._fit(None)
@@ -524,6 +724,46 @@ class TestAnalyzerCompile:
         text = A.format_report(rep)
         assert "measured compile wall 1.300s" in text
         assert "time to first step: 3.000s" in text
+
+    def test_jax_phase_records_are_the_wall_by_phase_and_by_fun(self, tmp_path):
+        """Where a log holds jax's own phases they are the compile wall
+        (the AOT spans and the loud event lie inside them): ``self_s``
+        summed, a nested trace counted once, hits apart from compiles."""
+        from tpuframe.track import analyze as A
+
+        def phase(name, t, dur, fun, **attrs):
+            return {"ts": t, "mono": t, "kind": "span", "ok": True,
+                    "name": f"compile/jax_{name}", "dur_s": dur,
+                    "attrs": {"fun": fun, "self_s": dur, **attrs}}
+
+        d = _mklog(tmp_path, [
+            {"ts": 100.0, "mono": 100.0, "kind": "event", "name": "fit/start"},
+            phase("trace", 100.4, 0.3, "layer_norm"),
+            phase("trace", 101.0, 1.0, "step", self_s=0.7),
+            {"ts": 101.5, "mono": 101.5, "kind": "span", "ok": True,
+             "name": "compile/lower", "dur_s": 1.5},
+            phase("lower", 101.5, 0.5, "jit(step)"),
+            phase("backend", 102.5, 1.0, "jit(step)", cache="hit",
+                  retrieval_s=0.8),
+            phase("backend", 102.9, 0.4, "jit(add)", cache="miss"),
+            {"ts": 102.9, "mono": 102.9, "kind": "event",
+             "name": "compile/backend_compile", "dur_s": 0.4},
+            {"ts": 103.0, "mono": 103.0, "kind": "span", "name": "train/step",
+             "dur_s": 0.1, "ok": True, "attrs": {"batch": 0}},
+        ])
+        rep = A.skew_report(A.load_dir(d))
+        comp = rep["compile"]
+        assert comp["records"] == 5
+        assert comp["wall_s"] == pytest.approx(0.3 + 0.7 + 0.5 + 1.0 + 0.4)
+        assert comp["by_phase"]["trace"] == {"s": 1.0, "records": 2}
+        assert comp["by_phase"]["backend"] == {
+            "s": 1.4, "records": 2, "hit": 1, "miss": 1, "retrieval_s": 0.8}
+        assert [(r["fun"], r["s"]) for r in comp["by_fun"][:2]] == [
+            ("jit(step)", 1.5), ("step", 0.7)]
+        text = A.format_report(rep)
+        assert "compile backend: 1.400s in 2 record(s): 1 cache hit(s), " \
+               "retrieval 0.800s; 1 compiled" in text
+        assert "compile by fun: jit(step) 1.500s (2), step 0.700s (1)" in text
 
     def test_ttfs_baseline_regression_gates_exit_3(self, tmp_path, capsys):
         from tpuframe.track import analyze as A

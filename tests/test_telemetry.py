@@ -181,6 +181,31 @@ class TestSpanLog:
         assert "child" not in events  # emit=False: in the log, not an event
         assert events["own"]["step"] == 9 and "step" not in events["orphan"]
 
+    def test_record_span_logs_a_region_that_has_already_ended(self, tmp_path):
+        """How a listener's callback becomes a record: parent and step from
+        the span its thread has open, the histogram, one JSONL line."""
+        tele = T.configure(jsonl_dir=str(tmp_path), rank=0)
+        t0 = time.perf_counter_ns()
+        with tele.span("parent", step=5) as parent:
+            rec = tele.record_span("late/region", t0, t0 + 2_000_000, fun="f")
+        bare = tele.record_span("late/region", t0, t0 + 1_000_000, emit=False)
+        assert (rec.parent_id, rec.step) == (parent.id, 5)
+        assert rec.id > parent.id and rec.stack == ["parent", "late/region"]
+        assert rec.thread == threading.current_thread().name
+        assert (rec.start_ns, rec.end_ns, rec.elapsed) == (t0, t0 + 2_000_000, 0.002)
+        assert (bare.parent_id, bare.step, bare.stack) == (None, None, ["late/region"])
+        # in the log in the order they were put there; the open span's stack
+        # is untouched by a record that was never on it
+        assert [r.id for r in tele.span_log()] == [rec.id, parent.id, bare.id]
+        assert tele.active_spans() == {}
+        hist = tele.registry.histogram("span/late/region")
+        assert hist.count == 2 and hist.total == pytest.approx(0.003)
+        lines = [json.loads(line) for line in
+                 (tmp_path / "events-rank0.jsonl").read_text().splitlines()]
+        (line,) = [r for r in lines if r["name"] == "late/region"]  # emit=False: none
+        assert line["kind"] == "span" and line["dur_s"] == 0.002 and line["step"] == 5
+        assert line["attrs"] == {"fun": "f"} and line["stack"] == rec.stack
+
     def test_annotation_hook_sees_every_span_with_its_step(self, monkeypatch):
         opened = []
 

@@ -8,8 +8,10 @@ on the hot path.  Two modules:
 
 - ``compile.cache``      — jax's persistent compilation cache behind the
   ``TPUFRAME_COMPILE_CACHE`` knob, size-capped keep-K eviction, and
-  monitoring listeners that surface every compile (hits, misses, real
-  backend compiles) in tpuframe telemetry.
+  monitoring listeners that surface every compile in tpuframe telemetry:
+  hits, misses and real backend compiles as counters, and one span-log
+  record a phase of every compile request with its program's name
+  (``compile/jax_trace``, ``compile/jax_lower``, ``compile/jax_backend``).
 - ``compile.precompile`` — batch-signature derivation from the loader
   spec, AOT ``lower().compile()`` of the train/eval steps (the Trainer
   overlaps it with loader spin-up in a background thread), and the

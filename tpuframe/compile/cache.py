@@ -23,11 +23,13 @@ that wiring, shaped like the rest of the observability stack:
 - The **listeners** map jax's ``/jax/compilation_cache/*`` and
   ``/jax/core/compile/*`` monitoring events into the metrics registry
   (``compile/cache_hits``, ``compile/cache_misses``,
-  ``compile/backend_compiles`` counters; ``compile/backend_compile_s``,
-  ``compile/lower_s`` histograms) and emit one loud
-  ``compile/backend_compile`` JSONL event per *real* backend compile —
-  a persistent-cache hit is a retrieval, not a compile, and is counted
-  but not shouted.
+  ``compile/backend_compiles`` counters), put one record a phase of
+  every compile request into the span log with the program's name
+  (``compile/jax_trace``, ``compile/jax_lower``, ``compile/jax_backend``:
+  :func:`_on_duration`), under whatever span the compiling thread has
+  open, and emit one loud ``compile/backend_compile`` JSONL event per
+  *real* backend compile — a persistent-cache hit is a retrieval, not a
+  compile, and is counted but not shouted.
 
 Env knobs (``COMPILE_ENV_VARS`` — shipped to every remote worker by
 ``launch.remote`` and printed by the doctor, exactly like
@@ -60,6 +62,7 @@ import contextlib
 import logging
 import os
 import threading
+import time
 from typing import Any, Iterator
 
 from tpuframe.track.telemetry import get_telemetry
@@ -288,14 +291,70 @@ def _on_event(name: str, **kw: Any) -> None:
         pass
 
 
+#: jax's duration events that become span-log records, one a phase of a
+#: compile request: tracing the Python into a jaxpr, lowering the jaxpr to
+#: an MLIR module (Mosaic kernels are lowered here), and the backend's
+#: part (a persistent-cache read or a real compile)
+_PHASE_RECORDS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile/jax_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile/jax_lower",
+    "/jax/core/compile/backend_compile_duration": "compile/jax_backend",
+}
+
+#: a trace nested in another phase (a step's trace holds the trace of
+#: every jitted function it calls, ``jnp.add`` among them: thousands a
+#: model) becomes a record of its own from this many seconds up; a shorter
+#: one stays in the time of the phase that holds it.  A compile request's
+#: own phases, and every lowering and backend phase, are always records.
+_NESTED_TRACE_MIN_S = 1e-3
+
+
+def _on_begin(name: str, value: float, **kw: Any) -> None:
+    # jax announces a phase at its start as a scalar (its start time) under
+    # the name its duration arrives with: one entry a phase this thread
+    # has open, holding the seconds of the records nested in it
+    if name in _PHASE_RECORDS:
+        _TLS.__dict__.setdefault("open", []).append(0.0)
+
+
 def _on_duration(name: str, dur: float, **kw: Any) -> None:
     try:
+        if name == "/jax/compilation_cache/cache_retrieval_time_sec":
+            # fires inside a request the cache answered, before that
+            # request's backend duration on the same thread: kept as the
+            # verdict is, for that record
+            _TLS.retrieval_s = dur
+            return
+        record = _PHASE_RECORDS.get(name)
+        if record is None:
+            return
+        # jax's own span is on time.time(); the span log's clock is
+        # perf_counter_ns, so the callback's moment is the end
+        end_ns = time.perf_counter_ns()
+        open_ = getattr(_TLS, "open", None)
+        inner_s = open_.pop() if open_ else 0.0
+        if open_:  # nested: the phase that holds this one is still open
+            if record == "compile/jax_trace" and dur < _NESTED_TRACE_MIN_S:
+                return
+            open_[-1] += dur
+        attrs: dict[str, Any] = {
+            "fun": str(kw.get("fun_name") or "?"),
+            # less the records nested in it, so that sums over records
+            # count every moment of a thread once
+            "self_s": round(max(0.0, dur - inner_s), 6),
+        }
         tele = get_telemetry()
-        if name == "/jax/core/compile/backend_compile_duration":
-            tele.registry.histogram("compile/backend_compile_s").observe(dur)
+        if record == "compile/jax_backend":
             verdict = getattr(_TLS, "verdict", None)
-            _TLS.verdict = None
+            retrieval_s = getattr(_TLS, "retrieval_s", None)
+            _TLS.verdict = _TLS.retrieval_s = None
             _TLS.last_compile = verdict or "uncached"
+            attrs["cache"] = _TLS.last_compile
+            attrs["label"] = getattr(_TLS, "label", None)
+            if verdict == "hit" and retrieval_s is not None:
+                # the read, deserialize and load; the rest of the record
+                # is the hashing of the key
+                attrs["retrieval_s"] = round(float(retrieval_s), 6)
             # a persistent-cache hit is a retrieval, not a compile; a
             # miss — or a compile that never consulted the cache — is
             # the real thing, counted and (unless an explicit compile
@@ -306,17 +365,13 @@ def _on_duration(name: str, dur: float, **kw: Any) -> None:
                     tele.event(
                         "compile/backend_compile",
                         dur_s=round(float(dur), 6),
-                        label=getattr(_TLS, "label", None),
+                        label=attrs["label"],
                         persistent_cache=(
                             verdict if _STATE["dir"] else "disabled"
                         ),
                     )
-        elif name in (
-            "/jax/core/compile/jaxpr_trace_duration",
-            "/jax/core/compile/jaxpr_to_mlir_module_duration",
-        ):
-            tele.registry.histogram("compile/lower_s").observe(dur)
-    except Exception:
+        tele.record_span(record, end_ns - int(dur * 1e9), end_ns, **attrs)
+    except Exception:  # a metrics hiccup must never break a compile
         pass
 
 
@@ -330,6 +385,7 @@ def install_listeners() -> None:
         from jax._src import monitoring
 
         monitoring.register_event_listener(_on_event)
+        monitoring.register_scalar_listener(_on_begin)
         monitoring.register_event_duration_secs_listener(_on_duration)
         _STATE["listeners"] = True
     except Exception as e:

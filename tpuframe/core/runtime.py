@@ -244,6 +244,9 @@ def initialize(
     # work so even the first compile of this process is cacheable.
     from tpuframe.compile import cache as _compile_cache
 
+    # the compile records (span log) need no cache: a process that opted
+    # out of it still sees every trace, lowering and compile by name
+    _compile_cache.install_listeners()
     _compile_cache.enable_from_env()
 
     coordinator_address = coordinator_address or _env_coordinator()

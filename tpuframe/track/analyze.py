@@ -88,8 +88,14 @@ _CKPT_SPANS = ("ckpt/save", "ckpt/restore", "fault/preempt_checkpoint")
 #: records that carry compile wall: AOT spans from the precompiler plus
 #: the cache listener's per-real-compile events (the listener suppresses
 #: its event inside an explicit compile span, so summing both never
-#: double-counts one compile)
-_COMPILE_RECORDS = ("compile/lower", "compile/backend_compile")
+#: double-counts one compile), and jax's own phases, one record a trace,
+#: a lowering and a backend part (cache load or compile) of every compile
+#: request of the process, AOT or lazy, each with its program's name
+#: (``fun``) and its seconds less those of the records nested in it
+#: (``self_s``): where a log holds them they ARE the wall, by phase and
+#: by ``fun``, and the first two kinds lie inside them
+_JAX_PHASES = ("compile/jax_trace", "compile/jax_lower", "compile/jax_backend")
+_COMPILE_RECORDS = ("compile/lower", "compile/backend_compile", *_JAX_PHASES)
 
 
 # -- loading + clock alignment ------------------------------------------------
@@ -449,20 +455,60 @@ def _classify(entry: dict, ckpt_wins: list[tuple[float, float]]) -> str:
 
 
 def _compile_wall(rl: RankLog) -> dict:
-    """Measured compile wall in this rank's log: ``compile/lower`` +
-    ``compile/backend_compile`` spans (the AOT path) and
-    ``compile/backend_compile`` events (implicit runtime compiles, each
-    a real backend compile — persistent-cache hits emit none)."""
+    """Measured compile wall in this rank's log.  Where the log holds
+    jax's own phases (``compile/jax_trace`` / ``_lower`` / ``_backend``),
+    their ``self_s`` summed, with the split by phase and by ``fun``:
+    every trace, lowering, cache load and compile of the process.  In a
+    log without them: ``compile/lower`` + ``compile/backend_compile``
+    spans (the AOT path) and ``compile/backend_compile`` events (implicit
+    runtime compiles, each a real backend compile — persistent-cache hits
+    emit none)."""
     wall, n = 0.0, 0
+    phases: dict[str, dict] = {}
+    funs: dict[str, dict] = {}
     for rec in rl.events:
-        if rec.get("name") not in _COMPILE_RECORDS:
+        name = rec.get("name")
+        if name not in _COMPILE_RECORDS:
             continue
+        attrs = rec.get("attrs") if isinstance(rec.get("attrs"), dict) else {}
         try:
-            wall += float(rec.get("dur_s", 0.0))
+            dur = float(rec.get("dur_s", 0.0))
+            own = float(attrs.get("self_s", dur))
+            retrieval = float(attrs.get("retrieval_s", 0.0))
         except (TypeError, ValueError):
             continue
-        n += 1
-    return {"wall_s": round(wall, 6), "records": n}
+        if name not in _JAX_PHASES:
+            wall += dur
+            n += 1
+            continue
+        phase = phases.setdefault(
+            name.rpartition("_")[2], {"s": 0.0, "records": 0})
+        phase["s"] += own
+        phase["records"] += 1
+        if name == "compile/jax_backend":
+            cache = str(attrs.get("cache", "uncached"))
+            phase[cache] = phase.get(cache, 0) + 1
+            phase["retrieval_s"] = phase.get("retrieval_s", 0.0) + retrieval
+        fun = funs.setdefault(str(attrs.get("fun", "?")), {"s": 0.0, "records": 0})
+        fun["s"] += own
+        fun["records"] += 1
+    if phases:
+        wall = sum(p["s"] for p in phases.values())
+        n = sum(p["records"] for p in phases.values())
+    return {"wall_s": round(wall, 6), "records": n,
+            "by_phase": phases, "by_fun": funs}
+
+
+def _merge_compile_split(per_rank: Sequence[dict], key: str) -> dict:
+    """Sum the ranks' ``by_phase`` / ``by_fun`` tables field by field."""
+    out: dict[str, dict] = {}
+    for c in per_rank:
+        for name, row in c[key].items():
+            into = out.setdefault(name, {})
+            for k, v in row.items():
+                into[k] = into.get(k, 0) + v
+    return {name: {k: round(v, 6) if isinstance(v, float) else v
+                   for k, v in row.items()} for name, row in out.items()}
 
 
 def _health_info(rl: RankLog) -> dict:
@@ -982,6 +1028,16 @@ def skew_report(ranks: Sequence[RankLog], *,
         "records": sum(c["records"] for c in per_rank_compile.values()),
         "per_rank": {r: c["wall_s"] for r, c in per_rank_compile.items()},
     }
+    splits = list(per_rank_compile.values())
+    by_fun = _merge_compile_split(splits, "by_fun")
+    if by_fun:
+        # the operator's "why did this job take three minutes to its first
+        # step": seconds by phase, and the ten programs that took the most
+        compile_info["by_phase"] = _merge_compile_split(splits, "by_phase")
+        compile_info["by_fun"] = [
+            {"fun": fun, **row} for fun, row in
+            sorted(by_fun.items(), key=lambda kv: -kv[1]["s"])[:10]
+        ]
     ttfs = {rl.rank: _time_to_first_step(rl) for rl in ranks}
     ttfs_vals = [t for t in ttfs.values() if t is not None]
     # training-health block: present only when the sentinel left a trail
@@ -1322,6 +1378,17 @@ def format_report(report: dict, diff: dict | None = None, *,
             f"  time to first step: {ttfs['s']:.3f}s (slowest rank; "
             f"fleet compile wall {comp.get('wall_s', 0.0):.3f}s)"
         )
+    for phase, row in (comp.get("by_phase") or {}).items():
+        note = ""
+        if phase == "backend":
+            note = (f": {row.get('hit', 0)} cache hit(s), retrieval "
+                    f"{row.get('retrieval_s', 0.0):.3f}s; "
+                    f"{row.get('miss', 0) + row.get('uncached', 0)} compiled")
+        lines.append(f"  compile {phase}: {row['s']:.3f}s in "
+                     f"{row['records']} record(s){note}")
+    if comp.get("by_fun"):
+        lines.append("  compile by fun: " + ", ".join(
+            f"{r['fun']} {r['s']:.3f}s ({r['records']})" for r in comp["by_fun"]))
     st = report.get("step_time") or {}
     if st:
         lines.append(

@@ -447,7 +447,11 @@ class Telemetry:
       rank: tag on every record; defaults to the launch env's rank.
       max_events: ring-buffer length (the watchdog dumps the tail of this).
       max_spans: span-log length (sized for a benchmark run of a few
-        hundred steps at ten spans a step, with room).
+        hundred steps at ten spans a step, with room: a traced run of a
+        benchmark cell leaves 2,490-3,008 records, 550-1,000 of them the
+        ``compile/jax_*`` records of its set-up and of the reference's
+        check, counted on the chip for PR 52; the set-up's, the oldest,
+        survive to the readers with four fifths of the log to spare).
       registry: share an existing :class:`MetricsRegistry` (default: new).
       watchdog: a ``track.watchdog.Watchdog`` to attach (wires both ways).
       span_histograms: auto-observe every span duration into
@@ -554,6 +558,30 @@ class Telemetry:
         ``cpu_ms`` on the JSONL line): work apart from wait.
         """
         return Span(self, name, attrs, step, emit, cpu)
+
+    def record_span(self, name: str, start_ns: int, end_ns: int, *,
+                    emit: bool = True, **attrs: Any) -> Span:
+        """Put a region that has already ended into the span log, as any
+        closed span is: next id; ``thread``, ``parent_id`` and ``step``
+        from the span the calling thread has open; the ``span/<name>``
+        histogram; the JSONL line where ``emit``.  ``start_ns``/``end_ns``
+        are on ``time.perf_counter_ns()``.  No profiler annotation: the
+        region is over.  This is how a listener's callback (a duration
+        reported at its end, ``compile/cache.py``) becomes a record."""
+        sp = Span(self, name, attrs, None, emit)
+        th = threading.current_thread()
+        sp.thread, sp._ident = th.name, th.ident
+        sp.start_ns, sp.end_ns = int(start_ns), int(end_ns)
+        sp.elapsed = (sp.end_ns - sp.start_ns) / 1e9
+        with self._lock:
+            sp.id = self._span_seq = self._span_seq + 1
+            stack = self._active.get(sp._ident)
+            if stack:
+                parent = stack[-1]
+                sp.parent_id, sp.step = parent.id, parent.step
+                sp.stack = parent.stack + [name]
+        self._close_span(sp)
+        return sp
 
     def _close_span(self, sp: Span) -> None:
         with self._lock:

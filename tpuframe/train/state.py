@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import optax
 
 from tpuframe.parallel.sharding import ParallelPlan
+from tpuframe.track.telemetry import get_telemetry
 
 
 def _any_host_resident(tree: Any) -> bool:
@@ -123,28 +124,32 @@ def create_train_state(
 
     step = jnp.zeros((), jnp.int32)
     health = init_health_state()
-    if plan is None:
-        params, batch_stats, opt_state = init_fn()
-    else:
-        a_params, a_stats, a_opt = jax.eval_shape(init_fn)
-        shardings = (
-            plan.param_shardings(a_params),
-            plan.param_shardings(a_stats),
-            # memory kinds are illegal in jit out_shardings; offload moves
-            # the state to pinned host right after init
-            plan.state_shardings(a_opt, a_params, with_offload=False),
-        )
-        params, batch_stats, opt_state = jax.jit(init_fn, out_shardings=shardings)()
-        offloaded = plan.state_shardings(a_opt, a_params)
-        if offloaded != shardings[2]:
-            opt_state = jax.device_put(opt_state, offloaded)
-        # Scalars must be *committed replicated* on the same mesh as the
-        # params: a checkpoint restore reproduces the template's placement,
-        # and a single-device committed step next to mesh-wide params is a
-        # jit device mismatch.
-        step = jax.device_put(step, plan.replicated())
-        state_rng = jax.device_put(state_rng, plan.replicated())
-        health = jax.device_put(health, plan.replicated())
+    # the jitted initialiser's trace, lowering, cache load or compile and
+    # its dispatch (its compile records land under this span)
+    with get_telemetry().span("setup/state_init"):
+        if plan is None:
+            params, batch_stats, opt_state = init_fn()
+        else:
+            a_params, a_stats, a_opt = jax.eval_shape(init_fn)
+            shardings = (
+                plan.param_shardings(a_params),
+                plan.param_shardings(a_stats),
+                # memory kinds are illegal in jit out_shardings; offload moves
+                # the state to pinned host right after init
+                plan.state_shardings(a_opt, a_params, with_offload=False),
+            )
+            params, batch_stats, opt_state = jax.jit(
+                init_fn, out_shardings=shardings)()
+            offloaded = plan.state_shardings(a_opt, a_params)
+            if offloaded != shardings[2]:
+                opt_state = jax.device_put(opt_state, offloaded)
+            # Scalars must be *committed replicated* on the same mesh as the
+            # params: a checkpoint restore reproduces the template's placement,
+            # and a single-device committed step next to mesh-wide params is a
+            # jit device mismatch.
+            step = jax.device_put(step, plan.replicated())
+            state_rng = jax.device_put(state_rng, plan.replicated())
+            health = jax.device_put(health, plan.replicated())
 
     return TrainState(
         step=step,

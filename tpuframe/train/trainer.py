@@ -384,6 +384,12 @@ class Trainer:
         self._shape_guard = ShapeGuard()
         self._precompile_thread: threading.Thread | None = None
         self._precompile_report: dict | None = None
+        # step kind -> its ``compile/precompile_step`` span, until that
+        # kind's first call says whether the executable was the one used
+        self._precompile_spans: dict[str, Any] = {}
+        # fit()'s entry on perf_counter_ns, until ``setup/fit_start`` is
+        # recorded where the loop's first iteration opens
+        self._fit_start_ns: int | None = None
 
         # live loop state
         self.state: TrainState | None = None
@@ -892,41 +898,49 @@ class Trainer:
             targets.append(("eval", self._eval_step, False))
         for kind, fn, train in targets:
             entry: dict[str, Any] = {"kind": kind}
-            try:
-                template = loader_batch_template(self, train=train)
-                if template is None:
-                    entry["skipped"] = "no derivable loader signature"
-                    report["steps"].append(entry)
-                    continue
-                sig = batch_signature(template)
-                entry["signature"] = format_signature(sig)
-                t1 = time.perf_counter()
-                compiled = precompile_step(
-                    fn, self.state, template,
-                    label=f"precompile/{kind}@{plan_sig}",
-                )
-                entry["wall_s"] = round(time.perf_counter() - t1, 6)
-                # hit = retrieved from the persistent cache, no backend
-                # compile ran (what a warm restart should report)
-                entry["persistent_cache"] = last_compile_verdict()
-                # arm the guard even when direct dispatch isn't possible
-                # (offload wrapper): the signature is still the contract,
-                # and the persistent cache is warm for the jit path
-                self._shape_guard.expect(kind, sig)
-                if compiled is not None:
-                    self._compiled[(kind, sig)] = compiled
-                entry["dispatchable"] = compiled is not None
-            except Exception as e:
-                # an OOM during AOT compile gets the forensics event
-                # (estimate vs compiled vs live + fit suggestion); the
-                # precompile itself still degrades to lazy-compile
-                _memory.maybe_oom_event(e, where="precompile")
-                entry["error"] = f"{type(e).__name__}: {e}"[:300]
-                tele.event(
-                    "compile/precompile_error", step_kind=kind,
-                    error=entry["error"],
-                )
             report["steps"].append(entry)
+            # one span a target, parent of the compile/lower and
+            # compile/backend_compile spans; ``used`` turns true where the
+            # kind's first real batch dispatches the executable kept here
+            # (never, where the signature does not match or no template
+            # could be built: the span's seconds were then for nothing)
+            with tele.span("compile/precompile_step", kind=kind,
+                           used=False) as sp:
+                self._precompile_spans[kind] = sp
+                try:
+                    template = loader_batch_template(self, train=train)
+                    if template is None:
+                        entry["skipped"] = "no derivable loader signature"
+                        continue
+                    sig = batch_signature(template)
+                    entry["signature"] = format_signature(sig)
+                    sp.attrs["signature"] = entry["signature"]
+                    t1 = time.perf_counter()
+                    compiled = precompile_step(
+                        fn, self.state, template,
+                        label=f"precompile/{kind}@{plan_sig}",
+                    )
+                    entry["wall_s"] = round(time.perf_counter() - t1, 6)
+                    # hit = retrieved from the persistent cache, no backend
+                    # compile ran (what a warm restart should report)
+                    entry["persistent_cache"] = last_compile_verdict()
+                    # arm the guard even when direct dispatch isn't possible
+                    # (offload wrapper): the signature is still the contract,
+                    # and the persistent cache is warm for the jit path
+                    self._shape_guard.expect(kind, sig)
+                    if compiled is not None:
+                        self._compiled[(kind, sig)] = compiled
+                    entry["dispatchable"] = compiled is not None
+                except Exception as e:
+                    # an OOM during AOT compile gets the forensics event
+                    # (estimate vs compiled vs live + fit suggestion); the
+                    # precompile itself still degrades to lazy-compile
+                    _memory.maybe_oom_event(e, where="precompile")
+                    entry["error"] = f"{type(e).__name__}: {e}"[:300]
+                    tele.event(
+                        "compile/precompile_error", step_kind=kind,
+                        error=entry["error"],
+                    )
         report["wall_s"] = round(time.perf_counter() - t0, 6)
         self._precompile_report = report
         tele.event("compile/precompile", **{
@@ -939,13 +953,16 @@ class Trainer:
             ),
         })
 
-    def _step_call(self, kind: str, fn, state, batch):
+    def _step_call(self, kind: str, fn, state, batch, span=None):
         """One step through the compile spine: join an in-flight
         precompile (first step = ``max(compile, loader warmup)``),
         dispatch straight to the AOT executable on a signature match,
         else fall back to the jitted fn with the shape guard shouting
         about unexpected signatures and the compile label attributing
-        whatever backend compile follows."""
+        whatever backend compile follows.  The kind's first call since
+        its precompile writes the verdict: ``used`` on the
+        ``compile/precompile_step`` span and ``aot`` on ``span`` (the
+        caller's ``train/step``)."""
         tele = get_telemetry()
         t = self._precompile_thread
         if t is not None and t.is_alive():
@@ -953,9 +970,17 @@ class Trainer:
                 t.join()
         sig = batch_signature(batch)
         compiled = self._compiled.get((kind, sig))
+        pre = (self._precompile_spans.pop(kind, None)
+               if self._precompile_spans else None)
+        if pre is not None:
+            verdict = span.attrs if span is not None else {}
+            verdict["aot"] = False  # until the kept executable runs
         if compiled is not None:
             try:
-                return compiled(state, batch)
+                out = compiled(state, batch)
+                if pre is not None:
+                    pre.attrs["used"] = verdict["aot"] = True
+                return out
             except Exception as e:
                 # sharding/layout drift: drop the executable, shout once,
                 # let the jit path (below) own the call
@@ -1151,6 +1176,7 @@ class Trainer:
         """Run to max_duration; returns the Ray-style FitResult."""
         from tpuframe.autotune.config import autotune_enabled
 
+        self._fit_start_ns = time.perf_counter_ns()
         if autotune_enabled():
             self.apply_persisted_tuning()
         result = FitResult()
@@ -1449,6 +1475,11 @@ class Trainer:
         # a window runs from the close of the drain before it (here: from
         # the epoch's start) to the close of its own
         machine0, window_t0 = machine_counters(), time.perf_counter_ns()
+        if self._fit_start_ns is not None:
+            # fit()'s entry to the loop's first iteration: resume lookup,
+            # the precompile thread's start, on_fit_start, loader spin-up
+            tele.record_span("setup/fit_start", self._fit_start_ns, window_t0)
+            self._fit_start_ns = None
         while True:
             # chaos site: a scheduled loader fault raises here, exactly
             # where a real worker-pool / shard-fetch failure surfaces
@@ -1496,7 +1527,8 @@ class Trainer:
                         if depth == 0:
                             empty_queue.inc()
                         self.state, metrics = self._step_call(
-                            "train", self._train_step, self.state, batch
+                            "train", self._train_step, self.state, batch,
+                            span=sp,
                         )
                 except Exception as e:
                     # OOM forensics: a RESOURCE_EXHAUSTED here (the chaos
